@@ -1,0 +1,135 @@
+"""Compiles for a DESCRIBED TPU v5e, kept as tests: the chip's own
+compiler (installed here without the chip) sees the grouped-sum kernel
+at its real shapes, the fused q1 stage program with the kernel in it,
+and the two programs of the materialized hash join — what it refuses
+fails here at no chip time. Nothing runs, so these say nothing about
+results or speed; ``chip_smoke.py`` is the run on the chip.
+
+This is the ONLY file that describes the chip. The topology is
+described inside a module-scoped fixture (never at import: every xdist
+worker imports every test file, and only one process may load the
+TPU's library), the persistent compile cache is off around the
+compiles (an entry written for a described chip cannot be read back
+without one), and there are no child processes.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+KERNEL_SHAPES = [(8, 1024), (24, 1 << 20), (24, 1 << 22)]
+Q1_CAPACITY = 1 << 22          # EngineConfig.max_batch_rows
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _struct(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _as_structs(tree, capacity, sharding):
+    """A pytree of real (tiny) arrays -> the same tree of shapes at
+    ``capacity`` rows placed on the described chip."""
+    return jax.tree.map(
+        lambda a: _struct((capacity,) if np.ndim(a) else (),
+                          jnp.asarray(a).dtype, sharding), tree)
+
+
+@pytest.mark.parametrize("k,cap", KERNEL_SHAPES)
+def test_grouped_sum_kernel_compiles(one_chip, no_persistent_cache,
+                                     k, cap):
+    from trino_tpu.ops import pallas_groupby as pg
+    compiled = pg._grouped_sums_impl.lower(
+        _struct((cap,), jnp.int32, one_chip),
+        _struct((k, cap), jnp.float32, one_chip), False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_q1_stage_program_compiles_with_kernel(one_chip,
+                                               no_persistent_cache,
+                                               monkeypatch):
+    """The fused q1 stage program (__graft_entry__._q1_step) at
+    max_batch_rows, with the kernel in it. Code that asks
+    jax.default_backend() sees the CPU during such a compile, so the
+    kernel selection is steered here."""
+    import __graft_entry__ as ge
+    from trino_tpu.ops import pallas_groupby as pg
+    monkeypatch.setattr(pg, "mode", lambda: "tpu")
+    ge.entry()                      # eager imports, outside the trace
+    args = _as_structs(ge._q1_inputs(8), Q1_CAPACITY, one_chip)
+    compiled = jax.jit(ge._q1_step).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # the program must fit the chip beside its own arguments
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
+        < 16 << 30
+
+
+def _q3_join_sides():
+    from trino_tpu import BIGINT, DOUBLE, batch_from_pylist
+    probe = batch_from_pylist({"l_orderkey": [1, 2], "rev": [1.0, 2.0]},
+                              {"l_orderkey": BIGINT, "rev": DOUBLE})
+    build = batch_from_pylist({"o_orderkey": [1, 2], "o_date": [3, 4]},
+                              {"o_orderkey": BIGINT, "o_date": BIGINT})
+    return probe, build
+
+
+def test_hash_join_expand_program_compiles(one_chip,
+                                           no_persistent_cache):
+    """Phase 2 of the materialized hash join at the shapes q3 runs at
+    sf1: 2^22 lineitem rows probing 2^20 order rows (64-bit cumsum,
+    searchsorted, gathers)."""
+    from trino_tpu.exec.executor import make_mjoin_expand_program
+    probe, build = _q3_join_sides()
+    pcap, bcap = 1 << 22, 1 << 20
+    fn = make_mjoin_expand_program("inner", None, 1 << 20)
+    lane = _struct((pcap,), jnp.int64, one_chip)
+    compiled = jax.jit(fn).lower(
+        _as_structs(probe, pcap, one_chip),
+        _as_structs(build, bcap, one_chip), lane, lane,
+        _struct((bcap,), jnp.int64, one_chip)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 30
+
+
+def test_hash_join_count_program_compiles(one_chip,
+                                          no_persistent_cache):
+    """Phase 1 (build-side sort on the 64-bit key lane + probe match
+    counts). The probe side is q3's at sf1; the build side is CUT to
+    2^12 rows: the sorting network's compile time grows with its size
+    (46 s at 2^20, PERF.md) and this file has to stay fast. What the
+    compiler accepts does not depend on the size."""
+    from trino_tpu.exec.executor import make_mjoin_count_program
+    probe, build = _q3_join_sides()
+    fn = make_mjoin_count_program(["l_orderkey"], ["o_orderkey"], False)
+    jax.jit(fn).lower(_as_structs(probe, 1 << 22, one_chip),
+                      _as_structs(build, 1 << 12, one_chip)).compile()

@@ -133,6 +133,29 @@ def test_topn():
     assert [r[1] for r in out.to_pylist()] == [80.0, 70.0]
 
 
+@pytest.mark.parametrize("n", [1, 7, 64, 65, 500])
+def test_topn_selection_equals_full_sort(n):
+    """Small LIMITs are selected, large ones sorted (ops/sort.py
+    TOPN_SELECT_MAX): both must give the rows of the stable full sort,
+    in its order — ties, NULLs, NaN, DESC and dead rows included."""
+    rng = np.random.default_rng(n)
+    rows = 300
+    v = rng.integers(0, 12, rows).astype(float)      # many ties
+    v[rng.integers(0, rows, 10)] = float("nan")
+    vs = [None if i % 17 == 0 else float(x) for i, x in enumerate(v)]
+    k = [int(x) for x in rng.integers(-3, 3, rows)]
+    s = [None if i % 29 == 0 else "abcdef"[x]
+         for i, x in enumerate(rng.integers(0, 6, rows))]
+    b = batch_from_pylist({"v": vs, "k": k, "s": s, "id": list(range(rows))},
+                          {"v": DOUBLE, "k": BIGINT, "s": VARCHAR,
+                           "id": BIGINT})
+    keys = [SortKey("v", ascending=False), SortKey("s"),
+            SortKey("k", ascending=False, nulls_first=True)]
+    want = [r[3] for r in sort_batch(b, keys).to_pylist()][:n]
+    got = [r[3] for r in topn_batch(b, keys, n).to_pylist()]
+    assert got == want
+
+
 def _join(probe, build, pk, bk, join_type="inner", prefix="b_"):
     start, count, order = match_counts(probe, build, pk, bk)
     total = int(jnp.maximum(count, 1).sum()) if join_type == "left" \

@@ -85,3 +85,36 @@ def test_sql_filtered_count_matches(monkeypatch):
         assert g[0] == w[0] and g[1] == w[1]
         assert g[2] == pytest.approx(w[2], rel=1e-9)
         assert g[3] == w[3] and g[4] == w[4]
+
+
+def test_tpu_backend_never_falls_back_silently(monkeypatch):
+    """On the tpu backend the kernel IS the path: mode() says "tpu"
+    without probing, and a kernel the chip's compiler refuses fails the
+    aggregation — it must never quietly select the XLA reductions (the
+    old probe returned "" here and every test still passed)."""
+    import jax
+    from trino_tpu import DOUBLE, VARCHAR, batch_from_pylist
+    from trino_tpu.ops import pallas_groupby as pg
+    from trino_tpu.ops.groupby import AggInput, group_aggregate
+
+    class MosaicRefused(Exception):
+        pass
+
+    def refuse(*_a, **_kw):
+        raise MosaicRefused("Mosaic failed to compile TPU kernel")
+
+    monkeypatch.delenv("TRINO_TPU_PALLAS", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(pg, "_grouped_sums_impl", refuse)
+    assert pg.mode() == "tpu"
+    # the explicit switches still win over the backend
+    monkeypatch.setenv("TRINO_TPU_PALLAS", "0")
+    assert pg.mode() == ""
+    monkeypatch.setenv("TRINO_TPU_PALLAS", "interpret")
+    assert pg.mode() == "interpret"
+    monkeypatch.delenv("TRINO_TPU_PALLAS")
+
+    b = batch_from_pylist({"k": ["a", "b", "a"], "v": [1.0, 2.0, 3.0]},
+                          {"k": VARCHAR, "v": DOUBLE})
+    with pytest.raises(MosaicRefused):
+        group_aggregate(b, ["k"], [AggInput("sum", "v", output="s")])

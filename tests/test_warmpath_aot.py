@@ -211,23 +211,38 @@ def test_repartition_aot_prewarms_bucket_kernel():
     assert _JIT_LOOKUPS.value(cache="repartition", result="hit") > h0
 
 
-def test_xla_cache_dir_env_pins_exact_directory(tmp_path):
-    """TRINO_TPU_XLA_CACHE_DIR (the bench's cross-round persistence
-    hook) pins jax's persistent compilation cache to the EXACT path —
-    no machine-tag suffix."""
-    target = str(tmp_path / "xla_rounds")
-    code = ("import jax, trino_tpu; "
+@pytest.mark.parametrize("placed_from_outside", [True, False])
+def test_xla_cache_placement(tmp_path, placed_from_outside):
+    """Compile cache placement (trino_tpu/config.py): where
+    JAX_COMPILATION_CACHE_DIR is set jax uses exactly that directory
+    and the engine sets none; unset, the cache lives at the one fixed
+    path inside the checkout, <repo>/.jax_cache/xla-<machine tag>."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    target = str(tmp_path / "xla_outside")
+    code = ("import jax, trino_tpu, jax.numpy as jnp; "
+            "jax.jit(lambda x: x * 2 + 1)(jnp.arange(64.0))"
+            ".block_until_ready(); "
             "print(jax.config.jax_compilation_cache_dir)")
     env = dict(os.environ)
-    env["TRINO_TPU_XLA_CACHE_DIR"] = target
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    env.pop("TRINO_TPU_XLA_CACHE", None)
+    env["TRINO_TPU_XLA_CACHE_MIN_SECS"] = "0"
+    env["JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"] = "-1"
     env["JAX_PLATFORMS"] = "cpu"
+    if placed_from_outside:
+        env["JAX_COMPILATION_CACHE_DIR"] = target
     p = subprocess.run([sys.executable, "-c", code],
                        capture_output=True, text=True, timeout=120,
-                       env=env, cwd=os.path.dirname(
-                           os.path.dirname(os.path.abspath(__file__))))
+                       env=env, cwd=repo)
     assert p.returncode == 0, p.stderr
-    assert p.stdout.strip().splitlines()[-1] == target
-    assert os.path.isdir(target)
+    got = p.stdout.strip().splitlines()[-1]
+    if placed_from_outside:
+        assert got == target
+        assert os.listdir(target), "nothing was cached where asked"
+    else:
+        assert os.path.dirname(got) == os.path.join(repo, ".jax_cache")
+        assert os.path.basename(got).startswith("xla-")
+        assert os.path.isdir(got)
 
 
 def test_streamed_join_with_string_probe_columns():

@@ -1,7 +1,8 @@
 """Global configuration for the TPU-native engine.
 
 The engine requires 64-bit types: SQL BIGINT is int64 and DOUBLE is float64
-(XLA emulates both on TPU; verified supported on v5e). This module must be
+(XLA rewrites both onto 32-bit words on TPU; what the v5e compiler
+accepts and refuses is in PERF.md). This module must be
 imported before any jax.numpy use, so every entry point imports trino_tpu
 first.
 
@@ -26,16 +27,25 @@ jax.config.update("jax_enable_x64", True)
 # sql/gen/ExpressionCompiler.java:56 keeps generated bytecode across
 # queries; we keep compiled XLA programs across PROCESSES). Fresh
 # processes re-running the same operator shapes skip recompilation:
-# measured 268s -> 8s for a 4-join TPC-DS query on a 1-core host.
-# Opt out with TRINO_TPU_XLA_CACHE=0 / relocate with =<dir>.
+# measured 268s -> 8s for a 4-join TPC-DS query on a 1-core CPU host.
 #
-# The directory is fingerprinted by the host CPU's feature set: XLA:CPU
-# persists AOT machine code, and an entry compiled on a host with a
-# different ISA extension set EXECUTES WRONG CODE when loaded (observed:
-# avx512-family mismatch loading a previous machine's cache produced
-# intermittent memory corruption — flaky page-checksum failures — with
-# cpu_aot_loader.cc warning "could lead to execution errors such as
-# SIGILL").
+# Placement: where JAX_COMPILATION_CACHE_DIR is set, jax reads it
+# itself and this module sets no directory. Unset, the cache lives at
+# ONE fixed path inside the checkout, <repo>/.jax_cache/xla-<tag>
+# (git-ignored; the path is part of jax's cache key, so a directory
+# that moves never hits). TRINO_TPU_XLA_CACHE=0 switches it off.
+#
+# The <tag> sub-directory fingerprints the host CPU's feature set:
+# XLA:CPU persists AOT machine code, and an entry compiled on a host
+# with a different ISA extension set EXECUTES WRONG CODE when loaded
+# (observed: avx512-family mismatch loading a previous machine's cache
+# produced intermittent memory corruption — flaky page-checksum
+# failures — with cpu_aot_loader.cc warning "could lead to execution
+# errors such as SIGILL"). A checkout directory copied between
+# machines carries its .jax_cache along, so the tag stays. A directory
+# named by JAX_COMPILATION_CACHE_DIR is used exactly as given.
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _machine_tag() -> str:
@@ -56,27 +66,17 @@ def _machine_tag() -> str:
     return hashlib.sha256(raw.encode()).hexdigest()[:12]
 
 
-# TRINO_TPU_XLA_CACHE_DIR pins the cache to an EXACT directory with
-# no machine-tag suffix — the cross-round persistence hook (bench.py
-# points every probe subprocess of every round at one stable path, so
-# round N+1 rides round N's compiles). The ISA-mismatch hazard above
-# is the caller's to manage with this override; same-machine reuse
-# (the bench case) is safe by construction.
-_cache_dir = os.environ.get("TRINO_TPU_XLA_CACHE_DIR", "") \
-    or os.environ.get("TRINO_TPU_XLA_CACHE", "")
-if _cache_dir != "0":
-    if not _cache_dir:
-        _cache_dir = os.path.join(
-            os.path.expanduser("~"), ".cache", "trino_tpu",
-            f"xla-{_machine_tag()}")
-    try:
-        os.makedirs(_cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", _cache_dir)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", float(
-                os.environ.get("TRINO_TPU_XLA_CACHE_MIN_SECS", "0.1")))
-    except (OSError, AttributeError):
-        pass  # unwritable home or an older jax: run uncached
+if os.environ.get("TRINO_TPU_XLA_CACHE", "") != "0":
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        from jax.experimental.compilation_cache import (
+            compilation_cache as _compilation_cache)
+        _compilation_cache.set_cache_dir(os.path.join(
+            REPO_ROOT, ".jax_cache", f"xla-{_machine_tag()}"))
+    jax.config.update(
+        "jax_persistent_cache_min_compile_time_secs", float(
+            os.environ.get("TRINO_TPU_XLA_CACHE_MIN_SECS", "0.1")))
+else:
+    jax.config.update("jax_enable_compilation_cache", False)
 
 
 def _env_int(name: str, default: int) -> int:

@@ -77,6 +77,21 @@ def _line_counts(order_idx: jax.Array) -> jax.Array:
     return _randint(_SEED["lineitem"] + 1, order_idx, 1, 7)
 
 
+# n / 100.0 for n in 0..10, divided on the HOST. The chip has no
+# 64-bit floats: XLA carries a float64 as a pair of float32 and divides
+# approximately, so a discount DIVIDED on the device need not be the
+# value the literal 0.05 becomes there, and q6's
+# ``l_discount between 0.05 and 0.07`` would drop a whole discount
+# class. Looked up from host doubles, a generated 0.05 and the literal
+# 0.05 are the same double going through the same conversion. On the
+# CPU both forms give the host generator's IEEE quotient.
+_HUNDREDTHS = np.arange(0, 11) / 100.0
+
+
+def _hundredths(n: jax.Array) -> jax.Array:
+    return jnp.take(jnp.asarray(_HUNDREDTHS), n)
+
+
 def _retailprice(partkey: jax.Array) -> jax.Array:
     pk = partkey.astype(jnp.int64)
     return (90000 + (pk // 10) % 20001 + 100 * (pk % 1000)) / 100.0
@@ -175,10 +190,10 @@ def lineitem_batch(lo: int, hi: int, sf: float,
                 DOUBLE, qty * _retailprice(partkey), None)
     if "l_discount" in need:
         out["l_discount"] = Column(
-            DOUBLE, _randint(S + 5, rid, 0, 10) / 100.0, None)
+            DOUBLE, _hundredths(_randint(S + 5, rid, 0, 10)), None)
     if "l_tax" in need:
         out["l_tax"] = Column(
-            DOUBLE, _randint(S + 6, rid, 0, 8) / 100.0, None)
+            DOUBLE, _hundredths(_randint(S + 6, rid, 0, 8)), None)
     if "l_shipdate" in need:
         out["l_shipdate"] = Column(DATE, shipdate.astype(jnp.int32),
                                    None)

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 
 from ..columnar import Batch, Column
 from .hashing import equality_lanes
+from .sort import stable_lexsort
 
 _U64MAX = jnp.uint64(0xFFFFFFFFFFFFFFFF)
 
@@ -413,7 +414,7 @@ def group_aggregate(batch: Batch, key_names: Sequence[str],
     live = batch.row_valid() if live is None else live
 
     lanes = _key_lanes(batch, key_names, live)
-    order = jnp.lexsort(lanes[::-1])
+    order = stable_lexsort(lanes)
     live_s = jnp.take(live, order)
 
     # key-change boundaries over the sorted live prefix

@@ -36,8 +36,11 @@ def float_equality_lanes(d: jax.Array):
     int64 lanes (mantissa*2^53, exponent).
 
     The natural encoding — bitcast f64->u64 — is NOT implemented by the
-    TPU backend's x64-emulation rewrite (verified on v5e), so we use
-    jnp.frexp instead, which lowers fine. Canonicalizes -0.0 == 0.0 and
+    TPU compiler's x64 rewrite, which is why this uses jnp.frexp. The
+    v5e compiler (jax 0.9.0, PR 23) refuses jnp.frexp on f64 as well —
+    it bitcasts f64->s64 inside — so FLOAT join/group keys do not
+    compile for the chip today (ROADMAP S5); integer, date and
+    dictionary keys never come here. Canonicalizes -0.0 == 0.0 and
     all NaNs equal (SQL distinct-from semantics, reference:
     spi/type/DoubleType.java#hash)."""
     d = jnp.asarray(d).astype(jnp.float64)

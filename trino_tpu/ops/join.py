@@ -32,6 +32,7 @@ import jax.numpy as jnp
 
 from ..columnar import Batch, Column
 from .hashing import combine_hashes, lane_to_u64, mix64
+from .sort import stable_lexsort
 
 _U64MAX = jnp.uint64(0xFFFFFFFFFFFFFFFF)
 
@@ -81,8 +82,17 @@ def build_side(batch: Batch, key_names: Sequence[str]):
     forced to U64MAX."""
     lane, usable = equality_lane(batch, key_names)
     cap = batch.capacity
-    primary = (~usable).astype(jnp.uint64)
-    order = jnp.lexsort((lane, primary))
+    if all(batch.column(k).valid is None for k in key_names):
+        # no NULL keys: the usable rows are exactly the live prefix, so
+        # they already precede every dead row in input order. ONE
+        # stable sort on the lane alone (dead rows forced to the
+        # maximum) then leaves the usable rows sorted in [0, m) — ties
+        # at the maximum keep input order, usable first. The second
+        # sort key below doubles this program's compile time on the
+        # chip (v5e compiler, 2^20 rows: 88 s against 46 s).
+        order = stable_lexsort([jnp.where(usable, lane, _U64MAX)])
+    else:
+        order = stable_lexsort([(~usable).astype(jnp.int32), lane])
     m = jnp.sum(usable.astype(jnp.int64))
     pos = jnp.arange(cap, dtype=jnp.int64)
     sorted_lane = jnp.where(pos < m, jnp.take(lane, order), _U64MAX)

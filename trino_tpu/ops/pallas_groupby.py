@@ -39,51 +39,30 @@ BLOCK = 512
 G_PAD = 128          # one-hot width: MXU-friendly and >= FAST_DOMAIN+1
 
 
-_TPU_OK: list = []          # memoized probe result
-
-
-def _tpu_kernel_works() -> bool:
-    """One-time probe: some TPU attachments (e.g. remote-compile
-    tunnels) cannot lower Mosaic kernels even though the backend
-    reports 'tpu'; compile a trivial kernel once and fall back to the
-    XLA path if that fails."""
-    if not _TPU_OK:
-        try:
-            gid = jnp.zeros((1024,), jnp.int32)
-            vals = jnp.ones((8, 1024), jnp.float32)
-            out = _grouped_sums_impl(gid, vals, False)
-            _TPU_OK.append(bool(out[0, 0] == 1024.0))
-        except Exception:
-            _TPU_OK.append(False)
-    return _TPU_OK[0]
-
-
 def mode() -> str:
     """'tpu' (real kernel), 'interpret' (forced, for CPU tests), or
-    '' (disabled)."""
+    '' (disabled). On the tpu backend the kernel is always used: a
+    Mosaic compile error propagates to the query, it never selects
+    the XLA path silently."""
     env = os.environ.get("TRINO_TPU_PALLAS", "auto")
-    if env == "0":
-        return ""
     if env == "interpret":
         return "interpret"
-    if env in ("auto", "1"):
-        try:
-            if jax.default_backend() != "tpu":
-                return ""
-            return "tpu" if _tpu_kernel_works() else ""
-        except Exception:
-            return ""
+    if env in ("auto", "1") and jax.default_backend() == "tpu":
+        return "tpu"
     return ""
 
 
 def _kernel(gid_ref, vals_ref, out_ref):
-    g = gid_ref[:]                                   # [B] int32
-    b = g.shape[0]
-    onehot = (g[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (b, G_PAD), 1)).astype(jnp.float32)
+    g = gid_ref[:]                                   # [1, B] int32
+    b = g.shape[1]
+    # one-hot built TRANSPOSED, [G, B]: the row ids stay on the lane
+    # axis they arrive on (a [B, G] one-hot needs g[:, None], a
+    # lane->sublane relayout per block)
+    onehot_t = (g == jax.lax.broadcasted_iota(
+        jnp.int32, (G_PAD, b), 0)).astype(jnp.float32)
     out_ref[0] = jax.lax.dot_general(
-        vals_ref[:], onehot,                         # [K, B] x [B, G]
-        dimension_numbers=(((1,), (0,)), ((), ())),
+        vals_ref[:], onehot_t,                       # [K, B] x [G, B]^T
+        dimension_numbers=(((1,), (1,)), ((), ())),
         # HIGHEST = true-f32 matmul (bf16 multi-pass decomposition on
         # the MXU); the default TPU bf16 path rounds the 12-bit digit
         # lanes and breaks the exact-sum design (measured 2e-4)
@@ -91,10 +70,17 @@ def _kernel(gid_ref, vals_ref, out_ref):
         preferred_element_type=jnp.float32)          # [K, G_PAD]
 
 
+def _i0():
+    # index maps must return int32: under jax_enable_x64 a literal 0
+    # is an int64, which Mosaic cannot legalize
+    return jnp.int32(0)
+
+
 @partial(jax.jit, static_argnames=("interpret",))
 def _grouped_sums_impl(gid: jax.Array, vals: jax.Array,
                        interpret: bool) -> jax.Array:
-    """vals [K, cap] f32 -> f64 [K, G_PAD] per-group sums."""
+    """gid [cap] int32, vals [K, cap] f32 -> f64 [K, G_PAD] per-group
+    sums."""
     from jax.experimental import pallas as pl
     k, cap = vals.shape
     b = min(BLOCK, cap)
@@ -103,14 +89,17 @@ def _grouped_sums_impl(gid: jax.Array, vals: jax.Array,
         _kernel,
         grid=(nblocks,),
         in_specs=[
-            pl.BlockSpec((b,), lambda i: (i,)),
-            pl.BlockSpec((k, b), lambda i: (0, i)),
+            # gid rides as [1, cap] with (1, b) blocks: a 1-D s32[cap]
+            # operand gets XLA tile T(1024) where Mosaic wants T(512)
+            pl.BlockSpec((1, b), lambda i: (_i0(), i)),
+            pl.BlockSpec((k, b), lambda i: (_i0(), i)),
         ],
-        out_specs=pl.BlockSpec((1, k, G_PAD), lambda i: (i, 0, 0)),
+        out_specs=pl.BlockSpec((1, k, G_PAD),
+                               lambda i: (i, _i0(), _i0())),
         out_shape=jax.ShapeDtypeStruct((nblocks, k, G_PAD),
                                        jnp.float32),
         interpret=interpret,
-    )(gid, vals)
+    )(gid.reshape(1, cap), vals)
     return jnp.sum(partials.astype(jnp.float64), axis=0)
 
 

@@ -24,22 +24,21 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:
-    # pre-0.6 jax ships shard_map under experimental with the old
-    # check_rep knob (check_vma is its rename); adapt so the call
-    # sites below stay on the modern spelling
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map_exp(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_vma)
+from jax import shard_map
 
 from ..columnar import Batch, Column
 from ..ops.groupby import AggInput, group_aggregate
 from ..ops.hashing import hash_columns
 from .mesh import AXIS, ShardedBatch, row_spec
+
+
+def _spmd(f, **kw):
+    """``shard_map`` as ONE jitted program per call. Called eagerly,
+    shard_map dispatches every primitive of ``f`` as its own mesh-wide
+    program: a q1 partial->exchange->final aggregation is hundreds of
+    separate SPMD compiles (a 4-device tpch.tiny q1 did not return in
+    240 s on a cold cache); jitted it is one."""
+    return jax.jit(shard_map(f, **kw))
 
 
 def _col_specs(cols: Dict[str, Column], spec) -> Dict[str, Column]:
@@ -163,7 +162,7 @@ def repartition_by_hash(sb: ShardedBatch, key_names: Sequence[str],
         return out, counts
 
     mesh = sb.mesh
-    fn = shard_map(
+    fn = _spmd(
         f, mesh=mesh,
         in_specs=(_col_specs(sb.columns, P(AXIS)), P()),
         out_specs=(_col_specs(sb.columns, P(AXIS)), P()),
@@ -231,10 +230,10 @@ def sample_range_splitters(sb: ShardedBatch, sort_keys,
             for name, c in sb.columns.items()}
     n_lanes_probe = len(sort_lanes(Batch(head, 0), sort_keys)) - 1
 
-    g = shard_map(f, mesh=sb.mesh,
-                  in_specs=(_col_specs(sb.columns, P(AXIS)), P()),
-                  out_specs=tuple([P(AXIS)] * (n_lanes_probe + 1)),
-                  check_vma=False)
+    g = _spmd(f, mesh=sb.mesh,
+              in_specs=(_col_specs(sb.columns, P(AXIS)), P()),
+              out_specs=tuple([P(AXIS)] * (n_lanes_probe + 1)),
+              check_vma=False)
     out = g(sb.columns, sb.num_rows)
     live = np.asarray(out[-1])
     if not live.any():
@@ -264,10 +263,10 @@ def range_dest_counts(sb: ShardedBatch, sort_keys,
             num_segments=n)
         return jax.lax.psum(counts, AXIS)
 
-    g = shard_map(f, mesh=sb.mesh,
-                  in_specs=(_col_specs(sb.columns, P(AXIS)), P()),
-                  out_specs=P(),
-                  check_vma=False)
+    g = _spmd(f, mesh=sb.mesh,
+              in_specs=(_col_specs(sb.columns, P(AXIS)), P()),
+              out_specs=P(),
+              check_vma=False)
     return g(sb.columns, sb.num_rows)
 
 
@@ -287,7 +286,7 @@ def repartition_by_range(sb: ShardedBatch, sort_keys, splitter_lanes,
         counts = jax.lax.all_gather(new_n, AXIS)
         return out, counts
 
-    fn = shard_map(
+    fn = _spmd(
         f, mesh=sb.mesh,
         in_specs=(_col_specs(sb.columns, P(AXIS)), P()),
         out_specs=(_col_specs(sb.columns, P(AXIS)), P()),
@@ -342,10 +341,10 @@ def distributed_group_aggregate(sb: ShardedBatch,
         return fin.columns, counts
 
     mesh = sb.mesh
-    fn = shard_map(f, mesh=mesh,
-                   in_specs=(_col_specs(sb.columns, P(AXIS)), P()),
-                   out_specs=(P(AXIS), P()),
-                   check_vma=False)
+    fn = _spmd(f, mesh=mesh,
+               in_specs=(_col_specs(sb.columns, P(AXIS)), P()),
+               out_specs=(P(AXIS), P()),
+               check_vma=False)
     cols, counts = fn(sb.columns, sb.num_rows)
     return ShardedBatch(cols, counts, mesh, exch_cap)
 
@@ -364,10 +363,10 @@ def shard_apply(sb: ShardedBatch, fn, out_cap: Optional[int] = None
         counts = jax.lax.all_gather(out.num_rows_device(), AXIS)
         return out.columns, counts
 
-    g = shard_map(f, mesh=sb.mesh,
-                  in_specs=(_col_specs(sb.columns, P(AXIS)), P()),
-                  out_specs=(P(AXIS), P()),
-                  check_vma=False)
+    g = _spmd(f, mesh=sb.mesh,
+              in_specs=(_col_specs(sb.columns, P(AXIS)), P()),
+              out_specs=(P(AXIS), P()),
+              check_vma=False)
     cols, counts = g(sb.columns, sb.num_rows)
     return ShardedBatch(cols, counts, sb.mesh, cap)
 
@@ -381,10 +380,10 @@ def shard_totals(sb: ShardedBatch, fn) -> jax.Array:
         t = fn(Batch(cols, num_rows_vec[d]))
         return jax.lax.all_gather(t, AXIS)
 
-    g = shard_map(f, mesh=sb.mesh,
-                  in_specs=(_col_specs(sb.columns, P(AXIS)), P()),
-                  out_specs=P(),
-                  check_vma=False)
+    g = _spmd(f, mesh=sb.mesh,
+              in_specs=(_col_specs(sb.columns, P(AXIS)), P()),
+              out_specs=P(),
+              check_vma=False)
     return g(sb.columns, sb.num_rows)
 
 
@@ -409,10 +408,10 @@ def repartition_dest_counts(sb: ShardedBatch,
             num_segments=n)
         return jax.lax.psum(counts, AXIS)
 
-    g = shard_map(f, mesh=sb.mesh,
-                  in_specs=(_col_specs(sb.columns, P(AXIS)), P()),
-                  out_specs=P(),
-                  check_vma=False)
+    g = _spmd(f, mesh=sb.mesh,
+              in_specs=(_col_specs(sb.columns, P(AXIS)), P()),
+              out_specs=P(),
+              check_vma=False)
     return g(sb.columns, sb.num_rows)
 
 
@@ -428,7 +427,7 @@ def shard_apply2s(sa: ShardedBatch, sb: ShardedBatch, fn,
         counts = jax.lax.all_gather(out.num_rows_device(), AXIS)
         return out.columns, counts
 
-    g = shard_map(
+    g = _spmd(
         f, mesh=sa.mesh,
         in_specs=(_col_specs(sa.columns, P(AXIS)), P(),
                   _col_specs(sb.columns, P(AXIS)), P()),
@@ -446,7 +445,7 @@ def shard_totals2s(sa: ShardedBatch, sb: ShardedBatch, fn) -> jax.Array:
         t = fn(Batch(acols, an[d]), Batch(bcols, bn[d]))
         return jax.lax.all_gather(t, AXIS)
 
-    g = shard_map(
+    g = _spmd(
         f, mesh=sa.mesh,
         in_specs=(_col_specs(sa.columns, P(AXIS)), P(),
                   _col_specs(sb.columns, P(AXIS)), P()),
@@ -467,7 +466,7 @@ def shard_apply2(sa: ShardedBatch, b_host: Batch, fn,
         counts = jax.lax.all_gather(out.num_rows_device(), AXIS)
         return out.columns, counts
 
-    g = shard_map(
+    g = _spmd(
         f, mesh=sa.mesh,
         in_specs=(_col_specs(sa.columns, P(AXIS)), P(),
                   _col_specs(b_host.columns, P()), P()),
@@ -486,7 +485,7 @@ def shard_totals2(sa: ShardedBatch, b_host: Batch, fn) -> jax.Array:
         t = fn(Batch(cols, num_rows_vec[d]), Batch(bcols, bn))
         return jax.lax.all_gather(t, AXIS)
 
-    g = shard_map(
+    g = _spmd(
         f, mesh=sa.mesh,
         in_specs=(_col_specs(sa.columns, P(AXIS)), P(),
                   _col_specs(b_host.columns, P()), P()),
@@ -507,10 +506,10 @@ def broadcast_sharded(sb: ShardedBatch,
         counts = jax.lax.all_gather(new_n, AXIS)
         return out, counts
 
-    fn = shard_map(f, mesh=sb.mesh,
-                   in_specs=(_col_specs(sb.columns, P(AXIS)), P()),
-                   out_specs=(P(AXIS), P()),
-                   check_vma=False)
+    fn = _spmd(f, mesh=sb.mesh,
+               in_specs=(_col_specs(sb.columns, P(AXIS)), P()),
+               out_specs=(P(AXIS), P()),
+               check_vma=False)
     cols, counts = fn(sb.columns, sb.num_rows)
     # broadcast output is replicated per shard; counts[d] all equal total
     return ShardedBatch(cols, counts, sb.mesh, cap)

@@ -85,7 +85,12 @@ def _do_load() -> Optional[ctypes.CDLL]:
     so = os.path.join(here, "native", "libpageserde.so")
     src = os.path.normpath(os.path.join(here, "..", "native",
                                         "pageserde.cpp"))
-    if not os.path.exists(so) and os.path.exists(src):
+    # build when the library is missing OR older than its source: the
+    # .so is git-ignored, so a stale one rides along in copies of the
+    # tree and would otherwise shadow the committed source forever
+    if os.path.exists(src) and (
+            not os.path.exists(so)
+            or os.path.getmtime(src) > os.path.getmtime(so)):
         try:
             os.makedirs(os.path.dirname(so), exist_ok=True)
             subprocess.run(
