@@ -106,7 +106,9 @@ def test_span_tree_single_node(runner):
         "SELECT l_returnflag, count(*) FROM lineitem "
         "GROUP BY l_returnflag")
     names = [s.name for s in res.trace.roots]
-    assert names == ["parse", "plan", "optimize", "execute"]
+    # a runner used directly: the four engine roots, then the
+    # device-to-host fetch of the rows (ISSUE 26)
+    assert names == ["parse", "plan", "optimize", "execute", "fetch"]
     assert all(s.wall_s >= 0 for s in res.trace.roots)
     assert res.trace.query_id == res.query_id
 
@@ -235,8 +237,16 @@ def test_query_detail_serves_cached_plan_and_spans(coordinator):
     assert d["plan"] == q.result.plan_lines
     assert "planError" not in d
     spans = d.get("spans") or []
-    assert [s["name"] for s in spans] == \
-        ["parse", "plan", "optimize", "execute"]
+    # the whole served life of the query, in order (ISSUE 26): the
+    # trace is born at submit; ``respond`` lands from the HTTP thread
+    # and ``finish`` may still be open when the client reads this
+    names = [s["name"] for s in spans]
+    assert [n for n in names if n not in ("respond", "finish")] == \
+        ["submit", "queued", "parse", "plan", "optimize", "execute",
+         "fetch"]
+    by_name = {s["name"]: s for s in spans}
+    for n in ("parse", "plan", "optimize", "execute"):
+        assert "parentSpanId" not in by_name[n]
     stats = d.get("nodeStats") or []
     assert stats and all("inputRows" in s and "compileMillis" in s
                          for s in stats)
@@ -335,8 +345,8 @@ def test_distributed_span_tree_has_fragment_children(worker_uris):
         collect_node_stats=True)
     res = d.execute("SELECT sum(l_quantity) FROM lineitem")
     roots = [s.name for s in res.trace.roots]
-    assert roots == ["plan", "optimize", "execute"]
-    execute = res.trace.roots[-1]
+    assert roots == ["plan", "optimize", "execute", "fetch"]
+    execute = res.trace.roots[2]
     kids = [c.name for c in execute.children]
     assert "schedule" in kids
     frags = [c for c in execute.children

@@ -503,7 +503,12 @@ class TpchConnector(Connector):
         lo = split.part * units // split.part_count
         hi = (split.part + 1) * units // split.part_count
         gen = lineitem_batch if table == "lineitem" else orders_batch
-        out = gen(lo, hi, sf, list(gen_cols))
+        # the generators are eager jnp code, one dispatch per
+        # primitive: the whole call is ONE span and one count
+        # (program tpch_gen:<table>), like a cached program's dispatch
+        from ..obs.trace import dispatch_span
+        with dispatch_span(None, f"tpch_gen:{table}"):
+            out = gen(lo, hi, sf, list(gen_cols))
         if handle.constraint is not None or handle.limit is not None:
             out = device_filter(out, handle.constraint, handle.limit)
             out = out.select_columns(list(columns))

@@ -126,11 +126,11 @@ def _mjoin_programs(payload: dict) -> list:
                                pspec, bspec, pcap, bcap, out_cap)
     return [
         (ckey, ex.make_mjoin_count_program(pkeys, bkeys, outer),
-         (probe, build), ex._MJOIN_JIT_CACHE),
+         (probe, build), ex._MJOIN_JIT_CACHE, ex.mjoin_kind(ckey), ckey),
         (ekey, ex.make_mjoin_expand_program(frag.join_type,
                                             frag.filter, out_cap),
          (probe, build, i64(pcap), i64(pcap), i64(bcap)),
-         ex._MJOIN_JIT_CACHE)]
+         ex._MJOIN_JIT_CACHE, ex.mjoin_kind(ekey), ekey)]
 
 
 def _repartition_program(payload: dict) -> tuple:
@@ -143,9 +143,9 @@ def _repartition_program(payload: dict) -> tuple:
                   for _ in range(nkeys))
     valids = tuple(jax.ShapeDtypeStruct((cap,), np.dtype(bool))
                    for _ in range(nkeys))
-    return (rp.bucket_program_key(nkeys, cap, nparts),
-            rp.make_bucket_program(nkeys, nparts), (lanes, valids),
-            rp._BUCKET_JIT_CACHE)
+    key = rp.bucket_program_key(nkeys, cap, nparts)
+    return (key, rp.make_bucket_program(nkeys, nparts),
+            (lanes, valids), rp._BUCKET_JIT_CACHE, "repartition", key)
 
 
 def compile_entry(entry: dict) -> Optional[float]:
@@ -168,7 +168,7 @@ def compile_entry(entry: dict) -> Optional[float]:
         # canonical chunk capacity too
         from .streamjoin import _JOIN_JIT_CACHE, aot_entry
         key, fn, args = aot_entry(payload)
-        programs = [(key, fn, args, _JOIN_JIT_CACHE)]
+        programs = [(key, fn, args, _JOIN_JIT_CACHE, "streamjoin", key)]
     elif kind == "join":
         # materialized hash join: same wire form as streamjoin, two
         # programs (exec/executor.py mjoin count/expand)
@@ -185,7 +185,7 @@ def compile_entry(entry: dict) -> Optional[float]:
         def wfn(b):
             return execute_window(b, wnode)
         programs = [(fps, wfn, (_aval_batch(payload, schema),),
-                     ex._WINDOW_JIT_CACHE)]
+                     ex._WINDOW_JIT_CACHE, "window", fps)]
     else:
         nodes, fps, schema = _peeled_fragment(payload)
 
@@ -213,18 +213,23 @@ def compile_entry(entry: dict) -> Optional[float]:
             fn = run if kind == "stream" else run_full
         else:
             raise ValueError(f"unknown hot-shape kind {kind!r}")
-        programs = [(key, fn, (_aval_batch(payload, schema),), cache)]
+        programs = [(key, fn, (_aval_batch(payload, schema),), cache,
+                     kind, fps)]
 
     wall = 0.0
     compiled = False
-    for key, fn, args, cache in programs:
+    # each program is jitted under the SAME name the executor would
+    # give it (progkey.named_jit: kind + canonical key), so the module
+    # compiled here is the one the first real query looks up
+    from .progkey import named_jit
+    for key, fn, args, cache, name_kind, name_key in programs:
         with ex._JIT_CACHE_LOCK:
             resident = key in cache
         if resident:
             continue
         t0 = time.perf_counter()
         try:
-            jitted = jax.jit(fn)
+            jitted = named_jit(fn, name_kind, name_key)
             jitted.lower(*args).compile()
         except Exception:
             _M_AOT.inc(kind=kind, result="error")

@@ -43,6 +43,7 @@ from ..session import Session
 from ..types import (BIGINT, BOOLEAN, DOUBLE, REAL, DecimalType, Type,
                      is_integral, is_string)
 from .expr import EvalError, eval_expr, eval_predicate
+from .progkey import named_jit
 
 
 class QueryError(Exception):
@@ -97,9 +98,10 @@ class NodeStats:
     output_bytes: int = -1
     compile_s: float = 0.0
     cache_hit: Optional[bool] = None
-    # device seconds this node's jitted dispatches spent (block-until-
-    # ready deltas, exec/executor.py _jit_call) — own dispatches only,
-    # NOT children's (unlike wall, which nests)
+    # seconds this node's jitted dispatches took on the HOST clock from
+    # dispatch to outputs ready (exec/executor.py _jit_call): an upper
+    # bound on device time — own dispatches only, NOT children's
+    # (unlike wall, which nests)
     device_s: float = 0.0
     # thread-CPU seconds across this node's execution (includes
     # children, like wall — the two are directly comparable)
@@ -266,7 +268,10 @@ _MJOIN_JIT_DENY: set = set()
 
 # process metrics (obs/metrics.py; scraped at GET /metrics). These are
 # per-query-phase increments, never per-row — the lock cost is noise.
+from contextlib import nullcontext as _nullcontext
 from ..obs.metrics import METRICS as _METRICS
+from ..obs.trace import active_span, dispatch_span
+_NO_SPAN = _nullcontext()
 # the jit-cache family is defined ONCE in obs/metrics.py (streamjoin's
 # probe-program cache feeds the same family — a second registration
 # here would trip the metrics-hygiene lint)
@@ -327,7 +332,12 @@ def _keys_inexact(cols, keys) -> bool:
     if len(keys) > 1:
         return True
     c = cols[keys[0]]
-    return c.data2 is not None or np.asarray(c.data).dtype.kind == "f"
+    if c.data2 is not None:
+        return True
+    # np.asarray on a device lane copies the WHOLE lane to the host to
+    # learn its dtype: a blocking read, so it is a span (ROADMAP S3)
+    with active_span("host_read", site="join_key_dtype"):
+        return np.asarray(c.data).dtype.kind == "f"
 
 
 def join_verify_filter(left_cols, right_cols, pkeys, bkeys, filt):
@@ -389,10 +399,11 @@ class Executor:
         # (raggedBatched) and rolled up by the remote/stage schedulers
         self.ragged_batched: int = 0
         # device-time attribution (ISSUE 15): seconds this executor's
-        # jitted dispatches spent to data-ready (_jit_call block-until-
-        # ready deltas), exported as deviceSeconds in worker task
-        # status and rolled up per stage — the number distinct from
-        # wall that explains tensor-engine latency
+        # jitted dispatches took from dispatch to data-ready on the
+        # host clock (_jit_call; an upper bound on device time),
+        # exported as deviceSeconds in worker task status and rolled
+        # up per stage — the number distinct from wall that explains
+        # tensor-engine latency
         self.device_s: float = 0.0
         # > 0 while a morsel-streamed chunk loop is driving dispatches
         # (exec/streamjoin.py run_streamed): device timing's block-
@@ -469,8 +480,10 @@ class Executor:
         # the output is accounting overhead, not the operator's work
         cpu_s = max(time.thread_time() - cpu0, 0.0)
         # blocking read for accurate per-node timing
-        n = (out.total_rows_host() if hasattr(out, "total_rows_host")
-             else out.num_rows_host())
+        with self._host_read("node_fence"):
+            n = (out.total_rows_host()
+                 if hasattr(out, "total_rows_host")
+                 else out.num_rows_host())
         obytes = sum(_col_bytes(c) for c in out.columns.values())
         name = type(node).__name__.replace("Node", "")
         if not name.startswith("_"):
@@ -495,66 +508,60 @@ class Executor:
             parent["bytes"] += obytes
         return out
 
+    def _host_read(self, site: str):
+        """The span of ONE blocking device-to-host read (``host_read``,
+        attr ``site``): every place where this executor pulls a value
+        to the host and the device drains meanwhile — a bubble the
+        counters at /metrics count by site. A no-op without a trace."""
+        tr = self.trace
+        return (tr.span("host_read", site=site) if tr is not None
+                else _NO_SPAN)
+
     def _jit_call(self, jitted, args: tuple, cache: str, hit: bool):
-        """Invoke a jitted program, separating jit_trace (first, cache-
-        miss call: trace + XLA compile + execute) from device_execute
-        (steady state) in the query trace, attributing compile wall to
-        the current node's stats frame, and measuring DEVICE time
-        distinct from wall: jax dispatch is async, so the delta from
-        dispatch return to ``jax.block_until_ready`` is the device's
-        completion wait (the fallback ISSUE 15 names; a real XLA-
-        profiler hook would refine, not replace, this number). On a
-        sync backend the dispatch itself runs the program, so a
-        cache-hit call's whole span is device work. The extra sync
-        only happens under telemetry — the stats fence next to it
-        already syncs per node, so the no-telemetry path keeps jax's
-        async pipeline untouched. AOT-compiled programs (exec/aot.py)
-        additionally surface XLA's cost analysis (flops) on the
-        span."""
+        """Invoke a jitted program under a live ``device_execute``
+        (steady state) or ``jit_trace`` (first, cache-miss call: trace
+        + XLA compile + execute) span carrying the program's identity
+        (``program=<kind>:<key8>``, exec/progkey.py named_jit),
+        attributing compile wall to the current node's stats frame.
+        ``device_ms`` is the HOST clock from dispatch to outputs ready
+        (jax dispatch is async, so the wait is ``block_until_ready``):
+        an upper bound on the program's device time, not a device
+        measurement — the profiler's trace holds that, under the same
+        program name. On a sync backend the dispatch itself runs the
+        program. The extra sync only happens under telemetry — the
+        stats fence next to it already syncs per node, so the
+        no-telemetry path keeps jax's async pipeline untouched."""
         tr = self.trace
         if tr is None and not self.collect_stats:
             return jitted(*args)
         t0 = time.perf_counter()
         t1 = dev_s = None
         try:
-            out = jitted(*args)
-            t1 = time.perf_counter()
-            if self._stream_depth == 0:
-                # device attribution syncs — inside a streamed chunk
-                # loop that sync would serialize the double-buffered
-                # transfer/compute overlap, so streamed dispatches
-                # skip it (their chunks report wall only)
-                try:
-                    jax.block_until_ready(out)
-                except Exception:   # noqa: BLE001 — non-array outputs
-                    pass
-                t2 = time.perf_counter()
-                # hit: the whole dispatch-to-ready window is device
-                # work; miss: only the post-trace completion wait is
-                # (the trace+compile share lands in compile_s below)
-                dev_s = (t2 - t0) if hit else (t2 - t1)
+            with dispatch_span(tr, getattr(jitted, "program", None)
+                               or f"{cache}:local", hit, cache) as sp:
+                out = jitted(*args)
+                t1 = time.perf_counter()
+                if self._stream_depth == 0:
+                    # device attribution syncs — inside a streamed
+                    # chunk loop that sync would serialize the double-
+                    # buffered transfer/compute overlap, so streamed
+                    # dispatches skip it (their chunks report wall
+                    # only)
+                    try:
+                        jax.block_until_ready(out)
+                    except Exception:   # noqa: BLE001 — non-array outputs
+                        pass
+                    t2 = time.perf_counter()
+                    # hit: the whole dispatch-to-ready window; miss:
+                    # only the post-trace completion wait (the trace+
+                    # compile share lands in compile_s below)
+                    dev_s = (t2 - t0) if hit else (t2 - t1)
+                    if sp is not None:
+                        sp.attrs["device_ms"] = round(dev_s * 1000, 3)
             return out
         finally:
-            tend = time.perf_counter()
             if t1 is None:
-                t1 = tend
-            if tr is not None:
-                attrs = {"cache": cache}
-                if dev_s is not None:
-                    attrs["device_ms"] = round(dev_s * 1000, 3)
-                if not hit:
-                    try:    # AOT Compiled objects carry cost analysis
-                        ca = getattr(jitted, "cost_analysis", None)
-                        if ca is not None:
-                            c = ca()
-                            c = c[0] if isinstance(c, (list, tuple)) \
-                                else c
-                            if c and c.get("flops"):
-                                attrs["flops"] = float(c["flops"])
-                    except Exception:   # noqa: BLE001 — advisory
-                        pass
-                tr.record("device_execute" if hit else "jit_trace",
-                          t0, tend, **attrs)
+                t1 = time.perf_counter()
             if dev_s:
                 self.device_s += dev_s
                 if self._frames:
@@ -577,7 +584,8 @@ class Executor:
         wall = time.perf_counter() - t0
         _M_SPLITS.inc()
         if self.collect_stats and self._frames:
-            self._frames[-1]["rows"] += b.num_rows_host()
+            with self._host_read("split_rows"):
+                self._frames[-1]["rows"] += b.num_rows_host()
             self._frames[-1]["bytes"] += sum(
                 _col_bytes(c) for c in b.columns.values())
         events = getattr(self.session, "events", None)
@@ -770,7 +778,7 @@ class Executor:
                     _M_JIT.inc(cache="stream",
                                result="hit" if full_hit else "miss")
                 if full_jit is None:
-                    full_jit = jax.jit(run_full)
+                    full_jit = named_jit(run_full, "stream_full", fkey)
                     if fullkey is not None:
                         _cache_put(_STREAM_JIT_CACHE, fullkey, full_jit)
                 batch = bind(Batch(
@@ -804,7 +812,7 @@ class Executor:
                 _M_JIT.inc(cache="stream",
                            result="hit" if jit_hit else "miss")
             if run_jit is None and fkey not in _STREAM_JIT_DENY:
-                run_jit = jax.jit(run)
+                run_jit = named_jit(run, "stream", fkey)
                 if fkey is not None:
                     _cache_put(_STREAM_JIT_CACHE, fkey, run_jit)
         def consume(batch: Batch) -> Batch:
@@ -985,7 +993,9 @@ class Executor:
         hit = jitted is not None
         _M_JIT.inc(cache="masked", result="hit" if hit else "miss")
         if jitted is None:
-            jitted = jax.jit(run)
+            # keyed by id(node): a per-query program, named without
+            # a key (program names come from canonical keys only)
+            jitted = named_jit(run, "masked", None)
             self._jit_chains[key] = jitted
         try:
             return self._jit_call(jitted, (base,), "masked", hit)
@@ -1028,7 +1038,7 @@ class Executor:
                 for nd in reversed(nodes):
                     b = helper._dispatch_apply(nd, b)
                 return b
-            jitted = jax.jit(fn)
+            jitted = named_jit(fn, "chain", key if structural else None)
             if structural:
                 _cache_put(_CHAIN_JIT_CACHE, key, jitted)
             else:
@@ -1123,7 +1133,8 @@ class Executor:
         from ..columnar import Column, concat_batches
         from ..types import BIGINT
         from .progkey import RAGGED_LANE, ragged_nodes
-        ns = [b.num_rows_host() for b in items]
+        with self._host_read("ragged_rows"):
+            ns = [b.num_rows_host() for b in items]
         total = sum(ns)
         combined = concat_batches(items)
         cap = combined.capacity
@@ -1152,7 +1163,7 @@ class Executor:
                 for nd in reversed(nodes):
                     b = helper._dispatch_apply(nd, b)
                 return b
-            jitted = jax.jit(fn)
+            jitted = named_jit(fn, "ragged", key)
             _cache_put(_RAGGED_JIT_CACHE, rkey, jitted)
         out = self._jit_call(jitted, (ragged,), "ragged", hit)
         # demux: ONE host sync for the lane, then a per-member row
@@ -1161,9 +1172,10 @@ class Executor:
         # filter compaction is STABLE (mask_to_gather's nonzero is
         # ascending) and members' input rows are contiguous, so each
         # member's relative row order matches its solo run exactly.
-        n_out = out.num_rows_host()
-        lane_out = np.asarray(
-            jax.device_get(out.column(RAGGED_LANE).data))[:n_out]
+        with self._host_read("ragged_demux"):
+            n_out = out.num_rows_host()
+            lane_out = np.asarray(
+                jax.device_get(out.column(RAGGED_LANE).data))[:n_out]
         bare = Batch({k: c for k, c in out.columns.items()
                       if k != RAGGED_LANE}, out.num_rows)
         results = []
@@ -1319,7 +1331,8 @@ class Executor:
 
     def _exec_EnforceSingleRowNode(self, node) -> Batch:
         src = self.execute(node.source)
-        n = src.num_rows_host()
+        with self._host_read("single_row"):
+            n = src.num_rows_host()
         if n > 1:
             raise QueryError(
                 "Scalar sub-query has returned multiple rows")
@@ -1421,7 +1434,8 @@ class Executor:
         for ln in lens.values():
             count = ln if count is None else jnp.maximum(count, ln)
         count = jnp.where(live, count, 0)
-        total = int(jnp.sum(count))
+        with self._host_read("unnest_total"):
+            total = int(jnp.sum(count))
         out_cap = capacity_for(max(total, 1))
         self._reserve(out_cap, len(node.replicate) + len(arrs) + 1,
                       "unnest output")
@@ -1456,7 +1470,9 @@ class Executor:
     # ------------------------------------------------------------------
     def _mjoin_program(self, key: tuple, builder):
         """Lookup-or-build one jitted materialized-join program in the
-        cross-query cache. None when the key is denied (a prior trace
+        cross-query cache (the key's tag, ``mjoin_count`` or
+        ``mjoin_expand``, gives the program its role: ``join_count``,
+        ``join_expand``). None when the key is denied (a prior trace
         hit host-only evaluation); the caller falls back to the eager
         two-phase path."""
         if key in _MJOIN_JIT_DENY:
@@ -1465,7 +1481,7 @@ class Executor:
         hit = jitted is not None
         _M_JIT.inc(cache="join", result="hit" if hit else "miss")
         if jitted is None:
-            jitted = jax.jit(builder())
+            jitted = named_jit(builder(), mjoin_kind(key), key)
             _cache_put(_MJOIN_JIT_CACHE, key, jitted)
         return jitted, hit
 
@@ -1590,14 +1606,16 @@ class Executor:
             if counted is not None:
                 start, count, order, total_dev = counted
                 eff = None      # only the oversized path needs it
-                total = int(total_dev)
+                with self._host_read("join_total"):
+                    total = int(total_dev)
             else:
                 start, count, order = join_ops.match_counts(
                     left, right, pkeys, bkeys)
                 live_p = left.row_valid()
                 eff = jnp.where(live_p, jnp.maximum(count, 1), 0) \
                     if outer else count
-                total = int(jnp.sum(eff))
+                with self._host_read("join_total"):
+                    total = int(jnp.sum(eff))
             width = len(left.columns) + len(right.columns)
             if total > CONFIG.max_batch_rows:
                 if eff is None:
@@ -1634,11 +1652,13 @@ class Executor:
         counted = self._mjoin_counts(probe, build, pkeys, bkeys, False)
         if counted is not None:
             start, count, order, total_dev = counted
-            total = int(total_dev)
+            with self._host_read("join_total"):
+                total = int(total_dev)
         else:
             start, count, order = join_ops.match_counts(
                 probe, build, pkeys, bkeys)
-            total = int(jnp.sum(count))
+            with self._host_read("join_total"):
+                total = int(jnp.sum(count))
         width = len(probe.columns) + len(build.columns)
         if total > CONFIG.max_batch_rows and jt == "inner":
             out = self._oversized_join(probe, build, start, count, count,
@@ -1716,10 +1736,11 @@ class Executor:
         the memory guard fires."""
         if not bool(self.session.get("spill_enabled")):
             self._reserve(total, width, "join output (spill disabled)")
-        eff_np = np.asarray(eff)
-        cum = np.cumsum(eff_np)
-        budget = CONFIG.max_batch_rows
-        n_live = probe.num_rows_host()
+        with self._host_read("join_spill"):
+            eff_np = np.asarray(eff)
+            cum = np.cumsum(eff_np)
+            budget = CONFIG.max_batch_rows
+            n_live = probe.num_rows_host()
         chunks: List[Batch] = []
         lo = 0
         consumed = 0
@@ -1747,10 +1768,12 @@ class Executor:
                 # survivors reach host RAM
                 mask = eval_predicate(residual, out)
                 out = compact.filter_batch(out, mask)
-                chunk_rows = out.num_rows_host()
+                with self._host_read("join_spill"):
+                    chunk_rows = out.num_rows_host()
                 if chunk_rows == 0:
                     continue
-            spilled = _to_host(out, chunk_rows)
+            with self._host_read("join_spill"):
+                spilled = _to_host(out, chunk_rows)
             nbytes = sum(_col_bytes(c) for c in spilled.columns.values())
             self.spilled_bytes += nbytes
             _M_SPILL.inc(nbytes)
@@ -1767,7 +1790,8 @@ class Executor:
         variants, probe/build positions are tracked through the filter so
         unmatched rows null-extend (JoinNode with empty criteria in
         sql/planner/plan/JoinNode.java; NestedLoopJoinOperator.java)."""
-        nl, nr = left.num_rows_host(), right.num_rows_host()
+        with self._host_read("cross_rows"):
+            nl, nr = left.num_rows_host(), right.num_rows_host()
         total = nl * nr
         self._reserve(total, len(left.columns) + len(right.columns),
                       "cross join output")
@@ -1821,7 +1845,9 @@ class Executor:
         sub = compact.filter_batch(left, row_mask)
         cols = dict(sub.columns)
         for s, c in right.columns.items():
-            z = jnp.zeros((sub.capacity,), dtype=np.asarray(c.data).dtype)
+            with self._host_read("null_extend_dtype"):
+                dt = np.asarray(c.data).dtype
+            z = jnp.zeros((sub.capacity,), dtype=dt)
             cols[s] = Column(c.type, z,
                              jnp.zeros((sub.capacity,), bool),
                              c.dictionary,
@@ -1835,7 +1861,9 @@ class Executor:
         sub = compact.filter_batch(right, row_mask)
         cols = {}
         for s, c in left.columns.items():
-            z = jnp.zeros((sub.capacity,), dtype=np.asarray(c.data).dtype)
+            with self._host_read("null_extend_dtype"):
+                dt = np.asarray(c.data).dtype
+            z = jnp.zeros((sub.capacity,), dtype=dt)
             cols[s] = Column(c.type, z, jnp.zeros((sub.capacity,), bool),
                              c.dictionary,
                              None if c.data2 is None else
@@ -1894,7 +1922,9 @@ class Executor:
                 probe, filt, skeys, fkeys)
         else:
             start, count, order = join_ops.cross_counts(probe, filt)
-        total = int(jnp.sum(count))
+        with self._host_read("unnest_total"):
+            with self._host_read("semijoin_total"):
+                total = int(jnp.sum(count))
         cap = capacity_for(total)
         cand = join_ops.expand_join(probe, filt, start, count, order,
                                     cap, "inner")
@@ -1953,7 +1983,7 @@ class Executor:
 
             def fn(b: Batch) -> Batch:
                 return execute_window(b, wnode)
-            jitted = jax.jit(fn)
+            jitted = named_jit(fn, "window", key)
             _cache_put(_WINDOW_JIT_CACHE, key, jitted)
         binding = canon.binding(src)
         cb = binding.rename_in(src)
@@ -2092,6 +2122,11 @@ def mjoin_expand_key(jt: str, residual_repr: str, probe_spec,
             int(probe_cap), int(build_cap), int(out_cap))
 
 
+def mjoin_kind(key: tuple) -> str:
+    """The program kind of a materialized-join cache key."""
+    return "join_count" if key[0] == "mjoin_count" else "join_expand"
+
+
 def make_mjoin_count_program(pkeys, bkeys, outer: bool):
     """Phase 1: build-side sort + probe match counts + the effective
     output total. Everything downstream of the total is host policy
@@ -2182,7 +2217,8 @@ def setop_batches(lb: Batch, rb: Batch, op: str, distinct: bool,
     out = compact.filter_batch(g, keep)
     if times is not None:
         times = jnp.take(times, compact.mask_to_gather(keep)[0])
-        total = int(jnp.sum(jnp.where(out.row_valid(), times, 0)))
+        with active_span("host_read", site="setop_total"):
+            total = int(jnp.sum(jnp.where(out.row_valid(), times, 0)))
         cap = capacity_for(max(total, 1))
         incl = jnp.cumsum(jnp.where(out.row_valid(), times, 0))
         i = jnp.arange(cap, dtype=jnp.int64)
@@ -2269,10 +2305,15 @@ def read_split_cached(conn, split, columns) -> Batch:
             return Batch({c: entry["cols"][c] for c in columns},
                          entry["num_rows"])
     _M_SCAN.inc(cache="split", result="miss")
-    raw = conn.read_split(split, missing)
     on_dev = jax.default_backend() != "cpu"
-    if on_dev:
-        raw = raw.on_device()          # pin the lanes in HBM
+    # the miss path, once per lane per process: read or generate the
+    # missing lanes and pin them — waited for, so that the span (and
+    # trino_tpu_scan_fill_seconds) holds the fill, not its dispatch
+    with active_span("scan_fill", table=h.table, lanes=len(missing)):
+        raw = conn.read_split(split, missing)
+        if on_dev:
+            raw = raw.on_device()          # pin the lanes in HBM
+            jax.block_until_ready([c.data for c in raw.columns.values()])
     size = sum(_col_bytes(c) for c in raw.columns.values())
     with _SCAN_CACHE_LOCK:
         state = _SCAN_CACHES.get(conn)
@@ -2914,7 +2955,8 @@ def device_concat(parts: Sequence[Batch]) -> Batch:
     parts = list(parts)
     if len(parts) == 1:
         return parts[0]
-    counts = [p.num_rows_host() for p in parts]
+    with active_span("host_read", site="concat_rows"):
+        counts = [p.num_rows_host() for p in parts]
     total = sum(counts)
     if total > CONFIG.max_batch_rows and any(
             isinstance(next(iter(p.columns.values())).data, np.ndarray)

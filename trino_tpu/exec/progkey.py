@@ -41,10 +41,24 @@ their typed planner values (``DATE '1998-09-02'`` and its int form
 key identically) but never erased — a constant is baked into the
 compiled program, so erasing it would alias genuinely different
 programs.
+
+Program NAMES (``program_name`` / ``named_jit``): every cached program
+is jitted under a function named ``<kind>_<key8>`` — kind = the cache
+the program lives in (with its role where one cache holds several:
+``join_count``, ``join_expand``), key8 = 8 hex of the canonical key —
+with a ``jax.named_scope`` of the same name inside, so the profiler's
+``XLA Modules`` line reads ``jit_join_count_1a2b3c4d`` and every
+operation's metadata names the program it belongs to. The name is a
+function of the canonical key ONLY (no ``id()``, no counter, no path):
+it is part of the lowered module, so the same query in two processes
+must lower to byte-identical HLO or the persistent compile cache
+misses. Programs without a canonical key are named ``<kind>_local``.
 """
 
 from __future__ import annotations
 
+import hashlib
+import re
 from dataclasses import replace as dc_replace
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -56,6 +70,37 @@ from ..plan.nodes import (Aggregate, AggregationNode, AssignUniqueIdNode,
                           SortNode, TopNNode, WindowFunction, WindowNode)
 from ..rex import (VOLATILE_FNS, Call, CaseExpr, Cast, Const, InputRef,
                    Lambda, RowExpr)
+
+
+_ADDRESS = re.compile(r" at 0x[0-9a-fA-F]+")
+
+
+def program_name(kind: str, key) -> str:
+    """``<kind>_<key8>``; ``<kind>_local`` for a program whose key is
+    per-process (``key=None``: plans outside the canonical subset)."""
+    if key is None:
+        return f"{kind}_local"
+    # a default object repr inside a key would carry an address: never
+    # let one reach a name that is baked into the compiled module
+    text = _ADDRESS.sub("", repr(key))
+    return f"{kind}_{hashlib.sha256(text.encode()).hexdigest()[:8]}"
+
+
+def named_jit(fn, kind: str, key, **jit_kwargs):
+    """``jax.jit(fn)`` under the program's name. The returned callable
+    carries ``program`` = ``<kind>:<key8>`` for the dispatch spans
+    (exec/executor.py ``device_call``)."""
+    import jax
+    name = program_name(kind, key)
+
+    def program(*args, **kwargs):
+        with jax.named_scope(name):
+            return fn(*args, **kwargs)
+
+    program.__name__ = program.__qualname__ = name
+    jitted = jax.jit(program, **jit_kwargs)
+    jitted.program = f"{kind}:{name[len(kind) + 1:]}"
+    return jitted
 
 
 class _NotCanonical(Exception):

@@ -1344,7 +1344,7 @@ class DistributedHostQueryRunner:
         import time as _time
         from ..obs.metrics import (QUERY_PEAK_MEMORY_BYTES,
                                    QUERY_WALL_SECONDS)
-        from ..obs.trace import QueryTrace, null_span
+        from ..obs.trace import null_span
         from ..planner.logical import LogicalPlanner
         from ..planner.optimizer import optimize
         from ..plan.nodes import plan_tree_lines
@@ -1367,10 +1367,12 @@ class DistributedHostQueryRunner:
         if not isinstance(stmt, A.QueryStatement):
             return self._local.execute(sql)   # DDL etc: coordinator-only
         collect = self.collect_node_stats or analyze
-        trace = (QueryTrace(getattr(self.session, "query_id", ""))
-                 if collect else None)
-        sp = trace.span if trace is not None else null_span
+        from ..obs import adopt_or_mint
         prev_trace = self.session.trace
+        trace, adopted = adopt_or_mint(
+            self.session, collect,
+            getattr(self.session, "query_id", ""))
+        sp = trace.span if trace is not None else null_span
         self.session.trace = trace
         try:
             with sp("plan"):
@@ -1401,7 +1403,7 @@ class DistributedHostQueryRunner:
             # through the configured sinks; in the finally so failed
             # queries' traces export too (they are the ones worth
             # reading)
-            if trace is not None and trace.roots:
+            if trace is not None and not adopted and trace.roots:
                 from ..obs.otlp import maybe_export
                 maybe_export(trace, session=self.session)
         if collect:
@@ -1423,9 +1425,11 @@ class DistributedHostQueryRunner:
             res.stats = sched.stats
             res.trace = trace
             return res
-        schema = batch.schema()
-        types = [schema[s] for s in plan.symbols]
-        res = QueryResult(list(plan.names), types, batch.to_pylist())
+        with sp("fetch"):
+            schema = batch.schema()
+            types = [schema[s] for s in plan.symbols]
+            res = QueryResult(list(plan.names), types,
+                              batch.to_pylist())
         res.plan_lines = plan_tree_lines(plan)
         res.trace = trace
         res.peak_memory_bytes = sched.peak_memory_bytes

@@ -73,6 +73,7 @@ from ..plan.nodes import (FilterNode, JoinNode, PlanNode, ProjectNode,
                           RemoteSourceNode, TableScanNode)
 from ..rex import Call as _RCall, InputRef, and_all
 from ..types import BOOLEAN, DecimalType
+from .progkey import named_jit
 
 # cross-query cache of jitted streamed-join probe programs, keyed by
 # (probe/build lane specs, keys, join type, residual, capacities);
@@ -474,7 +475,7 @@ def make_chain_runner(ex, chain: Sequence[PlanNode]):
                 for nd in reversed(nodes):
                     cb = helper._dispatch_apply(nd, cb)
                 return cb
-            jitted = jax.jit(fn)
+            jitted = named_jit(fn, "chain", key)
             _ex._cache_put(_ex._CHAIN_JIT_CACHE, key, jitted)
         try:
             out = ex._jit_call(jitted, (binding.rename_in(b),),
@@ -953,7 +954,7 @@ def maybe_stream_join(ex, node: JoinNode
             _M_JIT.inc(cache="streamjoin",
                        result="hit" if state["hit"] else "miss")
             if jitted is None:
-                jitted = jax.jit(fn)
+                jitted = named_jit(fn, "streamjoin", key)
                 _ex._cache_put(_JOIN_JIT_CACHE, key, jitted)
             entry = (jitted, key, False)
         state["prog"], state["prog_cap"] = entry, state["out_cap"]

@@ -9,15 +9,22 @@ Here the same three layers exist TPU-first:
 - ``obs.metrics``: process-wide counters/gauges/histograms with
   Prometheus text exposition (GET /metrics on the coordinator and the
   task worker) — the JMX/MBean analog.
-- ``obs.trace``: a per-query DISTRIBUTED span tree (parse -> plan ->
-  optimize -> execute, with jit_trace vs device_execute children) —
+- ``obs.trace``: a per-query DISTRIBUTED span tree over the whole
+  served life of a query (roots submit -> queued -> parse -> plan ->
+  optimize -> execute -> fetch -> persist -> finish, respond from the
+  HTTP thread; under execute: jit_trace vs device_execute per named
+  program, host_read per blocking device-to-host read, scan_fill) —
   every span carries a real 128-bit-trace/64-bit-span identity, W3C
   ``traceparent`` context propagates into worker task payloads, and
-  worker subtrees merge back id-preserving. On a tensor runtime
-  compilation/dispatch overheads dominate (PAPERS.md "Query
-  Processing on Tensor Computation Runtimes"), so trace-vs-execute
-  separation (and device_ms vs wall) is the single most important
-  measurement the JVM engine never needed.
+  worker subtrees merge back id-preserving. Spans are also
+  ``tpusql:<name>`` annotations in a profiler session (one clock with
+  the device trace) and feed the phase counters of ``obs.metrics``
+  through one hook. On a tensor runtime compilation/dispatch
+  overheads dominate (PAPERS.md "Query Processing on Tensor
+  Computation Runtimes"), so trace-vs-execute separation is the single
+  most important measurement the JVM engine never needed;
+  ``device_ms`` is the host clock from dispatch to outputs ready, an
+  upper bound on device time.
 - ``obs.otlp``: stdlib-only OTLP/JSON export of finished traces
   (ResourceSpans shape; file + HTTP sinks, plus the coordinator's
   GET /v1/trace/{query_id} pull surface).
@@ -26,7 +33,25 @@ Here the same three layers exist TPU-first:
   task results and the coordinator merges them per stage.
 """
 
-from .metrics import METRICS, MetricsRegistry
+from .metrics import METRICS, MetricsRegistry, observe_span
 from .trace import QueryTrace, Span
 
-__all__ = ["METRICS", "MetricsRegistry", "QueryTrace", "Span"]
+
+def adopt_or_mint(session, mint: bool, query_id: str = ""):
+    """(trace, adopted) for a runner about to execute on ``session``:
+    a served query's trace is born in ``QueryTracker.submit`` and rides
+    the Session — the runner ADOPTS it (its owner names and exports
+    it). A runner used directly mints its own when ``mint`` (it
+    collects stats: tracing is cheap but not free, a span per jitted
+    dispatch, so the no-telemetry path stays trace-less), else runs
+    untraced (None)."""
+    trace = session.trace
+    if trace is not None:
+        return trace, True
+    if mint:
+        return QueryTrace(query_id, on_close=observe_span), False
+    return None, False
+
+
+__all__ = ["METRICS", "MetricsRegistry", "QueryTrace", "Span",
+           "adopt_or_mint", "observe_span"]

@@ -262,7 +262,7 @@ class TraceRing:
         roots = [{"name": sp.name,
                   "wall_ms": round(sp.wall_s * 1000, 3),
                   "children": len(sp.children)}
-                 for sp in trace.roots[:8]]
+                 for sp in trace.roots[:12]]
         with self._lock:
             self._ring.append({
                 "traceId": getattr(trace, "trace_id", ""),

@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import math
 import threading
+from bisect import bisect_left
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 
@@ -96,7 +97,11 @@ class Counter(_Metric):
     def inc(self, amount: float = 1.0, **labels) -> None:
         if amount < 0:
             raise ValueError("counters only go up")
-        key = self._key(labels)
+        self.inc_at(self._key(labels), amount)
+
+    def inc_at(self, key: Tuple[str, ...], amount: float = 1.0) -> None:
+        """``inc`` for a caller that holds the label-value tuple (in
+        ``labelnames`` order) already: the per-span hot path."""
         with self._lock:
             self._values[key] = self._values.get(key, 0.0) + amount
 
@@ -130,26 +135,37 @@ class Histogram(_Metric):
                  buckets: Sequence[float] = DEFAULT_BUCKETS):
         super().__init__(name, help, labelnames, lock)
         self.buckets = tuple(sorted(buckets))
-        # per label-key: [bucket counts..., +Inf count, sum]
+        # per label-key: [observations that fell INTO each bucket...,
+        # those above the last bound, sum] — cumulated on read, so an
+        # observation is one bisect and two adds
         self._hist: Dict[Tuple[str, ...], List[float]] = {}
 
     def observe(self, value: float, **labels) -> None:
-        key = self._key(labels)
+        self.observe_at(self._key(labels), value)
+
+    def observe_at(self, key: Tuple[str, ...], value: float) -> None:
+        """``observe`` for a caller that holds the label-value tuple
+        (in ``labelnames`` order) already: the per-span hot path."""
         with self._lock:
             h = self._hist.get(key)
             if h is None:
                 h = [0.0] * (len(self.buckets) + 2)
                 self._hist[key] = h
-            for i, b in enumerate(self.buckets):
-                if value <= b:
-                    h[i] += 1
-            h[-2] += 1           # +Inf / count
+            h[bisect_left(self.buckets, value)] += 1
             h[-1] += value       # sum
+
+    def _cumulated(self, h: List[float]) -> Tuple[List[float], float]:
+        """(cumulative count per bucket bound, total count)."""
+        cum, run = [], 0.0
+        for c in h[:len(self.buckets)]:
+            run += c
+            cum.append(run)
+        return cum, run + h[-2]
 
     def count(self, **labels) -> float:
         with self._lock:
             h = self._hist.get(self._key(labels))
-            return h[-2] if h else 0.0
+            return self._cumulated(h)[1] if h else 0.0
 
     def snapshot(self, **labels) -> Tuple[Tuple[float, ...], float,
                                           float]:
@@ -161,7 +177,8 @@ class Histogram(_Metric):
             h = self._hist.get(self._key(labels))
             if h is None:
                 return (0.0,) * len(self.buckets), 0.0, 0.0
-            return tuple(h[:len(self.buckets)]), h[-2], h[-1]
+            cum, total = self._cumulated(h)
+            return tuple(cum), total, h[-1]
 
     @staticmethod
     def quantile_from_deltas(buckets: Sequence[float],
@@ -187,23 +204,24 @@ class Histogram(_Metric):
         lines = [f"# HELP {self.name} {self.help}",
                  f"# TYPE {self.name} {self.kind}"]
         with self._lock:
-            items = sorted(self._hist.items())
-        for key, h in items:
-            for i, b in enumerate(self.buckets):
+            items = sorted((key, self._cumulated(h), h[-1])
+                           for key, h in self._hist.items())
+        for key, (cum, total), hsum in items:
+            for b, c in zip(self.buckets, cum):
                 lines.append(
                     f"{self.name}_bucket"
                     f"{self._render_labels(key, [('le', _fmt(b))])}"
-                    f" {_fmt(h[i])}")
+                    f" {_fmt(c)}")
             lines.append(
                 f"{self.name}_bucket"
                 f"{self._render_labels(key, [('le', '+Inf')])}"
-                f" {_fmt(h[-2])}")
+                f" {_fmt(total)}")
             lines.append(
                 f"{self.name}_sum{self._render_labels(key)} "
-                f"{_fmt(h[-1])}")
+                f"{_fmt(hsum)}")
             lines.append(
                 f"{self.name}_count{self._render_labels(key)} "
-                f"{_fmt(h[-2])}")
+                f"{_fmt(total)}")
         return lines
 
 
@@ -515,6 +533,68 @@ CONTINUOUS_CYCLES = METRICS.counter(
 CONTINUOUS_JOBS = METRICS.gauge(
     "trino_tpu_continuous_queries",
     "Continuous-query jobs currently RUNNING on this coordinator")
+
+
+# the served path, phase by phase (obs/trace.py PHASES): fed by ONE
+# hook, ``observe_span``, that QueryTrace calls with every span that
+# closes — the spans are the timers, nothing is timed twice. A fixed
+# set of phase names; no label value holds a space (line-oriented
+# scrapers split the sample line at the first one).
+PHASE_BUCKETS = (0.00001, 0.0001, 0.0005, 0.001, 0.005, 0.025, 0.1,
+                 0.5, 2.5, 10.0, 60.0)
+QUERY_PHASE_SECONDS = METRICS.histogram(
+    "trino_tpu_query_phase_seconds",
+    "Wall time of the spans of a served query, by span name (root "
+    "phases submit..finish; device_execute, jit_trace, host_read and "
+    "scan_fill under execute)", ("phase",), buckets=PHASE_BUCKETS)
+DEVICE_PROGRAMS = METRICS.counter(
+    "trino_tpu_device_programs_total",
+    "Device programs dispatched by traced queries, by the cache (and "
+    "role) the program lives in", ("kind",))
+HOST_READS = METRICS.counter(
+    "trino_tpu_host_reads_total",
+    "Blocking device-to-host reads of the executor in traced queries, "
+    "by call site", ("site",))
+SCAN_FILL_SECONDS = METRICS.histogram(
+    "trino_tpu_scan_fill_seconds",
+    "Scan-cache miss path: reading or generating a split's missing "
+    "lanes and pinning them on the device", buckets=PHASE_BUCKETS)
+
+
+from .trace import PHASES as _PHASE_NAMES  # noqa: E402
+
+_PHASES = frozenset(_PHASE_NAMES)
+
+
+_LABELS: Dict[object, Tuple[str]] = {}
+
+
+def _label_key(v: object) -> Tuple[str]:
+    """The one-label key of ``v``, with no space in it; remembered,
+    since sites and kinds are a small fixed set."""
+    key = _LABELS.get(v)
+    if key is None:
+        key = _LABELS[v] = (str(v).replace(" ", "_") or "none",)
+    return key
+
+
+def observe_span(sp) -> None:
+    """The ``QueryTrace.on_close`` hook (set where a trace is born):
+    a closed span of a known phase becomes one histogram observation,
+    and one program / host-read count."""
+    name = sp.name
+    if name not in _PHASES:
+        return
+    wall = sp.wall_s
+    QUERY_PHASE_SECONDS.observe_at((name,), wall)
+    if name == "host_read":
+        HOST_READS.inc_at(_label_key(sp.attrs.get("site", "other")))
+    elif name in ("device_execute", "jit_trace"):
+        program = str(sp.attrs.get("program")
+                      or sp.attrs.get("cache") or "other")
+        DEVICE_PROGRAMS.inc_at(_label_key(program.split(":", 1)[0]))
+    elif name == "scan_fill":
+        SCAN_FILL_SECONDS.observe_at((), wall)
 
 
 def write_exposition(handler) -> None:

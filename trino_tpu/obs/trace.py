@@ -16,8 +16,32 @@ merged tree is one distributed trace, not a clock-rebased collage.
 On a tensor runtime the jit_trace/device_execute split is the headline
 number — compilation/dispatch dominates latency (PAPERS.md "Query
 Processing on Tensor Computation Runtimes"), and a wall-clock total
-cannot show it; ``device_ms`` attribution on those spans is what
-EXPLAIN ANALYZE rolls up per stage.
+cannot show it; ``device_ms`` on those spans is the HOST clock from
+dispatch to outputs ready — an upper bound on the program's device
+time, not a device measurement — and is what EXPLAIN ANALYZE rolls up
+per stage.
+
+The span list of a served query (ROOT spans, in order; ``PHASES``):
+``submit`` (POST body read -> tracker.submit returns), ``queued``
+(submit -> the query thread's first line; opened on the HTTP thread,
+closed on the query thread), ``parse``, ``plan``, ``optimize``,
+``execute``, ``fetch`` (device-to-host fetch of the result rows),
+``persist`` (restart-recovery spool), ``finish`` (terminal bookkeeping
+after the client is released) on the query thread, and ``respond``
+(payload + JSON + socket write, one per POST or poll that carries data
+or the terminal state) on the HTTP thread. Under ``execute``:
+``device_execute`` / ``jit_trace`` per dispatched program (attrs
+``program=<kind>:<key8>``, ``cache``, ``device_ms``), ``host_read``
+(attr ``site``) per blocking device-to-host read of the executor, and
+``scan_fill`` (attrs ``table``, ``lanes``) per scan-cache miss.
+
+One clock: every span opened through ``span()`` also enters a
+``jax.profiler.TraceAnnotation("tpusql:<name>", query_id=, span_id=)``
+— under a profiler session the span is IN the device trace, on the
+profiler's clock; without one a TraceMe is a flag test. The
+``time.time_ns()`` anchors stay (OTLP needs them). ``on_close`` is the
+ONE hook through which closed spans feed the counters at ``/metrics``
+(obs/metrics.py ``observe_span``), set where the trace is born.
 
 Concurrency: the open-span stack is a per-thread structure
 (``threading.local``), so a span opened on a fragment-dispatch thread
@@ -31,10 +55,48 @@ list appends, which concurrent threads do hit.
 from __future__ import annotations
 
 import os
+import random
 import threading
 import time
+from contextlib import nullcontext
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation as _Annotation
+
+# the fixed set of span names that feed trino_tpu_query_phase_seconds
+# (no spaces: the label value is read back by line-oriented scrapers)
+ROOT_PHASES = ("submit", "queued", "parse", "plan", "optimize",
+               "execute", "fetch", "persist", "respond", "finish")
+EXECUTE_PHASES = ("device_execute", "jit_trace", "host_read",
+                  "scan_fill")
+PHASES = ROOT_PHASES + EXECUTE_PHASES
+ANNOTATION_PREFIX = "tpusql:"
+
+# the traces with a span open on THIS thread, innermost last: lets
+# code without a Session in reach (the scan cache, the connectors'
+# device generators) attach spans to the query it is running for
+_ACTIVE = threading.local()
+
+
+def _active_stack() -> list:
+    st = getattr(_ACTIVE, "stack", None)
+    if st is None:
+        st = _ACTIVE.stack = []  # tt-lint: ignore[race-attr-write] threading.local attribute: each thread writes its OWN slot
+    return st
+
+
+def active_trace() -> Optional["QueryTrace"]:
+    """The trace whose span is innermost open on the calling thread."""
+    st = getattr(_ACTIVE, "stack", None)
+    return st[-1] if st else None
+
+
+def active_span(name: str, **attrs):
+    """``span(name)`` on the calling thread's active trace; a no-op
+    context outside a traced query."""
+    tr = active_trace()
+    return tr.span(name, **attrs) if tr is not None else nullcontext()
 
 
 def new_trace_id() -> str:
@@ -42,9 +104,17 @@ def new_trace_id() -> str:
     return os.urandom(16).hex()
 
 
+# span ids come from a process-local generator seeded once from the
+# OS: os.urandom per span is a system call that RELEASES THE GIL, and a
+# query thread that opens a span would hand the interpreter to whatever
+# thread waits for it (measured: the HTTP thread answered the client
+# before the query thread's terminal bookkeeping had begun)
+_SPAN_IDS = random.Random(os.urandom(16))
+
+
 def new_span_id() -> str:
     """64-bit W3C span id (16 lowercase hex chars)."""
-    return os.urandom(8).hex()
+    return f"{_SPAN_IDS.getrandbits(64):016x}"
 
 
 def format_traceparent(trace_id: str, span_id: str) -> str:
@@ -90,6 +160,10 @@ class Span:
     # a clock-preserving one
     start_unix_ns: Optional[int] = None
     end_unix_ns: Optional[int] = None
+    # the open profiler annotation (None for spans that were recorded
+    # already timed, grafted, or closed on another thread)
+    _ann: Optional[object] = field(default=None, repr=False,
+                                   compare=False)
 
     @property
     def wall_s(self) -> float:
@@ -153,15 +227,23 @@ class QueryTrace:
 
     def __init__(self, query_id: str = "",
                  trace_id: Optional[str] = None,
-                 parent_span_id: Optional[str] = None):
+                 parent_span_id: Optional[str] = None,
+                 on_close: Optional[Callable[[Span], None]] = None,
+                 origin_s: Optional[float] = None):
         self.query_id = query_id
         self.trace_id = trace_id or new_trace_id()
         # the REMOTE parent: root spans opened here carry it as their
         # parentSpanId, which is what makes the coordinator-side merge
         # id-preserving instead of positional
         self.parent_span_id = parent_span_id
-        self.origin_s = time.perf_counter()
-        self.origin_unix_ns = time.time_ns()
+        # called with every span that closes or is recorded here: the
+        # one place where spans feed counters (no second set of timers)
+        self.on_close = on_close
+        now_s, now_ns = time.perf_counter(), time.time_ns()
+        # ``origin_s``: a perf_counter reading BEFORE the trace existed
+        # (the POST's arrival) that the first span is back-dated to
+        self.origin_s = now_s if origin_s is None else origin_s
+        self.origin_unix_ns = now_ns - int((now_s - self.origin_s) * 1e9)
         self.roots: List[Span] = []
         self._tls = threading.local()   # per-thread open-span stack
         self._lock = threading.Lock()
@@ -192,30 +274,79 @@ class QueryTrace:
 
     # -- structured construction --------------------------------------
     def span(self, name: str, parent: Optional[Span] = None,
+             root: bool = False, start_s: Optional[float] = None,
              **attrs) -> "_SpanCtx":
-        return _SpanCtx(self, name, attrs, parent)
+        """``root=True`` opens a ROOT span whatever the calling thread
+        has open (the explicit form of "no parent"); ``start_s``
+        back-dates the span to a perf_counter reading taken before it
+        could be opened."""
+        return _SpanCtx(self, name, attrs, parent, root, start_s)
+
+    def begin(self, name: str, **attrs) -> Span:
+        """Open a ROOT span that ANOTHER thread will ``end``: it joins
+        no thread's stack and carries no profiler annotation (a TraceMe
+        begins and ends on one thread)."""
+        return self._open(name, attrs, root=True, push=False)
+
+    def end(self, sp: Span) -> None:
+        self._close(sp)
 
     def _open(self, name: str, attrs: Dict[str, object],
-              parent: Optional[Span] = None) -> Span:
+              parent: Optional[Span] = None, root: bool = False,
+              start_s: Optional[float] = None,
+              push: bool = True) -> Span:
         sp = Span(name, time.perf_counter(), attrs=dict(attrs))
         sp.start_unix_ns = time.time_ns()
+        if start_s is not None:
+            sp.start_unix_ns -= int((sp.start_s - start_s) * 1e9)
+            sp.start_s = start_s
         stack = self._stack()
-        if parent is None:
+        if parent is None and not root:
             parent = stack[-1] if stack else None
         if parent is None and self.parent_span_id:
             sp.parent_id = self.parent_span_id
         with self._lock:
             (parent.children if parent is not None
              else self.roots).append(sp)
-        stack.append(sp)
+        if push:
+            stack.append(sp)
+            _active_stack().append(self)
+            # on the profiler's clock too (a flag test without a
+            # profiler session)
+            sp._ann = _Annotation(ANNOTATION_PREFIX + name,
+                                  query_id=self.query_id,
+                                  span_id=sp.span_id)
+            sp._ann.__enter__()
         return sp
 
-    def _close(self, sp: Span) -> None:
+    def _close(self, sp: Span, dropped: bool = False) -> None:
         sp.end_s = time.perf_counter()
         sp.end_unix_ns = time.time_ns()
+        if sp._ann is not None:
+            sp._ann.__exit__(None, None, None)
+            sp._ann = None
         stack = self._stack()
         if stack and stack[-1] is sp:
             stack.pop()
+            active = _active_stack()
+            if active and active[-1] is self:
+                active.pop()
+        if dropped:
+            # a root span that turned out to time nothing worth
+            # keeping (a poll that carried neither data nor the
+            # terminal state): it leaves no trace and feeds no counter
+            with self._lock:
+                if sp in self.roots:
+                    self.roots.remove(sp)
+            return
+        self._closed(sp)
+
+    def _closed(self, sp: Span) -> None:
+        if self.on_close is not None:
+            try:
+                self.on_close(sp)
+            except Exception:   # noqa: BLE001 — telemetry never fails
+                pass            # the query it describes
 
     def current(self) -> Optional[Span]:
         stack = self._stack()
@@ -243,6 +374,7 @@ class QueryTrace:
         with self._lock:
             (parent.children if parent is not None
              else self.roots).append(sp)
+        self._closed(sp)
         return sp
 
     def graft(self, parent: Optional[Span], spans: List[dict],
@@ -314,31 +446,53 @@ class QueryTrace:
         return out
 
 
+def dispatch_span(trace: Optional[QueryTrace], program: str,
+                  hit: bool = True, cache: Optional[str] = None):
+    """The span of ONE device-program dispatch — the single helper
+    behind every dispatch, so each is a span and a count:
+    ``device_execute`` (``hit``) or ``jit_trace`` (first call: trace +
+    compile + run) with ``program=<kind>:<key8>``. ``trace=None``
+    falls back to the calling thread's active trace; outside a traced
+    query it is a no-op context (``as`` yields None)."""
+    if trace is None:
+        trace = active_trace()
+    if trace is None:
+        return nullcontext()
+    return trace.span("device_execute" if hit else "jit_trace",
+                      cache=cache or program.split(":", 1)[0],
+                      program=program)
+
+
 def null_span(name: str, **attrs):
     """Drop-in for ``QueryTrace.span`` when no trace is installed —
     callers write ``sp = trace.span if trace else null_span`` and keep
     one code path."""
-    from contextlib import nullcontext
     return nullcontext()
 
 
 class _SpanCtx:
-    __slots__ = ("_trace", "_name", "_attrs", "_parent", "span")
+    __slots__ = ("_trace", "_name", "_attrs", "_parent", "_root",
+                 "_start_s", "span", "dropped")
 
     def __init__(self, trace: QueryTrace, name: str, attrs,
-                 parent: Optional[Span] = None):
+                 parent: Optional[Span] = None, root: bool = False,
+                 start_s: Optional[float] = None):
         self._trace = trace
         self._name = name
         self._attrs = attrs
         self._parent = parent
+        self._root = root
+        self._start_s = start_s
         self.span: Optional[Span] = None
+        self.dropped = False    # set inside the block: see _close
 
     def __enter__(self) -> Span:
         self.span = self._trace._open(self._name, self._attrs,
-                                      self._parent)
+                                      self._parent, self._root,
+                                      self._start_s)
         return self.span
 
     def __exit__(self, exc_type, exc, tb) -> None:
         if exc_type is not None and self.span is not None:
-            self.span.attrs.setdefault("error", exc_type.__name__)
-        self._trace._close(self.span)
+            self.span.attrs.setdefault("error", exc_type.__name__)  # tt-lint: ignore[race-attr-mutate] an open span belongs to the thread that opened it; readers render it after close
+        self._trace._close(self.span, self.dropped)

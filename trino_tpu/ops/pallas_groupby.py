@@ -99,6 +99,7 @@ def _grouped_sums_impl(gid: jax.Array, vals: jax.Array,
         out_shape=jax.ShapeDtypeStruct((nblocks, k, G_PAD),
                                        jnp.float32),
         interpret=interpret,
+        name="grouped_sums",    # the kernel's name in the device trace
     )(gid.reshape(1, cap), vals)
     return jnp.sum(partials.astype(jnp.float64), axis=0)
 
@@ -134,8 +135,16 @@ def grouped_sums(gid: jax.Array, lanes: Sequence[jax.Array],
     while len(cols) < k8:
         cols.append(jnp.zeros_like(cols[0]))
     vals = jnp.stack(cols, axis=0)       # [K8, cap] f32
-    sums = _grouped_sums_impl(jnp.asarray(gid, jnp.int32), vals,
-                              interpret)
+    if isinstance(vals, jax.core.Tracer):
+        # inside a cached program's trace: part of THAT dispatch
+        sums = _grouped_sums_impl(jnp.asarray(gid, jnp.int32), vals,
+                                  interpret)
+    else:
+        # called eagerly: a dispatch of its own, so a span and a count
+        from ..obs.trace import dispatch_span
+        with dispatch_span(None, "grouped_sums:kernel"):
+            sums = _grouped_sums_impl(jnp.asarray(gid, jnp.int32),
+                                      vals, interpret)
     return [sums[i, :nseg] * (s / 4096.0)
             + sums[i + 1, :nseg] * (s / 16777216.0)
             + sums[i + 2, :nseg]

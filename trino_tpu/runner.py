@@ -110,16 +110,14 @@ class LocalQueryRunner:
 
     # ------------------------------------------------------------------
     def execute(self, sql: str) -> QueryResult:
+        from .obs import adopt_or_mint
         from .obs.metrics import (QUERY_PEAK_MEMORY_BYTES,
                                   QUERY_WALL_SECONDS)
-        from .obs.trace import QueryTrace
         t0 = time.perf_counter()
-        # tracing rides with stats collection: it is cheap but not
-        # free (a span per jitted dispatch), so the no-telemetry path
-        # must stay trace-less for _jit_call's early return to matter
-        trace = QueryTrace() if self.collect_node_stats else None
-        sp = trace.span if trace is not None else null_span
         prev_trace = self.session.trace
+        trace, adopted = adopt_or_mint(self.session,
+                                       self.collect_node_stats)
+        sp = trace.span if trace is not None else null_span
         self.session.trace = trace
         # deadline derivation for standalone runs: the coordinator's
         # tracker stamps session.deadline before dispatch; a runner
@@ -144,7 +142,7 @@ class LocalQueryRunner:
             # fresh runner-local id per query
             qid = self.session.query_id or self.session.next_query_id()
             self.session.query_id = qid
-            if trace is not None:
+            if trace is not None and not adopted:
                 trace.query_id = qid
             try:
                 result = self._dispatch(stmt, sql)
@@ -163,7 +161,7 @@ class LocalQueryRunner:
             QUERY_WALL_SECONDS.observe(time.perf_counter() - t0)
             # OTLP export (obs/otlp.py): best-effort, sink-configured
             # — in the finally so failed queries' traces export too
-            if trace is not None and trace.roots:
+            if trace is not None and not adopted and trace.roots:
                 from .obs.otlp import maybe_export
                 maybe_export(trace, session=self.session)
         result.query_id = qid
@@ -393,10 +391,12 @@ class LocalQueryRunner:
         ex = self._make_executor(collect_stats)
         with sp("execute"):
             batch = ex.execute(plan)
-        schema = batch.schema()
-        types = [schema[s] for s in plan.symbols]
-        rows = batch.to_pylist()
-        result = QueryResult(list(plan.names), types, rows)
+        with sp("fetch"):
+            # the device-to-host fetch of the result rows
+            schema = batch.schema()
+            types = [schema[s] for s in plan.symbols]
+            rows = batch.to_pylist()
+            result = QueryResult(list(plan.names), types, rows)
         # the rendering /v1/query/{id} serves — captured HERE so the
         # detail endpoint never re-plans the query (and never silently
         # diverges from what actually ran)
@@ -434,8 +434,9 @@ class LocalQueryRunner:
             trace = self.session.trace
             owned = trace is None
             if owned:
-                from .obs.trace import QueryTrace
-                trace = QueryTrace(self.session.query_id)
+                from .obs import adopt_or_mint
+                trace, _ = adopt_or_mint(self.session, True,
+                                         self.session.query_id)
                 self.session.trace = trace
             try:
                 res = self._run_query(inner, collect_stats=True)

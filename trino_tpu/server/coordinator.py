@@ -28,6 +28,7 @@ from typing import Dict, List, Optional
 from urllib.parse import urlparse
 
 from ..obs.metrics import METRICS
+from ..obs.trace import null_span
 from ..runner import LocalQueryRunner, QueryResult
 from ..session import Session
 
@@ -75,10 +76,12 @@ class _Query:
     ended: Optional[float] = None     # set at terminal transition
     source: str = ""
     group: Optional[object] = None   # assigned ResourceGroup
-    # True when admission actually queued this query (gates the
-    # post-hoc "queued" span: an immediately-admitted query's span
-    # tree stays parse/plan/optimize/execute)
-    admission_queued: bool = False
+    # the query's span tree (obs/trace.py), born in QueryTracker.submit
+    # and carried on the Session for the runner to adopt; ``queued`` is
+    # its live root span from registration to the query thread's first
+    # line, opened on the submitting thread, closed on the query thread
+    trace: Optional[object] = None
+    queued_span: Optional[object] = None
     # monotonic submit stamp: query_max_run_time budgets the WHOLE
     # run including queued time (the reference's QUERY_MAX_RUN_TIME,
     # as opposed to max_execution_time), so the deadline anchors here
@@ -105,6 +108,7 @@ class _Query:
         # the executor polls this event between plan nodes, so cancel
         # actually interrupts execution rather than just flipping state
         self.session.cancel = self._cancel  # tt-lint: ignore[race-attr-write] run-thread setup; only this thread's executor reads session.cancel
+        sp = self.trace.span if self.trace is not None else null_span
         try:
             runner = runner_factory(self.session)
             result = runner.execute(self.sql)
@@ -117,7 +121,8 @@ class _Query:
                 # a cancel landed — a CANCELED query's results must
                 # never become recoverable-as-FINISHED.
                 try:
-                    persisted = bool(on_result(self, result))
+                    with sp("persist"):
+                        persisted = bool(on_result(self, result))
                 except Exception:        # noqa: BLE001 — best-effort
                     pass
             if self._transition("FINISHED"):
@@ -240,23 +245,44 @@ class QueryTracker:
         self.history_sink = history_sink
 
     def submit(self, sql: str, session: Session,
-               source: str = "") -> _Query:
+               source: str = "",
+               received_s: Optional[float] = None) -> _Query:
+        """``received_s``: the perf_counter reading at which the
+        request arrived (before its body was read); the ``submit``
+        span is back-dated to it."""
+        from ..obs.metrics import observe_span
+        from ..obs.trace import QueryTrace
         from .events import QueryCreatedEvent
         qid = (time.strftime("%Y%m%d_%H%M%S") +
                f"_{next(self._counter):05d}_{self._instance}")
-        q = _Query(qid, uuid.uuid4().hex[:16], sql, session)
-        q.source = source
-        # stamp the session so the executor's split-completion path and
-        # the trace spans carry the coordinator query id and can fan
-        # out SplitCompletedEvents through this tracker's listeners
-        session.query_id = qid
-        session.events = self.events
-        with self._lock:
-            self._queries[qid] = q
-        _M_STATES.inc(state="QUEUED")
-        self.events.query_created(QueryCreatedEvent(
-            qid, sql, session.user, session.catalog, session.schema))
-        self._arm_deadline(q, session)
+        # the query's trace is born HERE, before admission, and rides
+        # the Session: the runner adopts it, so one tree holds the
+        # whole served life of the query (submit, queued, the runner's
+        # parse..fetch, persist, respond, finish), and closed spans
+        # feed the phase counters through the one hook
+        trace = QueryTrace(qid, on_close=observe_span,
+                           origin_s=received_s)
+        session.trace = trace
+        with trace.span("submit", start_s=received_s):
+            q = _Query(qid, uuid.uuid4().hex[:16], sql, session)
+            q.source = source
+            q.trace = trace
+            # stamp the session so the executor's split-completion path
+            # and the trace spans carry the coordinator query id and
+            # can fan out SplitCompletedEvents through this tracker's
+            # listeners
+            session.query_id = qid
+            session.events = self.events
+            with self._lock:
+                self._queries[qid] = q
+            _M_STATES.inc(state="QUEUED")
+            self.events.query_created(QueryCreatedEvent(
+                qid, sql, session.user, session.catalog,
+                session.schema))
+            self._arm_deadline(q, session)
+        # group selection, the admission wait and the thread's start
+        # are all ``queued``: it ends at run_and_release's first line
+        q.queued_span = trace.begin("queued")
         self._launch(q, session, source)
         return q
 
@@ -338,6 +364,12 @@ class QueryTracker:
         qid = q.query_id
 
         def run_and_release():
+            tr = q.trace
+            sp = tr.span if tr is not None else null_span
+            if q.queued_span is not None:
+                q.queued_span.attrs["group"] = getattr(
+                    q.group, "full_name", "")
+                tr.end(q.queued_span)
             if q.started is None:
                 # resumed queries arrive with the ORIGINAL admission
                 # stamp from the manifest — queued/elapsed accounting
@@ -385,75 +417,75 @@ class QueryTracker:
                 q.run(runner_factory or self._make_runner,
                       on_result=persist, on_discard=discard)
             finally:
-                if q.deadline_timer is not None:
-                    q.deadline_timer.cancel()
-                if self.manifests is not None:
-                    # terminal in ANY state: the execution manifest
-                    # exists only to let another coordinator finish a
-                    # RUNNING query — once this one reached a verdict
-                    # the manifest must not outlive it. The spooled
-                    # RESULT (fragment -1) survives; release_fragment
-                    # drops only f-2.
-                    self.manifests.release(qid)
-                if self.memory is not None:
-                    self.memory.unregister(qid)
-                    session.memory = None
-                if q.group is not None and self.groups is not None:
-                    self.groups.query_finished(q.group)
-                # queue-wait span: grafted post-hoc (the trace is born
-                # inside the runner, after dequeue) so /v1/query shows
-                # admission latency next to parse/plan/execute
-                queued_s = ((q.started or q.created) - q.created)
-                tr = getattr(q.result, "trace", None) \
-                    if q.result is not None else None
-                if tr is not None and q.admission_queued \
-                        and queued_s > 0:
-                    tr.record("queued", tr.origin_s - queued_s,
-                              tr.origin_s, group=getattr(
-                                  q.group, "full_name", ""))
-                _M_STATES.inc(state=q.state)
-                if self.results is not None:
-                    try:
-                        # ride-along TTL sweep (time-gated internally):
-                        # clients don't DELETE fully-drained queries,
-                        # so without this the persisted results of
-                        # retry_policy=NONE queries — whose dispatch
-                        # path never touches the spool — would pile up
-                        # forever
-                        self.results.spool.maybe_cleanup()
-                    except Exception:    # noqa: BLE001
-                        pass
-                r = q.result
-                stats = (getattr(r, "stats", None) or []) if r else []
-                cum = None
-                if stats:
-                    cum = {
-                        "input_rows": sum(max(s.input_rows, 0)
-                                          for s in stats),
-                        "output_rows": sum(max(s.output_rows, 0)
-                                           for s in stats),
-                        "output_bytes": sum(max(s.output_bytes, 0)
-                                            for s in stats),
-                        "compile_s": sum(s.compile_s for s in stats),
-                        "wall_s": sum(s.wall_s for s in stats),
-                    }
-                self.events.query_completed(QueryCompletedEvent(
-                    q.query_id, q.sql, q.session.user, q.state,
-                    time.time() - q.created,
-                    rows=len(r.rows) if r else 0,
-                    error_name=(q.error or {}).get("errorName"),
-                    error_message=(q.error or {}).get("message"),
-                    peak_memory_bytes=getattr(
-                        r, "peak_memory_bytes", 0) if r else 0,
-                    spill_bytes=getattr(r, "spill_bytes", 0) if r else 0,
-                    cumulative_operator_stats=cum,
-                    operator_summaries=tuple(
-                        s.to_dict() for s in stats)))
-                if self.history_sink is not None:
-                    try:
-                        self.history_sink(q)
-                    except Exception:    # noqa: BLE001 — history is
-                        pass             # best-effort bookkeeping
+                # everything below runs AFTER the client was released
+                # (q.run set _done): on this thread, under the GIL,
+                # while the next query of a closed loop is already
+                # being submitted — the ``finish`` span
+                with sp("finish"):
+                    if q.deadline_timer is not None:
+                        q.deadline_timer.cancel()
+                    if self.manifests is not None:
+                        # terminal in ANY state: the execution manifest
+                        # exists only to let another coordinator finish a
+                        # RUNNING query — once this one reached a verdict
+                        # the manifest must not outlive it. The spooled
+                        # RESULT (fragment -1) survives; release_fragment
+                        # drops only f-2.
+                        self.manifests.release(qid)
+                    if self.memory is not None:
+                        self.memory.unregister(qid)
+                        session.memory = None
+                    if q.group is not None and self.groups is not None:
+                        self.groups.query_finished(q.group)
+                    _M_STATES.inc(state=q.state)
+                    if self.results is not None:
+                        try:
+                            # ride-along TTL sweep (time-gated internally):
+                            # clients don't DELETE fully-drained queries,
+                            # so without this the persisted results of
+                            # retry_policy=NONE queries — whose dispatch
+                            # path never touches the spool — would pile up
+                            # forever
+                            self.results.spool.maybe_cleanup()
+                        except Exception:    # noqa: BLE001
+                            pass
+                    r = q.result
+                    stats = (getattr(r, "stats", None) or []) if r else []
+                    cum = None
+                    if stats:
+                        cum = {
+                            "input_rows": sum(max(s.input_rows, 0)
+                                              for s in stats),
+                            "output_rows": sum(max(s.output_rows, 0)
+                                               for s in stats),
+                            "output_bytes": sum(max(s.output_bytes, 0)
+                                                for s in stats),
+                            "compile_s": sum(s.compile_s for s in stats),
+                            "wall_s": sum(s.wall_s for s in stats),
+                        }
+                    self.events.query_completed(QueryCompletedEvent(
+                        q.query_id, q.sql, q.session.user, q.state,
+                        time.time() - q.created,
+                        rows=len(r.rows) if r else 0,
+                        error_name=(q.error or {}).get("errorName"),
+                        error_message=(q.error or {}).get("message"),
+                        peak_memory_bytes=getattr(
+                            r, "peak_memory_bytes", 0) if r else 0,
+                        spill_bytes=getattr(r, "spill_bytes", 0) if r else 0,
+                        cumulative_operator_stats=cum,
+                        operator_summaries=tuple(
+                            s.to_dict() for s in stats)))
+                    if self.history_sink is not None:
+                        try:
+                            self.history_sink(q)
+                        except Exception:    # noqa: BLE001 — history is
+                            pass             # best-effort bookkeeping
+                if tr is not None:
+                    # the trace's owner exports it (obs/otlp.py), once
+                    # the query thread's last span has closed; a
+                    # ``respond`` span of a later poll is not in it
+                    from ..obs.otlp import maybe_export
+                    maybe_export(tr, session=session)
 
         def start(group=None):
             # the group is recorded BEFORE the thread exists so a
@@ -483,9 +515,11 @@ class QueryTracker:
             try:
                 _, started_now = self.groups.submit(
                     session.user, source, start, tag=qid)
-                if not started_now:
-                    q.admission_queued = True
+                if not started_now and q.queued_span is not None:
+                    q.queued_span.attrs["admission"] = "queued"
             except QueryQueueFullError as e:
+                if q.queued_span is not None:
+                    q.trace.end(q.queued_span)
                 # protocol-correct rejection: the Trino error name with
                 # ITS code and INSUFFICIENT_RESOURCES type (was a
                 # hand-typed — and wrong — literal code), flowing to
@@ -1429,6 +1463,24 @@ def _make_handler(co: Coordinator):
             self.end_headers()
             self.wfile.write(body)
 
+        def _respond(self, q, token: int):
+            """One page of the statement protocol under the query's
+            ``respond`` ROOT span (this is the HTTP thread: ``root=``
+            says so explicitly): payload, JSON and the socket write.
+            A poll that carries neither data nor the terminal state
+            (the query still runs: ``nextUri`` alone) is dropped from
+            the trace and feeds no counter."""
+            tr = getattr(q, "trace", None)
+            if tr is None:
+                self._send(200, co.query_results(q, token))
+                return
+            ctx = tr.span("respond", root=True, token=token)
+            with ctx:
+                payload = co.query_results(q, token)
+                self._send(200, payload)
+                ctx.dropped = ("nextUri" in payload
+                               and "data" not in payload)
+
         def _send_html(self, body: str):
             raw = body.encode()
             self.send_response(200)
@@ -1498,6 +1550,7 @@ def _make_handler(co: Coordinator):
                 return
             path = urlparse(self.path).path
             if path == "/v1/statement":
+                received_s = time.perf_counter()
                 n = int(self.headers.get("Content-Length", 0))
                 sql = self.rfile.read(n).decode()
                 session = Session(
@@ -1525,7 +1578,8 @@ def _make_handler(co: Coordinator):
                 try:
                     q = co.tracker.submit(
                         sql, session,
-                        source=self.headers.get("X-Trino-Source", ""))
+                        source=self.headers.get("X-Trino-Source", ""),
+                        received_s=received_s)
                 except Exception as e:   # noqa: BLE001 — a submission
                     # failure outside the tracked-query machinery
                     # (selector bug, bad session property) must answer
@@ -1539,7 +1593,7 @@ def _make_handler(co: Coordinator):
                                   "errorType": etype}})
                     return
                 q.wait_done(0.05)   # fast queries answer immediately
-                self._send(200, co.query_results(q, 0))
+                self._respond(q, 0)
                 return
             if path == "/v1/announcement":
                 # worker join (discovery-service announcement analog);
@@ -1749,7 +1803,7 @@ def _make_handler(co: Coordinator):
                     self._send(404, {"error": "no such query"})
                     return
                 q.wait_done(1.0)   # long-poll like the reference
-                self._send(200, co.query_results(q, int(parts[5])))
+                self._respond(q, int(parts[5]))
                 return
             self._send(404, {"error": "not found"})
 

@@ -126,14 +126,19 @@ def partition_buckets(batch: Batch, keys: Sequence[str],
     JIT_CACHE_LOOKUPS.inc(cache="repartition",
                           result="hit" if hit else "miss")
     if jitted is None:
-        jitted = jax.jit(make_bucket_program(len(keys), nparts))
+        from ..exec.progkey import named_jit
+        jitted = named_jit(make_bucket_program(len(keys), nparts),
+                           "repartition", key)
         _ex._cache_put(_BUCKET_JIT_CACHE, key, jitted)
     record_program(
         "repartition", key, None, None, session,
         payload_fn=lambda: {"kind": "repartition",
                             "nkeys": len(keys), "capacity": cap,
                             "nparts": int(nparts)})
-    bk = jitted(tuple(lanes), tuple(valids))
+    from ..obs.trace import dispatch_span
+    with dispatch_span(getattr(session, "trace", None),
+                       jitted.program, hit):
+        bk = jitted(tuple(lanes), tuple(valids))
     return np.asarray(bk)[:n]
 
 
