@@ -14,6 +14,7 @@ without one), and there are no child processes.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -123,13 +124,44 @@ def test_hash_join_expand_program_compiles(one_chip,
 
 def test_hash_join_count_program_compiles(one_chip,
                                           no_persistent_cache):
-    """Phase 1 (build-side sort on the 64-bit key lane + probe match
-    counts). The probe side is q3's at sf1; the build side is CUT to
-    2^12 rows: the sorting network's compile time grows with its size
-    (46 s at 2^20, PERF.md) and this file has to stay fast. What the
-    compiler accepts does not depend on the size."""
+    """Phase 1: the build side sorted on its 64-bit key lane and
+    indexed (a scatter-add and two scans: the bucket directory and the
+    run lengths), then the probe (ops/join.py probe_runs: two
+    directory gathers, ONE bisection loop whose trip count is a device
+    value, one run-length gather). The probe side is q3's at sf1; the
+    build side is CUT to 2^12 rows: the sorting network's compile time
+    grows with its size (40 s at 2^20, PERF.md) and this file has to
+    stay fast. What the compiler accepts does not depend on the
+    size."""
     from trino_tpu.exec.executor import make_mjoin_count_program
     probe, build = _q3_join_sides()
     fn = make_mjoin_count_program(["l_orderkey"], ["o_orderkey"], False)
-    jax.jit(fn).lower(_as_structs(probe, 1 << 22, one_chip),
-                      _as_structs(build, 1 << 12, one_chip)).compile()
+    text = jax.jit(fn).lower(
+        _as_structs(probe, 1 << 22, one_chip),
+        _as_structs(build, 1 << 12, one_chip)).compile().as_text()
+    # one loop, not the two full-depth searches it replaced
+    assert len(re.findall(r" while\(", text)) == 1
+
+
+def test_streamed_join_probe_program_compiles(one_chip,
+                                              no_persistent_cache):
+    """The streamed join's per-chunk program (exec/streamjoin.py): the
+    same probe against a build side sorted and indexed ONCE outside it,
+    plus the expansion at a static capacity. Build 2^20 (nothing is
+    sorted in here, so it is not cut), chunk and output 2^16: the
+    expansion's compile time grows with them (43 s at 2^20, before
+    and after the directory)."""
+    from trino_tpu.exec.streamjoin import make_probe_program
+    from trino_tpu.ops.join import build_side
+    probe, build = _q3_join_sides()
+    cap = 1 << 16
+    bstructs = _as_structs(build, 1 << 20, one_chip)
+    side = jax.tree.map(
+        lambda a: _struct(a.shape, a.dtype, one_chip),
+        jax.eval_shape(lambda b: build_side(b, ["o_orderkey"]),
+                       bstructs))
+    fn = make_probe_program("inner", ["l_orderkey"], ["o_orderkey"],
+                            None, cap)
+    compiled = jax.jit(fn).lower(_as_structs(probe, cap, one_chip),
+                                 bstructs, side).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 30

@@ -8,10 +8,12 @@ etc.), but asserting against plain-python recomputation.
 import numpy as np
 import pytest
 
-from trino_tpu.columnar import Batch, batch_from_pylist, concat_batches
+from trino_tpu.columnar import (Batch, Column, batch_from_pylist,
+                                concat_batches)
 from trino_tpu.ops.compact import filter_batch, limit_batch, offset_batch
 from trino_tpu.ops.groupby import (AggInput, global_aggregate,
                                    group_aggregate)
+from trino_tpu.ops import join as join_ops
 from trino_tpu.ops.join import (cross_counts, expand_join, match_counts,
                                 semi_join_mask)
 from trino_tpu.ops.sort import SortKey, sort_batch, topn_batch
@@ -163,6 +165,135 @@ def _join(probe, build, pk, bk, join_type="inner", prefix="b_"):
     cap = max(8, 1 << max(0, (total - 1).bit_length()))
     return expand_join(probe, build, start, count, order, cap,
                        join_type, prefix)
+
+
+def _keys(cap, keys, null_at=(), rows=None, more=None):
+    """A one- or two-key BIGINT batch of ``cap`` rows' capacity."""
+    def col(vals):
+        data = np.zeros(cap, np.int64)
+        data[:len(vals)] = vals
+        valid = None
+        if len(null_at):
+            valid = np.ones(cap, bool)
+            valid[list(null_at)] = False
+        return Column(BIGINT, jnp.asarray(data),
+                      None if valid is None else jnp.asarray(valid))
+    cols = {"k": col(keys)}
+    if more is not None:
+        cols["k2"] = col(more)
+    return Batch(cols, len(keys) if rows is None else rows)
+
+
+def _probe_unique(rng):
+    # 2^16 distinct keys under a bijective hash: a uniform lane, so the
+    # directory leaves a handful of entries a bucket
+    cap = 1 << 16
+    build = rng.permutation(1 << 20)[:cap]
+    return (_keys(cap, rng.choice(build, cap)), _keys(cap, build),
+            lambda steps, m: steps <= 6)
+
+
+def _probe_one_key(rng):
+    # every build row the same key: one bucket holds them all and the
+    # search degrades to the full bisection, still exact
+    cap = 1 << 10
+    return (_keys(cap, rng.integers(5, 9, cap)),
+            _keys(cap, np.full(cap, 7)),
+            lambda steps, m: steps == 11)      # log2(cap) + 1
+
+
+def _probe_lineitem(rng):
+    # a build side with 1-7 rows a key, probed by its own distinct keys
+    orders = rng.permutation(1 << 16)[:3000]
+    build = np.repeat(orders, rng.integers(1, 8, orders.size))
+    return (_keys(1 << 12, orders), _keys(1 << 14, build[:1 << 14]),
+            lambda steps, m: 3 <= steps <= 7)
+
+
+def _probe_dead_build(rng):
+    return (_keys(64, rng.integers(0, 50, 64)), _keys(32, [], rows=0),
+            lambda steps, m: steps == 0 and m == 0)
+
+
+def _probe_null_keys(rng):
+    return (_keys(64, rng.integers(0, 40, 60), null_at=(0, 7, 59)),
+            _keys(128, rng.integers(0, 40, 100), null_at=(3, 4, 99)),
+            lambda steps, m: m == 97)
+
+
+def _probe_absent_keys(rng):
+    return (_keys(256, rng.integers(1000, 2000, 256)),
+            _keys(256, rng.integers(0, 1000, 200)),
+            lambda steps, m: steps <= 4)
+
+
+def _probe_smaller(rng):
+    return (_keys(8, rng.integers(0, 300, 8)),
+            _keys(1 << 12, rng.integers(0, 300, 4000)),
+            lambda steps, m: m == 4000)
+
+
+def _probe_larger(rng):
+    return (_keys(1 << 14, rng.integers(0, 300, 1 << 14)),
+            _keys(16, rng.integers(0, 300, 11)),
+            lambda steps, m: steps <= 4)
+
+
+def _probe_u64max_lane(rng):
+    # key 0 becomes the lane U64MAX, which dead build rows carry too:
+    # they must count into no run (see the patched mix64 below)
+    return (_keys(32, [0, 1, 2, 0, 5]), _keys(32, [0, 3, 0, 1, 0, 2]),
+            lambda steps, m: m == 6)
+
+
+def _probe_constant_hash(rng):
+    # two key columns under a combined hash that is ONE value: every
+    # row's lane is equal, each probe row counts the whole build side
+    a, b = rng.integers(0, 9, (2, 100))
+    return (_keys(64, a[:50], more=b[:50]), _keys(128, a, more=b),
+            lambda steps, m: steps == 7)       # bit_length(100)
+
+
+@pytest.mark.parametrize("case", [
+    _probe_unique, _probe_one_key, _probe_lineitem, _probe_dead_build,
+    _probe_null_keys, _probe_absent_keys, _probe_smaller, _probe_larger,
+    _probe_u64max_lane, _probe_constant_hash],
+    ids=lambda c: c.__name__[7:])
+def test_join_probe_equals_searchsorted(case, monkeypatch):
+    """The probe (bucket directory, bounded bisection, run lengths)
+    against numpy: ``left`` and ``count`` are what ``searchsorted``
+    left and right give on the sorted usable build lanes, whatever the
+    lane's distribution; the steps counter keeps its bound."""
+    if case is _probe_u64max_lane:
+        monkeypatch.setattr(
+            join_ops, "mix64", lambda x: ~jnp.asarray(x).astype(jnp.uint64))
+    if case is _probe_constant_hash:
+        monkeypatch.setattr(join_ops, "combine_hashes",
+                            lambda hs: jnp.zeros_like(hs[0]) + 7)
+    probe, build, steps_ok = case(np.random.default_rng(27))
+    keys = list(build.columns)
+    left, count, side = join_ops.match_runs(probe, build, keys, keys)
+    start, count2, order = match_counts(probe, build, keys, keys)
+
+    lane_b, usable_b = map(np.asarray, join_ops.equality_lane(build, keys))
+    lane_p, usable_p = map(np.asarray, join_ops.equality_lane(probe, keys))
+    m = int(usable_b.sum())
+    want_sorted = np.full(build.capacity, np.uint64(2**64 - 1))
+    want_sorted[:m] = np.sort(lane_b[usable_b])
+    lo = np.minimum(np.searchsorted(want_sorted, lane_p, "left"), m)
+    hi = np.minimum(np.searchsorted(want_sorted, lane_p, "right"), m)
+
+    assert int(side.m) == m
+    assert np.array_equal(np.asarray(side.sorted_lane), want_sorted)
+    assert np.array_equal(lane_b[np.asarray(order)[:m]], want_sorted[:m])
+    assert np.array_equal(np.asarray(left), lo)
+    assert np.array_equal(np.asarray(count), np.where(usable_p, hi - lo, 0))
+    assert np.array_equal(np.asarray(start), lo)
+    assert np.array_equal(np.asarray(count2), np.asarray(count))
+    assert left.dtype == count.dtype == jnp.int64
+    assert steps_ok(int(side.steps), m), (int(side.steps), m)
+    if case is _probe_u64max_lane:
+        assert list(np.asarray(count)[:5]) == [3, 1, 1, 3, 0]
 
 
 def test_inner_join():

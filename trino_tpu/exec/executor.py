@@ -1498,8 +1498,8 @@ class Executor:
     def _mjoin_counts(self, probe: Batch, build: Batch, pkeys, bkeys,
                       outer: bool):
         """Jitted count phase of the materialized join. Returns
-        (start, count, order, total) device arrays, or None on decline
-        — the caller runs ops/join.py eagerly."""
+        (start, count, order, [total, steps]) device arrays, or None
+        on decline — the caller runs ops/join.py eagerly."""
         if not (self.fragment_jit
                 and self._mjoin_jittable(probe, build)):
             return None
@@ -1519,6 +1519,16 @@ class Executor:
             _MJOIN_JIT_CACHE.pop(key, None)
             _MJOIN_JIT_DENY.add(key)
             return None
+
+    def _read_join_total(self, tail) -> int:
+        """The count program's one host read: its output total, and
+        beside it the steps its probe took (``steps`` on the span,
+        counted at /metrics: obs/metrics.py observe_span)."""
+        with self._host_read("join_total") as sp:
+            total, steps = (int(v) for v in np.asarray(tail))
+            if sp is not None:
+                sp.attrs["steps"] = steps
+        return total
 
     def _mjoin_expand(self, probe: Batch, build: Batch, start, count,
                       order, jt: str, residual, out_cap: int,
@@ -1604,10 +1614,9 @@ class Executor:
             counted = self._mjoin_counts(left, right, pkeys, bkeys,
                                          outer)
             if counted is not None:
-                start, count, order, total_dev = counted
+                start, count, order, tail = counted
                 eff = None      # only the oversized path needs it
-                with self._host_read("join_total"):
-                    total = int(total_dev)
+                total = self._read_join_total(tail)
             else:
                 start, count, order = join_ops.match_counts(
                     left, right, pkeys, bkeys)
@@ -1651,9 +1660,8 @@ class Executor:
         build = self._with_pos(right, _BPOS) if jt == "full" else right
         counted = self._mjoin_counts(probe, build, pkeys, bkeys, False)
         if counted is not None:
-            start, count, order, total_dev = counted
-            with self._host_read("join_total"):
-                total = int(total_dev)
+            start, count, order, tail = counted
+            total = self._read_join_total(tail)
         else:
             start, count, order = join_ops.match_counts(
                 probe, build, pkeys, bkeys)
@@ -2104,7 +2112,7 @@ def make_stream_runners(helper: "Executor", chain, node):
 # sync, the expansion runs at a static capacity bucket. Each phase is
 # therefore one traceable program; jitting them separately keeps the
 # host-side total/bucket decision OUT of the traced code while every
-# device op (lane hashing, searchsorted, gather expansion, residual
+# device op (lane hashing, directory probe, gather expansion, residual
 # filtering) fuses. Builders are module-level so exec/aot.py rebuilds
 # the EXACT closures the executor caches (progkey doctrine: one key
 # per program, shared by the live path and the pre-warmer).
@@ -2128,15 +2136,17 @@ def mjoin_kind(key: tuple) -> str:
 
 
 def make_mjoin_count_program(pkeys, bkeys, outer: bool):
-    """Phase 1: build-side sort + probe match counts + the effective
-    output total. Everything downstream of the total is host policy
-    (bucket choice, memory reserve, oversized spill), so the program
-    ends exactly at the host-sync boundary. Output dtypes are pinned
-    int64 — they cross into the separately-jitted expand program."""
+    """Phase 1: build-side sort and index + probe match counts + the
+    effective output total. Everything downstream of the total is host
+    policy (bucket choice, memory reserve, oversized spill), so the
+    program ends exactly at the host-sync boundary: ONE int64[2],
+    [total, the probe's bisection steps], read in one transfer
+    (``_read_join_total``). Output dtypes are pinned int64 — they
+    cross into the separately-jitted expand program."""
     pkeys, bkeys = list(pkeys), list(bkeys)
 
     def fn(probe: Batch, build: Batch):
-        start, count, order = join_ops.match_counts(
+        start, count, side = join_ops.match_runs(
             probe, build, pkeys, bkeys)
         if outer:
             eff = jnp.where(probe.row_valid(),
@@ -2144,7 +2154,8 @@ def make_mjoin_count_program(pkeys, bkeys, outer: bool):
         else:
             eff = count
         return (start.astype(jnp.int64), count.astype(jnp.int64),
-                order.astype(jnp.int64), jnp.sum(eff))
+                side.order.astype(jnp.int64),
+                jnp.stack([jnp.sum(eff), side.steps.astype(jnp.int64)]))
 
     return fn
 

@@ -623,10 +623,10 @@ _PPOS = "__probe_pos$"
 
 def make_probe_program(jt: str, pkeys: Sequence[str],
                        bkeys: Sequence[str], residual, out_cap: int):
-    """The per-chunk probe kernel: searchsorted match counts against
-    the prebuilt sorted build lane + output expansion at a STATIC
-    capacity, fused into one traceable function -> every chunk of one
-    streamed join runs the same compiled program. Returns
+    """The per-chunk probe kernel: match counts against the prebuilt,
+    indexed build side (ops/join.py probe_runs) + output expansion at
+    a STATIC capacity, fused into one traceable function -> every
+    chunk of one streamed join runs the same compiled program. Returns
     (out_batch, total_matches) — the total is the overflow signal the
     host checks (a chunk whose matches exceed ``out_cap`` reruns
     through a grown program). Module-level so exec/aot.py rebuilds the
@@ -636,13 +636,10 @@ def make_probe_program(jt: str, pkeys: Sequence[str],
     pkeys = list(pkeys)
     outer = jt == "left"
 
-    def fn(chunk: Batch, build: Batch, sorted_lane, order, m):
+    def fn(chunk: Batch, build: Batch, side: join_ops.BuildSide):
         lane_p, usable_p = join_ops.equality_lane(chunk, pkeys)
-        left = jnp.minimum(
-            jnp.searchsorted(sorted_lane, lane_p, side="left"), m)
-        right = jnp.minimum(
-            jnp.searchsorted(sorted_lane, lane_p, side="right"), m)
-        count = jnp.where(usable_p, right - left, 0)
+        left, count = join_ops.probe_runs(side, lane_p, usable_p)
+        order = side.order
         if residual is None:
             live_p = chunk.row_valid()
             eff = (jnp.where(live_p, jnp.maximum(count, 1), 0)
@@ -761,10 +758,9 @@ def aot_entry(payload: dict):
                          "capacity": build_cap,
                          "num_rows": payload.get("build_num_rows",
                                                  "int")}, bschema)
-    sorted_lane = jax.ShapeDtypeStruct((build_cap,), np.dtype(np.uint64))
-    order = jax.ShapeDtypeStruct((build_cap,), np.dtype(np.int64))
-    m = jax.ShapeDtypeStruct((), np.dtype(np.int64))
-    return key, fn, (chunk, build, sorted_lane, order, m)
+    from ..ops import join as join_ops
+    side = jax.eval_shape(lambda b: join_ops.build_side(b, bkeys), build)
+    return key, fn, (chunk, build, side)
 
 
 class _StreamDictEncoder:
@@ -884,23 +880,22 @@ def maybe_stream_join(ex, node: JoinNode
     residual = _verify_filter_types(pschema, bschema, pkeys, bkeys,
                                     node.filter)
 
-    # build once: the engine's hash table is the sorted key lane +
-    # permutation of ops/join.py (HashBuilderOperator's table, HBM-
-    # resident for the whole stream)
+    # build once: the engine's hash table is the sorted key lane, its
+    # permutation and its int32 directory and run lengths (ops/join.py
+    # build_side: HashBuilderOperator's table, HBM-resident for the
+    # whole stream)
     from ..ops import join as join_ops
     from .executor import _col_bytes, _host_concat, _to_host
     build = ex.execute(node.right)
     build_bytes = sum(_col_bytes(c) for c in build.columns.values()) \
-        + 2 * build.capacity * 8
+        + 3 * build.capacity * 8
     # the exact engagement rule: stream iff the probe does not fit in
     # what the budget leaves after the (capacity-rounded) build state
     # — the materialized path would hold probe + build concurrently
     if forced <= 0 and (est is None
                         or est <= max(budget - build_bytes, 0)):
         return None, build
-    sorted_lane, order, m = join_ops.build_side(build, bkeys)
-    order = order.astype(jnp.int64)
-    m = m.astype(jnp.int64)
+    side = join_ops.build_side(build, bkeys)
     # probe-side canonical dictionaries: key columns seed from the
     # BUILD dictionary so remapped probe codes compare directly
     # against the sorted build key lane just computed
@@ -964,7 +959,7 @@ def maybe_stream_join(ex, node: JoinNode
         if state["probe_spec"] is None:
             state["probe_spec"] = _lane_spec(probe_chunk)
         jitted, key, eager = program()
-        args = (probe_chunk, build, sorted_lane, order, m)
+        args = (probe_chunk, build, side)
         if eager:                   # deny/fallback path
             return jitted(*args)
         try:
@@ -1037,7 +1032,7 @@ def maybe_stream_join(ex, node: JoinNode
         chunk0 = chain_run(_h2d(empty_batch(
             {s: scan.schema[s] for s in scan.assignments})))
         z = jnp.zeros((chunk0.capacity,), jnp.int64)
-        out = join_ops.expand_join(chunk0, build, z, z, order,
+        out = join_ops.expand_join(chunk0, build, z, z, side.order,
                                    8, "inner")
         return _to_host(out, 0), None
     return _host_concat(outs, total_rows), None

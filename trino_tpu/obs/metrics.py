@@ -555,6 +555,17 @@ HOST_READS = METRICS.counter(
     "trino_tpu_host_reads_total",
     "Blocking device-to-host reads of the executor in traced queries, "
     "by call site", ("site",))
+JOIN_PROBES = METRICS.counter(
+    "trino_tpu_join_probes_total",
+    "Join probes whose step count traced queries read, by the host "
+    "read that carried it (join_total: the count program of a "
+    "materialized join)", ("site",))
+JOIN_SEARCH_STEPS = METRICS.counter(
+    "trino_tpu_join_search_steps_total",
+    "Bisection steps those probes took inside their directory buckets "
+    "(ops/join.py probe_runs): over the probes, 3-4 where the "
+    "directory engages, log2(build capacity)+1 where one key fills a "
+    "bucket", ("site",))
 SCAN_FILL_SECONDS = METRICS.histogram(
     "trino_tpu_scan_fill_seconds",
     "Scan-cache miss path: reading or generating a split's missing "
@@ -588,7 +599,12 @@ def observe_span(sp) -> None:
     wall = sp.wall_s
     QUERY_PHASE_SECONDS.observe_at((name,), wall)
     if name == "host_read":
-        HOST_READS.inc_at(_label_key(sp.attrs.get("site", "other")))
+        site = _label_key(sp.attrs.get("site", "other"))
+        HOST_READS.inc_at(site)
+        steps = sp.attrs.get("steps")
+        if steps is not None:
+            JOIN_PROBES.inc_at(site)
+            JOIN_SEARCH_STEPS.inc_at(site, steps)
     elif name in ("device_execute", "jit_trace"):
         program = str(sp.attrs.get("program")
                       or sp.attrs.get("cache") or "other")

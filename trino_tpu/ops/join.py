@@ -4,7 +4,7 @@ Reference parity: operator/HashBuilderOperator.java:51 (build side),
 operator/LookupJoinOperator.java:71 + JoinProbe (probe loop),
 NestedLoopJoinOperator, HashSemiJoinOperator. Redesign for XLA
 (SURVEY.md §7.3): the serial open-addressing probe becomes a vectorized
-sort + binary-search join:
+sort + bucket-directory join:
 
 1. build keys are reduced to a single uint64 equality lane (bijective
    splitmix64 for one integer key column — exact; multi-column and
@@ -13,9 +13,16 @@ sort + binary-search join:
    "hard parts"; string keys are first remapped onto a dictionary
    merged across both sides so codes are comparable),
 2. the build side is sorted by that lane (nulls/dead rows forced past the
-   valid prefix), and
-3. every probe row finds its match run via two ``searchsorted`` calls —
-   O(log n) per row, all rows in parallel on the VPU.
+   valid prefix) and indexed ONCE, at O(build capacity): a bucket
+   directory over the lane's top bits (one bucket per build row: the
+   lane is a hash, so a bucket holds a handful of entries) and the
+   length of the run of equal lanes that starts at each position, and
+3. every probe row reads its bucket's bounds from the directory,
+   bisects inside the bucket for the first entry >= its lane
+   (``probe_runs``: as many steps as the FULLEST bucket needs, a device
+   value — 3-4 for a uniform lane, log2(capacity)+1 when one key fills
+   a bucket) and reads its count from the run lengths. A gather costs
+   the chip per element, so the steps are what a probe costs.
 
 Output cardinality is data-dependent: callers run ``match_counts`` first,
 read the total on the host, pick a power-of-two capacity bucket, then run
@@ -25,7 +32,7 @@ Trino's incremental JoinProbe yielding pages).
 
 from __future__ import annotations
 
-from typing import List, Optional, Sequence, Tuple
+from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -76,10 +83,22 @@ def equality_lane(batch: Batch, key_names: Sequence[str]) -> Tuple[
     return lane, usable
 
 
-def build_side(batch: Batch, key_names: Sequence[str]):
-    """Sort the build side by key lane. Returns (sorted_keys, perm, m)
-    where the first m entries are usable sorted keys and the tail is
-    forced to U64MAX."""
+class BuildSide(NamedTuple):
+    """The sorted build side and the index a probe row finds its run
+    of equal lanes with (``probe_runs``)."""
+    sorted_lane: jax.Array  # uint64[cap]: m usable lanes ascending, then U64MAX
+    order: jax.Array        # sorted position -> build row
+    m: jax.Array            # usable build rows
+    directory: jax.Array    # int32[D+1]: first position in [0, m] whose
+    #                         lane's top log2(D) bits are >= b
+    run_len: jax.Array      # int32[cap]: entries of [i, m) equal to entry i
+    steps: jax.Array        # int32: bisection steps the fullest bucket needs
+
+
+def build_side(batch: Batch, key_names: Sequence[str]) -> BuildSide:
+    """Sort the build side by key lane and index it. The first m
+    entries of ``sorted_lane`` are the usable keys, the tail is forced
+    to U64MAX (and counted into no bucket and no run)."""
     lane, usable = equality_lane(batch, key_names)
     cap = batch.capacity
     if all(batch.column(k).valid is None for k in key_names):
@@ -94,26 +113,95 @@ def build_side(batch: Batch, key_names: Sequence[str]):
     else:
         order = stable_lexsort([(~usable).astype(jnp.int32), lane])
     m = jnp.sum(usable.astype(jnp.int64))
-    pos = jnp.arange(cap, dtype=jnp.int64)
-    sorted_lane = jnp.where(pos < m, jnp.take(lane, order), _U64MAX)
-    return sorted_lane, order, m
+    pos = jnp.arange(cap, dtype=jnp.int32)
+    live = pos < m
+    sorted_lane = jnp.where(live, jnp.take(lane, order), _U64MAX)
+
+    # directory, one bucket a build row: a histogram of the live
+    # entries' top bits, summed up. Never a search of the D boundaries,
+    # which would cost what the directory saves.
+    bits = max(1, (cap - 1).bit_length())
+    top = (sorted_lane >> jnp.uint64(64 - bits)).astype(jnp.int32)
+    directory = jnp.cumsum(
+        jnp.zeros(((1 << bits) + 1,), jnp.int32).at[top + 1].add(
+            live.astype(jnp.int32), indices_are_sorted=True,
+            mode="promise_in_bounds"))
+    # a lower-bound bisection over n entries takes bit_length(n) steps
+    fullest = jnp.max(directory[1:] - directory[:-1])
+    steps = jnp.sum((fullest >> jnp.arange(31, dtype=jnp.int32)) > 0,
+                    dtype=jnp.int32)
+
+    # run lengths: entry i's run ends at the first position after it
+    # that differs from the one before, or is dead (m at the latest)
+    after = pos + 1
+    ends = jnp.concatenate([sorted_lane[1:] != sorted_lane[:-1],
+                            jnp.ones((1,), bool)]) | (after >= m)
+    run_len = jnp.where(
+        live, jax.lax.cummin(jnp.where(ends, after, cap), reverse=True)
+        - pos, 0)
+    return BuildSide(sorted_lane, order, m, directory, run_len, steps)
+
+
+def _at(lane, index):
+    # a gather of indices known to be inside the lane: no clamp, no fill
+    return lane.at[index].get(mode="promise_in_bounds")
+
+
+@jax.jit
+def probe_runs(side: BuildSide, lane_p, usable_p):
+    """(left, count) per probe row: ``left`` is the first position of
+    the sorted build lane that is >= the row's lane (clipped to m),
+    ``count`` the entries equal to it — what ``searchsorted`` left and
+    right gave, exactly, for every lane whatever its distribution. The
+    ONE probe of the engine: joins, semi joins and the streamed probe
+    (exec/streamjoin.py) all come through here. Jitted, as
+    ``searchsorted`` is: an eager caller reuses one program a shape."""
+    bits = (side.directory.shape[0] - 1).bit_length() - 1
+    last = side.sorted_lane.shape[0] - 1
+    top = (lane_p >> jnp.uint64(64 - bits)).astype(jnp.int32)
+
+    def step(_, state):
+        # lower bound on [lo, hi); ``hit`` is whether the entry ``hi``
+        # was last moved onto equals the probe lane: where the search
+        # ends inside the bucket, that entry is the answer, so no
+        # gather is needed to check it
+        lo, hi, hit = state
+        mid = (lo + hi) >> 1
+        v = _at(side.sorted_lane, jnp.minimum(mid, last))
+        open_ = lo < hi
+        right = open_ & (v < lane_p)
+        down = open_ & ~right
+        return (jnp.where(right, mid + 1, lo), jnp.where(down, mid, hi),
+                jnp.where(down, v == lane_p, hit))
+
+    left, _, hit = jax.lax.fori_loop(
+        0, side.steps, step,
+        (_at(side.directory, top), _at(side.directory, top + 1),
+         jnp.zeros(lane_p.shape, bool)))
+    count = jnp.where(hit & usable_p,
+                      _at(side.run_len, jnp.minimum(left, last)), 0)
+    return left.astype(jnp.int64), count.astype(jnp.int64)
+
+
+def match_runs(probe: Batch, build: Batch,
+               probe_keys: Sequence[str], build_keys: Sequence[str]):
+    """(left, count, side): ``match_counts`` with the whole build side."""
+    probe, build = align_string_keys(probe, build, probe_keys, build_keys)
+    lane_p, usable_p = equality_lane(probe, probe_keys)
+    side = build_side(build, build_keys)
+    left, count = probe_runs(side, lane_p, usable_p)
+    return left, count, side
 
 
 def match_counts(probe: Batch, build: Batch,
                  probe_keys: Sequence[str], build_keys: Sequence[str]):
-    """Per-probe-row (start, count) of the build match run + total rows.
+    """Per-probe-row (start, count) of the build match run + the build
+    permutation.
 
     start indexes the *sorted* build order; map through perm for payload.
     """
-    probe, build = align_string_keys(probe, build, probe_keys, build_keys)
-    lane_p, usable_p = equality_lane(probe, probe_keys)
-    sorted_lane, order, m = build_side(build, build_keys)
-    left = jnp.searchsorted(sorted_lane, lane_p, side="left")
-    right = jnp.searchsorted(sorted_lane, lane_p, side="right")
-    left = jnp.minimum(left, m)
-    right = jnp.minimum(right, m)
-    count = jnp.where(usable_p, right - left, 0)
-    return left, count, order
+    left, count, side = match_runs(probe, build, probe_keys, build_keys)
+    return left, count, side.order
 
 
 def expand_join(probe: Batch, build: Batch, start, count, order,
@@ -163,12 +251,9 @@ def semi_join_mask(probe: Batch, build: Batch, probe_keys: Sequence[str],
     build-side null yields NULL, else TRUE/FALSE)."""
     probe, build = align_string_keys(probe, build, probe_keys, build_keys)
     lane_p, usable_p = equality_lane(probe, probe_keys)
-    sorted_lane, order, m = build_side(build, build_keys)
-    left = jnp.minimum(jnp.searchsorted(sorted_lane, lane_p, "left"), m)
-    right = jnp.minimum(jnp.searchsorted(sorted_lane, lane_p, "right"), m)
-    matched = (right > left) & usable_p
-    live_p = probe.row_valid()
-    key_null = live_p & ~usable_p
+    _, count = probe_runs(build_side(build, build_keys), lane_p, usable_p)
+    matched = count > 0
+    key_null = probe.row_valid() & ~usable_p
 
     live_b = build.row_valid()
     any_null_key = jnp.zeros((), dtype=bool)
