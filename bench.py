@@ -601,7 +601,7 @@ def _leg_fault(iters: int) -> dict:
 def _mpp_ici_subleg(sql: str, nrows: int) -> dict:
     """ICI-native exchange mini-leg: the SAME stage DAG, executed on a
     4-virtual-device mesh with the hash repartition lowered to
-    jax.lax.all_to_all (stage/ici.py) instead of spool+HTTP frames.
+    jax.lax.all_to_all (parallel/spmd.py) instead of spool+HTTP frames.
     Runs in a grandchild process because the virtual-device XLA flag
     must be set before jax imports (and must not perturb the other
     legs' single-device baseline)."""
@@ -612,7 +612,7 @@ def _mpp_ici_subleg(sql: str, nrows: int) -> dict:
         "sql = os.environ['BENCH_MPP_SQL']\n"
         "r = LocalQueryRunner(distributed=True, n_devices=4)\n"
         "r.execute(sql)\n"
-        "b = METRICS.counter('trino_tpu_exchange_ici_bytes_total')\n"
+        "b = METRICS.counter('trino_tpu_mesh_exchange_bytes_total')\n"
         "b0 = sum(v for _, v in b.samples())\n"
         "t0 = time.perf_counter(); r.execute(sql)\n"
         "wall = time.perf_counter() - t0\n"

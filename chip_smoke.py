@@ -4,7 +4,9 @@ the chip.
 
 One process, which alone touches JAX. It starts a coordinator the way
 ``trino_tpu/server/main.py`` does (default catalogs, no workers, so
-queries execute in this process on the default device), talks to it
+queries execute in this process: on the one chip of a one-chip
+machine; four chips are the benchmark cell ``tpch_sf10_mesh4.power``'s,
+not this script's), talks to it
 over HTTP with ``trino_tpu.client.StatementClient``, serves TPC-H q6,
 q1 and q3 on ``tpch.sf1`` twice each (cold = with compile, warm), and
 compares every result with a plain numpy computation over rows from the
@@ -13,9 +15,6 @@ dates, 1e-9 relative for float sums).
 
     python chip_smoke.py                 one chip (what the driver runs)
     python chip_smoke.py --sf10          ... plus q1 on tpch.sf10
-    python chip_smoke.py --chips 4       the mesh path only: q1 and q3
-                                         served over a 4-device mesh and
-                                         from a coordinator without it
     JAX_PLATFORMS=cpu TRINO_TPU_PALLAS=interpret TRINO_TPU_FRAGMENT_JIT=1 \\
     TRINO_TPU_WHOLE_TABLE=1 TRINO_TPU_DEVICE_GEN=1 \\
         python chip_smoke.py --rehearse --scale tiny
@@ -280,11 +279,10 @@ def rebuild_native_pageserde() -> bool:
     return bool(serde.native_available())
 
 
-def start_coordinator(distributed: bool = False):
+def start_coordinator():
     from trino_tpu.server.coordinator import Coordinator
     from trino_tpu.server.main import build_catalogs
-    return Coordinator(port=0, distributed=distributed,
-                       catalogs=build_catalogs(None, [])).start()
+    return Coordinator(port=0, catalogs=build_catalogs(None, [])).start()
 
 
 # --------------------------------------------------------------------------
@@ -350,75 +348,11 @@ def run_one_chip(args, counters: Counters, failures: list) -> None:
     co.stop()
 
 
-def run_mesh(args, counters: Counters, failures: list) -> None:
-    """The four-chip phase and what it is compared with, nothing else:
-    q1 and q3 served by Coordinator(distributed=True) over every
-    device, the same two from a coordinator without the mesh."""
-    import jax
-    from trino_tpu.benchmarks.tpch_queries import TPCH_QUERIES
-    from trino_tpu.client import StatementClient
-    from trino_tpu.exec import distributed as dist
-
-    if len(jax.devices()) != args.chips:
-        raise SystemExit(f"--chips {args.chips} needs exactly "
-                         f"{args.chips} devices, JAX has "
-                         f"{len(jax.devices())}")
-
-    # bytes of one scanned lane per device, read from the script where
-    # the mesh executor places its scans (exec/distributed.py
-    # _dexec_TableScanNode -> parallel/mesh.py shard_parts): the widest
-    # lane of the largest scan seen
-    shard_bytes = {}
-    shard_parts = dist.shard_parts
-
-    def recording_shard_parts(parts, mesh):
-        out = shard_parts(parts, mesh)
-        name, col = max(out.columns.items(),
-                        key=lambda kv: kv[1].data.nbytes)
-        if col.data.nbytes >= shard_bytes.get("lane_bytes", 0):
-            shard_bytes.clear()
-            shard_bytes.update(
-                lane=name, lane_bytes=int(col.data.nbytes),
-                per_device={str(s.device.id): int(s.data.nbytes)
-                            for s in col.data.addressable_shards})
-        return out
-
-    dist.shard_parts = recording_shard_parts
-
-    t0 = time.perf_counter()
-    ref = HostReference(args.scale, want=(1, 3))
-    emit(phase="host_reference", schema=args.scale,
-         lineitem_rows=ref.n_lineitem, seconds=time.perf_counter() - t0)
-
-    results = {}
-    for label, distributed in (("mesh", True), ("single", False)):
-        co = start_coordinator(distributed=distributed)
-        client = StatementClient(co.base_uri, catalog="tpch",
-                                 schema=args.scale, timeout=1800.0)
-        for q in (1, 3):
-            rows, _ = serve(client, f"{label}:q{q}@{args.scale}",
-                            TPCH_QUERIES[q], ref.answer(q), counters)
-            results[label, q] = rows
-        co.stop()
-    for q in (1, 3):
-        compare(f"mesh vs single q{q}", results["mesh", q],
-                results["single", q])
-    emit(phase="mesh_equals_single", queries=["q1", "q3"], ok=True)
-
-    emit(phase="shard_bytes", **shard_bytes)
-    per_dev = shard_bytes.get("per_device", {})
-    if len(per_dev) != args.chips or not all(per_dev.values()):
-        failures.append(f"scanned lane is not spread over "
-                        f"{args.chips} devices: {per_dev}")
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--scale", default="sf1",
                     help="tpch schema to serve (sf1; tiny for the "
                          "CPU rehearsal)")
-    ap.add_argument("--chips", type=int, default=1, choices=(1, 4),
-                    help="4 = run ONLY the mesh phase over four chips")
     ap.add_argument("--sf10", action="store_true",
                     help="one chip: also serve q1 on tpch.sf10")
     ap.add_argument("--rehearse", action="store_true",
@@ -444,10 +378,7 @@ def main(argv=None) -> int:
 
     counters = Counters()
     failures: list = []
-    if args.chips == 1:
-        run_one_chip(args, counters, failures)
-    else:
-        run_mesh(args, counters, failures)
+    run_one_chip(args, counters, failures)
 
     if args.rehearse:
         emit(phase="rehearsal_done", failed_checks=failures)

@@ -165,3 +165,53 @@ def test_streamed_join_probe_program_compiles(one_chip,
     compiled = jax.jit(fn).lower(_as_structs(probe, cap, one_chip),
                                  bstructs, side).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 30
+
+
+@pytest.mark.parametrize("kind", ["counts", "move"])
+def test_mesh_exchange_compiles_for_a_2x2_mesh(topo, no_persistent_cache,
+                                               kind):
+    """The mesh executor's hash repartition (parallel/spmd.py) over the
+    described 2x2 mesh, a shard of 2^20 rows of q3's lineitem lanes (an
+    int64 key and two float64: 64-bit lanes cross the chips through
+    ``all_to_all``): phase 1 counts rows per (source, destination);
+    phase 2 bins them with running counts and one int32 scatter, sends
+    sized buffers and lays the received runs end to end — no sort and
+    no ``nonzero`` at this size (PR 23: a sort costs this compiler
+    minutes)."""
+    from jax import shard_map
+    from jax.sharding import Mesh, NamedSharding
+    from trino_tpu import BIGINT, DOUBLE, batch_from_pylist
+    from trino_tpu.parallel import spmd
+    P, AXIS = spmd.P, spmd.AXIS
+    n, per = 4, 1 << 20
+    mesh = Mesh(np.asarray(topo.devices[:n]), (AXIS,))
+    cols = batch_from_pylist(
+        {"k": [1, 2], "a": [1.0, 2.0], "b": [1.0, 2.0]},
+        {"k": BIGINT, "a": DOUBLE, "b": DOUBLE}).columns
+    rows = NamedSharding(mesh, P(AXIS))
+    args = (jax.tree.map(lambda a: _struct((n * per,),
+                                           jnp.asarray(a).dtype, rows),
+                         cols),
+            _struct((n,), jnp.int64, NamedSharding(mesh, P())))
+
+    def counts(c, nvec):
+        my_n = nvec[jax.lax.axis_index(AXIS)]
+        live = jnp.arange(per, dtype=jnp.int64) < my_n
+        return jax.lax.all_gather(spmd._dest_counts(
+            spmd._hash_pid(c, ["k"], n), live, n), AXIS)
+
+    def move(c, nvec):
+        my_n = nvec[jax.lax.axis_index(AXIS)]
+        out, new_n = spmd._shard_exchange(
+            c, my_n, spmd._hash_pid(c, ["k"], n), n, per // 2, per)
+        return out, jax.lax.all_gather(new_n, AXIS)
+
+    in_specs = (spmd._col_specs(cols, P(AXIS)), P())
+    f, out_specs = ((counts, P()) if kind == "counts" else
+                    (move, (spmd._col_specs(cols, P(AXIS)), P())))
+    text = jax.jit(shard_map(f, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
+                   ).lower(*args).compile().as_text()
+    assert " sort(" not in text
+    if kind == "move":
+        assert "all-to-all" in text

@@ -96,9 +96,8 @@ def test_multistage_execution_gates_the_stage_fragmenter():
     sched.session.set("multistage_execution", False)
     assert not sched._multistage_enabled()
     assert int(sched.session.get("exchange_partition_count")) == 0
-    # the pipelining + ICI knobs ship default-on next to it
+    # the pipelining knob ships default-on next to it
     assert sched.session.get("stage_pipelining") is True
-    assert sched.session.get("ici_exchange") is True
 
 
 def test_unknown_property_rejected():

@@ -680,23 +680,20 @@ def test_pipelining_matches_barrier_and_overlaps(workers):
 
 
 # --------------------------------------------------------------------------
-# ICI-native exchange: the stage DAG on the device mesh
+# the mesh executor's exchanges are device collectives, not spool frames
 # --------------------------------------------------------------------------
 
-@pytest.mark.slow      # heaviest tier-1 test (~90s); the ici_exchange
-# escape-hatch test below keeps the ICI plumbing tier-1
-def test_ici_stage_execution_matches_local():
-    """The in-slice unification: LocalQueryRunner(distributed=True)
-    routes fragmentable plans through the SAME stage DAG with the hash
-    repartition lowered to jax.lax.all_to_all (stage/ici.py) — results
-    equal the local engine and the ICI byte counter moves while the
-    spool counter does not."""
+@pytest.mark.slow      # ~90s on 8 virtual devices; the nation x customer
+# test below keeps the mesh exchange counters tier-1
+def test_mesh_join_matches_local_and_moves_no_spool_frame():
+    """LocalQueryRunner(distributed=True) runs the plan node by node
+    over the mesh with all_to_all / all_gather exchanges — results
+    equal the local engine and the spool counter does not move."""
     sql = ("SELECT o_orderpriority, count(*), sum(l_extendedprice) "
            "FROM lineitem JOIN orders ON l_orderkey = o_orderkey "
            "GROUP BY o_orderpriority ORDER BY o_orderpriority")
     loc = LocalQueryRunner(
         session=Session(catalog="tpch", schema="tiny")).execute(sql)
-    ici_b = _counter("trino_tpu_exchange_ici_bytes_total")
     spool_b = _counter("trino_tpu_exchange_partition_bytes_total")
     dist = LocalQueryRunner(distributed=True, n_devices=8,
                             session=Session(catalog="tpch",
@@ -705,26 +702,29 @@ def test_ici_stage_execution_matches_local():
     for d, l in zip(dist.rows, loc.rows):
         assert d[0] == l[0] and d[1] == l[1]
         assert d[2] == pytest.approx(l[2], rel=1e-9)
-    assert _counter("trino_tpu_exchange_ici_bytes_total") > ici_b
     assert _counter(
         "trino_tpu_exchange_partition_bytes_total") == spool_b
 
 
-def test_ici_exchange_off_keeps_node_path():
-    """The escape hatch: ici_exchange=false keeps the node-at-a-time
-    distributed executor — same answers, no ICI edge counted."""
-    sql = ("SELECT n_name, count(*) FROM nation "
-           "JOIN customer ON c_nationkey = n_nationkey "
-           "GROUP BY n_name ORDER BY 1")
+def test_mesh_query_counts_its_exchanges_by_kind():
+    """A traced mesh query's exchanges land in
+    trino_tpu_mesh_exchange_{bytes,rows}_total{kind}: the build side of the
+    join is broadcast to every shard — same answers as the local
+    engine."""
+    sql = ("SELECT o_orderpriority, count(*) FROM orders "
+           "JOIN customer ON o_custkey = c_custkey "
+           "GROUP BY o_orderpriority ORDER BY 1")
     loc = LocalQueryRunner(
         session=Session(catalog="tpch", schema="tiny")).execute(sql)
-    edges = _counter("trino_tpu_exchange_ici_edges_total")
-    s = Session(catalog="tpch", schema="tiny")
-    s.set("ici_exchange", False)
+    before = {k: _counter(f"trino_tpu_mesh_exchange_{k}_total")
+              for k in ("bytes", "rows")}
     dist = LocalQueryRunner(distributed=True, n_devices=8,
-                            session=s).execute(sql)
+                            session=Session(catalog="tpch",
+                                            schema="tiny"),
+                            collect_node_stats=True).execute(sql)
     assert dist.rows == loc.rows
-    assert _counter("trino_tpu_exchange_ici_edges_total") == edges
+    for k, was in before.items():
+        assert _counter(f"trino_tpu_mesh_exchange_{k}_total") > was
 
 
 def test_partition_endpoint_serves_committed_frames():

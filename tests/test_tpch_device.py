@@ -73,3 +73,58 @@ def _run(sql, devgen, monkeypatch):
 def test_engine_results_identical_with_device_generation(monkeypatch,
                                                          sql):
     assert _run(sql, "1", monkeypatch) == _run(sql, "0", monkeypatch)
+
+
+@pytest.mark.parametrize("table,cols,where", [
+    ("lineitem", ["l_orderkey", "l_quantity", "l_extendedprice",
+                  "l_discount", "l_tax", "l_shipdate", "l_returnflag",
+                  "l_linestatus"], ""),
+    ("lineitem", ["l_orderkey", "l_extendedprice", "l_discount"],
+     " where l_shipdate > date '1995-03-15'"),
+    ("lineitem", ["l_extendedprice", "l_discount", "l_shipdate"],
+     " where l_quantity < 24 and l_shipdate >= date '1994-01-01'"),
+    ("orders", ["o_orderkey", "o_custkey", "o_orderdate",
+                "o_shippriority", "o_totalprice", "o_orderstatus"],
+     " where o_orderdate < date '1995-03-15'"),
+    ("orders", ["o_custkey"], ""),
+])
+def test_whole_shards_from_one_program_match_the_host_rows(
+        monkeypatch, table, cols, where):
+    """The mesh executor's scan fill (exec/executor.py
+    ``_generate_sharded`` over tpch_device.ShardGenerator): every shard
+    generated on its own device by two programs, split i on shard
+    i mod n — the same rows, bit for bit and in the same order, as the
+    HOST generator gives split by split with the constraint applied."""
+    import jax
+    from trino_tpu.exec.executor import _fill_sharded
+    from trino_tpu.parallel import get_mesh
+    monkeypatch.setenv("TRINO_TPU_DEVICE_GEN", "0")
+    r = LocalQueryRunner(session=Session(catalog="tpch", schema="tiny"))
+    plan = r.plan_sql(f"select {', '.join(cols)} from {table}{where}")
+    scan = plan
+    while scan.sources:
+        scan = scan.sources[0]
+    # small splits: 15 of lineitem, 4 of orders, so every shard has some
+    conn, h, n = TpchConnector(rows_per_split=1 << 12), scan.handle, 4
+    assert (h.constraint is not None) == bool(where)
+    splits = conn.get_splits(h, n)
+    assert len(splits) >= n
+    want = [[np.concatenate(lanes) for lanes in zip(*[
+        _rows(conn.read_split(sp, cols), cols) for sp in splits[d::n]])]
+        for d in range(n)]
+    monkeypatch.setenv("TRINO_TPU_DEVICE_GEN", "1")
+    sb = _fill_sharded(conn, h, cols, get_mesh(n))
+    counts = np.asarray(sb.num_rows)
+    assert counts.tolist() == [len(w[0]) for w in want]
+    assert sb.per_shard_cap < 2 * max(counts.max(), 8)
+    for i, name in enumerate(cols):
+        col = sb.columns[name]
+        lane = np.asarray(col.data)
+        for d in range(n):
+            got = lane[d * sb.per_shard_cap:][:counts[d]]
+            if col.dictionary is not None:
+                got = col.dictionary.values[got.astype(np.int64)]
+            assert np.array_equal(got, want[d][i]), (name, d)
+        shards = {s.device.id: s.data.shape for s in
+                  col.data.addressable_shards}
+        assert len(shards) == n

@@ -261,12 +261,9 @@ class _ModuleIndex(ast.NodeVisitor):
 # discipline must stay lint-reachable too. streamjoin joined in PR 12:
 # its jitted-program caches are mutated by query threads and the
 # worker pre-warm thread (exec/aot.py streamjoin entries).
-# distributed joined in PR 13: the mesh executor now runs stage DAGs
-# (stage/ici.py calls back into DistributedExecutor) and worker task
-# threads execute its kernels under the unified in-slice path, so its
-# state writes must stay lint-reachable next to the stage/ exchange
-# modules (the ICI exchange path itself lives under stage/, already
-# covered).
+# distributed joined in PR 13: worker task threads execute the mesh
+# executor's kernels, so its state writes must stay lint-reachable
+# next to the stage/ exchange modules.
 _CROSS_CALLEES = ("fte/", "stage/", "obs/metrics.py", "obs/trace.py",
                   "server/failure.py", "server/resourcegroups.py",
                   "server/memory.py", "exec/hotshapes.py",

@@ -63,6 +63,9 @@ _RUNTIME_TABLES = {
         ("node_id", VARCHAR), ("http_uri", VARCHAR),
         ("node_version", VARCHAR), ("coordinator", BOOLEAN),
         ("state", VARCHAR),
+        # chips this node's executor spans: the mesh's size where the
+        # coordinator runs queries over the mesh, else 1
+        ("devices", BIGINT),
     ),
     "resource_groups": (
         ("name", VARCHAR), ("running", BIGINT), ("queued", BIGINT),
@@ -190,7 +193,7 @@ class SystemConnector(Connector):
             rows = [
                 (i.get("nodeId", ""), i.get("uri", ""),
                  i.get("nodeVersion", ""), i.get("coordinator", False),
-                 i.get("state", "active"))
+                 i.get("state", "active"), int(i.get("devices", 1)))
                 for i in self.provider.node_infos()]
         else:
             rows = [

@@ -475,6 +475,28 @@ class TpchConnector(Connector):
             out = out.select_columns(list(columns))
         return out
 
+    @staticmethod
+    def _device_generation() -> bool:
+        import os
+        mode = os.environ.get("TRINO_TPU_DEVICE_GEN", "auto")
+        if mode in ("0", "1"):
+            return mode == "1"
+        import jax
+        return jax.default_backend() != "cpu"
+
+    def shard_generator(self, handle: TableHandle,
+                        columns: Sequence[str]):
+        """The table's rows as two traceable functions of a set of
+        order indices (tpch_device.ShardGenerator), for a scan that
+        fills whole shards of a mesh; None where ``read_split`` has to
+        serve it (host generation, a table or column without a device
+        generator, a pushed-down limit)."""
+        if not self._device_generation():
+            return None
+        from .tpch_device import shard_generator
+        return shard_generator(handle.table, SCHEMAS[handle.schema],
+                               columns, handle.constraint, handle.limit)
+
     def _read_split_device(self, split: Split, sf: float, table: str,
                            handle, gen_cols, columns) -> Optional[Batch]:
         """Generate the split's lanes ON DEVICE when the backend is an
@@ -483,14 +505,8 @@ class TpchConnector(Connector):
         600M sf100 lineitem rows would take minutes on a 1-core host
         before the first byte reaches HBM. Opt out with
         TRINO_TPU_DEVICE_GEN=0 (or force on CPU with =1 for tests)."""
-        import os
-        mode = os.environ.get("TRINO_TPU_DEVICE_GEN", "auto")
-        if mode == "0":
+        if not self._device_generation():
             return None
-        if mode != "1":
-            import jax
-            if jax.default_backend() == "cpu":
-                return None
         from .tpch_device import (device_columns, device_filter,
                                   lineitem_batch, orders_batch)
         allowed = device_columns(table)

@@ -92,9 +92,16 @@ def _hundredths(n: jax.Array) -> jax.Array:
     return jnp.take(jnp.asarray(_HUNDREDTHS), n)
 
 
-def _retailprice(partkey: jax.Array) -> jax.Array:
+# Divisions by 100 take the divisor as an ARGUMENT where they run inside
+# one compiled program (ShardGenerator): a compiler that sees the
+# literal may multiply by its reciprocal, which lands on the other
+# float neighbour for about a seventh of the values; a divisor it
+# cannot see is divided by, as the eager per-split path (one primitive
+# a dispatch) and the host generator do.
+
+def _retailprice(partkey: jax.Array, hundred=100.0) -> jax.Array:
     pk = partkey.astype(jnp.int64)
-    return (90000 + (pk // 10) % 20001 + 100 * (pk % 1000)) / 100.0
+    return (90000 + (pk // 10) % 20001 + 100 * (pk % 1000)) / hundred
 
 
 def _ps_suppkey(partkey: jax.Array, i: jax.Array,
@@ -150,8 +157,16 @@ def _line_grid(lo: int, hi: int):
 def lineitem_batch(lo: int, hi: int, sf: float,
                    columns: List[str]) -> Batch:
     """Device-generated lineitem rows for order indices (lo, hi]."""
+    order_rep, line_no, total, _cap = _line_grid(lo, hi)
+    out = _lineitem_columns(order_rep, line_no, sf, columns)
+    return Batch({c: out[c] for c in columns}, total)
+
+
+def _lineitem_columns(order_rep: jax.Array, line_no: jax.Array, sf: float,
+                      columns, hundred=100.0) -> Dict[str, Column]:
+    """The lanes of the lineitem rows (order index, line number): pure
+    functions of the two, row by row."""
     S = _SEED["lineitem"]
-    order_rep, line_no, total, cap = _line_grid(lo, hi)
     rid = order_rep * 8 + line_no
     p_count = table_rows("part", sf)
     s_count = table_rows("supplier", sf)
@@ -187,7 +202,7 @@ def lineitem_batch(lo: int, hi: int, sf: float,
             out["l_quantity"] = Column(DOUBLE, qty, None)
         if "l_extendedprice" in need:
             out["l_extendedprice"] = Column(
-                DOUBLE, qty * _retailprice(partkey), None)
+                DOUBLE, qty * _retailprice(partkey, hundred), None)
     if "l_discount" in need:
         out["l_discount"] = Column(
             DOUBLE, _hundredths(_randint(S + 5, rid, 0, 10)), None)
@@ -226,7 +241,7 @@ def lineitem_batch(lo: int, hi: int, sf: float,
     if "l_shipmode" in need:
         sm = _randint(S + 22, rid, 0, 6).astype(jnp.int32)
         out["l_shipmode"] = _dict_col(MODES, sm, VarcharType(10))
-    return Batch({c: out[c] for c in columns}, total)
+    return out
 
 
 # --------------------------------------------------------------------------
@@ -236,14 +251,22 @@ def lineitem_batch(lo: int, hi: int, sf: float,
 def orders_batch(lo: int, hi: int, sf: float,
                  columns: List[str]) -> Batch:
     """Device-generated orders rows for order indices (lo, hi]."""
-    S = _SEED["orders"]
-    idx = jnp.arange(lo + 1, hi + 1, dtype=jnp.int64)
     n = hi - lo
     cap = capacity_for(max(n, 1), minimum=8)
-    pad = cap - n
+    out = _orders_columns(jnp.arange(lo + 1, hi + 1, dtype=jnp.int64), sf,
+                          columns, pad=cap - n)
+    return Batch({c: out[c] for c in columns}, n)
+
+
+def _orders_columns(idx: jax.Array, sf: float, columns,
+                    pad: int = 0, hundred=100.0) -> Dict[str, Column]:
+    """The lanes of the orders rows with order indices ``idx``, each
+    padded by ``pad`` rows: pure functions of the index, row by row."""
+    S = _SEED["orders"]
+    n = int(idx.shape[0])
 
     def _padded(a):
-        return jnp.pad(a, (0, pad))
+        return jnp.pad(a, (0, pad)) if pad else a
 
     need = set(columns)
     out: Dict[str, Column] = {}
@@ -265,9 +288,9 @@ def orders_batch(lo: int, hi: int, sf: float,
         rid = o_grid * 8 + ln_grid
         pk = _randint(SL + 2, rid, 1, table_rows("part", sf))
         qty = _randint(SL + 4, rid, 1, 50).astype(jnp.float64)
-        disc = _randint(SL + 5, rid, 0, 10) / 100.0
-        tax = _randint(SL + 6, rid, 0, 8) / 100.0
-        price = qty * _retailprice(pk) * (1.0 + tax) * (1.0 - disc)
+        disc = _randint(SL + 5, rid, 0, 10) / hundred
+        tax = _randint(SL + 6, rid, 0, 8) / hundred
+        price = qty * _retailprice(pk, hundred) * (1.0 + tax) * (1.0 - disc)
         price = jnp.where(live, price, 0.0).reshape(n, 7)
         # sequential left-to-right adds: bit-identical to the host
         # leg's np.add.at accumulation (XLA's tree reduction rounds
@@ -278,7 +301,7 @@ def orders_batch(lo: int, hi: int, sf: float,
         # rint(x*100)/100 — numpy's around algorithm with a TRUE
         # division (jnp.round multiplies by the 0.01 reciprocal, which
         # lands on the other float neighbor for ~14% of values)
-        total = jnp.divide(jnp.rint(total * 100.0), 100.0)
+        total = jnp.divide(jnp.rint(total * 100.0), hundred)
         if "o_totalprice" in need:
             out["o_totalprice"] = Column(DOUBLE, _padded(total), None)
         if "o_orderstatus" in need:
@@ -301,53 +324,159 @@ def orders_batch(lo: int, hi: int, sf: float,
                                            VarcharType(15))
     if "o_shippriority" in need:
         out["o_shippriority"] = Column(
-            INTEGER, jnp.zeros((cap,), jnp.int32), None)
-    return Batch({c: out[c] for c in columns}, n)
+            INTEGER, jnp.zeros((n + pad,), jnp.int32), None)
+    return out
 
 
 # --------------------------------------------------------------------------
 # device-side pushdown enforcement (the filter_batch_host analog)
 # --------------------------------------------------------------------------
 
+def constraint_mask(batch: Batch, constraint) -> jax.Array:
+    """Rows of ``batch`` (live or not) that an accepted TupleDomain
+    keeps. Dictionary columns evaluate the domain once per dictionary
+    VALUE host-side (a tiny table), then gather the per-code verdicts;
+    numeric columns translate ranges to jnp comparisons. Generator
+    columns carry no NULLs."""
+    mask = jnp.ones((batch.capacity,), bool)
+    for col, dom in constraint.domains:
+        if col not in batch.columns or dom.is_all:
+            continue
+        c = batch.columns[col]
+        if c.dictionary is not None:
+            vals = c.dictionary.values.astype(str)
+            tbl = dom.mask_for(
+                np.arange(len(vals)), None,
+                lambda cds, v=vals: v[np.clip(
+                    cds.astype(np.int64), 0, len(v) - 1)])
+            m = jnp.take(jnp.asarray(tbl),
+                         jnp.asarray(c.data).astype(jnp.int32),
+                         mode="clip")
+        else:
+            data = jnp.asarray(c.data)
+            m = jnp.zeros(data.shape, bool)
+            for r in dom.ranges:
+                rm = jnp.ones(data.shape, bool)
+                if r.low is not None:
+                    rm = rm & ((data >= r.low) if r.low_inclusive
+                               else (data > r.low))
+                if r.high is not None:
+                    rm = rm & ((data <= r.high) if r.high_inclusive
+                               else (data < r.high))
+                m = m | rm
+        mask = mask & m
+    return mask
+
+
 def device_filter(batch: Batch, constraint, limit: Optional[int]) -> Batch:
     """Apply an accepted TupleDomain + limit to a device-resident batch
-    without a host round-trip. Dictionary columns evaluate the domain
-    once per dictionary VALUE host-side (a tiny table), then gather the
-    per-code verdicts; numeric columns translate ranges to jnp
-    comparisons. Generator columns carry no NULLs."""
+    without a host round-trip."""
     from ..ops import compact
     if constraint is not None and constraint.is_none:
         return Batch(batch.columns, 0)
     if constraint is not None and not constraint.is_all():
-        mask = batch.row_valid()
-        for col, dom in constraint.domains:
-            if col not in batch.columns or dom.is_all:
-                continue
-            c = batch.columns[col]
-            if c.dictionary is not None:
-                vals = c.dictionary.values.astype(str)
-                tbl = dom.mask_for(
-                    np.arange(len(vals)), None,
-                    lambda cds, v=vals: v[np.clip(
-                        cds.astype(np.int64), 0, len(v) - 1)])
-                m = jnp.take(jnp.asarray(tbl),
-                             jnp.asarray(c.data).astype(jnp.int32),
-                             mode="clip")
-            else:
-                data = jnp.asarray(c.data)
-                m = jnp.zeros(data.shape, bool)
-                for r in dom.ranges:
-                    rm = jnp.ones(data.shape, bool)
-                    if r.low is not None:
-                        rm = rm & ((data >= r.low) if r.low_inclusive
-                                   else (data > r.low))
-                    if r.high is not None:
-                        rm = rm & ((data <= r.high) if r.high_inclusive
-                                   else (data < r.high))
-                    m = m | rm
-            mask = mask & m
-        batch = compact.filter_batch(batch, mask)
+        batch = compact.filter_batch(
+            batch, batch.row_valid() & constraint_mask(batch, constraint))
     if limit is not None:
         from ..ops.compact import limit_batch
         batch = limit_batch(batch, limit)
     return batch
+
+
+# --------------------------------------------------------------------------
+# a whole shard in one program (the mesh executor's scan fill)
+# --------------------------------------------------------------------------
+
+class ShardGenerator:
+    """A table's rows for ANY set of order indices as two traceable
+    functions, so that the mesh executor fills a shard with two
+    programs (exec/executor.py ``_fill_sharded``) where the per-split
+    generators above are one dispatch, and on a cold cache one compile,
+    per primitive per split shape per chip:
+
+    - ``rows(oi, n, hundred)``: how many rows the first ``n`` order
+      indices of ``oi`` give under the pushed-down constraint (the
+      capacity is chosen from it on the host); ``hundred`` is 100.0 as
+      an operand (see ``_retailprice``);
+    - ``batch(oi, n, hundred, cap)``: those rows' lanes at capacity ``cap``,
+      compacted ONCE (generated cells that are no row, and rows the
+      constraint drops, never reach a lane).
+
+    Row order is the per-split generators': ascending order index, then
+    line number. ``key`` names everything the two close over."""
+
+    def __init__(self, table: str, sf: float, columns, constraint):
+        self.table, self.sf = table, sf
+        self.columns = list(columns)
+        self.constraint = (None if constraint is None or constraint.is_all()
+                           else constraint)
+        self.wanted = ([] if self.constraint is None else
+                       [c for c, _ in self.constraint.domains])
+        self.key = ("tpch", table, sf, tuple(self.columns), self.constraint)
+
+    def order_indices(self, splits) -> np.ndarray:
+        """The 1-based order indices (row indices, for orders) the
+        splits cover, in split order."""
+        units = table_rows("orders", self.sf)
+        return np.concatenate(
+            [np.arange(s.part * units // s.part_count + 1,
+                       (s.part + 1) * units // s.part_count + 1,
+                       dtype=np.int64) for s in splits]
+            or [np.zeros(0, np.int64)])
+
+    def _live(self, oi: jax.Array, n: jax.Array, hundred):
+        """(keep mask, order index lane, line number lane) over the
+        generated cells."""
+        real = jnp.arange(oi.shape[0], dtype=jnp.int64) < n
+        if self.table == "orders":
+            keep, order, line = real, oi, None
+            cols = (_orders_columns(oi, self.sf, self.wanted,
+                                    hundred=hundred)
+                    if self.wanted else {})
+        else:
+            counts = jnp.where(real, _line_counts(oi), 0)
+            order = jnp.repeat(oi, 7)                  # static repeat
+            line = jnp.tile(jnp.arange(1, 8, dtype=jnp.int64),
+                            oi.shape[0])
+            keep = line <= jnp.repeat(counts, 7)
+            cols = (_lineitem_columns(order, line, self.sf, self.wanted,
+                                      hundred)
+                    if self.wanted else {})
+        if self.constraint is not None:
+            if self.constraint.is_none:
+                keep = jnp.zeros(keep.shape, bool)
+            else:
+                keep = keep & constraint_mask(
+                    Batch(cols, keep.shape[0]), self.constraint)
+        return keep, order, line
+
+    def rows(self, oi: jax.Array, n: jax.Array, hundred) -> jax.Array:
+        return jnp.sum(self._live(oi, n, hundred)[0].astype(jnp.int64))
+
+    def batch(self, oi: jax.Array, n: jax.Array, hundred,
+              cap: int) -> Batch:
+        keep, order, line = self._live(oi, n, hundred)
+        at = jnp.nonzero(keep, size=cap, fill_value=0)[0]
+        order = jnp.take(order, at)
+        if self.table == "orders":
+            out = _orders_columns(order, self.sf, self.columns,
+                                  hundred=hundred)
+        else:
+            out = _lineitem_columns(order, jnp.take(line, at), self.sf,
+                                    self.columns, hundred)
+        return Batch({c: out[c] for c in self.columns},
+                     jnp.sum(keep.astype(jnp.int64)))
+
+
+def shard_generator(table: str, sf: float, columns, constraint,
+                    limit) -> Optional[ShardGenerator]:
+    """The shard generator of a scan, or None where the per-split path
+    has to serve it: a table or a column without a device generator, a
+    pushed-down limit."""
+    allowed = device_columns(table)
+    if allowed is None or limit is not None:
+        return None
+    gen = ShardGenerator(table, sf, columns, constraint)
+    if not set(gen.columns) | set(gen.wanted) <= allowed:
+        return None
+    return gen

@@ -5,15 +5,20 @@ Reference parity: the distributed scheduler + worker stack
 TPU-first redesign (SURVEY.md §7.4): a stage's tasks are the shards of
 one SPMD program; exchanges are collectives:
 
-- table scans: splits round-robin onto shards (SourcePartitionedScheduler
-  → shard_parts)
-- filter/project/partial-agg: per-shard shard_map segments
-- grouped aggregation: partial → all_to_all repartition → final
-  (PushPartialAggregationThroughExchange shape)
-- joins: REPLICATED (broadcast build via all_gather, two-phase size probe
-  — the DetermineJoinDistributionType REPLICATED branch); the
-  PARTITIONED branch (repartition both sides) applies to large
-  equi-inner joins
+- table scans: splits round-robin onto shards, each shard's lanes read
+  or generated on its own chip and kept resident there (the sharded
+  scan cache, exec/executor.py read_table_sharded)
+- filter/project: per-shard shard_map segments
+- aggregation over a filter/project chain: ONE mesh program — the
+  chain as a selection vector and the partial aggregation per shard,
+  the partial rows all_gathered, the final combine on every shard
+  (the one-chip ``stream_full`` program's two halves around a
+  collective); where the partials are not small: partial →
+  all_to_all repartition → final (PushPartialAggregationThroughExchange)
+- joins: the build side broadcast by all_gather (REPLICATED, the
+  DetermineJoinDistributionType branch) or both sides repartitioned
+  on the join keys (PARTITIONED); then ONE per-shard join for both, a
+  count program and an expand program around one read of the totals
 - semi joins: replicated filtering source + per-shard mask
 - TopN: per-shard TopN, gather, final TopN; Sort/Window/SetOps gather to
   the coordinator shard (single-node fallback)
@@ -25,7 +30,7 @@ shard_map, a host max, then the expansion shard_map with static shapes.
 from __future__ import annotations
 
 from dataclasses import replace as dc_replace
-from typing import Dict, Optional, Sequence, Union
+from typing import Dict, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -37,30 +42,72 @@ from ..config import capacity_for
 from ..ops import compact, join as join_ops, sort as sort_ops
 from ..ops.groupby import (COMBINABLE_KINDS as _COMBINABLE, AggInput,
                            global_aggregate, group_aggregate)
-from ..parallel.mesh import (AXIS, ShardedBatch, get_mesh, shard_parts,
+from ..parallel.mesh import (AXIS, ShardedBatch, get_mesh, shard_batch,
                              unshard_batch)
-from ..parallel.spmd import (broadcast_sharded,
-                             distributed_group_aggregate,
-                             repartition_by_hash, repartition_dest_counts,
-                             shard_apply, shard_apply2, shard_apply2s,
-                             shard_totals, shard_totals2, shard_totals2s)
+from ..parallel.spmd import (P, _col_specs, broadcast_sharded,
+                             distributed_group_aggregate, mesh_call,
+                             repartition_by_hash, shard_apply,
+                             shard_apply2, shard_apply2s, shard_totals2)
 from ..plan.nodes import (AggregationNode, FilterNode, JoinNode, LimitNode,
                           OutputNode, PlanNode, ProjectNode, SemiJoinNode,
                           TableScanNode, TopNNode)
 from ..planner.logical import SemiJoinMultiNode
 from ..session import Session
-from ..types import BOOLEAN, BIGINT, is_string
+from ..types import BOOLEAN, BIGINT
 from .executor import (Executor, QueryError, _Pre, _lower_aggregates,
-                       device_concat, join_verify_filter)
+                       join_verify_filter, make_stream_parts,
+                       read_table_sharded)
+from .progkey import canonicalize_nodes, node_fingerprint
 from .expr import eval_expr, eval_predicate
 
 Value = Union[Batch, ShardedBatch]
 
-# below this estimated build-side row count a join build is broadcast
-# (DetermineJoinDistributionType's size heuristic)
-BROADCAST_LIMIT = 1 << 20
 # a relation smaller than this isn't worth sharding at all
 MIN_SHARD_ROWS = 1 << 12
+# the fused mesh aggregation gathers every shard's partial rows onto
+# every shard: only where a shard's partial is this small (no GROUP
+# BY, or keys with small static domains); larger partials go through
+# the exchange
+FUSED_PARTIAL_ROWS = 1 << 10
+# a build side's key range or key set prunes the probe before the
+# exchange only where it is cheap to collect and can be selective
+DYNAMIC_FILTER_BUILD_ROWS = 100_000
+
+# canonical keys of aggregations that passed ``_small_partial`` and
+# still could not be one program: untraceable (host code in an
+# aggregate, as the one-chip path's ``_STREAM_JIT_DENY``), or a partial
+# the packed kernel declined for its aggregate kinds
+_FUSED_AGG_DENY: set = set()
+
+
+class _PartialTooLarge(Exception):
+    pass
+
+
+def _small_partial(node: AggregationNode, chain, cols) -> bool:
+    """Whether a shard's partial aggregation is a handful of rows, read
+    off the plan: no GROUP BY, or every group key a column of the scan
+    (through the chain's renames) with a small static domain
+    (dictionary codes, booleans), the packed kernel's case. A computed
+    key or a key of unknown domain (q3's orderkey) means partials as
+    large as the shard: those go through the exchange."""
+    from ..ops.groupby import FAST_DOMAIN_LIMIT, _static_domain
+    from ..rex import InputRef
+    groups = 1
+    for sym in node.group_keys:
+        for nd in chain:            # from the aggregation down
+            if isinstance(nd, ProjectNode):
+                e = nd.assignments.get(sym)
+                if not isinstance(e, InputRef):
+                    return False
+                sym = e.name
+        col = cols.get(sym)
+        d = None if col is None or col.data2 is not None \
+            else _static_domain(col)
+        if d is None:
+            return False
+        groups *= d + 1
+    return groups <= FAST_DOMAIN_LIMIT
 
 
 class DistributedExecutor(Executor):
@@ -72,13 +119,6 @@ class DistributedExecutor(Executor):
                  mesh=None, collect_stats: bool = False):
         super().__init__(catalogs, session, collect_stats)
         self.mesh = mesh or get_mesh()
-        # ICI-native stage execution (stage/ici.py): the ROOT execute
-        # call tries to cut the plan into the same StageDAG the remote
-        # scheduler runs and execute it here with device-collective
-        # exchanges; stage bodies then recurse through this executor
-        # with RemoteSource leaves resolving in _ici_values
-        self._ici_tried = False
-        self._ici_values = None
 
     # -- helpers ---------------------------------------------------------
     def _host(self, v: Value) -> Batch:
@@ -91,13 +131,6 @@ class DistributedExecutor(Executor):
         cancel = getattr(self.session, "cancel", None)
         if cancel is not None and cancel.is_set():
             raise QueryError("Query was canceled")
-        if not self._ici_tried:
-            # one attempt, at the root plan only: recursive execute
-            # calls (stage bodies included) take the node path below
-            self._ici_tried = True  # tt-lint: ignore[race-attr-write] an executor instance is owned by ONE query/task thread for its lifetime
-            out = self._try_ici_stages(node)
-            if out is not None:
-                return out
 
         def inner():
             method = getattr(self, "_dexec_" + type(node).__name__,
@@ -110,44 +143,7 @@ class DistributedExecutor(Executor):
         if not self.collect_stats:
             return inner()
         # same per-node stats discipline as the local executor
-        # (previously the mesh path silently collected nothing)
         return self._stats_wrap(node, inner)
-
-    def _try_ici_stages(self, plan: PlanNode) -> Optional[Batch]:
-        """Route the plan through the stage DAG with ICI-native
-        exchange (stage/ici.py) when the fragmenter admits it — the
-        unification of this mesh executor with the stage scheduler:
-        one fragmenter, one DAG shape, collectives instead of
-        spool+HTTP for every in-slice edge. Declined plans (None) keep
-        the node-at-a-time distributed path below."""
-        try:
-            if not (bool(self.session.get("multistage_execution"))
-                    and bool(self.session.get("ici_exchange"))):
-                return None
-        except KeyError:        # foreign session without the knobs
-            return None
-        if self.mesh.devices.size < 2:
-            return None
-        from ..stage.fragmenter import StageFragmenter
-        dag = StageFragmenter(self.catalogs, self.session).fragment(plan)
-        if dag is None:
-            return None
-        from ..stage.ici import IciStageExecution
-        return IciStageExecution(self, dag).run()
-
-    def _dexec_RemoteSourceNode(self, node) -> Value:
-        """In-slice exchange: a stage body's RemoteSource resolves to
-        the producer stage's device-resident value (stage/ici.py) —
-        no frames, no wire. Outside an ICI stage run this node has no
-        mesh meaning and takes the local (exchange reader) path."""
-        if self._ici_values is None:
-            return self._exec_local(node)
-        vals = [self._ici_values[int(fid)]
-                for fid in node.fragment_ids]
-        if len(vals) == 1:
-            return vals[0]
-        hosts = [self._host(v) for v in vals]
-        return device_concat(hosts)
 
     def _exec_local(self, node: PlanNode) -> Batch:
         method = getattr(super(), "_exec_" + type(node).__name__, None)
@@ -182,34 +178,21 @@ class DistributedExecutor(Executor):
     def _dexec_TableScanNode(self, node: TableScanNode) -> Value:
         conn = self.catalogs.connector(node.handle.catalog)
         columns = sorted(set(node.assignments.values()))
-        n = self.mesh.devices.size
-        splits = conn.get_splits(node.handle, n)
         est = conn.table_row_count(node.handle) or 0
-        if len(splits) == 1 and est < MIN_SHARD_ROWS:
+        if est < MIN_SHARD_ROWS and len(
+                conn.get_splits(node.handle, self.mesh.devices.size)) == 1:
             return self._exec_local(node)
-        per_dev = [[] for _ in range(n)]
-        for i, s in enumerate(splits):
-            per_dev[i % n].append(s)
-        parts = []
-        for d in range(n):
-            # _read_split = read_split_cached + telemetry (split
-            # counter, SplitCompletedEvent, input-flow accounting)
-            batches = [self._read_split(conn, s, columns)
-                       for s in per_dev[d]]
-            if not batches:
-                from ..columnar import empty_batch
-                meta = conn.get_table_metadata(node.handle.schema,
-                                               node.handle.table)
-                batches = [empty_batch(
-                    {c.name: c.type for c in meta.columns
-                     if c.name in set(columns)})]
-            parts.append(device_concat(batches)
-                         if len(batches) > 1 else batches[0])
-        sb = shard_parts(parts, self.mesh)
+        sb = read_table_sharded(conn, node.handle, columns, self.mesh)
+        if self.collect_stats and self._frames:
+            with self._host_read("split_rows"):
+                self._frames[-1]["rows"] += sb.total_rows_host()
         # rename connector columns to plan symbols
         cols = {sym: sb.columns[col]
                 for sym, col in node.assignments.items()}
         return ShardedBatch(cols, sb.num_rows, sb.mesh, sb.per_shard_cap)
+
+    def _dexec__Pre(self, node: _Pre) -> Value:
+        return node.batch
 
     # -- per-shard pipeline segments ------------------------------------
     def _dexec_FilterNode(self, node: FilterNode) -> Value:
@@ -219,7 +202,8 @@ class DistributedExecutor(Executor):
                 dc_replace(node, source=_Pre(src)))
         return shard_apply(
             src, lambda b: compact.filter_batch(
-                b, eval_predicate(node.predicate, b)))
+                b, eval_predicate(node.predicate, b)),
+            key=_node_key(node))
 
     def _dexec_ProjectNode(self, node: ProjectNode) -> Value:
         src = self.execute(node.source)
@@ -229,7 +213,8 @@ class DistributedExecutor(Executor):
         return shard_apply(
             src, lambda b: Batch({s: eval_expr(e, b)
                                   for s, e in node.assignments.items()},
-                                 b.num_rows))
+                                 b.num_rows),
+            key=_node_key(node))
 
     def _dexec_OutputNode(self, node: OutputNode) -> Batch:
         src = self._host(self.execute(node.source))
@@ -241,7 +226,8 @@ class DistributedExecutor(Executor):
         if isinstance(src, ShardedBatch):
             # per-shard pre-limit bounds the gather to n * count rows
             src = shard_apply(
-                src, lambda b: compact.limit_batch(b, node.count))
+                src, lambda b: compact.limit_batch(b, node.count),
+                key=_node_key(node))
             src = unshard_batch(src)
         return compact.limit_batch(src, node.count)
 
@@ -252,9 +238,13 @@ class DistributedExecutor(Executor):
         if isinstance(src, ShardedBatch):
             # per-shard partial TopN, gather, final TopN
             src = shard_apply(
-                src, lambda b: sort_ops.topn_batch(b, keys, node.count))
+                src, lambda b: sort_ops.topn_batch(b, keys, node.count),
+                key=_node_key(node))
             src = unshard_batch(src)
-        return sort_ops.topn_batch(src, keys, node.count)
+        # the final TopN as the one-device path runs it (under
+        # fragment_jit ONE cached ``chain`` program): called eagerly,
+        # ``topn_batch`` compiles its scan anew on every query
+        return self._execute_inner(dc_replace(node, source=_Pre(src)))
 
     def _dexec_SortNode(self, node) -> Value:
         """Distributed sort (distributed_sort session property): sampled
@@ -280,18 +270,16 @@ class DistributedExecutor(Executor):
         if not distributable:
             return super()._exec_SortNode(
                 dc_replace(node, source=_Pre(self._host(src))))
-        from ..parallel.spmd import (range_dest_counts,
-                                     repartition_by_range,
+        from ..parallel.spmd import (repartition_by_range,
                                      sample_range_splitters)
         splitters = sample_range_splitters(src, keys)
         if splitters is None:  # empty relation
             return super()._exec_SortNode(
                 dc_replace(node, source=_Pre(self._host(src))))
-        counts = range_dest_counts(src, keys, splitters)
-        cap = capacity_for(max(int(jnp.max(counts)), 1))
-        rp = repartition_by_range(src, keys, splitters, out_cap=cap)
+        rp = repartition_by_range(src, keys, splitters)
         return shard_apply(
-            rp, lambda b: sort_ops.sort_batch(b, keys), cap)
+            rp, lambda b: sort_ops.sort_batch(b, keys),
+            key=_node_key(node))
 
     # -- window ----------------------------------------------------------
     def _dexec_WindowNode(self, node) -> Value:
@@ -316,15 +304,11 @@ class DistributedExecutor(Executor):
         if not distributable:
             return super()._exec_WindowNode(
                 dc_replace(node, source=_Pre(self._host(src))))
-        from ..parallel.spmd import (repartition_by_hash,
-                                     repartition_dest_counts)
         from .window import execute_window
-        counts = repartition_dest_counts(src, pkeys)
-        cap = capacity_for(max(int(jnp.max(counts)), 1))
-        rp = repartition_by_hash(src, pkeys, out_cap=cap)
+        rp = repartition_by_hash(src, pkeys)
         try:
             return shard_apply(rp, lambda b: execute_window(b, node),
-                               cap)
+                               key=_node_key(node))
         except (jax.errors.TracerArrayConversionError,
                 jax.errors.ConcretizationTypeError):
             # a window shape the kernel can't trace (host-side frame
@@ -367,25 +351,36 @@ class DistributedExecutor(Executor):
             hb_r = self._host(rb) if isinstance(rb, ShardedBatch) else rb
             return setop_batches(hb_l, hb_r, node.op, node.distinct,
                                  out_syms)
-        from ..parallel.spmd import (repartition_by_hash,
-                                     repartition_dest_counts)
         lb, rb = _align_setop_dicts(lb, rb, out_syms)
-        lc = repartition_dest_counts(lb, out_syms)
-        rc = repartition_dest_counts(rb, out_syms)
-        lcap = capacity_for(max(int(jnp.max(lc)), 1))
-        rcap = capacity_for(max(int(jnp.max(rc)), 1))
-        lrp = repartition_by_hash(lb, out_syms, out_cap=lcap)
-        rrp = repartition_by_hash(rb, out_syms, out_cap=rcap)
-        out_cap = capacity_for(lcap + rcap)
+        lrp = repartition_by_hash(lb, out_syms)
+        rrp = repartition_by_hash(rb, out_syms)
+        out_cap = capacity_for(lrp.per_shard_cap + rrp.per_shard_cap)
         return shard_apply2s(
             lrp, rrp,
             lambda a, b: _setop_traced(a, b, node.op, node.distinct,
                                        out_syms, out_cap),
-            out_cap)
+            key=("setop", node.op, node.distinct, tuple(out_syms),
+                 out_cap))
 
     # -- aggregation -----------------------------------------------------
     def _dexec_AggregationNode(self, node: AggregationNode) -> Value:
-        src = self.execute(node.source)
+        # the filter/project chain under the aggregation runs inside
+        # the aggregation's own program as a selection vector (no
+        # compaction of the scan's rows), like the one-chip path
+        chain = []
+        cur = node.source
+        while isinstance(cur, (FilterNode, ProjectNode)):
+            chain.append(cur)
+            cur = cur.source
+        base = self.execute(cur)
+        if isinstance(base, ShardedBatch):
+            fused = self._fused_aggregation(node, chain, base)
+            if fused is not None:
+                return fused
+        below = _Pre(base)
+        for nd in reversed(chain):
+            below = dc_replace(nd, source=below)
+        src = self.execute(below)
         if not isinstance(src, ShardedBatch):
             return super()._exec_AggregationNode(
                 dc_replace(node, source=_Pre(src)))
@@ -430,7 +425,7 @@ class DistributedExecutor(Executor):
                 dc_replace(node, source=_Pre(self._host(src))))
         partial = shard_apply(
             src, lambda b: _pad_one(global_aggregate(b, phys)),
-            out_cap=8)
+            key=("global_partial", tuple(phys)))
         gathered = unshard_batch(partial)
         finals = [AggInput(_combine_kind(a.kind), a.output, None,
                            a.output) for a in phys]
@@ -443,6 +438,64 @@ class DistributedExecutor(Executor):
             cols = {s: c for s, c in cols.items() if s in keep}
             out = Batch(cols, 1)
         return out
+
+    def _fused_aggregation(self, node: AggregationNode, chain,
+                           src: ShardedBatch) -> Optional[Batch]:
+        """Aggregation over a filter/project chain over a sharded
+        value as ONE mesh program: per shard the chain as a selection
+        vector and the partial aggregation (``make_stream_parts``, the
+        halves of the one-chip ``stream_full`` program), the partial
+        rows all_gathered, the final combine and post-processing on
+        every shard. Applies where a shard's partial is small (no GROUP
+        BY, or keys with small static domains: the packed kernel);
+        returns None otherwise and the caller takes the exchange."""
+        if any(a.distinct or a.kind in self._NONSTREAMABLE
+               for a in node.aggregates.values()):
+            return None
+        if not _small_partial(node, chain, src.columns):
+            return None
+        canon = canonicalize_nodes([node] + chain)
+        node_x, chain_x = ((canon.nodes[0], canon.nodes[1:])
+                           if canon is not None else (node, chain))
+        key = None if canon is None else canon.key
+        if key is not None and key in _FUSED_AGG_DENY:
+            return None
+        binding = None
+        cols = src.columns
+        if canon is not None:
+            binding = canon.binding(Batch(cols, 0))
+            cols = binding.rename_in(Batch(cols, 0)).columns
+        partial, finish = make_stream_parts(self._detached(), chain_x,
+                                            node_x)
+
+        def build():
+            def f(cols, num_rows_vec):
+                d = jax.lax.axis_index(AXIS)
+                out, phys, post = partial(Batch(cols, num_rows_vec[d]))
+                if out.capacity > FUSED_PARTIAL_ROWS:
+                    raise _PartialTooLarge()
+                live = (jnp.arange(out.capacity, dtype=jnp.int64)
+                        < out.num_rows_device())
+                gathered = jax.tree.map(
+                    lambda lane: jax.lax.all_gather(lane, AXIS)
+                    .reshape(-1), out.columns)
+                glive = jax.lax.all_gather(live, AXIS).reshape(-1)
+                return finish(
+                    Batch(gathered, jnp.sum(glive.astype(jnp.int64))),
+                    phys, post, live=glive)
+            return f, (_col_specs(cols, P(AXIS)), P()), P()
+
+        try:
+            out = mesh_call("agg", key, src.mesh, (cols, src.num_rows),
+                            build)
+        except (_PartialTooLarge, jax.errors.TracerArrayConversionError,
+                jax.errors.ConcretizationTypeError):
+            if key is not None:
+                _FUSED_AGG_DENY.add(key)
+            return None
+        # the result is the same on every chip: keep the coordinator's
+        out = jax.tree.map(lambda a: a.addressable_shards[0].data, out)
+        return out if binding is None else binding.rename_out(out)
 
     # -- joins -----------------------------------------------------------
     def _dexec_JoinNode(self, node: JoinNode) -> Value:
@@ -478,7 +531,8 @@ class DistributedExecutor(Executor):
         # hash-collision re-verification for inexact key lanes
         # (JoinProbe real-equality semantics; see executor.py)
         node = dc_replace(node, filter=join_verify_filter(
-            left.columns, right.columns, pkeys, bkeys, node.filter))
+            _key_views(left.columns, pkeys),
+            _key_views(right.columns, bkeys), pkeys, bkeys, node.filter))
 
         # dynamic filtering: build-side key ranges prune probe rows
         # BEFORE any exchange (reference: DynamicFilterService.java:95 +
@@ -488,40 +542,74 @@ class DistributedExecutor(Executor):
         probe = self._dynamic_filter_probe(probe, right, pkeys, bkeys,
                                            jt)
 
-        # PARTITIONED distribution (DetermineJoinDistributionType's
-        # PARTITIONED branch): hash-repartition BOTH sides on the join
-        # keys so matching rows co-locate, then per-shard join — the
-        # build side is never replicated (VERDICT weak #7)
+        if not isinstance(right, ShardedBatch):
+            # a build side held by the coordinator (a table too small
+            # to shard): spread it, so that ONE join path serves it
+            right = shard_batch(right, self.mesh)
+        build = _align_sharded_dicts(probe, right, pkeys, bkeys)
         if (str(node.distribution or "").lower() == "partitioned"
-                and isinstance(right, ShardedBatch)
                 and jt in ("inner", "left")):
-            return self._partitioned_join(node, probe, right,
-                                          pkeys, bkeys, jt)
+            # PARTITIONED distribution (DetermineJoinDistributionType's
+            # PARTITIONED branch; AddExchanges.java's FIXED_HASH on both
+            # children): both sides move so that matching rows
+            # co-locate; the build side is never replicated
+            probe = repartition_by_hash(probe, pkeys)
+            build = repartition_by_hash(build, bkeys)
+        else:
+            # REPLICATED distribution: every shard gets the whole build
+            build = broadcast_sharded(build)
+        return self._join_shards(node, probe, build, pkeys, bkeys, jt)
 
-        # REPLICATED distribution: broadcast the build side
-        build_host = self._host(right)
-        build_host = _align_sharded_strings(probe, build_host,
-                                            pkeys, bkeys)
+    def _join_shards(self, node: JoinNode, probe: ShardedBatch,
+                     build: ShardedBatch, pkeys, bkeys, jt: str) -> Value:
+        """The per-shard join of two co-located operands (after the
+        repartition or the broadcast), two-phase: a count program that
+        keeps its run starts, counts and build order ON the shards, one
+        blocking read of the per-shard totals, an expand program at the
+        capacity they give."""
         outer = jt == "left"
+        filt = node.filter
+        pkeys, bkeys = tuple(pkeys), tuple(bkeys)
+        operands = (probe.columns, probe.num_rows,
+                    build.columns, build.num_rows)
+        in_specs = (_col_specs(probe.columns, P(AXIS)), P(),
+                    _col_specs(build.columns, P(AXIS)), P())
 
-        def phase1(pb: Batch, bb: Batch):
-            start, count, order = join_ops.match_counts(
-                pb, bb, pkeys, bkeys)
-            live = pb.row_valid()
-            eff = jnp.where(live, jnp.maximum(count, 1), 0) if (
-                outer and node.filter is None) else count
-            return jnp.sum(eff)
+        def build_count():
+            def f(pcols, pn, bcols, bn):
+                d = jax.lax.axis_index(AXIS)
+                pb, bb = Batch(pcols, pn[d]), Batch(bcols, bn[d])
+                start, count, order = join_ops.match_counts(
+                    pb, bb, list(pkeys), list(bkeys))
+                eff = jnp.where(pb.row_valid(), jnp.maximum(count, 1),
+                                0) if (outer and filt is None) else count
+                return (start, count, order,
+                        jax.lax.all_gather(jnp.sum(eff), AXIS))
+            return f, in_specs, (P(AXIS), P(AXIS), P(AXIS), P())
 
-        totals = shard_totals2(probe, build_host, phase1)
-        out_cap = capacity_for(max(int(jnp.max(totals)), 1))
+        start, count, order, totals = mesh_call(
+            "join_count", (pkeys, bkeys, outer and filt is None),
+            probe.mesh, operands, build_count)
+        with self._host_read("join_total"):
+            out_cap = capacity_for(max(int(np.asarray(totals).max()), 1))
         pad_cap = probe.per_shard_cap if (outer and
-                                          node.filter is not None) else 0
+                                          filt is not None) else 0
 
-        def phase2(pb: Batch, bb: Batch) -> Batch:
-            return _shard_join(pb, bb, pkeys, bkeys, jt, node.filter,
-                               out_cap, pad_cap)
+        def build_expand():
+            def f(pcols, pn, bcols, bn, start, count, order):
+                d = jax.lax.axis_index(AXIS)
+                out = _shard_join(Batch(pcols, pn[d]), Batch(bcols, bn[d]),
+                                  start, count, order, jt, filt, out_cap,
+                                  pad_cap)
+                return out.columns, jax.lax.all_gather(
+                    out.num_rows_device(), AXIS)
+            return (f, in_specs + (P(AXIS), P(AXIS), P(AXIS)),
+                    (P(AXIS), P()))
 
-        return shard_apply2(probe, build_host, phase2, out_cap + pad_cap)
+        cols, counts = mesh_call(
+            "join_expand", (jt, repr(filt), out_cap, pad_cap),
+            probe.mesh, operands + (start, count, order), build_expand)
+        return ShardedBatch(cols, counts, probe.mesh, out_cap + pad_cap)
 
     def _dynamic_filter_probe(self, probe: ShardedBatch, build: Value,
                               pkeys, bkeys, jt: str) -> ShardedBatch:
@@ -533,6 +621,14 @@ class DistributedExecutor(Executor):
         if jt != "inner" or not isinstance(probe, ShardedBatch):
             return probe
         if not bool(self.session.get("enable_dynamic_filtering")):
+            return probe
+        with self._host_read("dynamic_filter_rows"):
+            build_rows = (build.total_rows_host()
+                          if isinstance(build, ShardedBatch)
+                          else build.num_rows_host())
+        if build_rows > DYNAMIC_FILTER_BUILD_ROWS:
+            # collecting the keys of a large build side costs a copy of
+            # its key lane to the host, and its range prunes little
             return probe
         bounds = []
         for pk, bk in zip(pkeys, bkeys):
@@ -553,90 +649,72 @@ class DistributedExecutor(Executor):
             if bc.valid is not None:
                 live = live & np.asarray(bc.valid)
             vals = data[live]
+            # the collected values are OPERANDS of the pruning program,
+            # not constants of its trace: one program per key shape
             if vals.size == 0:
-                bounds.append((pk, 1, 0, None, False))  # drop all
-            else:
-                # small-domain exact set beats min/max by orders of
-                # magnitude on sparse keys (the reference's
-                # discrete-values DynamicFilter domain)
-                uniq = np.unique(vals)
-                exact = (jnp.asarray(uniq)
-                         if uniq.size <= 100_000 and
-                         uniq.dtype.kind in "iu" else None)
-                has_nan = (vals.dtype.kind == "f"
-                           and bool(np.isnan(vals).any()))
-                with np.errstate(invalid="ignore"):
-                    mn = (np.nanmin(vals) if has_nan else vals.min())
-                    mx = (np.nanmax(vals) if has_nan else vals.max())
-                bounds.append((pk, mn, mx, exact, has_nan))
+                bounds.append(((pk, "none", False),
+                               np.zeros(2, data.dtype)))  # drop all
+                continue
+            # small-domain exact set beats min/max by orders of
+            # magnitude on sparse keys (the reference's
+            # discrete-values DynamicFilter domain)
+            uniq = np.unique(vals)
+            if uniq.dtype.kind in "iu":
+                # padded with its own maximum: membership is unchanged
+                cap = capacity_for(uniq.size, minimum=8)
+                bounds.append(((pk, "set", False), np.pad(
+                    uniq, (0, cap - uniq.size), mode="edge")))
+                continue
+            has_nan = (vals.dtype.kind == "f"
+                       and bool(np.isnan(vals).any()))
+            with np.errstate(invalid="ignore"):
+                mn = (np.nanmin(vals) if has_nan else vals.min())
+                mx = (np.nanmax(vals) if has_nan else vals.max())
+            bounds.append(((pk, "range", has_nan),
+                           np.asarray([mn, mx], data.dtype)))
         if not bounds:
             return probe
+        specs = tuple(spec for spec, _ in bounds)
+        operands = tuple(arr for _, arr in bounds)
 
-        def f(b: Batch) -> Batch:
-            mask = b.row_valid()
-            for pk, mn, mx, exact, has_nan in bounds:
-                c = b.column(pk)
-                d = jnp.asarray(c.data)
-                if exact is not None:
-                    pos = jnp.searchsorted(exact, d)
-                    hit = jnp.take(exact, jnp.clip(pos, 0,
-                                                   exact.shape[0] - 1),
-                                   mode="clip") == d
-                    m = hit & (pos < exact.shape[0])
-                else:
-                    m = (d >= mn) & (d <= mx)
-                    if has_nan:
-                        # engine equality treats all NaNs as equal
-                        # (ops/hashing.py), so NaN probes can match a
-                        # NaN build key and must survive the filter
-                        m = m | jnp.isnan(d)
-                if c.valid is not None:
-                    # NULL keys never match an inner join
-                    m = m & jnp.asarray(c.valid)
-                mask = mask & m
-            return compact.filter_batch(b, mask)
+        def build_prune():
+            def f(cols, num_rows_vec, *values):
+                b = Batch(cols, num_rows_vec[jax.lax.axis_index(AXIS)])
+                mask = b.row_valid()
+                for (pk, mode, has_nan), v in zip(specs, values):
+                    c = b.column(pk)
+                    d = jnp.asarray(c.data)
+                    if mode == "none":
+                        m = jnp.zeros(d.shape, bool)
+                    elif mode == "set":
+                        pos = jnp.clip(jnp.searchsorted(v, d), 0,
+                                       v.shape[0] - 1)
+                        m = jnp.take(v, pos, mode="clip") == d
+                    else:
+                        m = (d >= v[0]) & (d <= v[1])
+                        if has_nan:
+                            # engine equality treats all NaNs as equal
+                            # (ops/hashing.py), so NaN probes can match
+                            # a NaN build key and must survive
+                            m = m | jnp.isnan(d)
+                    if c.valid is not None:
+                        # NULL keys never match an inner join
+                        m = m & jnp.asarray(c.valid)
+                    mask = mask & m
+                out = compact.filter_batch(b, mask)
+                return out.columns, jax.lax.all_gather(
+                    out.num_rows_device(), AXIS)
+            return (f, (_col_specs(probe.columns, P(AXIS)), P())
+                    + tuple(P() for _ in operands), (P(AXIS), P()))
 
+        cols, counts = mesh_call(
+            "dynamic_filter", specs, probe.mesh,
+            (probe.columns, probe.num_rows) + operands, build_prune)
+        kept = ShardedBatch(cols, counts, probe.mesh, probe.per_shard_cap)
         if self.collect_stats:
-            before = probe.total_rows_host()
-            kept = shard_apply(probe, f, probe.per_shard_cap)
-            self.dynamic_filter_rows = (before,
+            self.dynamic_filter_rows = (probe.total_rows_host(),
                                         kept.total_rows_host())
-            return kept
-        return shard_apply(probe, f, probe.per_shard_cap)
-
-    def _partitioned_join(self, node: JoinNode, probe: ShardedBatch,
-                          build: ShardedBatch, pkeys, bkeys,
-                          jt: str) -> Value:
-        """Repartition both inputs by join-key hash (AddExchanges.java's
-        FIXED_HASH on both children), then join shard-locally. Exchange
-        capacities come from real per-destination counts (two-phase)."""
-        build = _align_sharded_dicts(probe, build, pkeys, bkeys)
-        pc = repartition_dest_counts(probe, pkeys)
-        bc = repartition_dest_counts(build, bkeys)
-        pcap = capacity_for(max(int(jnp.max(pc)), 1))
-        bcap = capacity_for(max(int(jnp.max(bc)), 1))
-        probe = repartition_by_hash(probe, pkeys, out_cap=pcap)
-        build = repartition_by_hash(build, bkeys, out_cap=bcap)
-        outer = jt == "left"
-
-        def phase1(pb: Batch, bb: Batch):
-            start, count, order = join_ops.match_counts(
-                pb, bb, pkeys, bkeys)
-            live = pb.row_valid()
-            eff = jnp.where(live, jnp.maximum(count, 1), 0) if (
-                outer and node.filter is None) else count
-            return jnp.sum(eff)
-
-        totals = shard_totals2s(probe, build, phase1)
-        out_cap = capacity_for(max(int(jnp.max(totals)), 1))
-        pad_cap = probe.per_shard_cap if (outer and
-                                          node.filter is not None) else 0
-
-        def phase2(pb: Batch, bb: Batch) -> Batch:
-            return _shard_join(pb, bb, pkeys, bkeys, jt, node.filter,
-                               out_cap, pad_cap)
-
-        return shard_apply2s(probe, build, phase2, out_cap + pad_cap)
+        return kept
 
     def _dexec_SemiJoinNode(self, node: SemiJoinNode) -> Value:
         src = self.execute(node.source)
@@ -658,7 +736,7 @@ class DistributedExecutor(Executor):
             cols[node.output] = Column(BOOLEAN, matched, valid)
             return Batch(cols, b.num_rows)
 
-        return shard_apply2(src, filt, f, src.per_shard_cap)
+        return shard_apply2(src, filt, f)
 
     def _dexec_SemiJoinMultiNode(self, node: SemiJoinMultiNode) -> Value:
         src = self.execute(node.source)
@@ -673,7 +751,9 @@ class DistributedExecutor(Executor):
         filt = _align_sharded_strings(src, filt, skeys, fkeys)
         if skeys:
             node = dc_replace(node, filter=join_verify_filter(
-                src.columns, filt.columns, skeys, fkeys, node.filter))
+                _key_views(src.columns, skeys),
+                _key_views(filt.columns, fkeys), skeys, fkeys,
+                node.filter))
         if node.filter is None and skeys:
             def f(b: Batch, fb: Batch) -> Batch:
                 matched, _, _, _ = join_ops.semi_join_mask(
@@ -681,7 +761,7 @@ class DistributedExecutor(Executor):
                 cols = dict(b.columns)
                 cols[node.output] = Column(BOOLEAN, matched, None)
                 return Batch(cols, b.num_rows)
-            return shard_apply2(src, filt, f, src.per_shard_cap)
+            return shard_apply2(src, filt, f)
 
         def phase1(pb: Batch, fb: Batch):
             if skeys:
@@ -715,7 +795,7 @@ class DistributedExecutor(Executor):
             cols[node.output] = Column(BOOLEAN, matched, None)
             return Batch(cols, pb.num_rows)
 
-        return shard_apply2(src, filt, phase2, src.per_shard_cap)
+        return shard_apply2(src, filt, phase2)
 
 
 # --------------------------------------------------------------------------
@@ -880,13 +960,13 @@ def _trace_concat(a: Batch, b: Batch, out_cap: int) -> Batch:
     return Batch(cols, na + nb)
 
 
-def _shard_join(pb: Batch, bb: Batch, pkeys, bkeys, jt: str, filt,
-                out_cap: int, pad_cap: int) -> Batch:
-    """Trace-safe single-shard join against a replicated build side
-    (the per-shard body of a REPLICATED-distribution join)."""
+def _shard_join(pb: Batch, bb: Batch, start, count, order, jt: str,
+                filt, out_cap: int, pad_cap: int) -> Batch:
+    """Trace-safe single-shard join expansion from the count program's
+    run starts, counts and build order (the per-shard body of both
+    join distributions)."""
     outer = jt == "left"
     if filt is None:
-        start, count, order = join_ops.match_counts(pb, bb, pkeys, bkeys)
         return join_ops.expand_join(pb, bb, start, count, order, out_cap,
                                     "left" if outer else "inner")
     ppos = "__probe_pos$"
@@ -894,7 +974,6 @@ def _shard_join(pb: Batch, bb: Batch, pkeys, bkeys, jt: str, filt,
     pcols[ppos] = Column(BIGINT,
                          jnp.arange(pb.capacity, dtype=jnp.int64), None)
     probe2 = Batch(pcols, pb.num_rows)
-    start, count, order = join_ops.match_counts(probe2, bb, pkeys, bkeys)
     cand = join_ops.expand_join(probe2, bb, start, count, order, out_cap,
                                 "inner")
     mask = eval_predicate(filt, cand)
@@ -910,8 +989,7 @@ def _shard_join(pb: Batch, bb: Batch, pkeys, bkeys, jt: str, filt,
     pad_src = compact.filter_batch(pb, unmatched)
     pad_cols = dict(pad_src.columns)
     for s, c in bb.columns.items():
-        z = jnp.zeros((pad_src.capacity,),
-                      dtype=np.asarray(c.data).dtype)
+        z = jnp.zeros((pad_src.capacity,), dtype=jnp.asarray(c.data).dtype)
         pad_cols[s] = Column(c.type, z,
                              jnp.zeros((pad_src.capacity,), bool),
                              c.dictionary)
@@ -921,3 +999,17 @@ def _shard_join(pb: Batch, bb: Batch, pkeys, bkeys, jt: str, filt,
     return _trace_concat(out, pad, out_cap + pad_cap)
 
 
+def _key_views(cols, keys) -> Dict[str, Column]:
+    """The key columns with EMPTY data lanes of the same dtype:
+    ``join_verify_filter`` learns a key's dtype by copying its lane to
+    the host (ROADMAP S3), which a lane sharded over the mesh must not
+    pay; type, dtype and the second lane are all it reads."""
+    return {k: dc_replace(cols[k], data=np.empty(
+        0, np.dtype(cols[k].data.dtype))) for k in keys}
+
+
+def _node_key(node: PlanNode):
+    """The mesh-program key of a per-shard node segment: its structural
+    fingerprint (exec/progkey.py), or None where it has none."""
+    fp = node_fingerprint(node)
+    return None if fp is None else (type(node).__name__, fp)

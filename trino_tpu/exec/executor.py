@@ -2051,14 +2051,16 @@ class Executor:
         return _single_row(src)
 
 
-def make_stream_runners(helper: "Executor", chain, node):
-    """Build the streaming-aggregation programs over a chain +
-    AggregationNode: ``run`` (per-split partial aggregation) and
-    ``run_full`` (whole-table partial + final combine + post-processing
-    fused into ONE XLA computation — the shape of the hand-fused
-    micro). Module-level so the AOT compiler (exec/aot.py) rebuilds
-    the EXACT closures the executor caches — a pre-warmed program and
-    a live query trace the same jaxpr."""
+def make_stream_parts(helper: "Executor", chain, node):
+    """The two halves of a streamed aggregation over a chain +
+    AggregationNode: ``partial(batch)`` (the chain as a selection
+    vector, then the partial aggregation; returns the partial Batch
+    with the lowered aggregates and their post-processing) and
+    ``finish(partials, phys, post, live=None)`` (final combine +
+    post-processing). One chip runs them back to back in ONE program
+    (``make_stream_runners``); the mesh runs ``partial`` per shard,
+    gathers the partial rows and runs ``finish`` on them
+    (exec/distributed.py)."""
 
     def partial(b: Batch):
         # selection-vector execution: the filter chain becomes a
@@ -2080,18 +2082,15 @@ def make_stream_runners(helper: "Executor", chain, node):
             out = _pad_partial(global_aggregate(src, _p, live=live))
         return out, _p, _post
 
-    def run(b: Batch) -> Batch:
-        return partial(b)[0]
-
-    def run_full(b: Batch) -> Batch:
-        out, _p, _post = partial(b)
+    def finish(out: Batch, _p, _post, live=None) -> Batch:
         from ..ops.groupby import COMBINABLE_KINDS
         fin = [AggInput(COMBINABLE_KINDS[a.kind], a.output, None,
                         a.output) for a in _p]
         if node.group_keys:
-            out = group_aggregate(out, list(node.group_keys), fin)
+            out = group_aggregate(out, list(node.group_keys), fin,
+                                  live=live)
         else:
-            out = global_aggregate(out, fin)
+            out = global_aggregate(out, fin, live=live)
         if _post:
             cols = dict(out.columns)
             for sym, fn in _post.items():
@@ -2100,6 +2099,25 @@ def make_stream_runners(helper: "Executor", chain, node):
             cols = {s: c for s, c in cols.items() if s in keep}
             out = Batch(cols, out.num_rows)
         return out
+
+    return partial, finish
+
+
+def make_stream_runners(helper: "Executor", chain, node):
+    """Build the streaming-aggregation programs over a chain +
+    AggregationNode: ``run`` (per-split partial aggregation) and
+    ``run_full`` (whole-table partial + final combine + post-processing
+    fused into ONE XLA computation — the shape of the hand-fused
+    micro). Module-level so the AOT compiler (exec/aot.py) rebuilds
+    the EXACT closures the executor caches — a pre-warmed program and
+    a live query trace the same jaxpr."""
+    partial, finish = make_stream_parts(helper, chain, node)
+
+    def run(b: Batch) -> Batch:
+        return partial(b)[0]
+
+    def run_full(b: Batch) -> Batch:
+        return finish(*partial(b))
 
     return run, run_full
 
@@ -2283,12 +2301,30 @@ _SCAN_CACHE_LOCK = _threading.Lock()
 
 
 def _col_bytes(c: Column) -> int:
+    """Bytes of the column's lanes on the fullest chip: a lane sharded
+    across a mesh counts its per-chip share (the budgets it is held
+    against are a chip's)."""
     total = 0
     for lane in (c.data, c.valid, c.data2):
-        if lane is not None:
-            total += int(np.asarray(lane).nbytes) \
-                if isinstance(lane, np.ndarray) else int(lane.nbytes)
+        if lane is None:
+            continue
+        n = int(lane.nbytes)
+        sharding = getattr(lane, "sharding", None)
+        if sharding is not None and not lane.is_fully_replicated:
+            n //= len(sharding.device_set)
+        total += n
     return total
+
+
+def _make_room(state: dict, size: int) -> None:
+    """Evict a connector's oldest scan-cache entries until ``size``
+    more bytes fit its budget (the caller holds the lock)."""
+    while state["bytes"] + size > CONFIG.scan_cache_bytes \
+            and state["order"]:
+        old = state["entries"].pop(state["order"].pop(0), None)
+        if old is not None:
+            state["bytes"] -= sum(_col_bytes(c)
+                                  for c in old["cols"].values())
 
 
 def read_split_cached(conn, split, columns) -> Batch:
@@ -2332,13 +2368,7 @@ def read_split_cached(conn, split, columns) -> Batch:
             state = {"entries": {}, "order": [], "bytes": 0}
             _SCAN_CACHES[conn] = state
         if size <= CONFIG.scan_cache_bytes:
-            while state["bytes"] + size > CONFIG.scan_cache_bytes \
-                    and state["order"]:
-                old_key = state["order"].pop(0)
-                old = state["entries"].pop(old_key, None)
-                if old is not None:
-                    state["bytes"] -= sum(_col_bytes(c)
-                                          for c in old["cols"].values())
+            _make_room(state, size)
             entry = state["entries"].get(skey)
             if entry is None:
                 entry = {"cols": {}, "num_rows": raw.num_rows}
@@ -2522,13 +2552,7 @@ def read_table_cached(conn, handle, columns, par) -> Optional[Batch]:
                 state["bytes"] -= sum(_col_bytes(c)
                                       for c in old["cols"].values())
         size = sum(_col_bytes(c) for c in whole.columns.values())
-        while state["bytes"] + size > CONFIG.scan_cache_bytes \
-                and state["order"]:
-            old_key = state["order"].pop(0)
-            old = state["entries"].pop(old_key, None)
-            if old is not None:
-                state["bytes"] -= sum(_col_bytes(c)
-                                      for c in old["cols"].values())
+        _make_room(state, size)
         entry = state["entries"].get(wkey)
         if entry is None:
             entry = {"cols": {}, "num_rows": whole.num_rows}
@@ -2548,6 +2572,156 @@ def read_table_cached(conn, handle, columns, par) -> Optional[Batch]:
                          entry["num_rows"])
     # the budget evicted our own entry mid-insert: stream instead
     return None
+
+
+def _fill_sharded(conn, h, columns, mesh):
+    """The sharded scan cache's miss path; split i of the table belongs
+    to shard i mod n. Where the connector can give its rows as
+    functions of a set of row indices (``shard_generator``: the tpch
+    device generators), every shard is generated ON its own chip by two
+    mesh programs (``_generate_sharded``); else every shard's splits
+    are read on its own chip (``_read_sharded``). Waited for, so the
+    ``scan_fill`` span holds the fill."""
+    n = mesh.devices.size
+    splits = conn.get_splits(h, n)
+    make = getattr(conn, "shard_generator", None)
+    gen = make(h, columns) if make is not None else None
+    with active_span("scan_fill", table=h.table, lanes=len(columns),
+                     shards=n):
+        _M_SPLITS.inc(len(splits))
+        sb = (_generate_sharded(gen, splits, mesh) if gen is not None
+              else _read_sharded(conn, h, splits, columns, mesh))
+        jax.block_until_ready([c.data for c in sb.columns.values()])
+    return sb
+
+
+def _read_sharded(conn, h, splits, columns, mesh):
+    """Every shard's splits read on its own chip by its own host thread
+    (a connector's reads are eager code with blocking counts, so the
+    chips fill side by side only that way), glued there, and the global
+    lanes assembled in place."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    from ..parallel.mesh import shard_parts
+    n = mesh.devices.size
+    devices = list(mesh.devices.flat)
+
+    def fill(d: int) -> Batch:
+        with jax.default_device(devices[d]):
+            parts = [conn.read_split(sp, columns) for sp in splits[d::n]]
+            if not parts:
+                from ..columnar import empty_batch
+                meta = conn.get_table_metadata(h.schema, h.table)
+                parts = [empty_batch({c.name: c.type for c in meta.columns
+                                      if c.name in set(columns)})]
+            part = device_concat(parts).on_device()
+            jax.block_until_ready([c.data for c in part.columns.values()])
+            return part
+
+    with ThreadPoolExecutor(max_workers=n) as pool:
+        return shard_parts(list(pool.map(fill, range(n))), mesh)
+
+
+def _generate_sharded(gen, splits, mesh):
+    """Every shard's rows from ``gen`` (connectors/tpch_device.py
+    ``ShardGenerator``) on the shard's own chip: the row indices of its
+    splits go in, a count program sizes the lanes, a second program
+    makes them. Two compiles a scan, whatever the number of splits."""
+    from ..parallel.mesh import AXIS, ShardedBatch, replicated, row_spec
+    from ..parallel.spmd import P, mesh_call
+    n = mesh.devices.size
+    indices = [gen.order_indices(splits[d::n]) for d in range(n)]
+    width = capacity_for(max(len(i) for i in indices), minimum=8)
+    oi = jax.device_put(
+        np.concatenate([np.pad(i, (0, width - len(i))) for i in indices]),
+        row_spec(mesh))
+    live = jax.device_put(np.asarray([len(i) for i in indices], np.int64),
+                          replicated(mesh))
+    # 100.0 as an OPERAND: see connectors/tpch_device.py _retailprice
+    hundred = jax.device_put(np.float64(100.0), replicated(mesh))
+    operands = (oi, live, hundred)
+
+    def mine(vec):
+        return vec[jax.lax.axis_index(AXIS)]
+
+    def build_rows():
+        return (lambda o, k, h: jax.lax.all_gather(
+            gen.rows(o, mine(k), h), AXIS), (P(AXIS), P(), P()), P())
+
+    with active_span("host_read", site="scan_rows"):
+        totals = np.asarray(mesh_call("scan_rows", gen.key, mesh,
+                                      operands, build_rows))
+    cap = capacity_for(max(int(totals.max()), 1), minimum=8)
+
+    def build_lanes():
+        def f(o, k, h):
+            out = gen.batch(o, mine(k), h, cap)
+            return out.columns, jax.lax.all_gather(out.num_rows_device(),
+                                                   AXIS)
+        return f, (P(AXIS), P(), P()), (P(AXIS), P())
+
+    cols, counts = mesh_call("scan_gen", (gen.key, cap), mesh, operands,
+                             build_lanes)
+    return ShardedBatch(cols, counts, mesh, cap)
+
+
+def read_table_sharded(conn, handle, columns, mesh):
+    """A table's lanes row-sharded across ``mesh`` and resident there:
+    split i belongs to shard i mod n (``_fill_sharded``), and every
+    later scan of the table under the same pushed-down constraint is a
+    lookup. Lanes are cached per column under one entry of the
+    connector's scan cache (budget and eviction as for its other
+    entries; a sharded lane is charged its per-chip share). Returns a
+    ``ShardedBatch`` of connector columns."""
+    from ..parallel.mesh import ShardedBatch
+    h = handle
+    if not getattr(conn, "scan_cache_ok", False) \
+            or CONFIG.scan_cache_bytes <= 0:
+        return _fill_sharded(conn, h, list(columns), mesh)
+    skey = (h.schema, h.table, -2, mesh.devices.size, h.constraint,
+            h.limit, tuple(int(d.id) for d in mesh.devices.flat))
+
+    def cached(entry):
+        return ShardedBatch({c: entry["cols"][c] for c in columns},
+                            entry["num_rows"], mesh, entry["per"])
+
+    with _SCAN_CACHE_LOCK:
+        state = _SCAN_CACHES.get(conn)
+        entry = state["entries"].get(skey) if state else None
+        missing = [c for c in columns
+                   if entry is None or c not in entry["cols"]]
+        if not missing:
+            _M_SCAN.inc(cache="sharded", result="hit")
+            return cached(entry)
+    _M_SCAN.inc(cache="sharded", result="miss")
+    sb = _fill_sharded(conn, h, missing, mesh)
+    size = sum(_col_bytes(c) for c in sb.columns.values())
+    with _SCAN_CACHE_LOCK:
+        state = _SCAN_CACHES.get(conn)
+        if state is None:
+            state = {"entries": {}, "order": [], "bytes": 0}
+            _SCAN_CACHES[conn] = state
+        if size <= CONFIG.scan_cache_bytes:
+            _make_room(state, size)
+            entry = state["entries"].get(skey)
+            if entry is None:
+                entry = {"cols": {}, "num_rows": sb.num_rows,
+                         "per": sb.per_shard_cap}
+                state["entries"][skey] = entry
+                state["order"].append(skey)
+            for name, col in sb.columns.items():
+                if name not in entry["cols"]:
+                    entry["cols"][name] = col
+                    state["bytes"] += _col_bytes(col)
+            _M_SCAN_BYTES.set(state["bytes"],
+                              connector=getattr(conn, "name",
+                                                type(conn).__name__))
+            if all(c in entry["cols"] for c in columns):
+                return cached(entry)
+    # the budget cannot hold these lanes: serve the read uncached
+    if len(missing) == len(columns):
+        return sb
+    return _fill_sharded(conn, h, list(columns), mesh)
 
 
 def _amf_post(sym: str, k: int):

@@ -380,19 +380,6 @@ FAILOVER_PARTITIONS = METRICS.counter(
 MPP_OVERLAP_RATIO = METRICS.gauge(
     "trino_tpu_mpp_pipeline_overlap_ratio",
     "Pipelined stage overlap of the most recent stage-DAG query")
-# ICI-native exchange (stage/ici.py): bytes moved by device-collective
-# stage boundaries (jax.lax.all_to_all / in-slice replication) — the
-# counterpart of the spool/HTTP leg's
-# trino_tpu_exchange_partition_bytes_total
-EXCHANGE_ICI_BYTES = METRICS.counter(
-    "trino_tpu_exchange_ici_bytes_total",
-    "Bytes exchanged at in-slice (device collective) stage boundaries",
-    ("kind",))
-EXCHANGE_ICI_EDGES = METRICS.counter(
-    "trino_tpu_exchange_ici_edges_total",
-    "Stage-boundary exchanges lowered to in-slice device collectives",
-    ("kind",))
-
 # beyond-HBM morsel streaming (exec/streamjoin.py): registered here —
 # not in the lazily-imported streaming module — so every consumer
 # (bench deltas, /metrics scrapes, tests) sees the same labeled
@@ -546,7 +533,8 @@ QUERY_PHASE_SECONDS = METRICS.histogram(
     "trino_tpu_query_phase_seconds",
     "Wall time of the spans of a served query, by span name (root "
     "phases submit..finish; device_execute, jit_trace, host_read and "
-    "scan_fill under execute)", ("phase",), buckets=PHASE_BUCKETS)
+    "scan_fill under execute; exchange around each mesh exchange)",
+    ("phase",), buckets=PHASE_BUCKETS)
 DEVICE_PROGRAMS = METRICS.counter(
     "trino_tpu_device_programs_total",
     "Device programs dispatched by traced queries, by the cache (and "
@@ -566,6 +554,16 @@ JOIN_SEARCH_STEPS = METRICS.counter(
     "(ops/join.py probe_runs): over the probes, 3-4 where the "
     "directory engages, log2(build capacity)+1 where one key fills a "
     "bucket", ("site",))
+EXCHANGE_BYTES = METRICS.counter(
+    "trino_tpu_mesh_exchange_bytes_total",
+    "Bytes the mesh executor's exchanges moved in traced queries: live "
+    "rows times the widths of the lanes sent, not padding, by kind "
+    "(repartition: all_to_all by key hash or range; broadcast: "
+    "all_gather of a join's build side; gather: the collect on the "
+    "coordinator)", ("kind",))
+EXCHANGE_ROWS = METRICS.counter(
+    "trino_tpu_mesh_exchange_rows_total",
+    "Live rows those exchanges moved, by kind", ("kind",))
 SCAN_FILL_SECONDS = METRICS.histogram(
     "trino_tpu_scan_fill_seconds",
     "Scan-cache miss path: reading or generating a split's missing "
@@ -611,6 +609,10 @@ def observe_span(sp) -> None:
         DEVICE_PROGRAMS.inc_at(_label_key(program.split(":", 1)[0]))
     elif name == "scan_fill":
         SCAN_FILL_SECONDS.observe_at((), wall)
+    elif name == "exchange":
+        kind = _label_key(sp.attrs.get("kind", "other"))
+        EXCHANGE_BYTES.inc_at(kind, sp.attrs.get("bytes", 0))
+        EXCHANGE_ROWS.inc_at(kind, sp.attrs.get("rows", 0))
 
 
 def write_exposition(handler) -> None:

@@ -15,8 +15,8 @@ are the first ``num_rows[d]`` of its slice (num_rows is a replicated
 
 from __future__ import annotations
 
-from dataclasses import dataclass, replace as dc_replace
-from typing import Dict, List, Optional, Sequence
+from dataclasses import dataclass
+from typing import Dict, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -65,6 +65,13 @@ class ShardedBatch:
         return {k: c.type for k, c in self.columns.items()}
 
 
+def row_bytes(cols: Dict[str, Column]) -> int:
+    """Bytes one row of these columns occupies in the lanes an exchange
+    moves (data, validity, second lane)."""
+    return sum(int(np.dtype(lane.dtype).itemsize) for c in cols.values()
+               for lane in (c.data, c.valid, c.data2) if lane is not None)
+
+
 def shard_batch(batch: Batch, mesh: Mesh,
                 per_shard_cap: Optional[int] = None) -> ShardedBatch:
     """Round-robin-by-range scatter of a host Batch across the mesh
@@ -98,56 +105,88 @@ def shard_batch(batch: Batch, mesh: Mesh,
     return ShardedBatch(cols, jnp.asarray(counts), mesh, per)
 
 
+def mesh_by_default() -> bool:
+    """Whether a server with no instruction runs queries over the mesh:
+    only where the platform is a TPU AND this process sees more than
+    one chip. A one-chip host, and the CPU (where a test suite may force
+    any number of virtual devices), keep the one-device path."""
+    return jax.default_backend() == "tpu" and jax.local_device_count() > 1
+
+
 def shard_parts(parts: Sequence[Batch], mesh: Mesh) -> ShardedBatch:
     """Place per-worker Batches directly: part i -> device i (splits
-    already assigned per node, the SourcePartitionedScheduler path)."""
+    already assigned per node, the SourcePartitionedScheduler path).
+    Each part's lanes go to (or already live on) their own device and
+    the global lane is assembled from them in place: nothing passes
+    through one device, so a part may be as large as a chip holds."""
     n = mesh.devices.size
     assert len(parts) == n
     per = max(capacity_for(max(p.num_rows_host() for p in parts),
                            minimum=8), 8)
-    from ..columnar import pad_batch
-    parts = [pad_batch(p, per) for p in parts]
-    # merge dictionaries per column across parts
-    names = parts[0].names
+    from ..types import is_string
+    devices = list(mesh.devices.flat)
     spec = row_spec(mesh)
+
+    def fit(lane, d):
+        """The lane at ``per`` rows, committed to device ``d``; a
+        device lane is padded where it lives, never through the host."""
+        k = lane.shape[0]
+        if k != per:
+            if isinstance(lane, np.ndarray):
+                lane = np.concatenate(
+                    [lane[:per], np.zeros(max(per - k, 0), lane.dtype)])
+            else:
+                with jax.default_device(d):
+                    lane = jnp.pad(lane[:per], (0, max(per - k, 0)))
+        return jax.device_put(lane, d)
+
+    def glue(lanes):
+        return jax.make_array_from_single_device_arrays(
+            (n * per,), spec, [fit(l, d) for l, d in zip(lanes, devices)])
+
     cols = {}
-    counts = jnp.asarray([p.num_rows_host() for p in parts],
-                         dtype=jnp.int64)
-    for name in names:
+    counts = jax.device_put(
+        np.asarray([p.num_rows_host() for p in parts], dtype=np.int64),
+        replicated(mesh))
+    for name in parts[0].names:
         pcols = [p.column(name) for p in parts]
         typ = pcols[0].type
-        from ..types import is_string
-        if is_string(typ):
-            merged = pcols[0].dictionary
-            remaps = [np.arange(len(merged), dtype=np.int32)]
+        dic = pcols[0].dictionary
+        lanes = [c.data for c in pcols]
+        if is_string(typ) and any(c.dictionary is not dic
+                                  for c in pcols[1:]):
+            # merge the parts' dictionaries (host tables, tiny) and
+            # remap each part's codes
+            remaps = [np.arange(len(dic), dtype=np.int32)]
             for c in pcols[1:]:
-                merged, _, ro = merged.merge(c.dictionary)
+                dic, _, ro = dic.merge(c.dictionary)
                 remaps.append(ro)
-            lanes = [np.asarray(rm)[np.asarray(c.data)]
+            lanes = [np.asarray(rm)[np.asarray(c.data)].astype(np.int32)
                      for c, rm in zip(pcols, remaps)]
-            data = jax.device_put(
-                jnp.asarray(np.concatenate(lanes).astype(np.int32)), spec)
-            dic = merged
-        else:
-            data = jax.device_put(
-                jnp.concatenate([jnp.asarray(c.data) for c in pcols]),
-                spec)
-            dic = None
         valid = None
         if any(c.valid is not None for c in pcols):
-            vl = [np.ones(per, bool) if c.valid is None
-                  else np.asarray(c.valid) for c in pcols]
-            valid = jax.device_put(jnp.asarray(np.concatenate(vl)), spec)
-        cols[name] = Column(typ, data, valid, dic)
+            valid = glue([np.ones(per, bool) if c.valid is None
+                          else c.valid for c in pcols])
+        cols[name] = Column(typ, glue(lanes), valid,
+                            dic if is_string(typ) else None)
     return ShardedBatch(cols, counts, mesh, per)
 
 
 def unshard_batch(sb: ShardedBatch) -> Batch:
     """GATHER: collect live prefixes of every shard into one host Batch
     (the final exchange to the coordinator)."""
+    from ..obs.trace import active_span
     n, per = sb.n_shards, sb.per_shard_cap
-    counts = np.asarray(sb.num_rows)
+    with active_span("host_read", site="gather_rows"):
+        counts = np.asarray(sb.num_rows)
     total = int(counts.sum())
+    with active_span("exchange", kind="gather", rows=total,
+                     bytes=total * row_bytes(sb.columns)):
+        return _gather_live(sb, counts, total)
+
+
+def _gather_live(sb: ShardedBatch, counts, total: int) -> Batch:
+    n, per = sb.n_shards, sb.per_shard_cap
     cap = capacity_for(max(total, 1), minimum=8)
     idx_parts = [np.arange(counts[d], dtype=np.int64) + d * per
                  for d in range(n)]

@@ -5,40 +5,113 @@ Reference parity: the exchange layer — PartitionedOutputOperator.java:55
 :149 (pull + merge), BroadcastOutputBuffer (replicate). TPU-first redesign
 (SURVEY.md §2.7, §7.4): REMOTE REPARTITION == ``jax.lax.all_to_all`` over
 the ICI mesh inside a ``shard_map``; REPLICATE == ``all_gather``; GATHER
-== host collect (mesh.py unshard_batch). There is no wire serde or
-pull/ack protocol inside a slice — XLA schedules the collective.
+== collect on the coordinator (mesh.py unshard_batch). There is no wire
+serde or pull/ack protocol inside a slice — XLA schedules the collective.
 
 The same columnar kernels (ops/groupby, ops/join, exec/expr) run
 unchanged inside the shard_map trace: a Trino *task* is the per-shard
-slice of one SPMD program. Host syncs happen only between shard_map
-calls, for data-dependent capacity decisions (the two-phase pattern of
+slice of one SPMD program. Host syncs happen only between programs, for
+data-dependent capacity decisions (the two-phase pattern of
 ops/join.py, lifted to the distributed case).
+
+Mesh programs are cached and named like every other program of the
+engine (exec/progkey.py): ``mesh_program`` keeps ONE jitted
+``shard_map`` per (kind, key, mesh, operand structure) under the name
+``spmd_<kind>_<key8>``, so a repeated query traces nothing, and every
+dispatch is a ``device_execute`` / ``jit_trace`` span counted in
+``trino_tpu_device_programs_total{kind="spmd_<kind>"}``. A caller of
+``shard_apply`` and its kin that cannot name what its closure captures
+passes ``key=None`` and gets a per-call program
+(``spmd_<kind>_local``); the exchanges always have a key.
+
+The exchange is sized by what is sent. Phase 1 (``exchange_counts``)
+gives the [source, destination] row-count matrix; the host reads it
+once and picks the per-pair send capacity and the per-shard receive
+capacity. Phase 2 bins rows with one ``cumsum`` per destination and one
+int32 scatter (no sort), sends ``[n_dev, send_cap]`` buffers through
+``all_to_all`` and lays the received runs end to end with an index
+computed by arithmetic (no ``nonzero``). Received rows keep
+(source shard, source position) order within each destination.
 """
 
 from __future__ import annotations
 
-from functools import partial
-from typing import Dict, List, Optional, Sequence, Tuple
+import threading
+from typing import Callable, Dict, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from jax import shard_map
 
 from ..columnar import Batch, Column
+from ..config import CONFIG, capacity_for
+from ..obs.metrics import JIT_CACHE_LOOKUPS as _M_JIT
+from ..obs.trace import active_span, dispatch_span
 from ..ops.groupby import AggInput, group_aggregate
 from ..ops.hashing import hash_columns
-from .mesh import AXIS, ShardedBatch, row_spec
+from .mesh import AXIS, ShardedBatch, row_bytes
+
+# --------------------------------------------------------------------------
+# keyed, named mesh programs
+# --------------------------------------------------------------------------
+
+_PROGRAMS: Dict[tuple, object] = {}
+_PROGRAMS_LOCK = threading.Lock()
 
 
-def _spmd(f, **kw):
-    """``shard_map`` as ONE jitted program per call. Called eagerly,
-    shard_map dispatches every primitive of ``f`` as its own mesh-wide
-    program: a q1 partial->exchange->final aggregation is hundreds of
-    separate SPMD compiles (a 4-device tpch.tiny q1 did not return in
-    240 s on a cold cache); jitted it is one."""
-    return jax.jit(shard_map(f, **kw))
+def _mesh_key(mesh) -> tuple:
+    return tuple(int(d.id) for d in mesh.devices.flat)
+
+
+def mesh_program(kind: str, key, mesh, operands,
+                 build: Callable[[], tuple]):
+    """The jitted ``shard_map`` program of ``kind`` for ``key`` over
+    ``mesh``: (program, hit). ``operands`` is the tuple of input
+    pytrees — its STRUCTURE (column names, types, dictionaries, which
+    lanes exist) is part of the identity, shapes are not (jax
+    specializes per shape under one callable). ``build()`` returns
+    ``(f, in_specs, out_specs)`` and runs on a miss only. ``key`` must
+    name everything ``f`` closes over; ``None`` means it cannot be
+    named, and the program is built for this call alone."""
+    from ..exec.progkey import named_jit
+
+    def make():
+        f, in_specs, out_specs = build()
+        return named_jit(
+            shard_map(f, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs, check_vma=False),
+            "spmd_" + kind, key)
+
+    if key is None:
+        return make(), False
+    ck = (kind, key, _mesh_key(mesh), jax.tree.structure(operands))
+    with _PROGRAMS_LOCK:
+        prog = _PROGRAMS.get(ck)
+    hit = prog is not None
+    _M_JIT.inc(cache="spmd", result="hit" if hit else "miss")
+    if prog is None:
+        prog = make()
+        with _PROGRAMS_LOCK:
+            limit = max(int(CONFIG.jit_cache_entries), 1)
+            while len(_PROGRAMS) >= limit:
+                _PROGRAMS.pop(next(iter(_PROGRAMS)))
+            prog = _PROGRAMS.setdefault(ck, prog)
+    return prog, hit
+
+
+def mesh_call(kind: str, key, mesh, operands, build):
+    """Dispatch the mesh program on ``operands`` under its span. Inside
+    a traced query the outputs are waited for, so the span holds the
+    program's time on the chips and not only its dispatch."""
+    prog, hit = mesh_program(kind, key, mesh, operands, build)
+    with dispatch_span(None, prog.program, hit, "spmd_" + kind) as sp:
+        out = prog(*operands)
+        if sp is not None:
+            jax.block_until_ready(out)
+    return out
 
 
 def _col_specs(cols: Dict[str, Column], spec) -> Dict[str, Column]:
@@ -46,99 +119,122 @@ def _col_specs(cols: Dict[str, Column], spec) -> Dict[str, Column]:
     return jax.tree.map(lambda _: spec, cols)
 
 
+def _sharded_in(sb: ShardedBatch):
+    return (_col_specs(sb.columns, P(AXIS)), P())
+
+
+def _per_shard(cols: Dict[str, Column], n: int) -> int:
+    some = next(iter(cols.values()))
+    return int(jnp.asarray(some.data).shape[0]) // n
+
+
+def _lanes(c: Column) -> List[jax.Array]:
+    return [jnp.asarray(l) for l in (c.data, c.valid, c.data2)
+            if l is not None]
+
+
+def _relane(c: Column, moved: Sequence[jax.Array]) -> Column:
+    it = iter(moved)
+    data = next(it)
+    valid = next(it) if c.valid is not None else None
+    d2 = next(it) if c.data2 is not None else None
+    return Column(c.type, data, valid, c.dictionary, d2)
+
+
+def _read_counts(arr, site: str) -> np.ndarray:
+    """The blocking read of a small replicated count vector or matrix
+    between the halves of a two-phase pattern."""
+    with active_span("host_read", site=site):
+        return np.asarray(arr)
+
+
 # --------------------------------------------------------------------------
-# shard-level repartition (runs inside a shard_map trace)
+# the exchange (runs inside a shard_map trace)
 # --------------------------------------------------------------------------
 
-def _shard_repartition(cols: Dict[str, Column], my_n: jax.Array,
-                       key_names: Sequence[str], n_dev: int,
-                       out_cap: int) -> Tuple[Dict[str, Column],
-                                              jax.Array]:
-    """Per-shard: hash-bin rows by destination, all_to_all, compact.
-    Returns (received columns [out_cap], my new row count)."""
+def _hash_pid(cols: Dict[str, Column], key_names: Sequence[str],
+              n_dev: int) -> jax.Array:
     h = hash_columns([cols[k] for k in key_names])
-    pid = (h % jnp.uint64(n_dev)).astype(jnp.int32)
-    return _shard_exchange(cols, my_n, pid, n_dev, out_cap)
+    return (h % jnp.uint64(n_dev)).astype(jnp.int32)
+
+
+def _dest_counts(pid: jax.Array, live: jax.Array, n_dev: int) -> jax.Array:
+    """[n_dev] int32: live rows bound for each destination."""
+    hit = (pid[None, :] == jnp.arange(n_dev, dtype=jnp.int32)[:, None]) \
+        & live[None, :]
+    return jnp.sum(hit.astype(jnp.int32), axis=1)
 
 
 def _shard_exchange(cols: Dict[str, Column], my_n: jax.Array,
-                    pid: jax.Array, n_dev: int,
+                    pid: jax.Array, n_dev: int, send_cap: int,
                     out_cap: int) -> Tuple[Dict[str, Column], jax.Array]:
-    """Per-shard exchange body: given each row's destination shard id,
-    bin rows, all_to_all, compact. The received rows preserve
-    (source-shard, source-position) order within each destination."""
-    some = next(iter(cols.values()))
-    per = int(some.data.shape[0])
-    live = jnp.arange(per, dtype=jnp.int64) < my_n
-    sort_key = jnp.where(live, pid, n_dev)
-    order = jnp.argsort(sort_key, stable=True)
-
-    counts = jax.ops.segment_sum(
-        live.astype(jnp.int64), jnp.clip(pid, 0, n_dev - 1),
-        num_segments=n_dev)
-    starts = jnp.cumsum(counts) - counts
-
-    # send slot matrix [n_dev, per]: bin p's row j comes from
-    # order[starts[p] + j]
-    j = jnp.arange(per, dtype=jnp.int64)[None, :]
-    src = starts[:, None] + j
-    send_idx = jnp.take(order, jnp.clip(src, 0, per - 1), axis=0)
+    """Per-shard exchange body: given each row's destination shard,
+    move the live rows there. ``send_cap`` bounds the rows one shard
+    sends to one destination, ``out_cap`` the rows one shard receives;
+    both come from phase 1's real counts."""
+    per = int(pid.shape[0])
+    live = jnp.arange(per, dtype=jnp.int32) < my_n.astype(jnp.int32)
+    # slot of a row among the live rows with its destination: one
+    # running count per destination, no sort
+    rank = jnp.zeros((per,), jnp.int32)
+    counts = []
+    for p in range(n_dev):
+        m = live & (pid == p)
+        run = jnp.cumsum(m.astype(jnp.int32))
+        rank = jnp.where(m, run - 1, rank)
+        counts.append(run[-1])
+    counts = jnp.stack(counts)
+    slots = n_dev * send_cap
+    flat = jnp.where(live, pid * send_cap + rank, slots)
+    # source row of every send slot: ONE int32 scatter; dead rows fall
+    # off the end and are dropped
+    src = jnp.zeros((slots,), jnp.int32).at[flat].set(
+        jnp.arange(per, dtype=jnp.int32), mode="drop",
+        unique_indices=True)
 
     recv_counts = jax.lax.all_to_all(counts, AXIS, 0, 0)
-    new_n = jnp.sum(recv_counts)
-
-    # compact gather index over the received [n_dev, per] buffers
-    rj = jnp.arange(per, dtype=jnp.int64)[None, :]
-    recv_live = (rj < recv_counts[:, None]).reshape(-1)
-    flat_idx = jnp.nonzero(recv_live, size=out_cap, fill_value=0)[0]
+    starts = jnp.cumsum(recv_counts) - recv_counts
+    new_n = jnp.sum(recv_counts).astype(jnp.int64)
+    # output slot k holds row (k - starts[s]) of source s, where s is
+    # the last source whose run starts at or before k
+    k = jnp.arange(out_cap, dtype=jnp.int32)
+    s = jnp.zeros((out_cap,), jnp.int32)
+    for p in range(1, n_dev):
+        s = s + (k >= starts[p]).astype(jnp.int32)
+    ridx = jnp.clip(s * send_cap + (k - jnp.take(starts, s)), 0,
+                    slots - 1)
 
     out: Dict[str, Column] = {}
     for name, c in cols.items():
-        lanes = [c.data] + ([c.valid] if c.valid is not None else []) \
-            + ([c.data2] if c.data2 is not None else [])
         moved = []
-        for lane in lanes:
-            send = jnp.take(jnp.asarray(lane), send_idx, axis=0)
+        for lane in _lanes(c):
+            send = jnp.take(lane, src, axis=0).reshape(n_dev, send_cap)
             recv = jax.lax.all_to_all(send, AXIS, 0, 0)
-            moved.append(jnp.take(recv.reshape(-1), flat_idx, axis=0))
-        data = moved[0]
-        k = 1
-        valid = None
-        if c.valid is not None:
-            valid = moved[k]
-            k += 1
-        d2 = moved[k] if c.data2 is not None else None
-        out[name] = Column(c.type, data, valid, c.dictionary, d2)
+            moved.append(jnp.take(recv.reshape(-1), ridx, axis=0))
+        out[name] = _relane(c, moved)
     return out, new_n
 
 
 def _shard_broadcast(cols: Dict[str, Column], num_rows_vec: jax.Array,
                      out_cap: int) -> Tuple[Dict[str, Column], jax.Array]:
-    """Per-shard: replicate every shard's live rows to all shards
-    (REPLICATE exchange / broadcast join build side)."""
-    some = next(iter(cols.values()))
-    per = int(some.data.shape[0])
-    n_dev = num_rows_vec.shape[0]
-    j = jnp.arange(per, dtype=jnp.int64)[None, :]
-    live = (j < num_rows_vec[:, None]).reshape(-1)
-    flat_idx = jnp.nonzero(live, size=out_cap, fill_value=0)[0]
+    """Per-shard: every shard's live rows, on every shard (REPLICATE
+    exchange / broadcast join build side), shard-major."""
+    per = _per_shard(cols, 1)
+    n_dev = int(num_rows_vec.shape[0])
+    counts = num_rows_vec.astype(jnp.int32)
+    starts = jnp.cumsum(counts) - counts
+    k = jnp.arange(out_cap, dtype=jnp.int32)
+    s = jnp.zeros((out_cap,), jnp.int32)
+    for p in range(1, n_dev):
+        s = s + (k >= starts[p]).astype(jnp.int32)
+    idx = jnp.clip(s * per + (k - jnp.take(starts, s)), 0,
+                   n_dev * per - 1)
     new_n = jnp.sum(num_rows_vec)
     out: Dict[str, Column] = {}
     for name, c in cols.items():
-        lanes = [c.data] + ([c.valid] if c.valid is not None else []) \
-            + ([c.data2] if c.data2 is not None else [])
-        moved = []
-        for lane in lanes:
-            g = jax.lax.all_gather(jnp.asarray(lane), AXIS)  # [n_dev, per]
-            moved.append(jnp.take(g.reshape(-1), flat_idx, axis=0))
-        data = moved[0]
-        k = 1
-        valid = None
-        if c.valid is not None:
-            valid = moved[k]
-            k += 1
-        d2 = moved[k] if c.data2 is not None else None
-        out[name] = Column(c.type, data, valid, c.dictionary, d2)
+        moved = [jnp.take(jax.lax.all_gather(lane, AXIS).reshape(-1),
+                          idx, axis=0) for lane in _lanes(c)]
+        out[name] = _relane(c, moved)
     return out, new_n
 
 
@@ -146,29 +242,71 @@ def _shard_broadcast(cols: Dict[str, Column], num_rows_vec: jax.Array,
 # whole-mesh operations (host API over ShardedBatch)
 # --------------------------------------------------------------------------
 
-def repartition_by_hash(sb: ShardedBatch, key_names: Sequence[str],
-                        out_cap: Optional[int] = None) -> ShardedBatch:
-    """REMOTE REPARTITION: redistribute rows so equal keys land on the
-    same shard. ``out_cap`` bounds the post-exchange per-shard capacity;
-    default is the safe worst case n_dev * per_shard_cap."""
+def _counts_matrix(sb: ShardedBatch, key, pid_fn,
+                   extra: tuple = ()) -> jax.Array:
+    """Phase 1: the replicated [source, destination] matrix of
+    live-row counts. ``extra``: replicated operands handed on to ``pid_fn`` (a range
+    exchange's splitters), so that they are data of the program and
+    not of its trace."""
     n = sb.n_shards
-    cap = out_cap or n * sb.per_shard_cap
 
-    def f(cols, num_rows_vec):
-        d = jax.lax.axis_index(AXIS)
-        my_n = num_rows_vec[d]
-        out, new_n = _shard_repartition(cols, my_n, key_names, n, cap)
-        counts = jax.lax.all_gather(new_n, AXIS)
-        return out, counts
+    def build():
+        def f(cols, num_rows_vec, *more):
+            my_n = num_rows_vec[jax.lax.axis_index(AXIS)]
+            per = _per_shard(cols, 1)
+            live = jnp.arange(per, dtype=jnp.int64) < my_n
+            pid = pid_fn(cols, my_n, *more)
+            return jax.lax.all_gather(_dest_counts(pid, live, n), AXIS)
+        return f, _sharded_in(sb) + (P(),) * len(extra), P()
 
-    mesh = sb.mesh
-    fn = _spmd(
-        f, mesh=mesh,
-        in_specs=(_col_specs(sb.columns, P(AXIS)), P()),
-        out_specs=(_col_specs(sb.columns, P(AXIS)), P()),
-        check_vma=False)
-    cols, counts = fn(sb.columns, sb.num_rows)
-    return ShardedBatch(cols, counts, mesh, cap)
+    return mesh_call("exchange_counts", key, sb.mesh,
+                     (sb.columns, sb.num_rows) + tuple(extra), build)
+
+
+def _exchange(sb: ShardedBatch, key, pid_fn, counts: np.ndarray,
+              extra: tuple = ()) -> ShardedBatch:
+    """Phase 2: move the rows, at capacities read off ``counts``."""
+    n = sb.n_shards
+    send_cap = capacity_for(max(int(counts.max()), 1), minimum=8)
+    out_cap = capacity_for(max(int(counts.sum(axis=0).max()), 1),
+                           minimum=8)
+
+    def build():
+        def f(cols, num_rows_vec, *more):
+            my_n = num_rows_vec[jax.lax.axis_index(AXIS)]
+            out, new_n = _shard_exchange(
+                cols, my_n, pid_fn(cols, my_n, *more), n, send_cap,
+                out_cap)
+            return out, jax.lax.all_gather(new_n, AXIS)
+        return (f, _sharded_in(sb) + (P(),) * len(extra),
+                (_col_specs(sb.columns, P(AXIS)), P()))
+
+    cols, new_counts = mesh_call(
+        "exchange", (key, send_cap, out_cap), sb.mesh,
+        (sb.columns, sb.num_rows) + tuple(extra), build)
+    return ShardedBatch(cols, new_counts, sb.mesh, out_cap)
+
+
+def _two_phase_exchange(sb: ShardedBatch, key, pid_fn,
+                        extra: tuple = ()) -> ShardedBatch:
+    with active_span("exchange", kind="repartition") as sp:
+        counts = _read_counts(
+            _counts_matrix(sb, key, pid_fn, extra), "exchange_counts")
+        if sp is not None:
+            sp.attrs["rows"] = int(counts.sum())
+            sp.attrs["bytes"] = int(counts.sum()) * row_bytes(sb.columns)
+        return _exchange(sb, key, pid_fn, counts, extra)
+
+
+def repartition_by_hash(sb: ShardedBatch,
+                        key_names: Sequence[str]) -> ShardedBatch:
+    """REMOTE REPARTITION: redistribute rows so equal keys land on the
+    same shard, both phases (one blocking read of the counts between
+    them), inside one ``exchange`` span."""
+    keys = tuple(key_names)
+    n = sb.n_shards
+    return _two_phase_exchange(
+        sb, ("hash", keys), lambda cols, _n: _hash_pid(cols, keys, n))
 
 
 # --------------------------------------------------------------------------
@@ -204,23 +342,10 @@ def sample_range_splitters(sb: ShardedBatch, sort_keys,
     distributed_sort / MergeOperator's range exchange). Returns a list
     of per-lane splitter value arrays, or None when the relation is
     empty."""
-    import numpy as np
     from ..ops.sort import sort_lanes
     n = sb.n_shards
     S = samples_per_shard
-
-    def f(cols, num_rows_vec):
-        d = jax.lax.axis_index(AXIS)
-        my_n = num_rows_vec[d]
-        b = Batch(cols, my_n)
-        lanes = sort_lanes(b, sort_keys)[1:]
-        pos = (jnp.arange(S, dtype=jnp.int64)
-               * jnp.maximum(my_n, 1)) // S
-        samp = tuple(
-            jnp.take(l, jnp.clip(pos, 0, l.shape[0] - 1), mode="clip")
-            for l in lanes)
-        live = jnp.arange(S, dtype=jnp.int64) < my_n
-        return samp + (live,)
+    keys = tuple(sort_keys)
 
     # out_specs needs the lane count up front; derive it from a tiny
     # 8-row head batch so no full-column lane computation runs here
@@ -228,13 +353,24 @@ def sample_range_splitters(sb: ShardedBatch, sort_keys,
                          None if c.valid is None
                          else jnp.asarray(c.valid)[:8], c.dictionary)
             for name, c in sb.columns.items()}
-    n_lanes_probe = len(sort_lanes(Batch(head, 0), sort_keys)) - 1
+    n_lanes_probe = len(sort_lanes(Batch(head, 0), keys)) - 1
 
-    g = _spmd(f, mesh=sb.mesh,
-              in_specs=(_col_specs(sb.columns, P(AXIS)), P()),
-              out_specs=tuple([P(AXIS)] * (n_lanes_probe + 1)),
-              check_vma=False)
-    out = g(sb.columns, sb.num_rows)
+    def build():
+        def f(cols, num_rows_vec):
+            my_n = num_rows_vec[jax.lax.axis_index(AXIS)]
+            lanes = sort_lanes(Batch(cols, my_n), keys)[1:]
+            pos = (jnp.arange(S, dtype=jnp.int64)
+                   * jnp.maximum(my_n, 1)) // S
+            samp = tuple(
+                jnp.take(l, jnp.clip(pos, 0, l.shape[0] - 1), mode="clip")
+                for l in lanes)
+            live = jnp.arange(S, dtype=jnp.int64) < my_n
+            return samp + (live,)
+        return (f, _sharded_in(sb),
+                tuple([P(AXIS)] * (n_lanes_probe + 1)))
+
+    out = mesh_call("range_sample", (keys, S), sb.mesh,
+                    (sb.columns, sb.num_rows), build)
     live = np.asarray(out[-1])
     if not live.any():
         return None
@@ -245,271 +381,159 @@ def sample_range_splitters(sb: ShardedBatch, sort_keys,
     return [l[picks] for l in lanes_h]
 
 
-def range_dest_counts(sb: ShardedBatch, sort_keys,
-                      splitter_lanes) -> jax.Array:
-    """Per-destination row totals for a range exchange (two-phase
-    capacity sizing, mirroring repartition_dest_counts)."""
-    n = sb.n_shards
-
-    def f(cols, num_rows_vec):
-        d = jax.lax.axis_index(AXIS)
-        my_n = num_rows_vec[d]
-        some = next(iter(cols.values()))
-        per = int(some.data.shape[0])
-        live = jnp.arange(per, dtype=jnp.int64) < my_n
-        pid = _range_pid(Batch(cols, my_n), sort_keys, splitter_lanes)
-        counts = jax.ops.segment_sum(
-            live.astype(jnp.int64), jnp.clip(pid, 0, n - 1),
-            num_segments=n)
-        return jax.lax.psum(counts, AXIS)
-
-    g = _spmd(f, mesh=sb.mesh,
-              in_specs=(_col_specs(sb.columns, P(AXIS)), P()),
-              out_specs=P(),
-              check_vma=False)
-    return g(sb.columns, sb.num_rows)
-
-
-def repartition_by_range(sb: ShardedBatch, sort_keys, splitter_lanes,
-                         out_cap: Optional[int] = None) -> ShardedBatch:
+def repartition_by_range(sb: ShardedBatch, sort_keys,
+                         splitter_lanes) -> ShardedBatch:
     """Range exchange: redistribute rows so shard i holds the i-th
     ORDER BY slice. A per-shard sort afterwards yields a globally
-    sorted relation under shard-major gather (unshard_batch)."""
-    n = sb.n_shards
-    cap = out_cap or n * sb.per_shard_cap
-
-    def f(cols, num_rows_vec):
-        d = jax.lax.axis_index(AXIS)
-        my_n = num_rows_vec[d]
-        pid = _range_pid(Batch(cols, my_n), sort_keys, splitter_lanes)
-        out, new_n = _shard_exchange(cols, my_n, pid, n, cap)
-        counts = jax.lax.all_gather(new_n, AXIS)
-        return out, counts
-
-    fn = _spmd(
-        f, mesh=sb.mesh,
-        in_specs=(_col_specs(sb.columns, P(AXIS)), P()),
-        out_specs=(_col_specs(sb.columns, P(AXIS)), P()),
-        check_vma=False)
-    cols, counts = fn(sb.columns, sb.num_rows)
-    return ShardedBatch(cols, counts, sb.mesh, cap)
+    sorted relation under shard-major gather (unshard_batch). The
+    splitters go in as operands: one program per sort keys, whatever
+    the sample gave."""
+    keys = tuple(sort_keys)
+    return _two_phase_exchange(
+        sb, ("range", keys),
+        lambda cols, my_n, *splitters: _range_pid(
+            Batch(cols, my_n), keys, splitters),
+        tuple(jnp.asarray(l) for l in splitter_lanes))
 
 
 def distributed_group_aggregate(sb: ShardedBatch,
                                 key_names: Sequence[str],
-                                aggs: Sequence[AggInput],
-                                out_cap: Optional[int] = None
-                                ) -> ShardedBatch:
+                                aggs: Sequence[AggInput]) -> ShardedBatch:
     """PARTIAL agg per shard -> all_to_all by key hash -> FINAL agg.
 
-    This is the PushPartialAggregationThroughExchange plan shape
-    (SURVEY.md §2.7 partial/final row) as one SPMD program: every
-    aggregate below declares a combine that is itself a segment op,
-    so the partial output columns feed the final step directly."""
+    The PushPartialAggregationThroughExchange plan shape (SURVEY.md
+    §2.7 partial/final row): every aggregate below declares a combine
+    that is itself a segment op, so the partial output columns feed the
+    final step directly. Three mesh programs around one sized exchange;
+    the partial step is what keeps the exchange small when groups are
+    few."""
     from ..ops.groupby import COMBINABLE_KINDS
-    n = sb.n_shards
-    partial_cap = sb.per_shard_cap
-    exch_cap = n * partial_cap if out_cap is None else out_cap
-
-    decomposable = all(a.kind in COMBINABLE_KINDS for a in aggs)
-    if decomposable:
-        finals: List[AggInput] = [
-            AggInput(COMBINABLE_KINDS[a.kind], a.output, None, a.output)
-            for a in aggs]
-
-    def f(cols, num_rows_vec):
-        d = jax.lax.axis_index(AXIS)
-        my_n = num_rows_vec[d]
-        local = Batch(cols, my_n)
-        if decomposable:
-            part = group_aggregate(local, list(key_names), list(aggs),
-                                   groups_capacity=partial_cap)
-            moved, new_n = _shard_repartition(
-                part.columns, part.num_rows_device(), key_names, n,
-                exch_cap)
-            fin = group_aggregate(Batch(moved, new_n), list(key_names),
-                                  finals, groups_capacity=exch_cap)
-        else:
-            # non-decomposable aggregates (count_distinct / percentile /
-            # argmin / argmax): repartition ROWS by key hash first, then
-            # aggregate exactly — every group is wholly on one shard
-            moved, new_n = _shard_repartition(
-                cols, my_n, key_names, n, exch_cap)
-            fin = group_aggregate(Batch(moved, new_n), list(key_names),
-                                  list(aggs), groups_capacity=exch_cap)
-        counts = jax.lax.all_gather(fin.num_rows_device(), AXIS)
-        return fin.columns, counts
-
-    mesh = sb.mesh
-    fn = _spmd(f, mesh=mesh,
-               in_specs=(_col_specs(sb.columns, P(AXIS)), P()),
-               out_specs=(P(AXIS), P()),
-               check_vma=False)
-    cols, counts = fn(sb.columns, sb.num_rows)
-    return ShardedBatch(cols, counts, mesh, exch_cap)
+    keys, aggs = list(key_names), list(aggs)
+    ident = (tuple(keys), tuple(aggs))
+    if all(a.kind in COMBINABLE_KINDS for a in aggs):
+        finals = [AggInput(COMBINABLE_KINDS[a.kind], a.output, None,
+                           a.output) for a in aggs]
+        part = shard_apply(
+            sb, lambda b: group_aggregate(b, keys, aggs,
+                                          groups_capacity=b.capacity),
+            key=("agg_partial", ident))
+        moved = repartition_by_hash(part, keys)
+        return shard_apply(
+            moved, lambda b: group_aggregate(b, keys, finals,
+                                             groups_capacity=b.capacity),
+            key=("agg_final", ident))
+    # non-decomposable aggregates (count_distinct / percentile /
+    # argmin / argmax): repartition ROWS by key hash first, then
+    # aggregate exactly — every group is wholly on one shard
+    moved = repartition_by_hash(sb, keys)
+    return shard_apply(
+        moved, lambda b: group_aggregate(b, keys, aggs,
+                                         groups_capacity=b.capacity),
+        key=("agg_rows", ident))
 
 
-def shard_apply(sb: ShardedBatch, fn, out_cap: Optional[int] = None
-                ) -> ShardedBatch:
+def _sharded_result(cols, counts, mesh) -> ShardedBatch:
+    return ShardedBatch(cols, counts, mesh,
+                        _per_shard(cols, mesh.devices.size))
+
+
+def shard_apply(sb: ShardedBatch, fn, key=None) -> ShardedBatch:
     """Run a Batch->Batch transformation independently on every shard
     (the intra-task pipeline segment between exchanges: filter/project/
-    partial ops — SURVEY.md §2.7 intra-node row). ``fn`` must keep the
-    capacity at ``out_cap`` (default: unchanged)."""
-    cap = out_cap or sb.per_shard_cap
+    partial ops — SURVEY.md §2.7 intra-node row). ``key`` names what
+    ``fn`` closes over (see ``mesh_program``)."""
 
-    def f(cols, num_rows_vec):
-        d = jax.lax.axis_index(AXIS)
-        out = fn(Batch(cols, num_rows_vec[d]))
-        counts = jax.lax.all_gather(out.num_rows_device(), AXIS)
-        return out.columns, counts
+    def build():
+        def f(cols, num_rows_vec):
+            d = jax.lax.axis_index(AXIS)
+            out = fn(Batch(cols, num_rows_vec[d]))
+            counts = jax.lax.all_gather(out.num_rows_device(), AXIS)
+            return out.columns, counts
+        return f, _sharded_in(sb), (P(AXIS), P())
 
-    g = _spmd(f, mesh=sb.mesh,
-              in_specs=(_col_specs(sb.columns, P(AXIS)), P()),
-              out_specs=(P(AXIS), P()),
-              check_vma=False)
-    cols, counts = g(sb.columns, sb.num_rows)
-    return ShardedBatch(cols, counts, sb.mesh, cap)
-
-
-def shard_totals(sb: ShardedBatch, fn) -> jax.Array:
-    """Per-shard scalar reduction (e.g. join-size phase 1): fn(Batch) ->
-    int scalar; returns the [n_dev] vector (host-readable)."""
-
-    def f(cols, num_rows_vec):
-        d = jax.lax.axis_index(AXIS)
-        t = fn(Batch(cols, num_rows_vec[d]))
-        return jax.lax.all_gather(t, AXIS)
-
-    g = _spmd(f, mesh=sb.mesh,
-              in_specs=(_col_specs(sb.columns, P(AXIS)), P()),
-              out_specs=P(),
-              check_vma=False)
-    return g(sb.columns, sb.num_rows)
-
-
-def repartition_dest_counts(sb: ShardedBatch,
-                            key_names: Sequence[str]) -> jax.Array:
-    """Phase 1 of a two-phase repartition: the [n_dev] vector of row
-    totals each destination shard would receive — lets the caller size
-    the exchange capacity from real counts instead of the
-    n_dev * per_shard_cap worst case (VERDICT weak #10)."""
-    n = sb.n_shards
-
-    def f(cols, num_rows_vec):
-        d = jax.lax.axis_index(AXIS)
-        my_n = num_rows_vec[d]
-        some = next(iter(cols.values()))
-        per = int(some.data.shape[0])
-        live = jnp.arange(per, dtype=jnp.int64) < my_n
-        h = hash_columns([cols[k] for k in key_names])
-        pid = (h % jnp.uint64(n)).astype(jnp.int32)
-        counts = jax.ops.segment_sum(
-            live.astype(jnp.int64), jnp.clip(pid, 0, n - 1),
-            num_segments=n)
-        return jax.lax.psum(counts, AXIS)
-
-    g = _spmd(f, mesh=sb.mesh,
-              in_specs=(_col_specs(sb.columns, P(AXIS)), P()),
-              out_specs=P(),
-              check_vma=False)
-    return g(sb.columns, sb.num_rows)
+    cols, counts = mesh_call("apply", key, sb.mesh,
+                             (sb.columns, sb.num_rows), build)
+    return _sharded_result(cols, counts, sb.mesh)
 
 
 def shard_apply2s(sa: ShardedBatch, sb: ShardedBatch, fn,
-                  out_cap: int) -> ShardedBatch:
+                  key=None) -> ShardedBatch:
     """Per-shard transformation over two co-sharded operands (the
     PARTITIONED-distribution join body: both sides already hash-
-    repartitioned on the join keys, so a shard joins only its slice)."""
+    repartitioned on the join keys, so a shard joins only its slice;
+    a broadcast build side is co-sharded too, whole on every shard)."""
 
-    def f(acols, an, bcols, bn):
-        d = jax.lax.axis_index(AXIS)
-        out = fn(Batch(acols, an[d]), Batch(bcols, bn[d]))
-        counts = jax.lax.all_gather(out.num_rows_device(), AXIS)
-        return out.columns, counts
+    def build():
+        def f(acols, an, bcols, bn):
+            d = jax.lax.axis_index(AXIS)
+            out = fn(Batch(acols, an[d]), Batch(bcols, bn[d]))
+            counts = jax.lax.all_gather(out.num_rows_device(), AXIS)
+            return out.columns, counts
+        return f, _sharded_in(sa) + _sharded_in(sb), (P(AXIS), P())
 
-    g = _spmd(
-        f, mesh=sa.mesh,
-        in_specs=(_col_specs(sa.columns, P(AXIS)), P(),
-                  _col_specs(sb.columns, P(AXIS)), P()),
-        out_specs=(P(AXIS), P()),
-        check_vma=False)
-    cols, counts = g(sa.columns, sa.num_rows, sb.columns, sb.num_rows)
-    return ShardedBatch(cols, counts, sa.mesh, out_cap)
-
-
-def shard_totals2s(sa: ShardedBatch, sb: ShardedBatch, fn) -> jax.Array:
-    """Per-shard scalar over two co-sharded operands."""
-
-    def f(acols, an, bcols, bn):
-        d = jax.lax.axis_index(AXIS)
-        t = fn(Batch(acols, an[d]), Batch(bcols, bn[d]))
-        return jax.lax.all_gather(t, AXIS)
-
-    g = _spmd(
-        f, mesh=sa.mesh,
-        in_specs=(_col_specs(sa.columns, P(AXIS)), P(),
-                  _col_specs(sb.columns, P(AXIS)), P()),
-        out_specs=P(),
-        check_vma=False)
-    return g(sa.columns, sa.num_rows, sb.columns, sb.num_rows)
+    cols, counts = mesh_call(
+        "apply2", key, sa.mesh,
+        (sa.columns, sa.num_rows, sb.columns, sb.num_rows), build)
+    return _sharded_result(cols, counts, sa.mesh)
 
 
 def shard_apply2(sa: ShardedBatch, b_host: Batch, fn,
-                 out_cap: int) -> ShardedBatch:
+                 key=None) -> ShardedBatch:
     """Per-shard transformation with a REPLICATED second operand (a
-    broadcast-join build side / filtering source): fn(shard Batch,
-    replicated Batch) -> Batch of capacity out_cap."""
+    filtering source held by the coordinator): fn(shard Batch,
+    replicated Batch) -> Batch."""
 
-    def f(cols, num_rows_vec, bcols, bn):
-        d = jax.lax.axis_index(AXIS)
-        out = fn(Batch(cols, num_rows_vec[d]), Batch(bcols, bn))
-        counts = jax.lax.all_gather(out.num_rows_device(), AXIS)
-        return out.columns, counts
+    def build():
+        def f(cols, num_rows_vec, bcols, bn):
+            d = jax.lax.axis_index(AXIS)
+            out = fn(Batch(cols, num_rows_vec[d]), Batch(bcols, bn))
+            counts = jax.lax.all_gather(out.num_rows_device(), AXIS)
+            return out.columns, counts
+        return (f, _sharded_in(sa) + (_col_specs(b_host.columns, P()),
+                                      P()), (P(AXIS), P()))
 
-    g = _spmd(
-        f, mesh=sa.mesh,
-        in_specs=(_col_specs(sa.columns, P(AXIS)), P(),
-                  _col_specs(b_host.columns, P()), P()),
-        out_specs=(P(AXIS), P()),
-        check_vma=False)
-    cols, counts = g(sa.columns, sa.num_rows, b_host.columns,
-                     jnp.asarray(b_host.num_rows_host(), jnp.int64))
-    return ShardedBatch(cols, counts, sa.mesh, out_cap)
+    cols, counts = mesh_call(
+        "apply2r", key, sa.mesh,
+        (sa.columns, sa.num_rows, b_host.columns,
+         jnp.asarray(b_host.num_rows_host(), jnp.int64)), build)
+    return _sharded_result(cols, counts, sa.mesh)
 
 
-def shard_totals2(sa: ShardedBatch, b_host: Batch, fn) -> jax.Array:
+def shard_totals2(sa: ShardedBatch, b_host: Batch, fn,
+                  key=None) -> jax.Array:
     """Per-shard scalar with replicated second operand."""
 
-    def f(cols, num_rows_vec, bcols, bn):
-        d = jax.lax.axis_index(AXIS)
-        t = fn(Batch(cols, num_rows_vec[d]), Batch(bcols, bn))
-        return jax.lax.all_gather(t, AXIS)
+    def build():
+        def f(cols, num_rows_vec, bcols, bn):
+            d = jax.lax.axis_index(AXIS)
+            t = fn(Batch(cols, num_rows_vec[d]), Batch(bcols, bn))
+            return jax.lax.all_gather(t, AXIS)
+        return (f, _sharded_in(sa) + (_col_specs(b_host.columns, P()),
+                                      P()), P())
 
-    g = _spmd(
-        f, mesh=sa.mesh,
-        in_specs=(_col_specs(sa.columns, P(AXIS)), P(),
-                  _col_specs(b_host.columns, P()), P()),
-        out_specs=P(),
-        check_vma=False)
-    return g(sa.columns, sa.num_rows, b_host.columns,
-             jnp.asarray(b_host.num_rows_host(), jnp.int64))
+    return mesh_call(
+        "totals2r", key, sa.mesh,
+        (sa.columns, sa.num_rows, b_host.columns,
+         jnp.asarray(b_host.num_rows_host(), jnp.int64)), build)
 
 
-def broadcast_sharded(sb: ShardedBatch,
-                      out_cap: Optional[int] = None) -> ShardedBatch:
-    """REPLICATE exchange: every shard ends up with every row."""
-    n = sb.n_shards
-    cap = out_cap or n * sb.per_shard_cap
+def broadcast_sharded(sb: ShardedBatch) -> ShardedBatch:
+    """REPLICATE exchange: every shard ends up with every row (shard-
+    major), at the capacity of the live total."""
+    with active_span("exchange", kind="broadcast") as sp:
+        total = int(_read_counts(sb.num_rows, "broadcast_rows").sum())
+        if sp is not None:
+            sp.attrs["rows"] = total
+            sp.attrs["bytes"] = total * row_bytes(sb.columns)
+        cap = capacity_for(max(total, 1), minimum=8)
 
-    def f(cols, num_rows_vec):
-        out, new_n = _shard_broadcast(cols, num_rows_vec, cap)
-        counts = jax.lax.all_gather(new_n, AXIS)
-        return out, counts
+        def build():
+            def f(cols, num_rows_vec):
+                out, new_n = _shard_broadcast(cols, num_rows_vec, cap)
+                return out, jax.lax.all_gather(new_n, AXIS)
+            return f, _sharded_in(sb), (P(AXIS), P())
 
-    fn = _spmd(f, mesh=sb.mesh,
-               in_specs=(_col_specs(sb.columns, P(AXIS)), P()),
-               out_specs=(P(AXIS), P()),
-               check_vma=False)
-    cols, counts = fn(sb.columns, sb.num_rows)
-    # broadcast output is replicated per shard; counts[d] all equal total
+        cols, counts = mesh_call("broadcast", cap, sb.mesh,
+                                 (sb.columns, sb.num_rows), build)
+    # every shard holds the same rows; counts[d] all equal the total
     return ShardedBatch(cols, counts, sb.mesh, cap)

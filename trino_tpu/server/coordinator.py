@@ -584,7 +584,8 @@ class Coordinator:
     """HTTP server wrapper. ``start()`` binds an ephemeral (or given)
     port; ``base_uri`` mirrors server/Server.java's announcement."""
 
-    def __init__(self, port: int = 0, distributed: bool = False,
+    def __init__(self, port: int = 0,
+                 distributed: Optional[bool] = None,
                  catalogs=None, resource_groups=None,
                  event_listeners=None, authenticator=None,
                  worker_uris=None, failure_detector=None,
@@ -594,6 +595,12 @@ class Coordinator:
         from .events import EventListenerManager
         self.node_id = f"coordinator-{uuid.uuid4().hex[:8]}"
         self.started = time.time()
+        if distributed is None:
+            # no instruction: the mesh executor where this process is
+            # on a TPU host with more than one chip, else one device
+            # (parallel/mesh.py mesh_by_default)
+            from ..parallel.mesh import mesh_by_default
+            distributed = mesh_by_default()
         self._distributed = distributed
         self._catalogs = catalogs
         self.authenticator = authenticator
@@ -1305,9 +1312,11 @@ class Coordinator:
 
     # ---- SystemProvider SPI (connectors/system.py) --------------------
     def node_infos(self) -> list:
+        mesh = self._proto.mesh
         nodes = [{"nodeId": self.node_id, "uri": self.base_uri,
                   "nodeVersion": "trino-tpu-0.1", "coordinator": True,
-                  "state": "active"}]
+                  "state": "active",
+                  "devices": 1 if mesh is None else int(mesh.devices.size)}]
         detector = getattr(self, "failure_detector", None)
         workers = getattr(self, "workers", None) or []
         for w in workers:

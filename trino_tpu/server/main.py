@@ -69,8 +69,6 @@ def main(argv=None) -> int:
                     help="config directory (config.properties + "
                          "catalog/*.properties)")
     ap.add_argument("--port", type=int, default=None)
-    ap.add_argument("--distributed", action="store_true",
-                    help="execute over the device mesh")
     ap.add_argument("--workers", default=None,
                     help="comma-separated worker base URIs to dispatch "
                          "leaf fragments to (exec/remote.py); also "
@@ -166,7 +164,6 @@ def main(argv=None) -> int:
         pool_bytes = parse_data_size(props["query.max-memory"])
 
     co = Coordinator(port=port,
-                     distributed=args.distributed,
                      catalogs=build_catalogs(args.etc_dir, plugins),
                      resource_groups=resource_groups,
                      authenticator=authenticator,

@@ -112,14 +112,6 @@ SESSION_PROPERTIES: Dict[str, Tuple[type, object]] = {
     # the per-stage barrier (each stage waits for all of its inputs) —
     # kept as the A/B baseline and the conservative mode.
     "stage_pipelining": (bool, True),
-    # lower in-slice stage exchanges to device collectives
-    # (stage/ici.py): when the whole stage DAG executes on one TPU
-    # slice (LocalQueryRunner(distributed=True) / a mesh-backed
-    # worker), the hash repartition at stage boundaries runs as
-    # jax.lax.all_to_all over ICI instead of spool+HTTP frames — only
-    # cross-host edges touch the spool. Off = mesh queries keep the
-    # node-at-a-time distributed executor (exec/distributed.py).
-    "ici_exchange": (bool, True),
     # task fan-out of intermediate (exchange-fed) stages; 0 = one task
     # per live worker (the leaf fan-out keeps following
     # hash_partition_count — reference: SystemSessionProperties
