@@ -1,0 +1,119 @@
+"""The exact directory of the join probe (ISSUE 29) as the two
+executors run it: ``tpch.tiny`` through a ``Coordinator`` over HTTP, on
+one device (fragments jitted, as on the chip) and on a 4-device mesh of
+the suite's virtual CPU devices.
+
+- q3 equals the benchmark's plain reference, and BOTH of its joins
+  (lineitem x orders on the order key, their result x customer on the
+  customer key: dense integer keys) read ``exact`` in their one
+  ``host_read[join_total]``, with 0 steps;
+- a join on two columns and a join on a DOUBLE key count none, and
+  answer what numpy counts from the key columns.
+"""
+
+import collections
+import json
+import os
+import sys
+
+import pytest
+
+from trino_tpu.obs.metrics import METRICS
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmark")
+
+# name -> (the join, the two scans of its key columns)
+HASHED = {
+    "two_columns": (
+        "select count(*) from lineitem l join partsupp ps "
+        "on l.l_partkey = ps.ps_partkey and l.l_suppkey = ps.ps_suppkey",
+        "select l_partkey, l_suppkey from lineitem",
+        "select ps_partkey, ps_suppkey from partsupp"),
+    "double": (
+        "select count(*) from customer c join supplier s "
+        "on c.c_acctbal = s.s_acctbal",
+        "select c_acctbal from customer",
+        "select s_acctbal from supplier"),
+}
+
+
+@pytest.fixture(scope="module")
+def q3():
+    """(sql, the reference's answer, the comparison, the cell's limits)."""
+    sys.path.insert(0, BENCH)
+    try:
+        from reference.compare import gaps
+        from reference.tpch_answers import Answers
+    finally:
+        sys.path.remove(BENCH)
+    with open(os.path.join(BENCH, "configs", "tpch_sf1_1chip.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(BENCH, "traffic", "queries", "q3.sql")) as f:
+        sql = f.read()
+    answer = Answers(config["rehearsal_scale_factor"], ["q3"]).answer("q3")
+    return sql, answer, gaps, config["limits"]
+
+
+@pytest.fixture(scope="module", params=["one_device", "mesh"])
+def coordinator(request, tmp_path_factory):
+    from trino_tpu.parallel import get_mesh
+    from trino_tpu.server import Coordinator
+    mp = pytest.MonkeyPatch()
+    mp.setenv("TRINO_TPU_FRAGMENT_JIT", "1")
+    mesh = request.param == "mesh"
+    co = Coordinator(distributed=mesh, history_dir=str(
+        tmp_path_factory.mktemp("history")))
+    if mesh:
+        co._proto.mesh = get_mesh(4)    # four of the suite's eight devices
+    co.start()
+    yield co
+    co.stop()
+    mp.undo()
+
+
+def counted() -> tuple:
+    return tuple(
+        sum(v for _k, v in METRICS.counter(name).samples())
+        for name in ("trino_tpu_join_probes_total",
+                     "trino_tpu_join_exact_probes_total",
+                     "trino_tpu_join_search_steps_total"))
+
+
+def execute(co, sql):
+    """(rows, the (steps, exact) of each join's one read, the growth of
+    the three counters)."""
+    from trino_tpu.client import StatementClient
+    before = counted()
+    res = StatementClient(co.base_uri, catalog="tpch",
+                          schema="tiny").execute(sql)
+    assert res.state == "FINISHED", res.error
+    spans = co.tracker.get(res.query_id).trace.all_spans()
+    reads = [(s.attrs.get("steps"), s.attrs.get("exact")) for s in spans
+             if s.name == "host_read"
+             and s.attrs.get("site") == "join_total"]
+    return res.rows, reads, tuple(
+        a - b for a, b in zip(counted(), before))
+
+
+def test_q3_s_joins_are_both_exact(coordinator, q3):
+    sql, answer, gaps, limits = q3
+    rows, reads, grew = execute(coordinator, sql)
+    mismatches, rel = gaps(rows, answer)
+    assert mismatches <= limits["exact_mismatches"]
+    assert rel <= limits["max_rel_err"]
+    assert reads == [(0, 1), (0, 1)]
+    assert grew == (2, 2, 0)
+
+
+@pytest.mark.parametrize("key", sorted(HASHED))
+def test_a_hashed_key_counts_no_exact_probe(coordinator, key):
+    join, probe_keys, build_keys = HASHED[key]
+    rows, reads, grew = execute(coordinator, join)
+    have = collections.Counter(
+        map(tuple, execute(coordinator, build_keys)[0]))
+    want = sum(have[tuple(r)]
+               for r in execute(coordinator, probe_keys)[0])
+    assert rows == [[want]] and want > 0
+    assert len(reads) == 1 and reads[0][1] == 0 and reads[0][0] > 0
+    assert grew[:2] == (1, 0) and grew[2] == reads[0][0]

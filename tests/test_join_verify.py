@@ -26,8 +26,8 @@ def weak_hash(monkeypatch):
             acc = acc + h
         return acc % jnp.uint64(4)
 
-    # join key lanes use ops.join.combine_hashes (captured at import)
-    monkeypatch.setattr(join_ops, "combine_hashes", weak)
+    # join key lanes use ops.join.fold_hashes (captured at import)
+    monkeypatch.setattr(join_ops, "fold_hashes", weak)
     return weak
 
 

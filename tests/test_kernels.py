@@ -17,8 +17,10 @@ from trino_tpu.ops import join as join_ops
 from trino_tpu.ops.join import (cross_counts, expand_join, match_counts,
                                 semi_join_mask)
 from trino_tpu.ops.sort import SortKey, sort_batch, topn_batch
-from trino_tpu.types import BIGINT, DOUBLE, INTEGER, VARCHAR, DecimalType
+from trino_tpu.types import (BIGINT, DATE, DOUBLE, INTEGER, VARCHAR,
+                             DecimalType)
 
+import jax
 import jax.numpy as jnp
 
 
@@ -167,16 +169,17 @@ def _join(probe, build, pk, bk, join_type="inner", prefix="b_"):
                        join_type, prefix)
 
 
-def _keys(cap, keys, null_at=(), rows=None, more=None):
-    """A one- or two-key BIGINT batch of ``cap`` rows' capacity."""
+def _keys(cap, keys, null_at=(), rows=None, more=None, typ=BIGINT):
+    """A one- or two-key batch of ``cap`` rows' capacity (BIGINT, or
+    another integer-laned type)."""
     def col(vals):
-        data = np.zeros(cap, np.int64)
+        data = np.zeros(cap, np.int32 if typ is DATE else np.int64)
         data[:len(vals)] = vals
         valid = None
         if len(null_at):
             valid = np.ones(cap, bool)
             valid[list(null_at)] = False
-        return Column(BIGINT, jnp.asarray(data),
+        return Column(typ, jnp.asarray(data),
                       None if valid is None else jnp.asarray(valid))
     cols = {"k": col(keys)}
     if more is not None:
@@ -184,66 +187,75 @@ def _keys(cap, keys, null_at=(), rows=None, more=None):
     return Batch(cols, len(keys) if rows is None else rows)
 
 
+_I64 = np.iinfo(np.int64)
+# a stride that takes small keys out of every directory's reach (at
+# most 2^26 buckets): the cases that assert a step count stay hashed
+_FAR = 1 << 30
+
+
 def _probe_unique(rng):
     # 2^16 distinct keys under a bijective hash: a uniform lane, so the
     # directory leaves a handful of entries a bucket
     cap = 1 << 16
-    build = rng.permutation(1 << 20)[:cap]
+    build = rng.permutation(1 << 20)[:cap] * _FAR
     return (_keys(cap, rng.choice(build, cap)), _keys(cap, build),
-            lambda steps, m: steps <= 6)
+            lambda steps, m, exact: not exact and 0 < steps <= 6)
 
 
 def _probe_one_key(rng):
-    # every build row the same key: one bucket holds them all and the
+    # every build row but one the same key (the other keeps the range
+    # out of the directory's reach): one bucket holds them all and the
     # search degrades to the full bisection, still exact
     cap = 1 << 10
     return (_keys(cap, rng.integers(5, 9, cap)),
-            _keys(cap, np.full(cap, 7)),
-            lambda steps, m: steps == 11)      # log2(cap) + 1
+            _keys(2 * cap, np.append(np.full(cap, 7), 7 + _FAR)),
+            lambda steps, m, exact: not exact and steps == 11)  # log2 + 1
 
 
 def _probe_lineitem(rng):
     # a build side with 1-7 rows a key, probed by its own distinct keys
-    orders = rng.permutation(1 << 16)[:3000]
+    orders = rng.permutation(1 << 16)[:3000] * _FAR
     build = np.repeat(orders, rng.integers(1, 8, orders.size))
     return (_keys(1 << 12, orders), _keys(1 << 14, build[:1 << 14]),
-            lambda steps, m: 3 <= steps <= 7)
+            lambda steps, m, exact: not exact and 3 <= steps <= 7)
 
 
 def _probe_dead_build(rng):
     return (_keys(64, rng.integers(0, 50, 64)), _keys(32, [], rows=0),
-            lambda steps, m: steps == 0 and m == 0)
+            lambda steps, m, exact: not exact and steps == 0 and m == 0)
 
 
 def _probe_null_keys(rng):
-    return (_keys(64, rng.integers(0, 40, 60), null_at=(0, 7, 59)),
-            _keys(128, rng.integers(0, 40, 100), null_at=(3, 4, 99)),
-            lambda steps, m: m == 97)
+    return (_keys(64, rng.integers(0, 40, 60) * _FAR, null_at=(0, 7, 59)),
+            _keys(128, rng.integers(0, 40, 100) * _FAR,
+                  null_at=(3, 4, 99)),
+            lambda steps, m, exact: not exact and m == 97 and steps > 0)
 
 
 def _probe_absent_keys(rng):
-    return (_keys(256, rng.integers(1000, 2000, 256)),
-            _keys(256, rng.integers(0, 1000, 200)),
-            lambda steps, m: steps <= 4)
+    return (_keys(256, rng.integers(1000, 2000, 256) * _FAR),
+            _keys(256, rng.integers(0, 1000, 200) * _FAR),
+            lambda steps, m, exact: not exact and 0 < steps <= 4)
 
 
 def _probe_smaller(rng):
     return (_keys(8, rng.integers(0, 300, 8)),
             _keys(1 << 12, rng.integers(0, 300, 4000)),
-            lambda steps, m: m == 4000)
+            lambda steps, m, exact: exact and m == 4000)
 
 
 def _probe_larger(rng):
     return (_keys(1 << 14, rng.integers(0, 300, 1 << 14)),
             _keys(16, rng.integers(0, 300, 11)),
-            lambda steps, m: steps <= 4)
+            lambda steps, m, exact: exact and steps == 0)
 
 
 def _probe_u64max_lane(rng):
     # key 0 becomes the lane U64MAX, which dead build rows carry too:
     # they must count into no run (see the patched mix64 below)
-    return (_keys(32, [0, 1, 2, 0, 5]), _keys(32, [0, 3, 0, 1, 0, 2]),
-            lambda steps, m: m == 6)
+    return (_keys(32, [0, 1, 2, 0, 5]),
+            _keys(32, [0, 3, 0, 1, 0, 2, _FAR]),
+            lambda steps, m, exact: not exact and m == 7)
 
 
 def _probe_constant_hash(rng):
@@ -251,33 +263,169 @@ def _probe_constant_hash(rng):
     # row's lane is equal, each probe row counts the whole build side
     a, b = rng.integers(0, 9, (2, 100))
     return (_keys(64, a[:50], more=b[:50]), _keys(128, a, more=b),
-            lambda steps, m: steps == 7)       # bit_length(100)
+            # bit_length(100)
+            lambda steps, m, exact: not exact and steps == 7)
+
+
+def _probe_dense_unique(rng):
+    # a surrogate key: every value of a range once, negatives among
+    # them; the probe reaches past both ends
+    cap = 1 << 12
+    return (_keys(cap, rng.integers(-3000, 3000, cap)),
+            _keys(cap, rng.permutation(cap) - 2000),
+            lambda steps, m, exact: exact and steps == 0)
+
+
+def _probe_dense_duplicates(rng):
+    # lineitem's shape on a dense key: 1-7 rows a key, gaps between
+    orders = rng.permutation(1 << 13)[:3000] + 10**9
+    build = np.repeat(orders, rng.integers(1, 8, orders.size))
+    return (_keys(1 << 12, np.append(orders, orders[:1000] + 1)),
+            _keys(1 << 14, build),
+            lambda steps, m, exact: exact and steps == 0)
+
+
+def _probe_outside_range(rng):
+    # probe keys below min and above max, to the ends of int64: they
+    # read no bucket
+    build = rng.integers(1000, 2000, 100)
+    lo, hi = build.min(), build.max()
+    return (_keys(32, [lo - 1, hi + 1, lo, hi, 0, -1, _I64.min, _I64.max,
+                       lo - 2**40, hi + 2**40, hi + (1 << 13),
+                       lo + _I64.min, 1500]),
+            _keys(128, build),
+            lambda steps, m, exact: exact and steps == 0)
+
+
+def _edge(span):
+    # capacity 32: a directory of 1024 buckets
+    build = [5, 5 + span, 5, 700, 5 + span]
+    return (_keys(32, [4, 5, 6, 700, 5 + span - 1, 5 + span,
+                       5 + span + 1, 5 + 1024, 5 - 1024]),
+            _keys(32, build))
+
+
+def _probe_range_d_minus_1(rng):
+    # the widest range that engages: the last bucket holds a key
+    return _edge(1023) + (lambda steps, m, exact: exact and steps == 0,)
+
+
+def _probe_range_d(rng):
+    # one wider: searched, and as exact
+    return _edge(1024) + (lambda steps, m, exact: not exact and steps > 0,)
+
+
+def _probe_int64_extremes(rng):
+    # a span past 2^63 must not wrap into a small one
+    build = [_I64.min, _I64.max, 0, _I64.max, -1, _I64.min + 1]
+    return (_keys(16, [_I64.max, _I64.min, 0, 1, -1, _I64.min + 1,
+                       _I64.max - 1]),
+            _keys(16, build),
+            lambda steps, m, exact: not exact and m == 6)
+
+
+def _probe_date_key(rng):
+    days = rng.integers(8000, 10500, 300)        # 1992 to 1998, int32
+    return (_keys(256, rng.integers(7900, 10600, 256), typ=DATE),
+            _keys(512, days, typ=DATE),
+            lambda steps, m, exact: exact and steps == 0)
+
+
+def _probe_dictionary_key(rng):
+    # strings under two dictionaries: the codes a probe compares are
+    # the merged dictionary's (``align_string_keys``)
+    words = ["w%03d" % i for i in range(60)]
+    pick = lambda n, lo, hi: [words[i] for i in rng.integers(lo, hi, n)]
+    return (batch_from_pylist({"k": pick(40, 0, 60) + [None]},
+                              {"k": VARCHAR}),
+            batch_from_pylist({"k": pick(90, 20, 50) + [None, None]},
+                              {"k": VARCHAR}),
+            lambda steps, m, exact: exact and m == 90)
+
+
+def _wide(rng, span):
+    # a build capacity of 2^20: a directory of 2^25 buckets, more than
+    # its head of 2^24 (the bounds are read from the head alone unless
+    # an exact range reaches past it)
+    cap = 1 << 20
+    build = rng.integers(0, span, cap - 5, dtype=np.int64) - 7
+    build[:2] = -7, span - 7
+    probe = np.append(rng.choice(build, 4000),
+                      [-8, span - 6, span - 7, (1 << 24) - 7,
+                       (1 << 24) - 8, (1 << 25) - 7, (1 << 25) - 8])
+    return _keys(1 << 12, probe), _keys(cap, build)
+
+
+def _probe_wide_directory_head(rng):
+    return _wide(rng, (1 << 24) - 1) + (
+        lambda steps, m, exact: exact and steps == 0,)
+
+
+def _probe_wide_directory_past_head(rng):
+    return _wide(rng, (1 << 25) - 1) + (
+        lambda steps, m, exact: exact and steps == 0,)
+
+
+def _probe_wide_directory_hashed(rng):
+    return _wide(rng, 1 << 25) + (
+        lambda steps, m, exact: not exact and 0 < steps <= 6,)
+
+
+def _probe_null_keys_exact(rng):
+    return (_keys(64, rng.integers(0, 40, 60), null_at=(0, 7, 59)),
+            _keys(128, rng.integers(0, 40, 100), null_at=(3, 4, 99)),
+            lambda steps, m, exact: exact and m == 97 and steps == 0)
 
 
 @pytest.mark.parametrize("case", [
     _probe_unique, _probe_one_key, _probe_lineitem, _probe_dead_build,
     _probe_null_keys, _probe_absent_keys, _probe_smaller, _probe_larger,
-    _probe_u64max_lane, _probe_constant_hash],
+    _probe_u64max_lane, _probe_constant_hash, _probe_dense_unique,
+    _probe_dense_duplicates, _probe_outside_range, _probe_range_d_minus_1,
+    _probe_range_d, _probe_int64_extremes, _probe_date_key,
+    _probe_dictionary_key, _probe_null_keys_exact,
+    _probe_wide_directory_head, _probe_wide_directory_past_head,
+    _probe_wide_directory_hashed],
     ids=lambda c: c.__name__[7:])
 def test_join_probe_equals_searchsorted(case, monkeypatch):
-    """The probe (bucket directory, bounded bisection, run lengths)
-    against numpy: ``left`` and ``count`` are what ``searchsorted``
-    left and right give on the sorted usable build lanes, whatever the
-    lane's distribution; the steps counter keeps its bound."""
+    """The probe (bucket directory; where it is not exact, bounded
+    bisection and run lengths) against numpy: ``left`` and ``count``
+    are what ``searchsorted`` left and right give on the sorted usable
+    build lanes OF THE MODE THAT ENGAGED (``key - min`` where the
+    directory is exact, the hash otherwise; both computed here),
+    whatever the lane's distribution, and ``count`` is the number of
+    equal build keys; mode and steps are what the case says."""
     if case is _probe_u64max_lane:
         monkeypatch.setattr(
             join_ops, "mix64", lambda x: ~jnp.asarray(x).astype(jnp.uint64))
+        # the jitted probe hashes the probe's keys itself: a program
+        # traced under this mix64 must serve this case and no later one
+        # (jit caches by the function: a new one)
+        inner = join_ops.probe_runs.__wrapped__
+        monkeypatch.setattr(join_ops, "probe_runs",
+                            jax.jit(lambda *a: inner(*a)))
     if case is _probe_constant_hash:
-        monkeypatch.setattr(join_ops, "combine_hashes",
+        monkeypatch.setattr(join_ops, "fold_hashes",
                             lambda hs: jnp.zeros_like(hs[0]) + 7)
-    probe, build, steps_ok = case(np.random.default_rng(27))
+    probe, build, mode_ok = case(np.random.default_rng(27))
     keys = list(build.columns)
     left, count, side = join_ops.match_runs(probe, build, keys, keys)
     start, count2, order = match_counts(probe, build, keys, keys)
 
-    lane_b, usable_b = map(np.asarray, join_ops.equality_lane(build, keys))
-    lane_p, usable_p = map(np.asarray, join_ops.equality_lane(probe, keys))
+    probe, build = join_ops.align_string_keys(probe, build, keys, keys)
+    key_b, usable_b = map(np.asarray, join_ops.equality_lane(build, keys))
+    key_p, usable_p = map(np.asarray, join_ops.equality_lane(probe, keys))
     m = int(usable_b.sum())
+    exact = bool(side.exact)
+    if exact:
+        base = key_b[usable_b].astype(np.int64).min().astype(np.uint64)
+        assert int(side.base) == int(base)
+        lane_b, lane_p = key_b - base, key_p - base     # modulo 2^64
+        assert int(lane_b[usable_b].max()) < side.directory.shape[0] - 1
+    else:
+        lane_b, lane_p = (np.asarray(join_ops.mix64(k))
+                          for k in (key_b, key_p))
+    assert side.directory.shape[0] - 1 == min(32 * build.capacity, 1 << 26)
     want_sorted = np.full(build.capacity, np.uint64(2**64 - 1))
     want_sorted[:m] = np.sort(lane_b[usable_b])
     lo = np.minimum(np.searchsorted(want_sorted, lane_p, "left"), m)
@@ -291,7 +439,15 @@ def test_join_probe_equals_searchsorted(case, monkeypatch):
     assert np.array_equal(np.asarray(start), lo)
     assert np.array_equal(np.asarray(count2), np.asarray(count))
     assert left.dtype == count.dtype == jnp.int64
-    assert steps_ok(int(side.steps), m), (int(side.steps), m)
+    assert mode_ok(int(side.steps), m, exact), (int(side.steps), m, exact)
+    if case is not _probe_constant_hash:
+        # no lane at all: the build keys equal to each probe key
+        vals, n = np.unique(key_b[usable_b], return_counts=True)
+        at = np.minimum(np.searchsorted(vals, key_p), max(len(vals) - 1, 0))
+        same = (vals[at] == key_p) if len(vals) else np.zeros(len(key_p), bool)
+        assert np.array_equal(
+            np.asarray(count),
+            np.where(usable_p & same, n[at] if len(vals) else 0, 0))
     if case is _probe_u64max_lane:
         assert list(np.asarray(count)[:5]) == [3, 1, 1, 3, 0]
 

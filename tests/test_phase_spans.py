@@ -265,33 +265,47 @@ def test_every_sample_line_is_one_the_benchmark_reads(coordinator):
 
 
 def test_a_join_s_search_steps_ride_its_total_read(coordinator):
-    """q3's two count programs end in ONE host read each: the total,
-    and beside it the steps their probe took — ``steps`` on the
-    ``host_read[join_total]`` span, summed at /metrics; the ratio the
-    benchmark reads is a handful where the directory engages."""
+    """A count program ends in ONE host read: the total, and beside it
+    the steps its probe took and whether its directory was exact —
+    ``steps`` and ``exact`` on the ``host_read[join_total]`` span,
+    summed at /metrics. q3's two joins are on dense integer keys: exact,
+    0 steps, and the steps family is exported all the same (the
+    benchmark reads 0.0, not nothing); a join on two columns searches
+    a hashed lane."""
+    names = ("trino_tpu_join_probes_total",
+             "trino_tpu_join_search_steps_total",
+             "trino_tpu_host_reads_total",
+             "trino_tpu_join_exact_probes_total")
+
     def fams():
         f = parse_exposition(scrape_text(coordinator))
-        return [sum(f.get(n, {}).values()) for n in (
-            "trino_tpu_join_probes_total",
-            "trino_tpu_join_search_steps_total",
-            "trino_tpu_host_reads_total")]
-    served(coordinator, sql_of("q3"))           # warm
-    before = fams()
-    res, _lat = served(coordinator, sql_of("q3"))
-    grew = [a - b for a, b in zip(fams(), before)]
-    spans = coordinator.tracker.get(res.query_id).trace.all_spans()
-    reads = [s for s in spans if s.name == "host_read"]
-    totals = [s for s in reads if s.attrs.get("site") == "join_total"]
-    assert len(totals) == 2
-    assert all(0 < s.attrs["steps"] <= 6 for s in totals), totals
-    assert [s for s in reads if "steps" in s.attrs] == totals
-    assert grew[0] == 2
-    assert grew[1] == sum(s.attrs["steps"] for s in totals)
-    assert grew[2] == len(reads)        # no read added for the steps
-    assert 'trino_tpu_join_probes_total{site="join_total"}' in {
-        SAMPLE.match(ln).group(1)
-        for ln in scrape_text(coordinator).splitlines()
-        if not ln.startswith("#")}
+        return [sum(f.get(n, {}).values()) for n in names]
+
+    def one(sql):
+        served(coordinator, sql)                # warm
+        before = fams()
+        res, _lat = served(coordinator, sql)
+        grew = [a - b for a, b in zip(fams(), before)]
+        spans = coordinator.tracker.get(res.query_id).trace.all_spans()
+        reads = [s for s in spans if s.name == "host_read"]
+        totals = [s for s in reads if s.attrs.get("site") == "join_total"]
+        assert [s for s in reads if "steps" in s.attrs] == totals
+        assert [s for s in reads if "exact" in s.attrs] == totals
+        assert grew[0] == len(totals)
+        assert grew[1] == sum(s.attrs["steps"] for s in totals)
+        assert grew[2] == len(reads)    # no read added for the mode
+        assert grew[3] == sum(s.attrs["exact"] for s in totals)
+        return [(s.attrs["steps"], s.attrs["exact"]) for s in totals]
+
+    assert one(sql_of("q3")) == [(0, 1), (0, 1)]
+    samples = {SAMPLE.match(ln).group(1)
+               for ln in scrape_text(coordinator).splitlines()
+               if not ln.startswith("#")}
+    assert {n + '{site="join_total"}' for n in names} <= samples
+    (steps, exact), = one(
+        "select count(*) from lineitem l join partsupp ps "
+        "on l.l_partkey = ps.ps_partkey and l.l_suppkey = ps.ps_suppkey")
+    assert exact == 0 and 0 < steps <= 6
 
 
 def test_the_hook_counts_only_the_fixed_phases():

@@ -565,8 +565,9 @@ class DistributedExecutor(Executor):
         """The per-shard join of two co-located operands (after the
         repartition or the broadcast), two-phase: a count program that
         keeps its run starts, counts and build order ON the shards, one
-        blocking read of the per-shard totals, an expand program at the
-        capacity they give."""
+        blocking read of the per-shard totals (and the probe's mode
+        beside them, as on one chip), an expand program at the capacity
+        they give."""
         outer = jt == "left"
         filt = node.filter
         pkeys, bkeys = tuple(pkeys), tuple(bkeys)
@@ -579,19 +580,18 @@ class DistributedExecutor(Executor):
             def f(pcols, pn, bcols, bn):
                 d = jax.lax.axis_index(AXIS)
                 pb, bb = Batch(pcols, pn[d]), Batch(bcols, bn[d])
-                start, count, order = join_ops.match_counts(
+                start, count, side = join_ops.match_runs(
                     pb, bb, list(pkeys), list(bkeys))
                 eff = jnp.where(pb.row_valid(), jnp.maximum(count, 1),
                                 0) if (outer and filt is None) else count
-                return (start, count, order,
-                        jax.lax.all_gather(jnp.sum(eff), AXIS))
+                return (start, count, side.order, jax.lax.all_gather(
+                    join_ops.total_and_mode(eff, side), AXIS))
             return f, in_specs, (P(AXIS), P(AXIS), P(AXIS), P())
 
         start, count, order, totals = mesh_call(
             "join_count", (pkeys, bkeys, outer and filt is None),
             probe.mesh, operands, build_count)
-        with self._host_read("join_total"):
-            out_cap = capacity_for(max(int(np.asarray(totals).max()), 1))
+        out_cap = capacity_for(max(self._read_join_total(totals), 1))
         pad_cap = probe.per_shard_cap if (outer and
                                           filt is not None) else 0
 

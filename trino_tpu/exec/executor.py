@@ -1498,8 +1498,8 @@ class Executor:
     def _mjoin_counts(self, probe: Batch, build: Batch, pkeys, bkeys,
                       outer: bool):
         """Jitted count phase of the materialized join. Returns
-        (start, count, order, [total, steps]) device arrays, or None
-        on decline — the caller runs ops/join.py eagerly."""
+        (start, count, order, [total, steps, exact]) device arrays, or
+        None on decline — the caller runs ops/join.py eagerly."""
         if not (self.fragment_jit
                 and self._mjoin_jittable(probe, build)):
             return None
@@ -1521,14 +1521,18 @@ class Executor:
             return None
 
     def _read_join_total(self, tail) -> int:
-        """The count program's one host read: its output total, and
-        beside it the steps its probe took (``steps`` on the span,
-        counted at /metrics: obs/metrics.py observe_span)."""
+        """The count program's one host read (``ops/join.py
+        total_and_mode``, one row a shard on the mesh): its output
+        total — the largest shard's — and beside it the steps its probe
+        took and whether its directory was exact, on every shard
+        (``steps`` and ``exact`` on the span, counted at /metrics:
+        obs/metrics.py observe_span)."""
         with self._host_read("join_total") as sp:
-            total, steps = (int(v) for v in np.asarray(tail))
+            total, steps, exact = np.asarray(tail).reshape(-1, 3).T
             if sp is not None:
-                sp.attrs["steps"] = steps
-        return total
+                sp.attrs["steps"] = int(steps.max())
+                sp.attrs["exact"] = int(exact.min())
+        return int(total.max())
 
     def _mjoin_expand(self, probe: Batch, build: Batch, start, count,
                       order, jt: str, residual, out_cap: int,
@@ -2157,10 +2161,11 @@ def make_mjoin_count_program(pkeys, bkeys, outer: bool):
     """Phase 1: build-side sort and index + probe match counts + the
     effective output total. Everything downstream of the total is host
     policy (bucket choice, memory reserve, oversized spill), so the
-    program ends exactly at the host-sync boundary: ONE int64[2],
-    [total, the probe's bisection steps], read in one transfer
-    (``_read_join_total``). Output dtypes are pinned int64 — they
-    cross into the separately-jitted expand program."""
+    program ends exactly at the host-sync boundary: ONE int64[3],
+    [total, the probe's bisection steps, whether the directory was
+    exact], read in one transfer (``_read_join_total``). Output dtypes
+    are pinned int64 — they cross into the separately-jitted expand
+    program."""
     pkeys, bkeys = list(pkeys), list(bkeys)
 
     def fn(probe: Batch, build: Batch):
@@ -2173,7 +2178,7 @@ def make_mjoin_count_program(pkeys, bkeys, outer: bool):
             eff = count
         return (start.astype(jnp.int64), count.astype(jnp.int64),
                 side.order.astype(jnp.int64),
-                jnp.stack([jnp.sum(eff), side.steps.astype(jnp.int64)]))
+                join_ops.total_and_mode(eff, side))
 
     return fn
 

@@ -637,8 +637,8 @@ def make_probe_program(jt: str, pkeys: Sequence[str],
     outer = jt == "left"
 
     def fn(chunk: Batch, build: Batch, side: join_ops.BuildSide):
-        lane_p, usable_p = join_ops.equality_lane(chunk, pkeys)
-        left, count = join_ops.probe_runs(side, lane_p, usable_p)
+        key_p, usable_p = join_ops.equality_lane(chunk, pkeys)
+        left, count = join_ops.probe_runs(side, key_p, usable_p)
         order = side.order
         if residual is None:
             live_p = chunk.row_valid()

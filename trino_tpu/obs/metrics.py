@@ -547,13 +547,21 @@ JOIN_PROBES = METRICS.counter(
     "trino_tpu_join_probes_total",
     "Join probes whose step count traced queries read, by the host "
     "read that carried it (join_total: the count program of a "
-    "materialized join)", ("site",))
+    "materialized join; of a mesh join, one probe: the most steps of "
+    "its shards, exact where every shard was)", ("site",))
 JOIN_SEARCH_STEPS = METRICS.counter(
     "trino_tpu_join_search_steps_total",
     "Bisection steps those probes took inside their directory buckets "
-    "(ops/join.py probe_runs): over the probes, 3-4 where the "
-    "directory engages, log2(build capacity)+1 where one key fills a "
-    "bucket", ("site",))
+    "(ops/join.py probe_runs): over the probes, 0 where the directory "
+    "is exact (a bucket is one key value: no search ran), 2-4 over a "
+    "hashed lane, log2(build capacity)+1 where one key fills a bucket",
+    ("site",))
+JOIN_EXACT_PROBES = METRICS.counter(
+    "trino_tpu_join_exact_probes_total",
+    "Those probes whose build side chose the exact directory (one "
+    "integer key column whose usable values span less than the "
+    "directory: 0 steps); the others searched a hashed lane",
+    ("site",))
 EXCHANGE_BYTES = METRICS.counter(
     "trino_tpu_mesh_exchange_bytes_total",
     "Bytes the mesh executor's exchanges moved in traced queries: live "
@@ -602,7 +610,10 @@ def observe_span(sp) -> None:
         steps = sp.attrs.get("steps")
         if steps is not None:
             JOIN_PROBES.inc_at(site)
+            # 0 steps still creates the sample: a run of exact probes
+            # exports the family, reading 0
             JOIN_SEARCH_STEPS.inc_at(site, steps)
+            JOIN_EXACT_PROBES.inc_at(site, sp.attrs.get("exact", 0))
     elif name in ("device_execute", "jit_trace"):
         program = str(sp.attrs.get("program")
                       or sp.attrs.get("cache") or "other")

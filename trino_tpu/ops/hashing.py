@@ -92,12 +92,19 @@ def hash_column(data: jax.Array, valid: Optional[jax.Array]) -> jax.Array:
     return h
 
 
-def combine_hashes(hashes: Sequence[jax.Array]) -> jax.Array:
-    """CombineHashFunction.getHash: h = 31*h + x, vectorized."""
+def fold_hashes(hashes: Sequence[jax.Array]) -> jax.Array:
+    """CombineHashFunction.getHash: h = 31*h + x, vectorized — before
+    the finalizer (``combine_hashes``; ops/join.py applies it itself,
+    as to a single key)."""
     acc = jnp.zeros_like(hashes[0]) + _GOLDEN
     for h in hashes:
         acc = acc * jnp.uint64(31) + h
-    return mix64(acc)
+    return acc
+
+
+def combine_hashes(hashes: Sequence[jax.Array]) -> jax.Array:
+    """``fold_hashes`` and the finalizer: one hash of several."""
+    return mix64(fold_hashes(hashes))
 
 
 def hash_columns(cols) -> jax.Array:

@@ -6,23 +6,36 @@ NestedLoopJoinOperator, HashSemiJoinOperator. Redesign for XLA
 (SURVEY.md §7.3): the serial open-addressing probe becomes a vectorized
 sort + bucket-directory join:
 
-1. build keys are reduced to a single uint64 equality lane (bijective
-   splitmix64 for one integer key column — exact; multi-column and
-   float keys are hash-combined, accepting a ~n^2/2^64 collision
-   probability with NO re-verification — acknowledged in SURVEY.md §7
-   "hard parts"; string keys are first remapped onto a dictionary
-   merged across both sides so codes are comparable),
+1. build keys are reduced to a single uint64 equality lane, in one of
+   two modes the BUILD SIDE chooses on the device (``BuildSide.exact``):
+   where the key is one integer column (integers, dates, booleans,
+   dictionary codes) whose usable build values span less than the
+   directory's size, the lane is ``key - min`` — the integer compared
+   as the integer it is; otherwise a hash (bijective splitmix64 of one
+   column — exact; multi-column and float keys are hash-combined,
+   accepting a ~n^2/2^64 collision probability with NO re-verification
+   — acknowledged in SURVEY.md §7 "hard parts"). String keys are first
+   remapped onto a dictionary merged across both sides so codes are
+   comparable,
 2. the build side is sorted by that lane (nulls/dead rows forced past the
-   valid prefix) and indexed ONCE, at O(build capacity): a bucket
-   directory over the lane's top bits (one bucket per build row: the
-   lane is a hash, so a bucket holds a handful of entries) and the
-   length of the run of equal lanes that starts at each position, and
-3. every probe row reads its bucket's bounds from the directory,
-   bisects inside the bucket for the first entry >= its lane
-   (``probe_runs``: as many steps as the FULLEST bucket needs, a device
-   value — 3-4 for a uniform lane, log2(capacity)+1 when one key fills
-   a bucket) and reads its count from the run lengths. A gather costs
-   the chip per element, so the steps are what a probe costs.
+   valid prefix) and indexed ONCE, at O(directory): a bucket directory
+   of 32 buckets a build row (at most 2^26) — over the lane itself
+   where exact, ONE KEY VALUE a bucket; over the hash's top bits
+   otherwise (at most 24 of them: the directory's head, a table the
+   chip gathers from at its fast rate), a handful of entries a bucket
+   — and the length of the run of equal lanes that starts at each
+   position, and
+3. every probe row reads its bucket's bounds from the directory
+   (``probe_runs``; from its head alone unless an exact range reaches
+   past it). Where exact they ARE its run of equal keys: no search, 0
+   steps, two probe-sized gathers in all (dense surrogate keys, what
+   warehouse joins join on: the "perfect hash join").
+   Otherwise it bisects inside the bucket for the first entry >= its
+   lane (as many steps as the FULLEST bucket needs, a device value —
+   2-4 for a uniform hash, log2(capacity)+1 when one key fills a
+   bucket; each step gathers both halves of a 64-bit lane) and reads
+   its count from the run lengths. A gather costs the chip per
+   element, so the steps are what a probe costs.
 
 Output cardinality is data-dependent: callers run ``match_counts`` first,
 read the total on the host, pick a power-of-two capacity bucket, then run
@@ -38,7 +51,7 @@ import jax
 import jax.numpy as jnp
 
 from ..columnar import Batch, Column
-from .hashing import combine_hashes, lane_to_u64, mix64
+from .hashing import fold_hashes, lane_to_u64, mix64
 from .sort import stable_lexsort
 
 _U64MAX = jnp.uint64(0xFFFFFFFFFFFFFFFF)
@@ -66,9 +79,13 @@ def align_string_keys(probe: Batch, build: Batch,
 
 def equality_lane(batch: Batch, key_names: Sequence[str]) -> Tuple[
         jax.Array, jax.Array]:
-    """(lane, usable) — uint64 equality-preserving key lane; usable is
-    False for dead rows and rows with any NULL key (SQL: null join keys
-    never match, reference: JoinProbe skips null channels)."""
+    """(key, usable) — ``key`` is a uint64 lane whose equality is the
+    keys': one column's value as it is (an integer's two's complement,
+    exact), several columns' folded hashes. The lane that is sorted and
+    compared is ``_lane_of`` it, by the mode the build side chose.
+    ``usable`` is False for dead rows and rows with any NULL key (SQL:
+    null join keys never match, reference: JoinProbe skips null
+    channels)."""
     usable = batch.row_valid()
     lanes = []
     for name in key_names:
@@ -77,10 +94,44 @@ def equality_lane(batch: Batch, key_names: Sequence[str]) -> Tuple[
         if col.valid is not None:
             usable = usable & jnp.asarray(col.valid)
     if len(lanes) == 1:
-        lane = mix64(lanes[0])  # bijective -> exact equality
-    else:
-        lane = combine_hashes([mix64(l) for l in lanes])
-    return lane, usable
+        return lanes[0], usable
+    return fold_hashes([mix64(l) for l in lanes]), usable
+
+
+def _integer_key(batch: Batch, key_names: Sequence[str]) -> bool:
+    """Static: the key is ONE column whose whole value is one integer
+    lane (integers, dates, booleans, dictionary codes; not floats, not
+    an Int128's low half) — the keys an exact directory can serve."""
+    if len(key_names) != 1:
+        return False
+    col = batch.column(key_names[0])
+    return col.data2 is None and not jnp.issubdtype(
+        jnp.asarray(col.data).dtype, jnp.floating)
+
+
+def _lane_of(key, exact, base):
+    """The lane of a key (``equality_lane``) of either side, by the
+    build side's mode: both are bijections of the key, so equality is
+    the keys' (``key - min`` modulo 2^64: a key outside the build
+    side's range lands past its span)."""
+    return jnp.where(exact, key - base, mix64(key))
+
+
+def _directory_bits(capacity: int) -> int:
+    """log2 of the directory's size D, static from the build capacity
+    alone: 32 buckets a build row (a dense key's range is a small
+    multiple of the rows that survive to the build side: TPC-H's
+    o_orderkey spans 4 x rows(orders)), at most 2^26 entries (256 MB
+    of int32)."""
+    return min(max(1, (capacity - 1).bit_length()) + 5, 26)
+
+
+# log2 of the directory's HEAD: a table of up to 2^24 int32 entries
+# (64 MB) is gathered from at 8.6 ns an element on a v5e, whatever the
+# order of the indices; one of 2^25 at 15 (random) to 25 (ascending)
+# (PERF.md §6, PR 29). Hashed buckets stay inside the head, and so do
+# the bounds' gathers unless an exact range reaches past it.
+_HEAD_BITS = 24
 
 
 class BuildSide(NamedTuple):
@@ -90,17 +141,37 @@ class BuildSide(NamedTuple):
     order: jax.Array        # sorted position -> build row
     m: jax.Array            # usable build rows
     directory: jax.Array    # int32[D+1]: first position in [0, m] whose
-    #                         lane's top log2(D) bits are >= b
+    #                         bucket (the lane where exact, its top
+    #                         min(log2(D), 24) bits otherwise) is >= b
     run_len: jax.Array      # int32[cap]: entries of [i, m) equal to entry i
-    steps: jax.Array        # int32: bisection steps the fullest bucket needs
+    steps: jax.Array        # int32: bisection steps the fullest bucket
+    #                         needs; 0 where exact
+    exact: jax.Array        # bool: a bucket is ONE key value, no search
+    base: jax.Array         # uint64: the least usable build key
 
 
 def build_side(batch: Batch, key_names: Sequence[str]) -> BuildSide:
     """Sort the build side by key lane and index it. The first m
     entries of ``sorted_lane`` are the usable keys, the tail is forced
     to U64MAX (and counted into no bucket and no run)."""
-    lane, usable = equality_lane(batch, key_names)
+    key, usable = equality_lane(batch, key_names)
     cap = batch.capacity
+    bits = _directory_bits(cap)
+    m = jnp.sum(usable.astype(jnp.int64))
+    if _integer_key(batch, key_names):
+        # the range of the usable keys, in the order of the integers
+        # they are; the span in unsigned arithmetic, where one past
+        # 2^63 cannot wrap into a small number
+        signed = key.astype(jnp.int64)
+        info = jnp.iinfo(jnp.int64)
+        base = jnp.min(jnp.where(usable, signed, info.max))
+        span = (jnp.max(jnp.where(usable, signed, info.min))
+                .astype(jnp.uint64) - base.astype(jnp.uint64))
+        exact = (m > 0) & (span < jnp.uint64(1 << bits))
+        base = base.astype(jnp.uint64)
+    else:
+        exact, base = jnp.zeros((), bool), jnp.zeros((), jnp.uint64)
+    lane = _lane_of(key, exact, base)
     if all(batch.column(k).valid is None for k in key_names):
         # no NULL keys: the usable rows are exactly the live prefix, so
         # they already precede every dead row in input order. ONE
@@ -112,24 +183,25 @@ def build_side(batch: Batch, key_names: Sequence[str]) -> BuildSide:
         order = stable_lexsort([jnp.where(usable, lane, _U64MAX)])
     else:
         order = stable_lexsort([(~usable).astype(jnp.int32), lane])
-    m = jnp.sum(usable.astype(jnp.int64))
     pos = jnp.arange(cap, dtype=jnp.int32)
     live = pos < m
     sorted_lane = jnp.where(live, jnp.take(lane, order), _U64MAX)
 
-    # directory, one bucket a build row: a histogram of the live
-    # entries' top bits, summed up. Never a search of the D boundaries,
-    # which would cost what the directory saves.
-    bits = max(1, (cap - 1).bit_length())
-    top = (sorted_lane >> jnp.uint64(64 - bits)).astype(jnp.int32)
+    # directory: a histogram of the live entries' buckets, summed up.
+    # Never a search of the D boundaries, which would cost what the
+    # directory saves. Both buckets rise with the lane.
+    bucket = jnp.where(
+        exact, jnp.minimum(sorted_lane, jnp.uint64((1 << bits) - 1)),
+        sorted_lane >> jnp.uint64(64 - min(bits, _HEAD_BITS))
+    ).astype(jnp.int32)
     directory = jnp.cumsum(
-        jnp.zeros(((1 << bits) + 1,), jnp.int32).at[top + 1].add(
+        jnp.zeros(((1 << bits) + 1,), jnp.int32).at[bucket + 1].add(
             live.astype(jnp.int32), indices_are_sorted=True,
             mode="promise_in_bounds"))
     # a lower-bound bisection over n entries takes bit_length(n) steps
     fullest = jnp.max(directory[1:] - directory[:-1])
-    steps = jnp.sum((fullest >> jnp.arange(31, dtype=jnp.int32)) > 0,
-                    dtype=jnp.int32)
+    steps = jnp.where(exact, 0, jnp.sum(
+        (fullest >> jnp.arange(31, dtype=jnp.int32)) > 0, dtype=jnp.int32))
 
     # run lengths: entry i's run ends at the first position after it
     # that differs from the one before, or is dead (m at the latest)
@@ -139,7 +211,8 @@ def build_side(batch: Batch, key_names: Sequence[str]) -> BuildSide:
     run_len = jnp.where(
         live, jax.lax.cummin(jnp.where(ends, after, cap), reverse=True)
         - pos, 0)
-    return BuildSide(sorted_lane, order, m, directory, run_len, steps)
+    return BuildSide(sorted_lane, order, m, directory, run_len, steps,
+                     exact, base)
 
 
 def _at(lane, index):
@@ -148,17 +221,42 @@ def _at(lane, index):
 
 
 @jax.jit
-def probe_runs(side: BuildSide, lane_p, usable_p):
-    """(left, count) per probe row: ``left`` is the first position of
-    the sorted build lane that is >= the row's lane (clipped to m),
-    ``count`` the entries equal to it — what ``searchsorted`` left and
-    right gave, exactly, for every lane whatever its distribution. The
-    ONE probe of the engine: joins, semi joins and the streamed probe
-    (exec/streamjoin.py) all come through here. Jitted, as
-    ``searchsorted`` is: an eager caller reuses one program a shape."""
-    bits = (side.directory.shape[0] - 1).bit_length() - 1
+def probe_runs(side: BuildSide, key_p, usable_p):
+    """(left, count) per probe row, from its key (``equality_lane``):
+    ``left`` is the first position of the sorted build lane that is >=
+    the row's lane (clipped to m), ``count`` the entries equal to it —
+    what ``searchsorted`` left and right gave, exactly, for every lane
+    whatever its distribution. The ONE probe of the engine: joins, semi
+    joins and the streamed probe (exec/streamjoin.py) all come through
+    here. Jitted, as ``searchsorted`` is: an eager caller reuses one
+    program a shape."""
+    size = side.directory.shape[0] - 1
+    head_bits = min(size.bit_length() - 1, _HEAD_BITS)
+    head = 1 << head_bits
     last = side.sorted_lane.shape[0] - 1
-    top = (lane_p >> jnp.uint64(64 - bits)).astype(jnp.int32)
+    lane_p = _lane_of(key_p, side.exact, side.base)
+
+    def bounds(directory):
+        # the bucket's bounds. Where exact, a key outside the build
+        # side's range has a lane of D or more and reads [m, m): its
+        # lower bound
+        top = directory.shape[0] - 1
+        bucket = jnp.where(
+            side.exact, jnp.minimum(lane_p, jnp.uint64(top)),
+            lane_p >> jnp.uint64(64 - head_bits)).astype(jnp.int32)
+        return (_at(directory, bucket),
+                _at(directory, jnp.minimum(bucket + 1, top)))
+
+    if size > head:
+        # every live bucket lies in the head unless an exact range
+        # passes it: the head alone is a table the chip gathers from
+        # at its fast rate
+        lo, hi = jax.lax.cond(
+            _at(side.directory, head) < side.m,
+            lambda: bounds(side.directory),
+            lambda: bounds(side.directory[:head + 1]))
+    else:
+        lo, hi = bounds(side.directory)
 
     def step(_, state):
         # lower bound on [lo, hi); ``hit`` is whether the entry ``hi``
@@ -174,23 +272,37 @@ def probe_runs(side: BuildSide, lane_p, usable_p):
         return (jnp.where(right, mid + 1, lo), jnp.where(down, mid, hi),
                 jnp.where(down, v == lane_p, hit))
 
-    left, _, hit = jax.lax.fori_loop(
-        0, side.steps, step,
-        (_at(side.directory, top), _at(side.directory, top + 1),
-         jnp.zeros(lane_p.shape, bool)))
-    count = jnp.where(hit & usable_p,
-                      _at(side.run_len, jnp.minimum(left, last)), 0)
-    return left.astype(jnp.int64), count.astype(jnp.int64)
+    def search():
+        left, _, hit = jax.lax.fori_loop(
+            0, side.steps, step, (lo, hi, jnp.zeros(lane_p.shape, bool)))
+        return left, jnp.where(
+            hit, _at(side.run_len, jnp.minimum(left, last)), 0)
+
+    # a bucket of ONE key value is the run itself: neither the search
+    # nor the run-length gather runs (a branch, not a select: a gather
+    # costs the chip per element whatever becomes of it)
+    left, count = jax.lax.cond(side.exact, lambda: (lo, hi - lo), search)
+    return (left.astype(jnp.int64),
+            jnp.where(usable_p, count, 0).astype(jnp.int64))
 
 
 def match_runs(probe: Batch, build: Batch,
                probe_keys: Sequence[str], build_keys: Sequence[str]):
     """(left, count, side): ``match_counts`` with the whole build side."""
     probe, build = align_string_keys(probe, build, probe_keys, build_keys)
-    lane_p, usable_p = equality_lane(probe, probe_keys)
+    key_p, usable_p = equality_lane(probe, probe_keys)
     side = build_side(build, build_keys)
-    left, count = probe_runs(side, lane_p, usable_p)
+    left, count = probe_runs(side, key_p, usable_p)
     return left, count, side
+
+
+def total_and_mode(eff, side: BuildSide):
+    """int64[3], [output rows, the probe's bisection steps, whether the
+    directory was exact]: what a count program hands the host in its
+    ONE read (the executors' ``join_total``)."""
+    return jnp.stack([jnp.sum(eff).astype(jnp.int64),
+                      side.steps.astype(jnp.int64),
+                      side.exact.astype(jnp.int64)])
 
 
 def match_counts(probe: Batch, build: Batch,
@@ -250,8 +362,8 @@ def semi_join_mask(probe: Batch, build: Batch, probe_keys: Sequence[str],
     (reference: operator/HashSemiJoinOperator.java — probe null or
     build-side null yields NULL, else TRUE/FALSE)."""
     probe, build = align_string_keys(probe, build, probe_keys, build_keys)
-    lane_p, usable_p = equality_lane(probe, probe_keys)
-    _, count = probe_runs(build_side(build, build_keys), lane_p, usable_p)
+    key_p, usable_p = equality_lane(probe, probe_keys)
+    _, count = probe_runs(build_side(build, build_keys), key_p, usable_p)
     matched = count > 0
     key_null = probe.row_valid() & ~usable_p
 
