@@ -19,7 +19,8 @@ from trino_tpu.exec import executor as exmod
 from trino_tpu.exec.executor import Executor
 from trino_tpu.exec.hotshapes import (HOT_SHAPES, HotShapeRegistry,
                                       record_program)
-from trino_tpu.exec.progkey import canonicalize_nodes
+from trino_tpu.exec.progkey import (PROGRAMS, ProgramCache,
+                                    canonicalize_nodes)
 from trino_tpu.obs.metrics import METRICS, parse_exposition
 from trino_tpu.plan.nodes import FilterNode, ProjectNode
 from trino_tpu.planner import LogicalPlanner
@@ -268,8 +269,8 @@ def test_aot_compile_from_registry_payload(monkeypatch):
     assert entries
     # round-trip through JSON: the endpoint serves exactly this form
     entries = json.loads(json.dumps(entries))
-    exmod._STREAM_JIT_CACHE.clear()
-    exmod._CHAIN_JIT_CACHE.clear()
+    PROGRAMS.clear("stream")
+    PROGRAMS.clear("chain")
     summary = aot.compile_entries(entries)
     assert summary["compiled"] >= 1 and summary["errors"] == 0
     h0 = _JIT_LOOKUPS.value(cache="stream", result="hit") \
@@ -376,8 +377,8 @@ def test_prewarmed_worker_serves_first_fragment_as_cache_hit(
                    for e in HOT_SHAPES.top(50))
         # fresh-worker simulation: in-process caches wiped; ONLY the
         # pre-warm pull can repopulate them
-        exmod._STREAM_JIT_CACHE.clear()
-        exmod._CHAIN_JIT_CACHE.clear()
+        PROGRAMS.clear("stream")
+        PROGRAMS.clear("chain")
         w2 = TaskWorkerServer().start()
         try:
             w2.announce(co.base_uri, prewarm=True)
@@ -407,18 +408,199 @@ def test_prewarmed_worker_serves_first_fragment_as_cache_hit(
 
 
 # --------------------------------------------------------------------------
-# jit-cache eviction satellite
+# the one program cache (exec/progkey.py ProgramCache): every bucket
+# behaves alike, so every case runs over all eight
 # --------------------------------------------------------------------------
 
+BUCKETS = ProgramCache.BUCKETS
+_EVICTED = METRICS.counter("trino_tpu_jit_cache_evictions_total")
+_SHED = METRICS.counter("trino_tpu_cache_pressure_evictions_total")
+
+
+@pytest.fixture
+def cache():
+    """A cache of its own: the process's ``PROGRAMS`` keeps what other
+    tests compiled."""
+    return ProgramCache()
+
+
+def _builder(calls):
+    def build():
+        calls.append(1)
+        return lambda x: x + 1
+    return build
+
+
+def test_the_buckets_are_the_metric_labels():
+    assert BUCKETS == ("chain", "stream", "ragged", "join", "window",
+                       "streamjoin", "repartition", "spmd")
+    assert isinstance(PROGRAMS, ProgramCache)
+
+
+@pytest.mark.parametrize("bucket", BUCKETS)
+def test_program_misses_then_hits_and_counts_by_bucket(cache, bucket):
+    calls = []
+    key = ("k", bucket)
+    miss0 = _JIT_LOOKUPS.value(cache=bucket, result="miss")
+    hit0 = _JIT_LOOKUPS.value(cache=bucket, result="hit")
+    first, hit = cache.program(bucket, key, _builder(calls),
+                               "kind_" + bucket, key)
+    assert hit is False and calls == [1]
+    assert _JIT_LOOKUPS.value(cache=bucket, result="miss") == miss0 + 1
+    again, hit = cache.program(bucket, key, _builder(calls),
+                               "kind_" + bucket, key)
+    assert hit is True and again is first and calls == [1]
+    assert _JIT_LOOKUPS.value(cache=bucket, result="hit") == hit0 + 1
+    # the program is the named jit of what build() gave
+    assert first.program.startswith("kind_" + bucket + ":")
+    assert int(first(1)) == 2
+    # a key of None cannot be named: built for the call alone, kept
+    # nowhere and counted nowhere
+    local, hit = cache.program(bucket, None, _builder(calls),
+                               "kind_" + bucket, None)
+    assert hit is False and calls == [1, 1]
+    assert local.program == f"kind_{bucket}:local"
+    assert not cache.resident(bucket, None)
+    assert _JIT_LOOKUPS.value(cache=bucket, result="miss") == miss0 + 1
+    assert _JIT_LOOKUPS.value(cache=bucket, result="hit") == hit0 + 1
+
+
+@pytest.mark.parametrize("bucket", BUCKETS)
 def test_cache_put_honors_configured_capacity_and_counts_evictions(
-        monkeypatch):
+        cache, bucket, monkeypatch):
     from trino_tpu.config import CONFIG
     monkeypatch.setattr(CONFIG, "jit_cache_entries", 2)
-    evict = METRICS.counter("trino_tpu_jit_cache_evictions_total")
-    e0 = evict.value()
-    scratch = {}
+    other = BUCKETS[BUCKETS.index(bucket) - 1]
+    cache.put(other, ("k", 0), object())
+    e0 = _EVICTED.value()
     for i in range(4):
-        exmod._cache_put(scratch, ("k", i), object())
-    assert len(scratch) == 2
-    assert evict.value() == e0 + 2
-    assert ("k", 3) in scratch and ("k", 2) in scratch
+        cache.put(bucket, ("k", i), object())
+    # the capacity is each bucket's own, oldest out first
+    assert [cache.resident(bucket, ("k", i)) for i in range(4)] \
+        == [False, False, True, True]
+    assert _EVICTED.value() == e0 + 2
+    assert cache.resident(other, ("k", 0))
+    # a key that is already kept evicts nothing and keeps its program
+    kept = cache.put(bucket, ("k", 3), object())
+    assert cache.put(bucket, ("k", 3), object()) is kept
+    assert cache.resident(bucket, ("k", 2))
+    assert _EVICTED.value() == e0 + 2
+
+
+@pytest.mark.parametrize("bucket", BUCKETS)
+def test_deny_refuses_and_pops(cache, bucket):
+    calls = []
+    key, sibling = ("k", bucket), (("k", bucket), "full")
+    cache.program(bucket, key, _builder(calls), bucket, key)
+    cache.program(bucket, sibling, _builder(calls), bucket, key)
+    assert not cache.denied(bucket, key)
+    lookups0 = (_JIT_LOOKUPS.value(cache=bucket, result="miss")
+                + _JIT_LOOKUPS.value(cache=bucket, result="hit"))
+    cache.deny(bucket, key)
+    assert cache.denied(bucket, key)
+    assert not cache.resident(bucket, key)
+    # refused: nothing is built and nothing is counted
+    assert cache.program(bucket, key, _builder(calls), bucket,
+                         key) is None
+    assert calls == [1, 1]
+    assert (_JIT_LOOKUPS.value(cache=bucket, result="miss")
+            + _JIT_LOOKUPS.value(cache=bucket, result="hit")) == lookups0
+    # a key is refused alone: the whole-table program of the same
+    # canonical key (stream) and a program kept under a key that
+    # CONTAINS the refused one (the mesh's fused aggregation) stay
+    assert not cache.denied(bucket, sibling)
+    assert cache.resident(bucket, sibling)
+    # ...and only in its bucket
+    other = BUCKETS[BUCKETS.index(bucket) - 1]
+    assert not cache.denied(other, key)
+    # clear() forgets the refusal with the programs
+    cache.clear(bucket)
+    assert not cache.denied(bucket, key)
+    assert not cache.resident(bucket, sibling)
+
+
+@pytest.mark.parametrize("bucket", BUCKETS)
+def test_put_and_resident_as_the_aot_compiler_uses_them(cache, bucket):
+    """exec/aot.py compile_entry: skip what is resident, else compile
+    and put; the executor's next lookup of that slot is a hit on the
+    very program that was put."""
+    key = ("k", bucket)
+    assert not cache.resident(bucket, key)
+    warmed = object()
+    assert cache.put(bucket, key, warmed) is warmed
+    assert cache.resident(bucket, key)
+    calls = []
+    got, hit = cache.program(bucket, key, _builder(calls), bucket, key)
+    assert got is warmed and hit is True and calls == []
+    assert all(not cache.resident(b, key) for b in BUCKETS
+               if b != bucket)
+
+
+@pytest.mark.parametrize("bucket", BUCKETS)
+def test_shed_halves_the_three_plan_buckets_and_no_other(cache, bucket):
+    for b in BUCKETS:
+        for i in range(5):
+            cache.put(b, ("k", i), object())
+    s0 = _SHED.value(cache="jit")
+    assert cache.shed() == 6
+    assert _SHED.value(cache="jit") == s0 + 6
+    kept = [cache.resident(bucket, ("k", i)) for i in range(5)]
+    if bucket in ("chain", "stream", "ragged"):
+        assert kept == [False, False, True, True, True]   # oldest go
+    else:
+        assert kept == [True] * 5
+
+
+@pytest.mark.parametrize("bucket", BUCKETS)
+def test_two_threads_inserting_at_capacity(cache, bucket, monkeypatch):
+    """Insert-with-eviction from several threads at once (query
+    threads and the pre-warm thread do): no entry is lost or counted
+    twice, and the bucket never outgrows its capacity."""
+    import sys
+    import threading
+    from trino_tpu.config import CONFIG
+    monkeypatch.setattr(CONFIG, "jit_cache_entries", 8)
+    n_threads, per_thread = 16, 150
+    e0 = _EVICTED.value()
+    errors, inserted = [], []
+    start = threading.Barrier(n_threads)
+
+    def insert(t):
+        mine = 0
+        try:
+            start.wait(timeout=30)
+            for i in range(per_thread):
+                key = ("k", t, i)
+                _, hit = cache.program(bucket, key,
+                                       lambda: (lambda x: x), bucket, key)
+                mine += not hit
+                # a key every thread puts: whoever finds it gone (it
+                # was evicted meanwhile) inserts it again
+                token = object()
+                mine += cache.put(bucket, ("shared", i % 4),
+                                  token) is token
+        except Exception as e:      # noqa: BLE001 — reported below
+            errors.append(e)
+        inserted.append(mine)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=insert, args=(t,))
+                   for t in range(n_threads)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=120)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(th.is_alive() for th in threads)
+    assert errors == [] and len(inserted) == n_threads
+    keys = [("k", t, i) for t in range(n_threads)
+            for i in range(per_thread)] \
+        + [("shared", i) for i in range(4)]
+    resident = sum(cache.resident(bucket, k) for k in keys)
+    assert resident == 8
+    # every insertion is still there or was evicted once, and counted
+    assert sum(inserted) >= n_threads * per_thread
+    assert _EVICTED.value() - e0 + resident == sum(inserted)

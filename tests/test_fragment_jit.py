@@ -98,20 +98,23 @@ def test_structural_jit_cache_reuses_program(monkeypatch):
     """Two separately planned executions of the same SQL must share one
     cached streaming-aggregation program (plan-fingerprint keyed —
     the ExpressionCompiler generated-class cache analog)."""
-    from trino_tpu.exec import executor as ex
+    from trino_tpu.obs.metrics import JIT_CACHE_LOOKUPS
     monkeypatch.setenv("TRINO_TPU_WHOLE_TABLE", "1")
     r = LocalQueryRunner()
     sql = ("SELECT l_returnflag, sum(l_quantity), avg(l_discount) "
            "FROM lineitem WHERE l_shipdate <= DATE '1998-09-02' "
            "GROUP BY l_returnflag ORDER BY l_returnflag")
     outs = []
+    hits = []
     for _ in range(2):
         stmt = parse_statement(sql)
         plan = optimize(
             LogicalPlanner(r.catalogs, r.session).plan(stmt))
         outs.append(Executor(r.catalogs, r.session,
                              fragment_jit=True).execute(plan).to_pylist())
+        hits.append(JIT_CACHE_LOOKUPS.value(cache="stream",
+                                            result="hit"))
     assert_rows_close(outs[0], outs[1])
-    # both executions landed on the same fingerprint entries
-    assert any(isinstance(k, tuple) and k and k[-1] == "full"
-               for k in ex._STREAM_JIT_CACHE)
+    # both executions landed on the same fingerprint entry: the
+    # second plan's whole-table program was a lookup hit
+    assert hits[1] > hits[0]

@@ -107,17 +107,24 @@ def test_served_mesh_answers_equal_the_reference(coordinator, reference,
     assert "spmd_agg" in kinds
 
 
+@pytest.fixture
+def fresh_mesh_programs():
+    """No mesh program or refusal from before the test, and none of
+    the test's (traced under a patched limit) after it."""
+    from trino_tpu.exec.progkey import PROGRAMS
+    PROGRAMS.clear("spmd")
+    yield
+    PROGRAMS.clear("spmd")
+
+
 def test_q3_with_both_sides_repartitioned_and_partials_exchanged(
-        coordinator, reference, monkeypatch):
+        coordinator, reference, monkeypatch, fresh_mesh_programs):
     """sf10's shape at tiny: the PARTITIONED join (both sides through
     the all_to_all exchange) and a grouped aggregation whose partials
     are too large to gather (partial -> exchange -> final)."""
     from trino_tpu.exec import distributed
-    from trino_tpu.parallel import spmd
     gaps, answers, limits = reference
     monkeypatch.setattr(distributed, "FUSED_PARTIAL_ROWS", 4)
-    monkeypatch.setattr(distributed, "_FUSED_AGG_DENY", set())
-    monkeypatch.setattr(spmd, "_PROGRAMS", {})  # traced under the limit
     moved = counter("trino_tpu_mesh_exchange_rows_total", kind="repartition")
     res = execute(coordinator, sql_of("q3"),
                   join_distribution_type="PARTITIONED")

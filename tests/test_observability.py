@@ -398,14 +398,13 @@ def test_worker_metrics_endpoint_and_task_stats(worker_uris):
 
 
 # ---------------------------------------------------------------------------
-# overhead budget (bench.py telemetry_overhead tripwire)
+# overhead budget (the telemetry-overhead tripwire)
 # ---------------------------------------------------------------------------
 
 @pytest.mark.slow
 def test_telemetry_overhead_under_5_percent():
     """Stats collection must stay cheap enough to leave always-on at
-    the coordinator (the reference keeps OperatorStats always-on);
-    bench.py emits the same measurement as telemetry_overhead.
+    the coordinator (the reference keeps OperatorStats always-on).
     Iterations INTERLEAVE the two modes so machine-load drift hits
     both sides equally; best-of-N per side."""
     import time as _time
@@ -435,9 +434,8 @@ def test_telemetry_overhead_under_5_percent_distributed_mpp(tmp_path):
     """The PR 15 re-run of the overhead bound on the DEFAULT
     (multistage MPP) distributed path with the FULL telemetry stack
     on: distributed tracing (traceparent propagation + id-preserving
-    span merge), device/CPU attribution, and OTLP file export —
-    mirrors bench.py's rebuilt telemetry leg. Interleaved best-of-N
-    as above."""
+    span merge), device/CPU attribution, and OTLP file export.
+    Interleaved best-of-N as above."""
     import time as _time
     from trino_tpu.benchmarks.tpch_queries import TPCH_QUERIES
     from trino_tpu.config import CONFIG

@@ -97,6 +97,7 @@ def test_pressure_ladder_sheds_result_cache_before_jit(monkeypatch):
     (expensive to rebuild: saved compile storms), and counts the shed
     under {cache="result"}."""
     from trino_tpu.exec import executor as ex
+    from trino_tpu.exec.progkey import PROGRAMS
     from trino_tpu.obs.metrics import CACHE_PRESSURE_EVICTS
 
     # drain the scan/replicate tiers other tests populated — they
@@ -107,16 +108,21 @@ def test_pressure_ladder_sheds_result_cache_before_jit(monkeypatch):
     assert len(RESULT_CACHE) >= 1
     nbytes = RESULT_CACHE.bytes()
     assert ex.cache_memory_bytes() >= nbytes    # governance sees it
-    monkeypatch.setitem(ex._CHAIN_JIT_CACHE, ("sentinel-a",), object())
-    monkeypatch.setitem(ex._CHAIN_JIT_CACHE, ("sentinel-b",), object())
-    jit_before = len(ex._CHAIN_JIT_CACHE)
+    PROGRAMS.clear("chain")     # shedding takes the OLDEST half
+    PROGRAMS.put("chain", ("sentinel-a",), object())
+    PROGRAMS.put("chain", ("sentinel-b",), object())
     r0 = CACHE_PRESSURE_EVICTS.value(cache="result")
+    j0 = CACHE_PRESSURE_EVICTS.value(cache="jit")
     entries_before = len(RESULT_CACHE)
     freed = ex.evict_cache_pressure(1)      # tiny deficit: result-cache
     assert freed >= 1                       # rung alone must cover it
     assert len(RESULT_CACHE) < entries_before
     assert CACHE_PRESSURE_EVICTS.value(cache="result") > r0
-    assert len(ex._CHAIN_JIT_CACHE) == jit_before   # jit tier untouched
+    # jit tier untouched
+    assert CACHE_PRESSURE_EVICTS.value(cache="jit") == j0
+    assert PROGRAMS.resident("chain", ("sentinel-a",))
+    assert PROGRAMS.resident("chain", ("sentinel-b",))
+    PROGRAMS.clear("chain")
 
 
 def test_lru_and_capacity_bounds():

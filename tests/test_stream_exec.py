@@ -220,9 +220,9 @@ def test_streamjoin_hot_shape_recorded_and_aot_compiles(mem_tables):
     under their canonical chunk capacity, and the AOT path rebuilds +
     compiles the probe program into the exact cache slot — a
     pre-warmed worker's first streamed chunk is a cache hit."""
-    from trino_tpu.exec import streamjoin as sj
     from trino_tpu.exec.aot import compile_entries
     from trino_tpu.exec.hotshapes import HOT_SHAPES
+    from trino_tpu.exec.progkey import PROGRAMS
     HOT_SHAPES.clear()
     sql = ("SELECT count(*), sum(w) FROM memory.default.sprobe "
            "JOIN memory.default.sbuild ON k = bk")
@@ -239,7 +239,7 @@ def test_streamjoin_hot_shape_recorded_and_aot_compiles(mem_tables):
     # wipe the in-process program cache, AOT-compile from the payload,
     # then prove the live query path lands on the pre-warmed program:
     # zero jit_trace spans inside the stream
-    sj._JOIN_JIT_CACHE.clear()
+    PROGRAMS.clear("streamjoin")
     out = compile_entries(entries)
     assert out["compiled"] == 1 and out["errors"] == 0
     r = LocalQueryRunner(session=s, catalogs=mem_tables.catalogs,

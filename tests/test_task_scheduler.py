@@ -545,12 +545,57 @@ def test_replicate_fetch_once_cache_unit(tmp_path):
     assert replicate_cache_bytes() == 0
 
 
+def test_replicate_second_read_on_one_reader_is_served_from_the_cache(
+        tmp_path, monkeypatch):
+    """What sibling consumer tasks gain, shown where it cannot race:
+    ONE exchange reader reading the same replicate source twice pulls
+    the frame once; the second read is a cache hit. (Siblings started
+    together may all miss: ``read_fragment`` is a get-then-put and
+    nothing makes the second wait for the first.)"""
+    from trino_tpu.columnar import batch_from_pylist
+    from trino_tpu.fte.spool import LocalDirSpool
+    from trino_tpu.serde import serialize_batch
+    from trino_tpu.stage.exchange import (ExchangePuller,
+                                          evict_replicate_cache)
+    from trino_tpu.types import BIGINT
+    evict_replicate_cache(None)
+    spool = LocalDirSpool(str(tmp_path))
+    frame = serialize_batch(batch_from_pylist(
+        {"x": [4, 5]}, {"x": BIGINT}))
+    spool.commit("qh.s0.p0", 0, 0, 0, [frame])
+    reader = ExchangePuller(
+        {"0": {"tasks": ["qh.s0.p0"], "uris": [None],
+               "kind": "replicate", "candidates": [], "eager": False}},
+        part=0, spool=spool)
+    pulls = []
+    pull_frame = reader.pull_frame
+
+    def counting_pull(key, uri, **kw):
+        pulls.append(key)
+        return pull_frame(key, uri, **kw)
+    monkeypatch.setattr(reader, "pull_frame", counting_pull)
+    hits0 = _counter("trino_tpu_exchange_replicate_cache_total",
+                     result="hit")
+    miss0 = _counter("trino_tpu_exchange_replicate_cache_total",
+                     result="miss")
+    first = reader.read_fragment(0)
+    assert pulls == ["qh.s0.p0"]
+    assert _counter("trino_tpu_exchange_replicate_cache_total",
+                    result="miss") == miss0 + 1
+    second = reader.read_fragment(0)
+    assert pulls == ["qh.s0.p0"]            # no second pull_frame
+    assert _counter("trino_tpu_exchange_replicate_cache_total",
+                    result="hit") == hits0 + 1
+    assert first[0].to_pylist() == second[0].to_pylist() == [[4], [5]]
+    evict_replicate_cache(None)
+
+
 def test_replicate_cache_e2e_semi_join():
     """A semi join's replicated filtering side over THREE consumer
     tasks (one per worker, all in this process sharing the fetch-once
-    cache): the cache takes re-pulls off the exchange and the result
-    is exact. Barrier mode, so the committed frames are pulled at
-    consumer starts staggered by task dispatch."""
+    cache): the result is exact and the broadcast frame ends up in the
+    cache. Whether a sibling HITS depends on how the three starts
+    interleave (see the test above), so no hit is asserted here."""
     from trino_tpu.stage.exchange import (evict_replicate_cache,
                                           replicate_cache_bytes)
     evict_replicate_cache(None)
@@ -561,18 +606,13 @@ def test_replicate_cache_e2e_semi_join():
     try:
         expected = LocalQueryRunner(
             session=Session(catalog="tpch", schema="tiny")).execute(sql)
-        hits0 = _counter("trino_tpu_exchange_replicate_cache_total",
-                         result="hit")
         s = Session(catalog="tpch", schema="tiny")
         s.set("stage_pipelining", False)
         res = DistributedHostQueryRunner(
             [w.base_uri for w in workers], session=s).execute(sql)
         assert res.rows == expected.rows
-        # the broadcast frames were cached per worker PROCESS...
+        # the broadcast frames were cached per worker PROCESS
         assert replicate_cache_bytes() > 0
-        # ...and sibling consumer tasks were served from the cache
-        assert _counter("trino_tpu_exchange_replicate_cache_total",
-                        result="hit") > hits0
     finally:
         for w in workers:
             w.stop()

@@ -23,7 +23,6 @@ import numpy as np
 import pytest
 
 from trino_tpu.exec import aot
-from trino_tpu.exec import executor as exmod
 from trino_tpu.exec.executor import Executor
 from trino_tpu.exec.hotshapes import HOT_SHAPES
 from trino_tpu.obs.metrics import METRICS
@@ -74,14 +73,8 @@ def _wipe_program_caches():
     jax's per-callable trace caches are gone — only the AOT path can
     repopulate them."""
     import jax
-    from trino_tpu.exec.streamjoin import _JOIN_JIT_CACHE
-    from trino_tpu.stage import repartition as rp
-    exmod._CHAIN_JIT_CACHE.clear()
-    exmod._STREAM_JIT_CACHE.clear()
-    exmod._MJOIN_JIT_CACHE.clear()
-    exmod._WINDOW_JIT_CACHE.clear()
-    _JOIN_JIT_CACHE.clear()
-    rp._BUCKET_JIT_CACHE.clear()
+    from trino_tpu.exec.progkey import PROGRAMS
+    PROGRAMS.clear()
     jax.clear_caches()
 
 

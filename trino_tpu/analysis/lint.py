@@ -259,8 +259,8 @@ class _ModuleIndex(ast.NodeVisitor):
 # query threads, task threads, and the worker pre-warm thread
 # concurrently (HOT_SHAPES.record/merge/export_since), so its lock
 # discipline must stay lint-reachable too. streamjoin joined in PR 12:
-# its jitted-program caches are mutated by query threads and the
-# worker pre-warm thread (exec/aot.py streamjoin entries).
+# its probe programs are inserted by query threads and the worker
+# pre-warm thread (exec/aot.py streamjoin entries).
 # distributed joined in PR 13: worker task threads execute the mesh
 # executor's kernels, so its state writes must stay lint-reachable
 # next to the stage/ exchange modules.
@@ -302,7 +302,11 @@ _CROSS_CALLEES = ("fte/", "stage/", "obs/metrics.py", "obs/trace.py",
                   # run on worker task threads; every shared index
                   # (partition positions, topic cache, job registry)
                   # must stay visible to the race detector
-                  "streaming/", "connectors/stream.py")
+                  "streaming/", "connectors/stream.py",
+                  # PR 30: the one program cache — query threads, task
+                  # threads and the pre-warm thread insert, deny and
+                  # shed through it
+                  "exec/progkey.py")
 
 
 class _CrossIndex:

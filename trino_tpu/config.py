@@ -146,10 +146,10 @@ class EngineConfig:
         "TRINO_TPU_OBJECTSTORE_ATTEMPTS", 4)
     objectstore_backoff_s: float = float(os.environ.get(
         "TRINO_TPU_OBJECTSTORE_BACKOFF_S", "0.02"))
-    # in-process structural jit-program cache capacity (exec/executor.py
-    # _cache_put over _CHAIN_JIT_CACHE/_STREAM_JIT_CACHE): entries are
-    # compiled-program closures, cheap to hold, expensive to rebuild —
-    # evictions are counted in trino_tpu_jit_cache_evictions_total
+    # capacity of EACH bucket of the in-process program cache
+    # (exec/progkey.py PROGRAMS): entries are compiled-program
+    # closures, cheap to hold, expensive to rebuild — evictions are
+    # counted in trino_tpu_jit_cache_evictions_total
     jit_cache_entries: int = _env_int("TRINO_TPU_JIT_CACHE_ENTRIES",
                                       256)
     # compile-amortization subsystem (exec/progkey.py + exec/aot.py +

@@ -16,9 +16,9 @@ closure the executor would build for that program, fabricates an
 argument Batch of ``jax.ShapeDtypeStruct`` avals — no real data — and
 runs ``jax.jit(fn).lower(batch).compile()``. The compile:
 
-- inserts the jitted callable into the in-process structural cache
-  under the SAME canonical key the executor probes
-  (``_CHAIN_JIT_CACHE`` / ``_STREAM_JIT_CACHE``), and
+- inserts the jitted callable into the program cache
+  (exec/progkey.py ``PROGRAMS``) in the bucket and under the SAME
+  canonical key the executor probes, and
 - writes the compiled program into jax's persistent compilation cache
   (config.py), so even a later signature variation (a different
   capacity bucket, a fresh dictionary identity) pays only a re-trace,
@@ -126,11 +126,11 @@ def _mjoin_programs(payload: dict) -> list:
                                pspec, bspec, pcap, bcap, out_cap)
     return [
         (ckey, ex.make_mjoin_count_program(pkeys, bkeys, outer),
-         (probe, build), ex._MJOIN_JIT_CACHE, ex.mjoin_kind(ckey), ckey),
+         (probe, build), "join", ex.mjoin_kind(ckey), ckey),
         (ekey, ex.make_mjoin_expand_program(frag.join_type,
                                             frag.filter, out_cap),
          (probe, build, i64(pcap), i64(pcap), i64(bcap)),
-         ex._MJOIN_JIT_CACHE, ex.mjoin_kind(ekey), ekey)]
+         "join", ex.mjoin_kind(ekey), ekey)]
 
 
 def _repartition_program(payload: dict) -> tuple:
@@ -145,18 +145,19 @@ def _repartition_program(payload: dict) -> tuple:
                    for _ in range(nkeys))
     key = rp.bucket_program_key(nkeys, cap, nparts)
     return (key, rp.make_bucket_program(nkeys, nparts),
-            (lanes, valids), rp._BUCKET_JIT_CACHE, "repartition", key)
+            (lanes, valids), "repartition", "repartition", key)
 
 
 def compile_entry(entry: dict) -> Optional[float]:
     """AOT-compile one hot-shape registry entry — every jitted program
     the entry's shape needs (a materialized join carries two: count +
     expand). Returns the total compile wall in seconds, or None when
-    all programs were already resident in their in-process caches (a
-    hit — nothing to do). Raises on a broken payload; callers treat
-    per-entry failures as skippable."""
-    import jax
+    all programs were already resident in the program cache (a hit —
+    nothing to do). Raises on a broken payload; callers treat per-entry
+    failures as skippable. Every branch gives (key, function, argument
+    avals, bucket, name kind, name key): one loop compiles them all."""
     from . import executor as ex
+    from .progkey import PROGRAMS, named_jit
 
     payload = entry["payload"] if "payload" in entry else entry
     kind = str(payload["kind"])
@@ -166,9 +167,9 @@ def compile_entry(entry: dict) -> Optional[float]:
         # carrying RemoteSource leaves + both sides' lane specs, so a
         # pre-warming worker compiles the chunk kernel at its
         # canonical chunk capacity too
-        from .streamjoin import _JOIN_JIT_CACHE, aot_entry
+        from .streamjoin import aot_entry
         key, fn, args = aot_entry(payload)
-        programs = [(key, fn, args, _JOIN_JIT_CACHE, "streamjoin", key)]
+        programs = [(key, fn, args, "streamjoin", "streamjoin", key)]
     elif kind == "join":
         # materialized hash join: same wire form as streamjoin, two
         # programs (exec/executor.py mjoin count/expand)
@@ -178,14 +179,10 @@ def compile_entry(entry: dict) -> Optional[float]:
         # fragment, just the (key count, capacity, nparts) signature
         programs = [_repartition_program(payload)]
     elif kind == "window":
-        from .window import execute_window
         nodes, fps, schema = _peeled_fragment(payload)
-        wnode = nodes[0]
-
-        def wfn(b):
-            return execute_window(b, wnode)
-        programs = [(fps, wfn, (_aval_batch(payload, schema),),
-                     ex._WINDOW_JIT_CACHE, "window", fps)]
+        programs = [(fps, ex.make_window_program(nodes[0]),
+                     (_aval_batch(payload, schema),),
+                     "window", "window", fps)]
     else:
         nodes, fps, schema = _peeled_fragment(payload)
 
@@ -195,25 +192,19 @@ def compile_entry(entry: dict) -> Optional[float]:
         helper = ex.Executor(CatalogManager(), Session())
 
         if kind == "chain":
-            key = fps
-            cache = ex._CHAIN_JIT_CACHE
-            chain = nodes
-
-            def fn(b):
-                for nd in reversed(chain):
-                    b = helper._dispatch_apply(nd, b)
-                return b
+            key, bucket = fps, "chain"
+            fn = ex.make_chain_program(helper, nodes)
         elif kind in ("stream", "stream_full"):
             # stream node stacks lead with the AggregationNode
             # (progkey.canonicalize_nodes order)
             agg, chain = nodes[0], nodes[1:]
             run, run_full = ex.make_stream_runners(helper, chain, agg)
             key = fps if kind == "stream" else (fps, "full")
-            cache = ex._STREAM_JIT_CACHE
+            bucket = "stream"
             fn = run if kind == "stream" else run_full
         else:
             raise ValueError(f"unknown hot-shape kind {kind!r}")
-        programs = [(key, fn, (_aval_batch(payload, schema),), cache,
+        programs = [(key, fn, (_aval_batch(payload, schema),), bucket,
                      kind, fps)]
 
     wall = 0.0
@@ -221,11 +212,8 @@ def compile_entry(entry: dict) -> Optional[float]:
     # each program is jitted under the SAME name the executor would
     # give it (progkey.named_jit: kind + canonical key), so the module
     # compiled here is the one the first real query looks up
-    from .progkey import named_jit
-    for key, fn, args, cache, name_kind, name_key in programs:
-        with ex._JIT_CACHE_LOCK:
-            resident = key in cache
-        if resident:
+    for key, fn, args, bucket, name_kind, name_key in programs:
+        if PROGRAMS.resident(bucket, key):
             continue
         t0 = time.perf_counter()
         try:
@@ -238,7 +226,7 @@ def compile_entry(entry: dict) -> Optional[float]:
         # the jitted callable (now holding the compiled program in its
         # own cache) lands under the executor's key: the first real
         # query with this shape is an in-process cache hit
-        ex._cache_put(cache, key, jitted)
+        PROGRAMS.put(bucket, key, jitted)
         compiled = True
     if not compiled:
         _M_AOT.inc(kind=kind, result="cached")

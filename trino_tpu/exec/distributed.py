@@ -57,7 +57,8 @@ from ..types import BOOLEAN, BIGINT
 from .executor import (Executor, QueryError, _Pre, _lower_aggregates,
                        join_verify_filter, make_stream_parts,
                        read_table_sharded)
-from .progkey import canonicalize_nodes, node_fingerprint
+from .progkey import (PROGRAMS, UNTRACEABLE, canonicalize_nodes,
+                      node_fingerprint)
 from .expr import eval_expr, eval_predicate
 
 Value = Union[Batch, ShardedBatch]
@@ -73,12 +74,12 @@ FUSED_PARTIAL_ROWS = 1 << 10
 # exchange only where it is cheap to collect and can be selective
 DYNAMIC_FILTER_BUILD_ROWS = 100_000
 
-# canonical keys of aggregations that passed ``_small_partial`` and
-# still could not be one program: untraceable (host code in an
-# aggregate, as the one-chip path's ``_STREAM_JIT_DENY``), or a partial
-# the packed kernel declined for its aggregate kinds
-_FUSED_AGG_DENY: set = set()
-
+# An aggregation that passed ``_small_partial`` and still could not be
+# one program (untraceable: host code in an aggregate, as on the
+# one-chip path; or a partial the packed kernel declined for its
+# aggregate kinds) is refused in the program cache's "spmd" bucket by
+# its canonical key ALONE: whatever mesh and operand structure its
+# programs are kept under, none is tried again.
 
 class _PartialTooLarge(Exception):
     pass
@@ -309,8 +310,7 @@ class DistributedExecutor(Executor):
         try:
             return shard_apply(rp, lambda b: execute_window(b, node),
                                key=_node_key(node))
-        except (jax.errors.TracerArrayConversionError,
-                jax.errors.ConcretizationTypeError):
+        except UNTRACEABLE:
             # a window shape the kernel can't trace (host-side frame
             # math): correctness first, gather and run locally
             return super()._exec_WindowNode(
@@ -458,7 +458,7 @@ class DistributedExecutor(Executor):
         node_x, chain_x = ((canon.nodes[0], canon.nodes[1:])
                            if canon is not None else (node, chain))
         key = None if canon is None else canon.key
-        if key is not None and key in _FUSED_AGG_DENY:
+        if key is not None and PROGRAMS.denied("spmd", key):
             return None
         binding = None
         cols = src.columns
@@ -488,10 +488,9 @@ class DistributedExecutor(Executor):
         try:
             out = mesh_call("agg", key, src.mesh, (cols, src.num_rows),
                             build)
-        except (_PartialTooLarge, jax.errors.TracerArrayConversionError,
-                jax.errors.ConcretizationTypeError):
+        except (_PartialTooLarge,) + UNTRACEABLE:
             if key is not None:
-                _FUSED_AGG_DENY.add(key)
+                PROGRAMS.deny("spmd", key)
             return None
         # the result is the same on every chip: keep the coordinator's
         out = jax.tree.map(lambda a: a.addressable_shards[0].data, out)

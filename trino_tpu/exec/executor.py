@@ -43,7 +43,7 @@ from ..session import Session
 from ..types import (BIGINT, BOOLEAN, DOUBLE, REAL, DecimalType, Type,
                      is_integral, is_string)
 from .expr import EvalError, eval_expr, eval_predicate
-from .progkey import named_jit
+from .progkey import PROGRAMS, UNTRACEABLE, named_jit
 
 
 class QueryError(Exception):
@@ -242,29 +242,14 @@ def stats_lines(stats: Sequence["NodeStats"]) -> List[str]:
 _TRACEABLE = ()  # filled after class definition
 _PPOS, _BPOS = "__probe_pos$", "__build_pos$"
 
-# cross-query caches of jitted plan programs, keyed by canonical
-# program key (exec/progkey.py); deny-lists for plans whose chains
-# touch host-only evaluation paths. Reference analog: the generated-
-# class caches of sql/gen/ExpressionCompiler.java (keyed on
-# RowExpression trees) — a re-traced identical plan pays trace +
-# persistent-cache executable reload per query (cost on the chip: not
-# measured).
-_STREAM_JIT_CACHE: Dict[tuple, object] = {}
-_STREAM_JIT_DENY: set = set()
-_CHAIN_JIT_CACHE: Dict[tuple, object] = {}
-_CHAIN_JIT_DENY: set = set()
-# ragged multi-query batch programs (canonical chain + the __rq
-# provenance lane threaded through, exec/progkey.py ragged_nodes):
-# keyed on the canonical chain key — jax specializes per combined
-# capacity under one callable, same as the solo chain cache
-_RAGGED_JIT_CACHE: Dict[tuple, object] = {}
-# window programs (execute_window over one canonical WindowNode) and
-# the two-phase materialized hash-join programs (count + expand over
-# ops/join.py) — the "window" and "join" AOT kinds of exec/aot.py
-_WINDOW_JIT_CACHE: Dict[tuple, object] = {}
-_WINDOW_JIT_DENY: set = set()
-_MJOIN_JIT_CACHE: Dict[tuple, object] = {}
-_MJOIN_JIT_DENY: set = set()
+# jitted plan programs live ACROSS queries in the one program cache
+# (exec/progkey.py PROGRAMS), keyed by the canonical program keys of
+# that module's ONE canonicalizer (shared with the hot-shape registry,
+# exec/hotshapes.py, and the AOT compiler, exec/aot.py); the buckets
+# filled from this module: "chain", "stream" (per-split and
+# whole-table aggregation), "ragged" (canonical chain + the __rq
+# provenance lane, progkey.ragged_nodes), "window" and "join" (the
+# count + expand programs of the materialized hash join).
 
 # process metrics (obs/metrics.py; scraped at GET /metrics). These are
 # per-query-phase increments, never per-row — the lock cost is noise.
@@ -294,35 +279,6 @@ _M_SPLITS = _METRICS.counter(
 # planner); these aliases keep the executor-local names working
 from ..rex import VOLATILE_FNS as _VOLATILE_FNS, \
     expr_volatile as _expr_volatile
-
-
-# structural node fingerprints + the canonical program keys built on
-# them live in exec/progkey.py — ONE canonicalizer shared by the
-# in-process caches here, the hot-shape registry (exec/hotshapes.py),
-# and the AOT compiler (exec/aot.py)
-
-import threading as _jit_threading
-
-_JIT_CACHE_LOCK = _jit_threading.Lock()
-
-_M_JIT_EVICT = _METRICS.counter(
-    "trino_tpu_jit_cache_evictions_total",
-    "Structural jitted-program cache entries evicted at capacity "
-    "(TRINO_TPU_JIT_CACHE_ENTRIES)")
-
-
-def _cache_put(cache: Dict[tuple, object], key: tuple, val) -> None:
-    # the coordinator runs one thread per query (server/coordinator.py)
-    # — insert-with-eviction must not race another thread's eviction
-    with _JIT_CACHE_LOCK:
-        limit = max(int(CONFIG.jit_cache_entries), 1)
-        while len(cache) >= limit:
-            try:
-                cache.pop(next(iter(cache)))
-                _M_JIT_EVICT.inc()
-            except (KeyError, StopIteration):
-                break
-        cache[key] = val
 
 
 def _keys_inexact(cols, keys) -> bool:
@@ -641,24 +597,22 @@ class Executor:
                 # per-query identity keys
                 from .progkey import canonicalize_nodes
                 canon = canonicalize_nodes(chain)
-                structural = canon is not None
-                key = canon.key if structural \
+                key = canon.key if canon is not None \
                     else tuple(id(n) for n in chain)
                 base = self.execute(cur)
-                if key not in self._no_jit_chains \
-                        and key not in _CHAIN_JIT_DENY:
+                if key not in self._no_jit_chains:
                     try:
-                        return self._run_chain_jit(key, chain, base,
-                                                   structural, canon)
-                    except (jax.errors.TracerArrayConversionError,
-                            jax.errors.ConcretizationTypeError):
+                        out = self._run_chain_jit(key, chain, base,
+                                                  canon)
+                        if out is not None:
+                            return out
+                    except UNTRACEABLE:
                         # chain touches host-only paths (row-
                         # materializing string fns); run it eagerly
                         # from here on
                         self._no_jit_chains.add(key)
-                        if structural:
-                            _CHAIN_JIT_CACHE.pop(key, None)
-                            _CHAIN_JIT_DENY.add(key)
+                        if canon is not None:
+                            PROGRAMS.deny("chain", key)
                 b = base
                 for nd in reversed(chain):
                     b = self._dispatch_apply(nd, b)
@@ -768,19 +722,10 @@ class Executor:
 
         if raws is not None and len(raws) == 1 and self.fragment_jit:
             fullkey = None if fkey is None else (fkey, "full")
-            if fullkey not in _STREAM_JIT_DENY:
-                full_jit = (_STREAM_JIT_CACHE.get(fullkey)
-                            if fullkey is not None else None)
-                full_hit = full_jit is not None
-                if fullkey is not None:
-                    # only real cache lookups count — an uncacheable
-                    # plan (no structural key) is not a miss
-                    _M_JIT.inc(cache="stream",
-                               result="hit" if full_hit else "miss")
-                if full_jit is None:
-                    full_jit = named_jit(run_full, "stream_full", fkey)
-                    if fullkey is not None:
-                        _cache_put(_STREAM_JIT_CACHE, fullkey, full_jit)
+            got = PROGRAMS.program("stream", fullkey, lambda: run_full,
+                                   "stream_full", fkey)
+            if got is not None:
+                full_jit, full_hit = got
                 batch = bind(Batch(
                     {sym: raws[0].column(col)
                      for sym, col in cur.assignments.items()},
@@ -792,11 +737,9 @@ class Executor:
                 try:
                     return unbind(self._jit_call(
                         full_jit, (batch,), "stream", full_hit))
-                except (jax.errors.TracerArrayConversionError,
-                        jax.errors.ConcretizationTypeError):
+                except UNTRACEABLE:
                     if fullkey is not None:
-                        _STREAM_JIT_CACHE.pop(fullkey, None)
-                        _STREAM_JIT_DENY.add(fullkey)
+                        PROGRAMS.deny("stream", fullkey)
 
         # one jitted program serves every split (uniform capacities);
         # the program is cached across QUERIES by canonical program
@@ -806,20 +749,16 @@ class Executor:
         jit_hit = False
         recorded = False
         if self.fragment_jit:
-            if fkey is not None and fkey not in _STREAM_JIT_DENY:
-                run_jit = _STREAM_JIT_CACHE.get(fkey)
-                jit_hit = run_jit is not None
-                _M_JIT.inc(cache="stream",
-                           result="hit" if jit_hit else "miss")
-            if run_jit is None and fkey not in _STREAM_JIT_DENY:
-                run_jit = named_jit(run, "stream", fkey)
-                if fkey is not None:
-                    _cache_put(_STREAM_JIT_CACHE, fkey, run_jit)
+            got = PROGRAMS.program("stream", fkey, lambda: run,
+                                   "stream", fkey)
+            if got is not None:
+                run_jit, jit_hit = got
+
         def consume(batch: Batch) -> Batch:
             nonlocal phys, post, recorded, run_jit, jit_hit
             batch = bind(batch)
             if fkey is not None and not recorded \
-                    and fkey not in _STREAM_JIT_DENY:
+                    and not PROGRAMS.denied("stream", fkey):
                 # deny-listed programs must not climb the pre-warm
                 # ranking: every joining worker would burn a top-K
                 # slot AOT-compiling a shape that cannot trace
@@ -835,12 +774,10 @@ class Executor:
                     out = self._jit_call(run_jit, (batch,), "stream",
                                          jit_hit)
                     jit_hit = True   # later splits reuse the program
-                except (jax.errors.TracerArrayConversionError,
-                        jax.errors.ConcretizationTypeError):
+                except UNTRACEABLE:
                     run_jit = None
                     if fkey is not None:
-                        _STREAM_JIT_CACHE.pop(fkey, None)
-                        _STREAM_JIT_DENY.add(fkey)
+                        PROGRAMS.deny("stream", fkey)
                     out = run(batch)
             else:
                 out = run(batch)
@@ -999,8 +936,7 @@ class Executor:
             self._jit_chains[key] = jitted
         try:
             return self._jit_call(jitted, (base,), "masked", hit)
-        except (jax.errors.TracerArrayConversionError,
-                jax.errors.ConcretizationTypeError):
+        except UNTRACEABLE:
             # host-materializing expressions in the chain: run eagerly
             self._no_jit_chains.add(key)
             return run(base)
@@ -1014,48 +950,49 @@ class Executor:
         except EvalError as e:
             raise QueryError(str(e)) from e
 
-    def _run_chain_jit(self, key, chain, base: Batch,
-                       structural: bool = False, canon=None) -> Batch:
-        # cache the jitted callable per chain so repeated executions of
-        # the same plan reuse the compiled XLA program (jax.jit's cache
-        # is keyed on function identity). Structural keys live in a
-        # module-level cache shared ACROSS queries; identity keys stay
-        # per-executor (they can't outlive their plan objects safely).
-        # Structural programs execute the CANONICAL node stack with the
-        # input/output columns renamed through the plan's binding
-        # (exec/progkey.py) — the traced jaxpr is identical across
-        # renamed plans, so jax's persistent compilation cache is
-        # effectively keyed on the canonical program too.
-        cache = _CHAIN_JIT_CACHE if structural else self._jit_chains
-        jitted = cache.get(key)
-        hit = jitted is not None
-        _M_JIT.inc(cache="chain", result="hit" if hit else "miss")
-        if jitted is None:
-            helper = self._detached() if structural else self
-            nodes = canon.nodes if structural else chain
+    def _chain_program(self, canon):
+        """``(jitted, hit)`` of a canonical chain's program in the
+        cross-query cache, None where its key is denied. The program
+        executes the CANONICAL node stack (callers rename columns in
+        and out through the plan's binding, exec/progkey.py): the
+        traced jaxpr is identical across renamed plans, so jax's
+        persistent compilation cache is effectively keyed on the
+        canonical program too."""
+        return PROGRAMS.program(
+            "chain", canon.key,
+            lambda: make_chain_program(self._detached(), canon.nodes),
+            "chain", canon.key)
 
-            def fn(b):
-                for nd in reversed(nodes):
-                    b = helper._dispatch_apply(nd, b)
-                return b
-            jitted = named_jit(fn, "chain", key if structural else None)
-            if structural:
-                _cache_put(_CHAIN_JIT_CACHE, key, jitted)
-            else:
-                cache[key] = jitted
-        if structural:
-            binding = canon.binding(base)
-            cb = binding.rename_in(base)
-            from .hotshapes import record_program
-            # record the SOLO canonical program: the hot shape the
-            # fleet pre-warms is the chain itself, not the ragged
-            # variant (whose capacity depends on who co-arrives)
-            record_program("chain", key, canon, cb, self.session)
-            out = self._try_ragged_chain(key, canon, cb)
-            if out is None:
-                out = self._jit_call(jitted, (cb,), "chain", hit)
-            return binding.rename_out(out)
-        return self._jit_call(jitted, (base,), "chain", hit)
+    def _run_chain_jit(self, key, chain, base: Batch,
+                       canon=None) -> Optional[Batch]:
+        """Run a traceable chain as one jitted program; None where the
+        canonical program is denied. Canonical programs are shared
+        ACROSS queries; a chain outside the canonical subset keeps its
+        program per executor under its identity key (it cannot outlive
+        its plan objects safely)."""
+        if canon is None:
+            jitted = self._jit_chains.get(key)
+            hit = jitted is not None
+            _M_JIT.inc(cache="chain", result="hit" if hit else "miss")
+            if jitted is None:
+                jitted = self._jit_chains[key] = named_jit(
+                    make_chain_program(self, chain), "chain", None)
+            return self._jit_call(jitted, (base,), "chain", hit)
+        got = self._chain_program(canon)
+        if got is None:
+            return None
+        jitted, hit = got
+        binding = canon.binding(base)
+        cb = binding.rename_in(base)
+        from .hotshapes import record_program
+        # record the SOLO canonical program: the hot shape the
+        # fleet pre-warms is the chain itself, not the ragged
+        # variant (whose capacity depends on who co-arrives)
+        record_program("chain", key, canon, cb, self.session)
+        out = self._try_ragged_chain(key, canon, cb)
+        if out is None:
+            out = self._jit_call(jitted, (cb,), "chain", hit)
+        return binding.rename_out(out)
 
     # ------------------------------------------------------------------
     # ragged multi-query batching (tentpole, ISSUE 18): compatible
@@ -1151,20 +1088,11 @@ class Executor:
         ragged = Batch(
             {**combined.columns,
              RAGGED_LANE: Column(BIGINT, jnp.asarray(lane))}, total)
-        rkey = ("ragged",) + tuple(key)
-        jitted = _RAGGED_JIT_CACHE.get(rkey)
-        hit = jitted is not None
-        _M_JIT.inc(cache="ragged", result="hit" if hit else "miss")
-        if jitted is None:
-            helper = self._detached()
-            nodes = ragged_nodes(canon.nodes)
-
-            def fn(b):
-                for nd in reversed(nodes):
-                    b = helper._dispatch_apply(nd, b)
-                return b
-            jitted = named_jit(fn, "ragged", key)
-            _cache_put(_RAGGED_JIT_CACHE, rkey, jitted)
+        jitted, hit = PROGRAMS.program(
+            "ragged", ("ragged",) + tuple(key),
+            lambda: make_chain_program(self._detached(),
+                                       ragged_nodes(canon.nodes)),
+            "ragged", key)
         out = self._jit_call(jitted, (ragged,), "ragged", hit)
         # demux: ONE host sync for the lane, then a per-member row
         # gather (the engine's own compaction primitive — dictionaries,
@@ -1468,23 +1396,6 @@ class Executor:
     # ------------------------------------------------------------------
     # joins
     # ------------------------------------------------------------------
-    def _mjoin_program(self, key: tuple, builder):
-        """Lookup-or-build one jitted materialized-join program in the
-        cross-query cache (the key's tag, ``mjoin_count`` or
-        ``mjoin_expand``, gives the program its role: ``join_count``,
-        ``join_expand``). None when the key is denied (a prior trace
-        hit host-only evaluation); the caller falls back to the eager
-        two-phase path."""
-        if key in _MJOIN_JIT_DENY:
-            return None
-        jitted = _MJOIN_JIT_CACHE.get(key)
-        hit = jitted is not None
-        _M_JIT.inc(cache="join", result="hit" if hit else "miss")
-        if jitted is None:
-            jitted = named_jit(builder(), mjoin_kind(key), key)
-            _cache_put(_MJOIN_JIT_CACHE, key, jitted)
-        return jitted, hit
-
     @staticmethod
     def _mjoin_jittable(probe: Batch, build: Batch) -> bool:
         # nested ARRAY/MAP/ROW lanes keep the eager path (their AOT
@@ -1507,17 +1418,17 @@ class Executor:
         key = mjoin_count_key(outer, pkeys, bkeys, _lane_spec(probe),
                               _lane_spec(build), probe.capacity,
                               build.capacity)
-        got = self._mjoin_program(
-            key, lambda: make_mjoin_count_program(pkeys, bkeys, outer))
-        if got is None:
+        got = PROGRAMS.program(
+            "join", key,
+            lambda: make_mjoin_count_program(pkeys, bkeys, outer),
+            mjoin_kind(key), key)
+        if got is None:     # denied: a prior trace hit host-only code
             return None
         jitted, hit = got
         try:
             return self._jit_call(jitted, (probe, build), "join", hit)
-        except (jax.errors.TracerArrayConversionError,
-                jax.errors.ConcretizationTypeError):
-            _MJOIN_JIT_CACHE.pop(key, None)
-            _MJOIN_JIT_DENY.add(key)
+        except UNTRACEABLE:
+            PROGRAMS.deny("join", key)
             return None
 
     def _read_join_total(self, tail) -> int:
@@ -1548,9 +1459,10 @@ class Executor:
         key = mjoin_expand_key(jt, repr(residual), _lane_spec(probe),
                                _lane_spec(build), probe.capacity,
                                build.capacity, out_cap)
-        got = self._mjoin_program(
-            key, lambda: make_mjoin_expand_program(jt, residual,
-                                                   out_cap))
+        got = PROGRAMS.program(
+            "join", key,
+            lambda: make_mjoin_expand_program(jt, residual, out_cap),
+            mjoin_kind(key), key)
         if got is None:
             return None
         jitted, hit = got
@@ -1559,10 +1471,8 @@ class Executor:
                 jnp.asarray(order, jnp.int64))
         try:
             out = self._jit_call(jitted, args, "join", hit)
-        except (jax.errors.TracerArrayConversionError,
-                jax.errors.ConcretizationTypeError):
-            _MJOIN_JIT_CACHE.pop(key, None)
-            _MJOIN_JIT_DENY.add(key)
+        except UNTRACEABLE:
+            PROGRAMS.deny("join", key)
             return None
         if criteria is not None:
             from .hotshapes import record_program
@@ -1593,8 +1503,8 @@ class Executor:
         # probe side is a scan chain whose working set exceeds the
         # memory budget, build the hash table once and stream probe
         # chunks through double-buffered host->device transfers
-        # instead of materializing the probe (BENCH_r05's q18@sf100
-        # "exceeds single-chip HBM" gap)
+        # instead of materializing the probe (q18 at sf100: ~34GB of
+        # lanes, more than one chip's HBM)
         from .streamjoin import maybe_stream_join
         streamed, pre_built = maybe_stream_join(self, node)
         if streamed is not None:
@@ -1984,31 +1894,24 @@ class Executor:
             return execute_window(src, node)
         from .progkey import canonicalize_nodes
         canon = canonicalize_nodes([node])
-        if canon is None or canon.key in _WINDOW_JIT_DENY:
+        got = None if canon is None else PROGRAMS.program(
+            "window", canon.key,
+            lambda: make_window_program(canon.nodes[0]),
+            "window", canon.key)
+        if got is None:
             return execute_window(src, node)
         key = canon.key
-        jitted = _WINDOW_JIT_CACHE.get(key)
-        hit = jitted is not None
-        _M_JIT.inc(cache="window", result="hit" if hit else "miss")
-        if jitted is None:
-            wnode = canon.nodes[0]
-
-            def fn(b: Batch) -> Batch:
-                return execute_window(b, wnode)
-            jitted = named_jit(fn, "window", key)
-            _cache_put(_WINDOW_JIT_CACHE, key, jitted)
+        jitted, hit = got
         binding = canon.binding(src)
         cb = binding.rename_in(src)
         from .hotshapes import record_program
         record_program("window", key, canon, cb, self.session)
         try:
             out = self._jit_call(jitted, (cb,), "window", hit)
-        except (jax.errors.TracerArrayConversionError,
-                jax.errors.ConcretizationTypeError):
+        except UNTRACEABLE:
             # a lane/function combination that materializes on host
             # despite the traceability gate: run eagerly ever after
-            _WINDOW_JIT_CACHE.pop(key, None)
-            _WINDOW_JIT_DENY.add(key)
+            PROGRAMS.deny("window", key)
             return execute_window(src, node)
         return binding.rename_out(out)
 
@@ -2105,6 +2008,31 @@ def make_stream_parts(helper: "Executor", chain, node):
         return out
 
     return partial, finish
+
+
+def make_chain_program(helper: "Executor", nodes):
+    """The program of a traceable node chain (top-down order): every
+    node applied bottom-up over one batch. Module-level, like the
+    builders below, so the AOT compiler (exec/aot.py) rebuilds the
+    EXACT closure the executor caches — a pre-warmed program and a
+    live query trace the same jaxpr."""
+
+    def fn(b: Batch) -> Batch:
+        for nd in reversed(nodes):
+            b = helper._dispatch_apply(nd, b)
+        return b
+
+    return fn
+
+
+def make_window_program(wnode: WindowNode):
+    """The program of one canonical WindowNode."""
+    from .window import execute_window
+
+    def fn(b: Batch) -> Batch:
+        return execute_window(b, wnode)
+
+    return fn
 
 
 def make_stream_runners(helper: "Executor", chain, node):
@@ -2472,15 +2400,7 @@ def evict_cache_pressure(need_bytes: int) -> int:
         except Exception:   # noqa: BLE001 — relief is best-effort
             pass
     if freed < need:
-        with _JIT_CACHE_LOCK:
-            for cache in (_CHAIN_JIT_CACHE, _STREAM_JIT_CACHE,
-                          _RAGGED_JIT_CACHE):
-                for _ in range(len(cache) // 2):
-                    try:
-                        cache.pop(next(iter(cache)))
-                    except (KeyError, StopIteration):
-                        break
-                    _M_CACHE_PRESSURE.inc(cache="jit")
+        PROGRAMS.shed()
     return freed
 
 

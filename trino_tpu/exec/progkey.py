@@ -8,8 +8,8 @@ matter what the analyzer named their symbols. Here the compiled unit
 is an XLA program and the cache has THREE layers that must agree on
 identity:
 
-1. the in-process structural caches (``exec/executor.py``
-   ``_CHAIN_JIT_CACHE`` / ``_STREAM_JIT_CACHE``),
+1. the in-process program cache (``PROGRAMS`` below: one bucket per
+   metric label, every compiled program of the engine),
 2. jax's own per-callable trace cache (keyed on the pytree treedef —
    which includes Batch COLUMN NAMES and their order, columnar.py
    ``_batch_flatten``),
@@ -59,10 +59,16 @@ from __future__ import annotations
 
 import hashlib
 import re
+import threading
 from dataclasses import replace as dc_replace
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
+
+import jax
 
 from ..columnar import Batch
+from ..config import CONFIG
+from ..obs.metrics import (CACHE_PRESSURE_EVICTS, JIT_CACHE_LOOKUPS,
+                           METRICS)
 from ..plan.nodes import (Aggregate, AggregationNode, AssignUniqueIdNode,
                           FilterNode, LimitNode, MarkDistinctNode,
                           OffsetNode, PlanNode, ProjectNode,
@@ -90,7 +96,6 @@ def named_jit(fn, kind: str, key, **jit_kwargs):
     """``jax.jit(fn)`` under the program's name. The returned callable
     carries ``program`` = ``<kind>:<key8>`` for the dispatch spans
     (exec/executor.py ``device_call``)."""
-    import jax
     name = program_name(kind, key)
 
     def program(*args, **kwargs):
@@ -101,6 +106,119 @@ def named_jit(fn, kind: str, key, **jit_kwargs):
     jitted = jax.jit(program, **jit_kwargs)
     jitted.program = f"{kind}:{name[len(kind) + 1:]}"
     return jitted
+
+
+# what a trace raises where a program touches host-only evaluation
+# (row-materializing string functions, host-side frame math): the
+# caller denies the key and runs eagerly from then on
+UNTRACEABLE = (jax.errors.TracerArrayConversionError,
+               jax.errors.ConcretizationTypeError)
+
+_M_JIT_EVICT = METRICS.counter(
+    "trino_tpu_jit_cache_evictions_total",
+    "Structural jitted-program cache entries evicted at capacity "
+    "(TRINO_TPU_JIT_CACHE_ENTRIES)")
+
+
+class ProgramCache:
+    """Cross-query cache of jitted programs: the ONE place that says
+    which compiled program serves a (bucket, key), how many are kept
+    and which are refused. One bucket per label of
+    ``trino_tpu_jit_cache_total{cache}``, keyed by canonical program
+    key, each keeping ``CONFIG.jit_cache_entries`` programs, oldest out
+    first; beside each bucket the keys it refuses (programs whose
+    trace touched host-only evaluation). Reference analog: the
+    generated-class caches of sql/gen/ExpressionCompiler.java (keyed
+    on RowExpression trees). Query threads, worker task threads and
+    the pre-warm thread (exec/aot.py) share it: every mutation is
+    under the one lock (this module is on the race-lint cross-module
+    allowlist, analysis/lint.py), a lookup takes none."""
+
+    BUCKETS = ("chain", "stream", "ragged", "join", "window",
+               "streamjoin", "repartition", "spmd")
+    # what memory pressure halves (exec/executor.py
+    # evict_cache_pressure): the plan-program buckets
+    SHED = ("chain", "stream", "ragged")
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._programs: Dict[str, Dict[tuple, object]] = {
+            b: {} for b in self.BUCKETS}
+        self._denied: Dict[str, set] = {b: set() for b in self.BUCKETS}
+
+    def program(self, bucket: str, key, build: Callable[[], Callable],
+                kind: str, name_key):
+        """``(jitted, hit)`` for ``key`` in ``bucket``, or None where
+        the key is denied. On a miss ``build()`` gives the function,
+        which is jitted under the name of (``kind``, ``name_key``) and
+        kept. ``key`` must name everything the function closes over;
+        ``None`` means it cannot be named: the program is built for
+        this call alone and nothing is counted."""
+        if key is None:
+            return named_jit(build(), kind, name_key), False
+        if key in self._denied[bucket]:
+            return None
+        jitted = self._programs[bucket].get(key)
+        hit = jitted is not None
+        JIT_CACHE_LOOKUPS.inc_at((bucket, "hit" if hit else "miss"))
+        if not hit:
+            jitted = self.put(bucket, key,
+                              named_jit(build(), kind, name_key))
+        return jitted, hit
+
+    def put(self, bucket: str, key, jitted):
+        """Keep ``jitted`` under ``key`` and return what the slot holds:
+        the program that got there first where two threads built the
+        same one (its trace is the one already paid for)."""
+        programs = self._programs[bucket]
+        with self._lock:
+            kept = programs.get(key)
+            if kept is not None:
+                return kept
+            limit = max(int(CONFIG.jit_cache_entries), 1)
+            while len(programs) >= limit:
+                programs.pop(next(iter(programs)))
+                _M_JIT_EVICT.inc()
+            programs[key] = jitted
+        return jitted
+
+    def resident(self, bucket: str, key) -> bool:
+        with self._lock:
+            return key in self._programs[bucket]
+
+    def deny(self, bucket: str, key) -> None:
+        """Refuse ``key`` from now on and drop its program."""
+        with self._lock:
+            self._programs[bucket].pop(key, None)
+            self._denied[bucket].add(key)
+
+    def denied(self, bucket: str, key) -> bool:
+        return key in self._denied[bucket]
+
+    def shed(self) -> int:
+        """Memory-pressure relief: drop the oldest half of each
+        ``SHED`` bucket (entry sizes are opaque, so the relief is
+        entry-counted; the persistent XLA cache backs recompiles)."""
+        dropped = 0
+        with self._lock:
+            for bucket in self.SHED:
+                programs = self._programs[bucket]
+                for _ in range(len(programs) // 2):
+                    programs.pop(next(iter(programs)))
+                    dropped += 1
+        if dropped:
+            CACHE_PRESSURE_EVICTS.inc(dropped, cache="jit")
+        return dropped
+
+    def clear(self, bucket: Optional[str] = None) -> None:
+        """Forget programs and refusals (a fresh process, for tests)."""
+        with self._lock:
+            for b in (bucket,) if bucket is not None else self.BUCKETS:
+                self._programs[b].clear()
+                self._denied[b].clear()
+
+
+PROGRAMS = ProgramCache()
 
 
 class _NotCanonical(Exception):

@@ -6,8 +6,8 @@ construction (operator/Driver.java's pull loop never materializes a
 table), so "working set exceeds memory" is a spill concern there, not
 an executor-mode concern. This engine's whole-column execution model
 (columnar.py) materializes an operator's entire input in device
-memory — which caps query scale at one chip's HBM (BENCH_r05: q18@sf100
-"not attempted: ~34GB of q18 lanes exceeds single-chip HBM").
+memory — which caps query scale at one chip's HBM (q18 at sf100 was
+not attempted: ~34GB of q18 lanes exceeds single-chip HBM).
 
 This module is the morsel-driven answer (tensor-runtime query
 processing, PAPERS arxiv 2203.01877: operator-as-tensor-program chunk
@@ -49,11 +49,11 @@ chunk would re-trace every chunk; strings read off the scan stream
 through the per-stream canonical layout of ``_StreamDictEncoder``),
 nested (ARRAY/MAP/ROW) scan columns, and semi joins.
 
-Shared-runtime code: the jitted-program caches here are mutated by
-query executor threads and the worker pre-warm thread concurrently —
-mutations go through exec/executor.py's ``_cache_put`` under its cache
-lock (this module is on the race-lint cross-module allowlist,
-analysis/lint.py)."""
+Shared-runtime code: the probe programs (bucket "streamjoin") are
+inserted by query executor threads and the worker pre-warm thread
+concurrently — through the one program cache and its lock
+(exec/progkey.py PROGRAMS; this module is on the race-lint
+cross-module allowlist, analysis/lint.py)."""
 
 from __future__ import annotations
 
@@ -66,21 +66,13 @@ import numpy as np
 
 from ..columnar import Batch, Column, StringDictionary, empty_batch
 from ..config import CONFIG, capacity_for
-from ..obs.metrics import (JIT_CACHE_LOOKUPS as _M_JIT, METRICS,
-                           STREAM_CHUNKS, STREAM_H2D_BYTES,
+from ..obs.metrics import (METRICS, STREAM_CHUNKS, STREAM_H2D_BYTES,
                            STREAM_OVERLAPPED)
 from ..plan.nodes import (FilterNode, JoinNode, PlanNode, ProjectNode,
                           RemoteSourceNode, TableScanNode)
 from ..rex import Call as _RCall, InputRef, and_all
 from ..types import BOOLEAN, DecimalType
-from .progkey import named_jit
-
-# cross-query cache of jitted streamed-join probe programs, keyed by
-# (probe/build lane specs, keys, join type, residual, capacities);
-# populated by live queries AND by worker pre-warm (exec/aot.py
-# "streamjoin" entries). Deny set for programs that refuse to trace.
-_JOIN_JIT_CACHE: Dict[tuple, object] = {}
-_JOIN_JIT_DENY: set = set()
+from .progkey import PROGRAMS, UNTRACEABLE
 
 
 # --------------------------------------------------------------------------
@@ -448,48 +440,34 @@ def make_chain_runner(ex, chain: Sequence[PlanNode]):
 
     if not ex.fragment_jit:
         return eager, (lambda b: None)
-    from . import executor as _ex
     from .progkey import canonicalize_nodes
     canon = canonicalize_nodes(chain)
     if canon is None:
         return eager, (lambda b: None)
     key = canon.key
-    state = {"binding": None, "hit": None}
+    state = {"binding": None, "prog": None}
 
     def run(b: Batch) -> Batch:
-        if key in _ex._CHAIN_JIT_DENY:
+        if state["prog"] is None:       # one lookup per operator
+            state["prog"] = ex._chain_program(canon) or (None, False)
+        jitted, hit = state["prog"]
+        if jitted is None:              # denied
             return eager(b)
         if state["binding"] is None:
             state["binding"] = canon.binding(b)
         binding = state["binding"]
-        jitted = _ex._CHAIN_JIT_CACHE.get(key)
-        if state["hit"] is None:        # count the lookup once per op
-            state["hit"] = jitted is not None
-            _M_JIT.inc(cache="chain",
-                       result="hit" if state["hit"] else "miss")
-        if jitted is None:
-            helper = ex._detached()
-            nodes = canon.nodes
-
-            def fn(cb):
-                for nd in reversed(nodes):
-                    cb = helper._dispatch_apply(nd, cb)
-                return cb
-            jitted = named_jit(fn, "chain", key)
-            _ex._cache_put(_ex._CHAIN_JIT_CACHE, key, jitted)
         try:
             out = ex._jit_call(jitted, (binding.rename_in(b),),
-                               "chain", bool(state["hit"]))
-            state["hit"] = True         # later chunks ride the program
+                               "chain", hit)
+            state["prog"] = (jitted, True)  # later chunks ride it
             return binding.rename_out(out)
-        except (jax.errors.TracerArrayConversionError,
-                jax.errors.ConcretizationTypeError):
-            _ex._CHAIN_JIT_CACHE.pop(key, None)
-            _ex._CHAIN_JIT_DENY.add(key)
+        except UNTRACEABLE:
+            PROGRAMS.deny("chain", key)
+            state["prog"] = (None, False)
             return eager(b)
 
     def record(b: Batch) -> None:
-        if key in _ex._CHAIN_JIT_DENY:
+        if PROGRAMS.denied("chain", key):
             return
         from .hotshapes import record_program
         if state["binding"] is None:
@@ -928,10 +906,8 @@ def maybe_stream_join(ex, node: JoinNode
         rebuilt ONLY when the capacity grows: the key derivation
         (residual repr, lane-spec walks) is host work sitting in the
         double-buffer window, so it must not repeat per chunk. Jitted
-        programs live in the cross-query cache, keyed like every
-        structural cache (exec/progkey.py doctrine: one key per
-        program, shared across queries)."""
-        from . import executor as _ex
+        programs live in the cross-query cache (exec/progkey.py
+        doctrine: one key per program, shared across queries)."""
         if state["prog"] is not None \
                 and state["prog_cap"] == state["out_cap"]:
             return state["prog"]
@@ -941,16 +917,12 @@ def maybe_stream_join(ex, node: JoinNode
             state["out_cap"])
         fn = make_probe_program(jt, pkeys, bkeys, residual,
                                 state["out_cap"])
-        if state["eager"] or key in _JOIN_JIT_DENY:
+        got = None if state["eager"] else PROGRAMS.program(
+            "streamjoin", key, lambda: fn, "streamjoin", key)
+        if got is None:
             entry = (fn, key, True)
         else:
-            jitted = _JOIN_JIT_CACHE.get(key)
-            state["hit"] = jitted is not None
-            _M_JIT.inc(cache="streamjoin",
-                       result="hit" if state["hit"] else "miss")
-            if jitted is None:
-                jitted = named_jit(fn, "streamjoin", key)
-                _ex._cache_put(_JOIN_JIT_CACHE, key, jitted)
+            jitted, state["hit"] = got
             entry = (jitted, key, False)
         state["prog"], state["prog_cap"] = entry, state["out_cap"]
         return entry
@@ -977,10 +949,8 @@ def maybe_stream_join(ex, node: JoinNode
                 record_program("streamjoin", key, None, None,
                                ex.session, payload_fn=build_pl)
             return out
-        except (jax.errors.TracerArrayConversionError,
-                jax.errors.ConcretizationTypeError):
-            _JOIN_JIT_CACHE.pop(key, None)
-            _JOIN_JIT_DENY.add(key)
+        except UNTRACEABLE:
+            PROGRAMS.deny("streamjoin", key)
             state["eager"] = True
             state["prog"] = None
             fn = make_probe_program(jt, pkeys, bkeys, residual,

@@ -15,9 +15,9 @@ data-dependent capacity decisions (the two-phase pattern of
 ops/join.py, lifted to the distributed case).
 
 Mesh programs are cached and named like every other program of the
-engine (exec/progkey.py): ``mesh_program`` keeps ONE jitted
-``shard_map`` per (kind, key, mesh, operand structure) under the name
-``spmd_<kind>_<key8>``, so a repeated query traces nothing, and every
+engine (exec/progkey.py, bucket "spmd"): ``mesh_program`` keeps ONE
+jitted ``shard_map`` per (kind, key, mesh, operand structure) under the
+name ``spmd_<kind>_<key8>``, so a repeated query traces nothing, and every
 dispatch is a ``device_execute`` / ``jit_trace`` span counted in
 ``trino_tpu_device_programs_total{kind="spmd_<kind>"}``. A caller of
 ``shard_apply`` and its kin that cannot name what its closure captures
@@ -36,7 +36,6 @@ computed by arithmetic (no ``nonzero``). Received rows keep
 
 from __future__ import annotations
 
-import threading
 from typing import Callable, Dict, List, Sequence, Tuple
 
 import jax
@@ -47,8 +46,7 @@ from jax.sharding import PartitionSpec as P
 from jax import shard_map
 
 from ..columnar import Batch, Column
-from ..config import CONFIG, capacity_for
-from ..obs.metrics import JIT_CACHE_LOOKUPS as _M_JIT
+from ..config import capacity_for
 from ..obs.trace import active_span, dispatch_span
 from ..ops.groupby import AggInput, group_aggregate
 from ..ops.hashing import hash_columns
@@ -57,10 +55,6 @@ from .mesh import AXIS, ShardedBatch, row_bytes
 # --------------------------------------------------------------------------
 # keyed, named mesh programs
 # --------------------------------------------------------------------------
-
-_PROGRAMS: Dict[tuple, object] = {}
-_PROGRAMS_LOCK = threading.Lock()
-
 
 def _mesh_key(mesh) -> tuple:
     return tuple(int(d.id) for d in mesh.devices.flat)
@@ -76,30 +70,16 @@ def mesh_program(kind: str, key, mesh, operands,
     ``(f, in_specs, out_specs)`` and runs on a miss only. ``key`` must
     name everything ``f`` closes over; ``None`` means it cannot be
     named, and the program is built for this call alone."""
-    from ..exec.progkey import named_jit
+    from ..exec.progkey import PROGRAMS
 
-    def make():
+    def sharded():
         f, in_specs, out_specs = build()
-        return named_jit(
-            shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_vma=False),
-            "spmd_" + kind, key)
+        return shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
-    if key is None:
-        return make(), False
-    ck = (kind, key, _mesh_key(mesh), jax.tree.structure(operands))
-    with _PROGRAMS_LOCK:
-        prog = _PROGRAMS.get(ck)
-    hit = prog is not None
-    _M_JIT.inc(cache="spmd", result="hit" if hit else "miss")
-    if prog is None:
-        prog = make()
-        with _PROGRAMS_LOCK:
-            limit = max(int(CONFIG.jit_cache_entries), 1)
-            while len(_PROGRAMS) >= limit:
-                _PROGRAMS.pop(next(iter(_PROGRAMS)))
-            prog = _PROGRAMS.setdefault(ck, prog)
-    return prog, hit
+    ck = None if key is None else (
+        kind, key, _mesh_key(mesh), jax.tree.structure(operands))
+    return PROGRAMS.program("spmd", ck, sharded, "spmd_" + kind, key)
 
 
 def mesh_call(kind: str, key, mesh, operands, build):

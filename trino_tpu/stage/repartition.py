@@ -27,15 +27,14 @@ upstream task — content-addressed, no manifest needed.
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Sequence
+from typing import List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..columnar import Batch, Column
-from ..obs.metrics import (EXCHANGE_PARTITION_BYTES, EXCHANGE_PARTITIONS,
-                           JIT_CACHE_LOOKUPS)
+from ..obs.metrics import EXCHANGE_PARTITION_BYTES, EXCHANGE_PARTITIONS
 from ..ops.hashing import lane_to_u64, mix64, partition_of
 
 _FNV_OFFSET = 0xCBF29CE484222325
@@ -61,12 +60,11 @@ def dictionary_value_hashes(dictionary) -> np.ndarray:
     return out
 
 
-# cross-query cache of jitted bucket kernels (exec/progkey.py cache
-# doctrine). Key lanes are ALWAYS uint64 and valids always bool, so
-# (key count, capacity, partition count) is the whole jit signature —
-# the one structural cache in the engine that needs no lane-spec walk.
-_BUCKET_JIT_CACHE: Dict[tuple, object] = {}
-
+# The jitted bucket kernels live in the program cache's "repartition"
+# bucket (exec/progkey.py PROGRAMS). Key lanes are ALWAYS uint64 and
+# valids always bool, so (key count, capacity, partition count) is the
+# whole jit signature — the one program key in the engine that needs
+# no lane-spec walk.
 
 def bucket_program_key(nkeys: int, capacity: int, nparts: int) -> tuple:
     return ("repartition", int(nkeys), int(capacity), int(nparts))
@@ -110,8 +108,8 @@ def _key_lane(col: Column) -> jax.Array:
 def partition_buckets(batch: Batch, keys: Sequence[str],
                       nparts: int, session=None) -> np.ndarray:
     """Bucket index in [0, nparts) for each LIVE row of ``batch``."""
-    from ..exec import executor as _ex
     from ..exec.hotshapes import record_program
+    from ..exec.progkey import PROGRAMS
     n = batch.num_rows_host()
     lanes, valids = [], []
     for k in keys:
@@ -121,15 +119,10 @@ def partition_buckets(batch: Batch, keys: Sequence[str],
                       else jnp.asarray(c.valid).astype(bool))
     cap = int(batch.capacity)
     key = bucket_program_key(len(keys), cap, nparts)
-    jitted = _BUCKET_JIT_CACHE.get(key)
-    hit = jitted is not None
-    JIT_CACHE_LOOKUPS.inc(cache="repartition",
-                          result="hit" if hit else "miss")
-    if jitted is None:
-        from ..exec.progkey import named_jit
-        jitted = named_jit(make_bucket_program(len(keys), nparts),
-                           "repartition", key)
-        _ex._cache_put(_BUCKET_JIT_CACHE, key, jitted)
+    jitted, hit = PROGRAMS.program(
+        "repartition", key,
+        lambda: make_bucket_program(len(keys), nparts),
+        "repartition", key)
     record_program(
         "repartition", key, None, None, session,
         payload_fn=lambda: {"kind": "repartition",
