@@ -1,9 +1,10 @@
 """Compiles for a DESCRIBED TPU v5e, kept as tests: the chip's own
 compiler (installed here without the chip) sees the grouped-sum kernel
 at its real shapes, the fused q1 stage program with the kernel in it,
-and the two programs of the materialized hash join — what it refuses
-fails here at no chip time. Nothing runs, so these say nothing about
-results or speed; ``chip_smoke.py`` is the run on the chip.
+q6's filter, and the two programs of the materialized hash join — what
+it refuses fails here at no chip time. Nothing runs, so these say
+nothing about results or speed; ``chip_smoke.py`` is the run on the
+chip.
 
 This is the ONLY file that describes the chip. The topology is
 described inside a module-scoped fixture (never at import: every xdist
@@ -94,6 +95,36 @@ def test_q1_stage_program_compiles_with_kernel(one_chip,
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
         < 16 << 30
+
+
+def test_q6_filter_constants_leave_the_per_row_program(
+        one_chip, no_persistent_cache):
+    """q6's WHERE as the planner leaves it in the Filter (``l_shipdate <
+    date '1994-01-01' + interval '1' year`` and the two decimal bounds
+    of the discount), at max_batch_rows. ``date + interval`` is civil
+    calendar arithmetic in int64 with floor divisions: evaluated per
+    row it was 2.96 MB of HLO and 4,331 flops a row (88% of
+    tpch_sf10.power's device time, PERF.md PR 31); evaluated at one
+    row (exec/expr.py ``_eval_constant``) the compiler folds it, and
+    what is left per row is compares."""
+    from trino_tpu import batch_from_pylist
+    from trino_tpu.exec.expr import eval_predicate
+    from trino_tpu.plan.nodes import FilterNode
+    from trino_tpu.runner import LocalQueryRunner
+    with open(os.path.join(os.path.dirname(__file__), os.pardir,
+                           "benchmark", "traffic", "queries",
+                           "q6.sql")) as f:
+        node = LocalQueryRunner().plan_sql(f.read())
+    while not isinstance(node, FilterNode):
+        node, = node.sources
+    assert "date_add_interval" in str(node.predicate)
+    schema = node.source.output_schema()
+    rows = batch_from_pylist({c: [1, 2] for c in schema}, schema)
+    compiled = jax.jit(
+        lambda b: eval_predicate(node.predicate, b)).lower(
+            _as_structs(rows, Q1_CAPACITY, one_chip)).compile()
+    assert len(compiled.as_text()) < 100_000
+    assert compiled.cost_analysis()["flops"] < 64 * Q1_CAPACITY
 
 
 def _q3_join_sides():

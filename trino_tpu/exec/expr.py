@@ -28,7 +28,9 @@ import numpy as np
 
 from ..columnar import Batch, Column, StringDictionary
 from ..ops.datetime import (add_months, date_trunc_days, extract_field)
-from ..rex import Call, CaseExpr, Cast, Const, InputRef, RowExpr
+from ..obs.metrics import EXPR_CONSTANT_SUBTREES
+from ..rex import (Call, CaseExpr, Cast, Const, InputRef, RowExpr,
+                   expr_volatile, walk)
 from ..types import (BIGINT, BOOLEAN, DATE, DOUBLE, INTEGER, REAL, UNKNOWN,
                      VARCHAR, CharType, DecimalType, IntervalDayTime,
                      IntervalYearMonth, TimestampType, Type, VarcharType,
@@ -44,6 +46,8 @@ def eval_expr(e: RowExpr, batch: Batch) -> Column:
         return batch.column(e.name)
     if isinstance(e, Const):
         return _const_column(e, batch.capacity)
+    if batch.capacity > 1 and _constant_subtree(e):
+        return _eval_constant(e, batch.capacity)
     if isinstance(e, Cast):
         return _eval_cast(e, batch)
     if isinstance(e, CaseExpr):
@@ -61,6 +65,43 @@ def eval_predicate(e: RowExpr, batch: Batch) -> jax.Array:
     if col.valid is not None:
         m = m & jnp.asarray(col.valid)
     return m & batch.row_valid()
+
+
+# --------------------------------------------------------------------------
+# constant subtrees: evaluated once, at one row
+# --------------------------------------------------------------------------
+
+_ONE_ROW = Batch({"": Column(BOOLEAN, np.zeros((1,), dtype=bool))}, 1)
+
+
+def _plain_lane(t: Type) -> bool:
+    """One fixed-width value lane (+ validity): no dictionary, no
+    ``data2``, no children."""
+    return (t in (BOOLEAN, DATE) or isinstance(t, TimestampType)
+            or (is_numeric(t) and t.lanes == 1))
+
+
+def _constant_subtree(e: RowExpr) -> bool:
+    """A call, cast or case that reads no column (nor a lambda's
+    parameter: an InputRef too), holds no volatile call and yields a
+    plain lane: every row gets the same value."""
+    return (_plain_lane(e.type)
+            and not any(isinstance(x, InputRef) for x in walk(e))
+            and not expr_volatile(e))
+
+
+def _eval_constant(e: RowExpr, cap: int) -> Column:
+    """The same handlers over a one-row batch, the row broadcast to
+    ``cap``. Under jit the compiler folds the one-row chain into a
+    literal, which it does not do through per-row int64 division
+    (q6's ``date + interval '1' year``: 4,331 flops a row before)."""
+    col = eval_expr(e, _ONE_ROW)
+    EXPR_CONSTANT_SUBTREES.inc_at(
+        (e.fn if isinstance(e, Call)
+         else "cast" if isinstance(e, Cast) else "case",))
+    valid = (None if col.valid is None
+             else jnp.broadcast_to(jnp.asarray(col.valid), (cap,)))
+    return Column(col.type, jnp.broadcast_to(_lane(col), (cap,)), valid)
 
 
 # --------------------------------------------------------------------------

@@ -562,6 +562,13 @@ JOIN_EXACT_PROBES = METRICS.counter(
     "integer key column whose usable values span less than the "
     "directory: 0 steps); the others searched a hashed lane",
     ("site",))
+EXPR_CONSTANT_SUBTREES = METRICS.counter(
+    "trino_tpu_expr_constant_subtrees_total",
+    "Subtrees of an expression with no column and no volatile call "
+    "that exec/expr.py evaluated at ONE row and broadcast, by the "
+    "subtree's root (a call's name, cast, case); grows when a program "
+    "is traced or an eager batch is evaluated, not per dispatch",
+    ("fn",))
 EXCHANGE_BYTES = METRICS.counter(
     "trino_tpu_mesh_exchange_bytes_total",
     "Bytes the mesh executor's exchanges moved in traced queries: live "
