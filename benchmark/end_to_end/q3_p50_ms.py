@@ -1,7 +1,11 @@
-"""Median client latency of q3 in the window (SQL sent to last page)."""
+"""Median client latency of q3 in the window (SQL sent to last page).
+A metric of ONE query class: BENCHMARK.json lists the cells that have
+the class under its ``workloads``."""
 
 from harness import stats
 
+CLASS = "q3"
+
 
 def read(run):
-    return stats.class_p50_ms(run.records).get("q3")
+    return stats.class_p50_ms(run.records).get(CLASS)

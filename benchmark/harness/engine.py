@@ -18,7 +18,7 @@ ROOT_SPANS = ("parse", "plan", "optimize", "execute")
 
 
 class Engine:
-    def __init__(self, schema: str, state_dir: str):
+    def __init__(self, catalog: str, schema: str, state_dir: str):
         import trino_tpu  # noqa: F401  (x64, compile cache placement)
         from trino_tpu.server.coordinator import Coordinator
         from trino_tpu.server.main import build_catalogs
@@ -26,7 +26,7 @@ class Engine:
         # make this run's plans depend on it: every run starts empty
         shutil.rmtree(state_dir, ignore_errors=True)
         os.makedirs(state_dir, exist_ok=True)
-        self.schema = schema
+        self.catalog, self.schema = catalog, schema
         self.co = Coordinator(port=0, catalogs=build_catalogs(None, []),
                               history_dir=state_dir).start()
         self._clients = {}
@@ -35,7 +35,7 @@ class Engine:
         from trino_tpu.client import StatementClient
         if stream not in self._clients:
             self._clients[stream] = StatementClient(
-                self.co.base_uri, catalog="tpch", schema=self.schema,
+                self.co.base_uri, catalog=self.catalog, schema=self.schema,
                 timeout=1800.0)
         return self._clients[stream]
 
@@ -62,6 +62,28 @@ class Engine:
                         out[s["name"]] = (int(s["startTimeUnixNano"]),
                                           int(s["endTimeUnixNano"]))
         return out or None
+
+    def scans(self, sql: str, stream="scans"):
+        """[{table, rows, lanes}] of the table scans of one statement,
+        in plan order, from the program's ``EXPLAIN ANALYZE``: the rows
+        each scan DELIVERED (after the conjuncts pushed into it) and,
+        where the scan cache missed and filled, the lanes it filled
+        (the ``scan_fill`` span; ``None`` on a hit)."""
+        res = self.client(stream).execute("EXPLAIN ANALYZE " + sql)
+        text = [line for r in res.rows for line in r[0].splitlines()]
+        tables = [m.group(1) for line in text if
+                  (m := re.search(r"- TableScan\[[\w$]+\.[\w$]+\.([\w$]+)",
+                                  line))]
+        rows = [int(m.group(1)) for line in text if
+                (m := re.match(r"TableScan: .* out (\d+) rows", line))]
+        lanes = {m.group(2): int(m.group(1)) for line in text if
+                 (m := re.search(r"scan_fill: .* lanes=(\d+), table=(\w+)",
+                                 line))}
+        if len(tables) != len(rows):
+            raise ValueError(f"EXPLAIN ANALYZE names {len(tables)} scans "
+                             f"and gives statistics for {len(rows)}")
+        return [{"table": t, "rows": n, "lanes": lanes.get(t)}
+                for t, n in zip(tables, rows)]
 
     def counters(self) -> dict:
         """{'name{labels}': value} of every sample on ``/metrics``."""

@@ -1,10 +1,15 @@
 """Peaks of the chip, and the bytes a query class has to read.
 
-The least time of a query class is the bytes of the lanes it reads,
-LIVE rows (not padded capacity) times each lane's SQL width, over the
-chip's peak HBM rate. It counts the work of the QUERY, whatever program
-does it: the same number for a Pallas kernel, for XLA, or for a later
-kernel. The per-lane table sits in the configuration's file.
+The least time of a query class is the bytes its programs have to
+bring from HBM once, over the chip's peak HBM rate: for each table, the
+LIVE rows its scan delivers (not padded capacity) times the SQL widths
+of the lanes it delivers. The scan delivers what the conjuncts the
+planner pushes into it leave, compacted and kept resident per
+constraint, so the programs of a class never see the other rows: the
+configuration's ``scan_rows`` has that count per class and table
+(``reference/pins.py`` computes it), ``lanes_read`` the lanes. The
+count is the same whatever program reads them: a Pallas kernel, XLA, or
+a later kernel.
 """
 
 import json
@@ -23,11 +28,15 @@ def peaks_for(device_kind: str) -> dict:
 
 
 def query_bytes(config: dict, cls: str) -> int:
-    """Bytes the class must read once: for each table it scans, that
-    table's live rows times the summed widths of the lanes it reads."""
+    """Bytes the class must read once: for each table it scans, the rows
+    the scan delivers (``scan_rows``; a configuration without the key
+    counts the table's live rows) times the summed widths of the lanes
+    it reads."""
+    delivered = config.get("scan_rows", {}).get(cls, {})
     total = 0
     for table, lanes in config["lanes_read"][cls].items():
-        rows = int(config["tables"][table]["rows"])
+        rows = int(delivered[table]["rows"] if table in delivered
+                   else config["tables"][table]["rows"])
         total += rows * sum(int(config["lane_bytes"][lane])
                             for lane in lanes)
     return total

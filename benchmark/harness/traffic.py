@@ -44,8 +44,14 @@ def load_mix(name: str) -> dict:
     return mix
 
 
-def load_sql(cls: str) -> str:
-    with open(os.path.join(HERE, "traffic", "queries", f"{cls}.sql")) as f:
+def load_sql(cls: str, config: dict) -> str:
+    """The SQL of a query class of ``config``: ``traffic/queries/`` holds
+    it, in the sub-directory the configuration names under
+    ``queries_dir`` (so two benchmarks may each have a ``q3``), else
+    flat."""
+    with open(os.path.join(HERE, "traffic", "queries",
+                           config.get("queries_dir", ""),
+                           f"{cls}.sql")) as f:
         return f.read()
 
 

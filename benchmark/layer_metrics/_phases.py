@@ -5,7 +5,8 @@ observation per closed span), read as growth between the window's
 start (``run.engine_before``) and its end (``run.engine_after``) and
 divided by the queries the window executed (growth of the ``execute``
 phase's ``_count``). A program without the family (one older than the
-spans) gives ``None`` everywhere: the metric is left out of the line.
+spans) gives ``None`` everywhere, and a run that lacks a metric of its
+cell ends without a result (``run.py read_metrics``).
 """
 
 FAMILY = "trino_tpu_query_phase_seconds"

@@ -1,11 +1,14 @@
 """Bisection steps a join probe takes to find a probe row's run of
 equal keys in the sorted build side: growth of
 ``trino_tpu_join_search_steps_total`` over that of
-``trino_tpu_join_probes_total`` in the window. 42 on the arithmetic of
-two full binary searches over 2^20 rows (2 x 21); 3-4 where the bucket
-directory engages; log2(build capacity)+1 where one key fills a
-bucket. Each step is two probe-sized gathers on the device. A program
-without the counters (one older than the directory) gives ``None``."""
+``trino_tpu_join_probes_total`` in the window. 0.0 where the build
+side's directory is exact (one integer key column whose values span
+less than the directory: both of q3's joins since PR 29); 2-4 over a
+hashed bucket directory; 42 on the arithmetic of two full binary
+searches over 2^20 rows (2 x 21); log2(build capacity)+1 where one key
+fills a bucket. Each step is two probe-sized gathers on the device. A
+program without the counters (one older than the directory) gives
+``None``."""
 
 from ._phases import family_growth
 

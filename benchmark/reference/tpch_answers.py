@@ -17,6 +17,7 @@ import datetime
 import numpy as np
 
 from . import tpch_rows as rows
+from .pins import pins  # noqa: F401  (the rehearsal's wanted data pins)
 
 ORDERS_PER_CHUNK = 500_000
 EPOCH = datetime.date(1970, 1, 1)

@@ -17,7 +17,10 @@ with ``--trace 1`` ``breakdown``, and ``checks`` last).
 
 Without a TPU the run exits 2 at once and prints no result. With
 ``--rehearse`` it walks every phase on the CPU at the configuration's
-rehearsal scale, never prints ``"correct": true`` and exits 3.
+rehearsal scale, never prints ``"correct": true`` and exits 3. A metric
+that BENCHMARK.json gives the cell and whose reader finds nothing to
+read ends the run with exit code 4 and no result, in a rehearsal too:
+the driver's check would refuse the line that lacks it.
 
 Everything that belongs to one configuration, one mix or one metric is
 a file of its own, found by the name in BENCHMARK.json: see README.md.
@@ -43,6 +46,7 @@ for p in (HERE, ROOT):
 
 RUN_DIR = os.path.join(HERE, ".run")        # wiped by every run
 CACHE_DIR = os.path.join(HERE, ".cache")    # reference answers
+EXIT_NOTHING_TO_READ = 4    # not 0 (a result), 2 (no chip), 3 (rehearsal)
 
 
 def say(*a) -> None:
@@ -103,14 +107,32 @@ def metrics_of(bench: dict, cell: str, kind: str):
                 else m["moves"] in mine)]
 
 
-def read_metrics(run: Run, specs, package: str) -> dict:
-    """{metric: {value, unit}} from each metric's own reader."""
+class NothingToRead(Exception):
+    """A metric of the cell whose reader returned ``None``."""
+
+
+def read_metrics(run: Run, specs, package: str, excused: str = "") -> dict:
+    """{metric: {value, unit}} from each metric's own reader. A reader
+    that finds nothing to read (``None``) raises ``NothingToRead``: the
+    cell lists a metric it cannot report. With ``excused`` (why this run
+    has nothing for some readers: no chip, no engine) it is named on
+    standard error and left out."""
     out = {}
     for m in specs:
         reader = importlib.import_module(f"{package}.{m['name']}")
         value = reader.read(run)
         if value is not None:
             out[m["name"]] = {"value": float(value), "unit": m["unit"]}
+            continue
+        what = (f"cell {run.cell['name']}: the metric {m['name']} is the "
+                f"cell's by BENCHMARK.json and its reader "
+                f"{os.path.relpath(reader.__file__, ROOT)} found nothing "
+                "to read")
+        if not excused:
+            raise NothingToRead(
+                f"{what}: give the metric a \"workloads\" list without "
+                "this cell, or the cell what the reader reads")
+        say(f"benchmark: {what} ({excused})")
     return out
 
 
@@ -138,19 +160,46 @@ def reference_answers(config: dict, sf: float, classes):
 
 
 def check_pins(engine, config: dict, sf: float, rehearse: bool):
-    """Ask the served tables for their pins. Returns (mismatches, log)."""
+    """Ask the served tables for their pins. Returns (mismatches, log).
+
+    The wanted pins are the configuration's; a rehearsal runs at another
+    scale and takes them from ``pins(sf)`` of the configuration's own
+    reference module. An entry with ``"pins": "deployment"`` pins the
+    deployment and not data (how many chips the coordinator spans): the
+    reference knows nothing of it and the CPU of a rehearsal is not it,
+    so a rehearsal asks, says what it found and holds nothing to it."""
+    tables = config["tables"]
+    want = {t: (d["rows"], d["pin_sum"]) for t, d in tables.items()}
     if rehearse:
-        from reference.pins import pins
-        want = {t: (p["rows"], p["pin_sum"]) for t, p in pins(sf).items()}
-    else:
-        want = {t: (d["rows"], d["pin_sum"])
-                for t, d in config["tables"].items()}
+        module = importlib.import_module(f"reference.{config['reference']}")
+        want.update((t, (p["rows"], p["pin_sum"]))
+                    for t, p in module.pins(sf).items())
     bad, log = 0, {}
-    for table, spec in config["tables"].items():
-        res = engine.client("pins").execute(spec["pin_sql"])
-        got = tuple(res.rows[0]) if res.state == "FINISHED" else None
-        log[table] = {"got": got, "want": want[table]}
-        bad += got != want[table]
+    for table, spec in tables.items():
+        deployment = spec.get("pins") == "deployment"
+        try:
+            res = engine.client("pins").execute(spec["pin_sql"])
+            got = tuple(res.rows[0]) if res.state == "FINISHED" else None
+        except Exception as e:   # noqa: BLE001 — the statement failed
+            if not deployment:
+                raise
+            got = f"{type(e).__name__}: {e}"[:300]
+        held = not (deployment and rehearse)
+        log[table] = {"got": got, "want": want[table], "held": held}
+        if got == want[table]:
+            continue
+        bad += held
+        if deployment:
+            words = (f"deployment pin {table}: `{spec['pin_sql']}` answered "
+                     f"{got}, the configuration wants {want[table]}: ")
+            if not held:
+                say(words + "not held in a rehearsal, whose CPU is not "
+                    "the deployment")
+            elif isinstance(got, str):
+                raise SystemExit(words + "the program cannot serve this "
+                                 "deployment, the run ends here")
+            else:
+                say(words + "the program does not serve this deployment")
     return bad, log
 
 
@@ -268,7 +317,7 @@ def main(argv=None) -> int:
     run.mix = traffic.load_mix(cell["traffic"])
     run.classes = traffic.classes_of(run.mix, config)
     run.streams = int(run.mix.get("clients", 1))
-    sql = {c: traffic.load_sql(c) for c in run.classes}
+    sql = {c: traffic.load_sql(c, config) for c in run.classes}
     schema = config["rehearsal_schema" if args.rehearse else "schema"]
     sf = float(config["rehearsal_scale_factor" if args.rehearse
                       else "scale_factor"])
@@ -283,7 +332,8 @@ def main(argv=None) -> int:
         engine = eng.ControlEngine(
             module.Answers(sf, run.classes, dtype=args.control), sql)
     else:
-        engine = eng.Engine(schema, os.path.join(RUN_DIR, "state"))
+        engine = eng.Engine(config.get("catalog", "tpch"), schema,
+                            os.path.join(RUN_DIR, "state"))
     run.phases["start"] = time.perf_counter() - t
 
     def execute(stream, cls):
@@ -375,15 +425,24 @@ def main(argv=None) -> int:
     # ---- metrics ------------------------------------------------------------
     device = dict(run.device, memory_peak_bytes=run.memory_peak_bytes)
     breakdown = None
-    if args.trace:
-        device["busy_s"], device["window_s"], breakdown = reduce_trace(
-            run, trace_dir, anchor_unix, args.dump_trace)
-        metrics = read_metrics(
-            run, metrics_of(bench, cell["name"], "per_layer"),
-            "layer_metrics")
-    else:
-        metrics = read_metrics(
-            run, metrics_of(bench, cell["name"], "end_to_end"), "end_to_end")
+    # per-layer readers that need the chip or the engine find nothing here
+    excused = ("the rehearsal has no chip" if args.rehearse
+               else "the control has no spans and no counters"
+               if args.control else "")
+    try:
+        if args.trace:
+            device["busy_s"], device["window_s"], breakdown = reduce_trace(
+                run, trace_dir, anchor_unix, args.dump_trace)
+            metrics = read_metrics(
+                run, metrics_of(bench, cell["name"], "per_layer"),
+                "layer_metrics", excused)
+        else:
+            metrics = read_metrics(
+                run, metrics_of(bench, cell["name"], "end_to_end"),
+                "end_to_end")
+    except NothingToRead as e:
+        say(f"benchmark: {e}; no result")
+        return EXIT_NOTHING_TO_READ
 
     from harness import stats
     say("queries (class, stream, latency ms, engine root spans ms):")

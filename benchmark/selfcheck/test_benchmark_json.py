@@ -17,22 +17,48 @@ def bench():
         return json.load(f)
 
 
+def reader_of(kind, metric):
+    package = {"end_to_end": "end_to_end", "per_layer": "layer_metrics"}
+    return importlib.import_module(f"{package[kind]}.{metric['name']}")
+
+
 def test_every_named_file_exists():
     b = bench()
     for w in b["workloads"]:
         _b, cell, config = bench_run.load_cell(w["name"])
         mix = traffic.load_mix(cell["traffic"])
         for cls in traffic.classes_of(mix, config):
-            assert traffic.load_sql(cls).strip()
-            assert cls in config["lanes_read"]
-        importlib.import_module(f"reference.{config['reference']}")
+            assert traffic.load_sql(cls, config).strip()
+        reference = importlib.import_module(f"reference.{config['reference']}")
+        assert callable(reference.Answers) and callable(reference.pins)
         assert set(config["limits"]) == {"max_rel_err", "exact_mismatches",
                                          "failed_queries", "pin_mismatches"}
-    for kind, package in (("end_to_end", "end_to_end"),
-                          ("per_layer", "layer_metrics")):
+    for kind in ("end_to_end", "per_layer"):
         for m in b[kind]:
-            reader = importlib.import_module(f"{package}.{m['name']}")
-            assert callable(reader.read)
+            assert callable(reader_of(kind, m).read)
+
+
+def test_a_metric_of_one_class_is_given_only_to_cells_with_the_class():
+    """A reader that names its query class (``CLASS``) finds nothing in a
+    cell without it, and a run that lacks a metric is refused: so every
+    cell such a metric is given to has the class in its configuration
+    and mix; a class's roofline also needs the class's ``lanes_read``
+    (and only those classes need one)."""
+    b = bench()
+    for w in b["workloads"]:
+        _b, cell, config = bench_run.load_cell(w["name"])
+        classes = traffic.classes_of(traffic.load_mix(cell["traffic"]),
+                                     config)
+        for kind in ("end_to_end", "per_layer"):
+            for m in bench_run.metrics_of(b, w["name"], kind):
+                cls = getattr(reader_of(kind, m), "CLASS", None)
+                if cls is None:
+                    continue
+                assert cls in classes, (w["name"], m["name"])
+                if m["name"].endswith("_roofline"):
+                    assert config["lanes_read"][cls], (w["name"], cls)
+    listless = [m["name"] for m in b["end_to_end"] if "workloads" not in m]
+    assert listless == ["queries_per_s", "setup_s"]
 
 
 def test_lines_keep_to_the_contract_s_lengths():

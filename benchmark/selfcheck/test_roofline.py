@@ -1,5 +1,7 @@
-"""The roofline's byte function against a hand count, and the table of
-peaks: an unknown device kind is an error, never a default."""
+"""The roofline's byte function against a hand count (the rows the scan
+delivers, ``scan_rows``, times the lanes it delivers; the table's rows
+where a configuration has no ``scan_rows``), and the table of peaks: an
+unknown device kind is an error, never a default."""
 
 import json
 import os
@@ -17,22 +19,36 @@ def config(name):
 
 
 def test_q6_bytes_by_hand_sf1():
-    # q6 reads l_quantity, l_extendedprice, l_discount (DOUBLE, 8 each)
-    # and l_shipdate (DATE, 4) of 6,002,677 live lineitem rows
+    # the scan delivers the 1,992,515 lineitem rows that `l_shipdate >=
+    # 1994-01-01 and l_quantity < 24` leave, in three lanes:
+    # l_extendedprice, l_discount (DOUBLE, 8 each), l_shipdate (DATE, 4);
+    # l_quantity is consumed by the pushed conjunct
     assert roofline.query_bytes(config("tpch_sf1_1chip"), "q6") == \
-        6_002_677 * (8 + 8 + 8 + 4)
-    assert roofline.query_bytes(config("tpch_sf1_1chip"), "q6") == 168_074_956
+        1_992_515 * (8 + 8 + 4)
+    assert roofline.query_bytes(config("tpch_sf1_1chip"), "q6") == 39_850_300
 
 
 def test_q1_and_q3_bytes_by_hand_sf1():
     c = config("tpch_sf1_1chip")
-    # q1: four DOUBLE lanes, a DATE, two dictionary codes
+    # q1: nothing pushed, the table: four DOUBLE lanes, a DATE, two
+    # dictionary codes
     assert roofline.query_bytes(c, "q1") == 6_002_677 * (4 * 8 + 4 + 4 + 4)
-    # q3: lineitem key + 2 DOUBLE + DATE; orders 2 keys + DATE + INTEGER;
-    # customer key + dictionary code
+    # q3: lineitem after `l_shipdate > 1995-03-15`: key + 2 DOUBLE;
+    # orders before that date: 2 keys + DATE + INTEGER; customer whole:
+    # key + dictionary code
     assert roofline.query_bytes(c, "q3") == (
-        6_002_677 * (8 + 8 + 8 + 4) + 1_500_000 * (8 + 8 + 4 + 4)
+        3_232_552 * (8 + 8 + 8) + 729_205 * (8 + 8 + 4 + 4)
         + 150_000 * (8 + 4))
+
+
+def test_without_scan_rows_the_table_s_rows_count():
+    c = config("tpch_sf1_1chip")
+    del c["scan_rows"]
+    assert roofline.query_bytes(c, "q6") == 6_002_677 * (8 + 8 + 4)
+    c["scan_rows"] = {"q3": {"orders": {"rows": 10, "pushed": "x"}}}
+    assert roofline.query_bytes(c, "q6") == 6_002_677 * (8 + 8 + 4)
+    assert roofline.query_bytes(c, "q3") == (
+        6_002_677 * 24 + 10 * 24 + 150_000 * 12)
 
 
 def test_least_seconds_v5e():
@@ -40,12 +56,12 @@ def test_least_seconds_v5e():
     peaks = roofline.peaks_for("TPU v5 lite")
     assert peaks["hbm_gb_per_s"] == 819
     assert roofline.least_seconds(c, "q6", peaks) == pytest.approx(
-        168_074_956 / 819e9)
+        39_850_300 / 819e9)
 
 
-def test_sf10_counts_ten_times_the_rows():
+def test_sf10_counts_what_its_scan_delivers():
     c = config("tpch_sf10_1chip")
-    assert roofline.query_bytes(c, "q6") == 60_007_494 * 28
+    assert roofline.query_bytes(c, "q6") == 19_928_602 * 20
     assert "q1" not in c["lanes_read"]
 
 
