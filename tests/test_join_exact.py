@@ -117,3 +117,28 @@ def test_a_hashed_key_counts_no_exact_probe(coordinator, key):
     assert rows == [[want]] and want > 0
     assert len(reads) == 1 and reads[0][1] == 0 and reads[0][0] > 0
     assert grew[:2] == (1, 0) and grew[2] == reads[0][0]
+
+
+def test_the_one_read_carries_the_join_s_shape(coordinator):
+    """``probe_rows`` and ``total`` ride the same int64 vector as
+    ``steps`` and ``exact`` (ISSUE 34); on the mesh they are summed
+    over the shards, so both executors report the join's whole shape."""
+    from trino_tpu.client import StatementClient
+    names = ("trino_tpu_join_probe_rows_total",
+             "trino_tpu_join_output_rows_total")
+
+    def grown():
+        return [sum(v for _k, v in METRICS.counter(n).samples())
+                for n in names]
+    before = grown()
+    res = StatementClient(coordinator.base_uri, catalog="tpch",
+                          schema="tiny").execute(
+        "select count(*) from orders o join customer c "
+        "on o.o_custkey = c.c_custkey")
+    assert res.state == "FINISHED", res.error
+    spans = coordinator.tracker.get(res.query_id).trace.all_spans()
+    reads = [s.attrs for s in spans if s.name == "host_read"
+             and s.attrs.get("site") == "join_total"]
+    assert res.rows == [[15000]] and len(reads) == 1
+    assert reads[0]["probe_rows"] == 15000 and reads[0]["total"] == 15000
+    assert [a - b for a, b in zip(grown(), before)] == [15000, 15000]

@@ -111,6 +111,25 @@ def counts(co) -> dict:
     return out
 
 
+def settled_counts(co, since: dict, queries: int) -> dict:
+    """``counts`` once the spans of the ``queries`` served since the
+    scrape ``since`` are all IN them: a span is counted by the hook that
+    runs after its end is stamped, and the ``finish`` and last
+    ``respond`` spans of a query close after its client was released,
+    so a scrape right behind ``served`` can miss them. Settled: every
+    one of those queries' ``finish`` is counted and two scrapes 20 ms
+    apart agree."""
+    deadline = time.time() + 10
+    last = None
+    while time.time() < deadline:
+        now = counts(co)
+        if now == last and now["finish"] >= since["finish"] + queries:
+            return now
+        last = now
+        time.sleep(0.02)
+    raise AssertionError(f"the counters never settled: {last}")
+
+
 # ---------------------------------------------------------------------------
 # the span tree of a served query
 # ---------------------------------------------------------------------------
@@ -218,8 +237,9 @@ def test_submit_is_back_dated_to_the_request_s_arrival():
 # ---------------------------------------------------------------------------
 
 def test_each_phase_counts_once_per_query(coordinator):
+    start = counts(coordinator)
     served(coordinator, sql_of("q6"))           # warm: traces, fills
-    before = counts(coordinator)
+    before = settled_counts(coordinator, start, 1)
     n = 3
     seen = dict.fromkeys(EXECUTE_PHASES, 0)
     for _ in range(n):
@@ -227,7 +247,7 @@ def test_each_phase_counts_once_per_query(coordinator):
         for s in coordinator.tracker.get(res.query_id).trace.all_spans():
             if s.name in seen:
                 seen[s.name] += 1
-    after = counts(coordinator)
+    after = settled_counts(coordinator, before, n)
     grew = {k: after[k] - before[k] for k in after}
     for phase in ROOT_PHASES:
         assert grew[phase] == n, (phase, grew)

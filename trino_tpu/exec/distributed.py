@@ -584,7 +584,7 @@ class DistributedExecutor(Executor):
                 eff = jnp.where(pb.row_valid(), jnp.maximum(count, 1),
                                 0) if (outer and filt is None) else count
                 return (start, count, side.order, jax.lax.all_gather(
-                    join_ops.total_and_mode(eff, side), AXIS))
+                    join_ops.total_and_mode(eff, side, pb), AXIS))
             return f, in_specs, (P(AXIS), P(AXIS), P(AXIS), P())
 
         start, count, order, totals = mesh_call(

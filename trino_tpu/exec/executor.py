@@ -1409,8 +1409,9 @@ class Executor:
     def _mjoin_counts(self, probe: Batch, build: Batch, pkeys, bkeys,
                       outer: bool):
         """Jitted count phase of the materialized join. Returns
-        (start, count, order, [total, steps, exact]) device arrays, or
-        None on decline — the caller runs ops/join.py eagerly."""
+        (start, count, order, [total, steps, exact, probe rows]) device
+        arrays, or None on decline — the caller runs ops/join.py
+        eagerly."""
         if not (self.fragment_jit
                 and self._mjoin_jittable(probe, build)):
             return None
@@ -1435,14 +1436,19 @@ class Executor:
         """The count program's one host read (``ops/join.py
         total_and_mode``, one row a shard on the mesh): its output
         total — the largest shard's — and beside it the steps its probe
-        took and whether its directory was exact, on every shard
-        (``steps`` and ``exact`` on the span, counted at /metrics:
+        took, whether its directory was exact, on every shard, and the
+        join's shape: the probe side's live rows and the rows it puts
+        out, summed over the shards (``steps``, ``exact``,
+        ``probe_rows`` and ``total`` on the span, counted at /metrics:
         obs/metrics.py observe_span)."""
         with self._host_read("join_total") as sp:
-            total, steps, exact = np.asarray(tail).reshape(-1, 3).T
+            total, steps, exact, probe_rows = \
+                np.asarray(tail).reshape(-1, 4).T
             if sp is not None:
                 sp.attrs["steps"] = int(steps.max())
                 sp.attrs["exact"] = int(exact.min())
+                sp.attrs["probe_rows"] = int(probe_rows.sum())
+                sp.attrs["total"] = int(total.sum())
         return int(total.max())
 
     def _mjoin_expand(self, probe: Batch, build: Batch, start, count,
@@ -2089,9 +2095,10 @@ def make_mjoin_count_program(pkeys, bkeys, outer: bool):
     """Phase 1: build-side sort and index + probe match counts + the
     effective output total. Everything downstream of the total is host
     policy (bucket choice, memory reserve, oversized spill), so the
-    program ends exactly at the host-sync boundary: ONE int64[3],
+    program ends exactly at the host-sync boundary: ONE int64[4],
     [total, the probe's bisection steps, whether the directory was
-    exact], read in one transfer (``_read_join_total``). Output dtypes
+    exact, the probe side's live rows], read in one transfer
+    (``_read_join_total``). Output dtypes
     are pinned int64 — they cross into the separately-jitted expand
     program."""
     pkeys, bkeys = list(pkeys), list(bkeys)
@@ -2106,7 +2113,7 @@ def make_mjoin_count_program(pkeys, bkeys, outer: bool):
             eff = count
         return (start.astype(jnp.int64), count.astype(jnp.int64),
                 side.order.astype(jnp.int64),
-                join_ops.total_and_mode(eff, side))
+                join_ops.total_and_mode(eff, side, probe))
 
     return fn
 
@@ -2441,6 +2448,12 @@ def read_table_cached(conn, handle, columns, par) -> Optional[Batch]:
             _M_SCAN.inc(cache="table", result="hit")
             return Batch({c: entry["cols"][c] for c in columns},
                          entry["num_rows"])
+    splits = conn.get_splits(h, par)
+    if len(splits) == 1:
+        # a table of one split IS its split: no whole-table entry is
+        # ever made for it, so the split's own lookup counts the hit or
+        # the miss (a resident dimension is not a table-level miss)
+        return read_split_cached(conn, splits[0], columns)
     _M_SCAN.inc(cache="table", result="miss")
     # cheap pre-check from the handle's row estimate so an over-budget
     # table (inventory@sf10 is ~4GB of lanes) is never transiently
@@ -2454,9 +2467,6 @@ def read_table_cached(conn, handle, columns, par) -> Optional[Batch]:
         est = int(est_rows) * max(len(missing), 1) * 9  # data8+valid1
         if 2 * est > CONFIG.scan_cache_bytes:
             return None
-    splits = conn.get_splits(h, par)
-    if len(splits) == 1:
-        return read_split_cached(conn, splits[0], columns)
     parts = [read_split_cached(conn, s, missing) for s in splits]
     total_bytes = sum(_col_bytes(c) for b in parts
                       for c in b.columns.values())

@@ -562,6 +562,15 @@ JOIN_EXACT_PROBES = METRICS.counter(
     "integer key column whose usable values span less than the "
     "directory: 0 steps); the others searched a hashed lane",
     ("site",))
+JOIN_PROBE_ROWS = METRICS.counter(
+    "trino_tpu_join_probe_rows_total",
+    "Live rows on the probe side of those joins (a mesh join: summed "
+    "over its shards), from the same read", ("site",))
+JOIN_OUTPUT_ROWS = METRICS.counter(
+    "trino_tpu_join_output_rows_total",
+    "Rows those joins put out (a mesh join: summed over its shards), "
+    "from the same read: with the probe rows, a join chain's shape",
+    ("site",))
 EXPR_CONSTANT_SUBTREES = METRICS.counter(
     "trino_tpu_expr_constant_subtrees_total",
     "Subtrees of an expression with no column and no volatile call "
@@ -621,6 +630,8 @@ def observe_span(sp) -> None:
             # exports the family, reading 0
             JOIN_SEARCH_STEPS.inc_at(site, steps)
             JOIN_EXACT_PROBES.inc_at(site, sp.attrs.get("exact", 0))
+            JOIN_PROBE_ROWS.inc_at(site, sp.attrs.get("probe_rows", 0))
+            JOIN_OUTPUT_ROWS.inc_at(site, sp.attrs.get("total", 0))
     elif name in ("device_execute", "jit_trace"):
         program = str(sp.attrs.get("program")
                       or sp.attrs.get("cache") or "other")

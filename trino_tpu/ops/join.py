@@ -296,13 +296,15 @@ def match_runs(probe: Batch, build: Batch,
     return left, count, side
 
 
-def total_and_mode(eff, side: BuildSide):
-    """int64[3], [output rows, the probe's bisection steps, whether the
-    directory was exact]: what a count program hands the host in its
-    ONE read (the executors' ``join_total``)."""
+def total_and_mode(eff, side: BuildSide, probe: Batch):
+    """int64[4], [output rows, the probe's bisection steps, whether the
+    directory was exact, the probe side's live rows]: what a count
+    program hands the host in its ONE read (the executors'
+    ``join_total``)."""
     return jnp.stack([jnp.sum(eff).astype(jnp.int64),
                       side.steps.astype(jnp.int64),
-                      side.exact.astype(jnp.int64)])
+                      side.exact.astype(jnp.int64),
+                      probe.num_rows_device()])
 
 
 def match_counts(probe: Batch, build: Batch,
