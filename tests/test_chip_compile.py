@@ -1,8 +1,10 @@
 """Compiles for a DESCRIBED TPU v5e, kept as tests: the chip's own
 compiler (installed here without the chip) sees the grouped-sum kernel
 at its real shapes, the fused q1 stage program with the kernel in it,
-q6's filter, and the two programs of the materialized hash join — what
-it refuses fails here at no chip time. Nothing runs, so these say
+q6's filter, the two programs of the materialized hash join (the
+expand in both forms of its ``run_positions``), the streamed probe,
+and the mesh executor's exchange and per-shard expand — what it
+refuses fails here at no chip time. Nothing runs, so these say
 nothing about results or speed; ``chip_smoke.py`` is the run on the
 chip.
 
@@ -136,21 +138,34 @@ def _q3_join_sides():
     return probe, build
 
 
+@pytest.mark.parametrize("out_cap,loops", [(1 << 20, 0), (1 << 10, 1)],
+                         ids=["histogram", "search"])
 def test_hash_join_expand_program_compiles(one_chip,
-                                           no_persistent_cache):
+                                           no_persistent_cache,
+                                           out_cap, loops):
     """Phase 2 of the materialized hash join at the shapes q3 runs at
-    sf1: 2^22 lineitem rows probing 2^20 order rows (64-bit cumsum,
-    searchsorted, gathers)."""
+    sf1, 2^22 lineitem rows probing 2^20 order rows: the 64-bit cumsum
+    of the counts, each output row's probe row (ops/join.py
+    run_positions), the gathers. Into 2^20 output rows the probe rows
+    are found by a histogram and an int32 cumsum: NO loop in the
+    program (the 23-step ``searchsorted`` over the cumsum was the
+    largest device operation of every join cell, PERF.md PR 35); into
+    2^10, past the constant at which the histogram's 2^22 updates cost
+    more than the search, exactly the search's one loop."""
     from trino_tpu.exec.executor import make_mjoin_expand_program
+    from trino_tpu.ops.join import expand_form
     probe, build = _q3_join_sides()
     pcap, bcap = 1 << 22, 1 << 20
-    fn = make_mjoin_expand_program("inner", None, 1 << 20)
+    assert expand_form(pcap, out_cap) == ("search" if loops
+                                          else "histogram")
+    fn = make_mjoin_expand_program("inner", None, out_cap)
     lane = _struct((pcap,), jnp.int64, one_chip)
     compiled = jax.jit(fn).lower(
         _as_structs(probe, pcap, one_chip),
         _as_structs(build, bcap, one_chip), lane, lane,
         _struct((bcap,), jnp.int64, one_chip)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 30
+    assert len(re.findall(r" while\(", compiled.as_text())) == loops
 
 
 def test_hash_join_count_program_compiles(one_chip,
@@ -178,10 +193,9 @@ def test_streamed_join_probe_program_compiles(one_chip,
                                               no_persistent_cache):
     """The streamed join's per-chunk program (exec/streamjoin.py): the
     same probe against a build side sorted and indexed ONCE outside it,
-    plus the expansion at a static capacity. Build 2^20 (nothing is
-    sorted in here, so it is not cut), chunk and output 2^16: the
-    expansion's compile time grows with them (43 s at 2^20, before
-    and after the directory)."""
+    plus the expansion at a static capacity (chunk 2^16 into 2^16: the
+    histogram form, so the probe's bisection is the program's ONE
+    loop). Build 2^20 (nothing is sorted in here, so it is not cut)."""
     from trino_tpu.exec.streamjoin import make_probe_program
     from trino_tpu.ops.join import build_side
     probe, build = _q3_join_sides()
@@ -196,6 +210,7 @@ def test_streamed_join_probe_program_compiles(one_chip,
     compiled = jax.jit(fn).lower(_as_structs(probe, cap, one_chip),
                                  bstructs, side).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 30
+    assert len(re.findall(r" while\(", compiled.as_text())) == 1
 
 
 @pytest.mark.parametrize("kind", ["counts", "move"])
@@ -246,3 +261,50 @@ def test_mesh_exchange_compiles_for_a_2x2_mesh(topo, no_persistent_cache,
     assert " sort(" not in text
     if kind == "move":
         assert "all-to-all" in text
+
+
+def test_mesh_join_expand_has_no_loop_on_a_2x2_mesh(topo,
+                                                    no_persistent_cache):
+    """The mesh executor's per-shard expand (exec/distributed.py
+    ``_shard_join`` inside ``shard_map``) at the shapes q3 runs at on
+    the four-chip cell: a shard of 2^23 lineitem rows against 2^21
+    order rows into 2^19 output rows. The sorted scatter-add of
+    ``run_positions`` lowers per shard as on one chip: one scatter and
+    no loop, where the search over the running sums was the cell's
+    largest operation (PERF.md PR 35)."""
+    from jax import shard_map
+    from jax.sharding import Mesh, NamedSharding
+    from trino_tpu.columnar import Batch
+    from trino_tpu.exec.distributed import _shard_join
+    from trino_tpu.parallel import spmd
+    P, AXIS = spmd.P, spmd.AXIS
+    n, per_p, per_b, out_cap = 4, 1 << 23, 1 << 21, 1 << 19
+    mesh = Mesh(np.asarray(topo.devices[:n]), (AXIS,))
+    rows, whole = NamedSharding(mesh, P(AXIS)), NamedSharding(mesh, P())
+    probe, build = _q3_join_sides()
+
+    def lanes(batch, per):
+        return jax.tree.map(
+            lambda a: _struct((n * per,), jnp.asarray(a).dtype, rows),
+            batch.columns)
+
+    def f(pcols, pn, bcols, bn, start, count, order):
+        d = jax.lax.axis_index(AXIS)
+        out = _shard_join(Batch(pcols, pn[d]), Batch(bcols, bn[d]),
+                          start, count, order, "inner", None, out_cap, 0)
+        return out.columns, jax.lax.all_gather(out.num_rows_device(),
+                                               AXIS)
+
+    lane = _struct((n * per_p,), jnp.int64, rows)
+    live = _struct((n,), jnp.int64, whole)
+    in_specs = (spmd._col_specs(probe.columns, P(AXIS)), P(),
+                spmd._col_specs(build.columns, P(AXIS)), P(),
+                P(AXIS), P(AXIS), P(AXIS))
+    text = jax.jit(shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=(P(AXIS), P()),
+        check_vma=False)).lower(
+            lanes(probe, per_p), live, lanes(build, per_b), live, lane,
+            lane, _struct((n * per_b,), jnp.int64, rows)
+    ).compile().as_text()
+    assert " while(" not in text
+    assert len(re.findall(r" scatter\(", text)) == 1

@@ -452,7 +452,123 @@ def test_join_probe_equals_searchsorted(case, monkeypatch):
         assert list(np.asarray(count)[:5]) == [3, 1, 1, 3, 0]
 
 
-def test_inner_join():
+def _counts_all_zero(rng):
+    return np.zeros(64, np.int64), 8
+
+
+def _counts_all_one(rng):
+    return np.ones(64, np.int64), 64
+
+
+def _counts_zeros_at_head(rng):
+    return np.r_[np.zeros(20), rng.integers(1, 3, 44)], 128
+
+
+def _counts_zeros_inside(rng):
+    c = rng.integers(0, 2, 256) * rng.integers(1, 4, 256)
+    c[40:90] = 0
+    return c, 512
+
+
+def _counts_zeros_at_tail(rng):
+    return np.r_[rng.integers(1, 3, 30), np.zeros(34)], 64
+
+
+def _counts_above_one(rng):
+    # q3's lineitem rows an order, a cross join's nb
+    return rng.integers(0, 8, 128), 512
+
+
+def _counts_left_join_dead_rows(rng):
+    # a left join's max(count, 1) over the live prefix, 0 past it
+    live = np.arange(128) < 77
+    return np.where(live, np.maximum(rng.integers(0, 3, 128), 1), 0), 256
+
+
+def _counts_total_equals_capacity(rng):
+    c = np.zeros(64, np.int64)
+    c[rng.permutation(64)[:32]] = 2
+    return c, 64
+
+
+def _counts_total_below_capacity(rng):
+    return rng.integers(0, 2, 64), 64
+
+
+def _counts_total_above_capacity(rng):
+    # an output cut short (the oversized join expands in chunks; a
+    # streamed chunk that overflows is rerun): positions past the
+    # capacity are asked of no row
+    return rng.integers(0, 5, 128), 32
+
+
+def _counts_capacity_one(rng):
+    return np.r_[0, 0, 3, 1, np.zeros(4)], 1
+
+
+def _counts_runs_far_above(rng):
+    # a few rows kept of a large probe side: the search's regime
+    c = np.zeros(1 << 16, np.int64)
+    c[rng.permutation(1 << 16)[:11]] = rng.integers(1, 3, 11)
+    return c, 16
+
+
+def _counts_runs_far_below(rng):
+    # every row many times over: the histogram's regime
+    return rng.integers(100, 300, 8), 2048
+
+
+def _counts_sum_past_int32(rng):
+    # int64 counts whose running sum passes 2^31 inside and beyond the
+    # capacity asked for
+    c = rng.integers(0, 3, 64).astype(np.int64)
+    c[37] = (1 << 31) + 5
+    c[50] = 1 << 40
+    return c, 128
+
+
+@pytest.mark.parametrize("case", [
+    _counts_all_zero, _counts_all_one, _counts_zeros_at_head,
+    _counts_zeros_inside, _counts_zeros_at_tail, _counts_above_one,
+    _counts_left_join_dead_rows, _counts_total_equals_capacity,
+    _counts_total_below_capacity, _counts_total_above_capacity,
+    _counts_capacity_one, _counts_runs_far_above, _counts_runs_far_below,
+    _counts_sum_past_int32], ids=lambda c: c.__name__[8:])
+def test_run_positions_equals_searchsorted(case, monkeypatch):
+    """``run_positions`` against numpy, in the form its shapes choose
+    and in BOTH forms forced (the constant moved under it): the same
+    int32 array each time, what ``searchsorted(incl, i, "right")``
+    gives for every output position."""
+    counts, out_capacity = case(np.random.default_rng(35))
+    incl = np.cumsum(np.asarray(counts, np.int64))
+    want = np.searchsorted(incl, np.arange(out_capacity), "right")
+    chosen = join_ops.expand_form(len(incl), out_capacity)
+    if case is _counts_runs_far_above:
+        assert chosen == "search"
+    if case is _counts_runs_far_below:
+        assert chosen == "histogram"
+    got = {chosen: join_ops.run_positions(jnp.asarray(incl), out_capacity)}
+    for form, k in (("histogram", 1 << 40), ("search", 0)):
+        monkeypatch.setattr(join_ops, "_HISTOGRAM_K", k)
+        assert join_ops.expand_form(len(incl), out_capacity) == form
+        forced = join_ops.run_positions(jnp.asarray(incl), out_capacity)
+        assert np.array_equal(np.asarray(forced), np.asarray(got[chosen]))
+        got[form] = forced
+    for form, p in got.items():
+        assert p.dtype == jnp.int32 and p.shape == (out_capacity,), form
+        assert np.array_equal(np.asarray(p), want), form
+
+
+@pytest.fixture(params=["histogram", "search"])
+def expand_form(request, monkeypatch):
+    """Every expansion of the test in ONE form of ``run_positions``,
+    whatever its shapes would choose."""
+    monkeypatch.setattr(join_ops, "_HISTOGRAM_K",
+                        (1 << 40) if request.param == "histogram" else 0)
+    return request.param
+
+
+def test_inner_join(expand_form):
     probe = batch_from_pylist({"k": [1, 2, 3, None, 5]},
                               {"k": BIGINT})
     build = batch_from_pylist({"k": [1, 1, 2, None], "w": [7, 8, 9, 10]},
@@ -462,7 +578,7 @@ def test_inner_join():
     assert rows == [(1, 1, 7), (1, 1, 8), (2, 2, 9)]
 
 
-def test_left_join():
+def test_left_join(expand_form):
     probe = batch_from_pylist({"k": [1, 3, None]}, {"k": BIGINT})
     build = batch_from_pylist({"k": [1, 2], "w": [7, 9]},
                               {"k": BIGINT, "w": BIGINT})
@@ -472,7 +588,7 @@ def test_left_join():
     assert rows == [(1, 1, 7), (3, None, None), (None, None, None)]
 
 
-def test_multikey_join():
+def test_multikey_join(expand_form):
     probe = batch_from_pylist({"a": [1, 1, 2], "b": [10, 11, 10]},
                               {"a": BIGINT, "b": BIGINT})
     build = batch_from_pylist({"a": [1, 2], "b": [10, 10],
@@ -493,7 +609,7 @@ def test_semi_join_mask():
     assert bool(has_null) and bool(nonempty)
 
 
-def test_cross_join():
+def test_cross_join(expand_form):
     probe = batch_from_pylist({"a": [1, 2]}, {"a": BIGINT})
     build = batch_from_pylist({"b": [10, 20, 30]}, {"b": BIGINT})
     start, count, order = cross_counts(probe, build)
@@ -524,7 +640,7 @@ def test_decimal_half_up_rounding():
     assert b.to_pylist() == [[decimal.Decimal("1.12")]]
 
 
-def test_string_join_across_dictionaries():
+def test_string_join_across_dictionaries(expand_form):
     probe = batch_from_pylist({"s": ["a", "b"]}, {"s": VARCHAR})
     build = batch_from_pylist({"s": ["b", "c"], "w": [1, 2]},
                               {"s": VARCHAR, "w": BIGINT})

@@ -328,6 +328,38 @@ def test_a_join_s_search_steps_ride_its_total_read(coordinator):
     assert exact == 0 and 0 < steps <= 6
 
 
+def test_a_join_s_expand_program_says_its_form(coordinator):
+    """The host that dispatches an expand program knows which form its
+    static shapes chose for mapping output rows to probe rows
+    (``ops/join.py expand_form``): ``form`` on the program's dispatch
+    span, counted in ``trino_tpu_join_expands_total{site, form}``: no
+    new read, nothing from the device. q3 at ``tiny`` runs two joins,
+    both with the histogram; no other dispatch carries a form."""
+    from trino_tpu.ops.join import expand_form
+
+    def expands():
+        f = parse_exposition(scrape_text(coordinator))
+        return dict(f.get("trino_tpu_join_expands_total", {}))
+
+    served(coordinator, sql_of("q3"))                # warm
+    before = expands()
+    res, _lat = served(coordinator, sql_of("q3"))
+    spans = coordinator.tracker.get(res.query_id).trace.all_spans()
+    dispatches = [s for s in spans
+                  if s.name in ("device_execute", "jit_trace")]
+    joins = [s for s in dispatches
+             if str(s.attrs.get("program")).startswith("join_expand:")]
+    assert len(joins) == 2
+    assert [s for s in dispatches if "form" in s.attrs] == joins
+    assert [s.attrs["form"] for s in joins] == ["histogram"] * 2
+    grew = {k: v - before.get(k, 0.0) for k, v in expands().items()}
+    assert {k: v for k, v in grew.items() if v} == {
+        ("site=join_expand", "form=histogram"): 2.0}
+    # few rows kept of many: the other form, by the same rule
+    assert expand_form(1 << 25, 1 << 15) == "search"
+    assert expand_form(1 << 25, 1 << 22) == "histogram"
+
+
 def test_the_hook_counts_only_the_fixed_phases():
     from trino_tpu.obs.metrics import (DEVICE_PROGRAMS, HOST_READS,
                                        QUERY_PHASE_SECONDS,

@@ -607,7 +607,8 @@ class DistributedExecutor(Executor):
 
         cols, counts = mesh_call(
             "join_expand", (jt, repr(filt), out_cap, pad_cap),
-            probe.mesh, operands + (start, count, order), build_expand)
+            probe.mesh, operands + (start, count, order), build_expand,
+            form=join_ops.expand_form(probe.per_shard_cap, out_cap))
         return ShardedBatch(cols, counts, probe.mesh, out_cap + pad_cap)
 
     def _dynamic_filter_probe(self, probe: ShardedBatch, build: Value,
@@ -852,9 +853,8 @@ def _setop_traced(lb: Batch, rb: Batch, op: str, distinct: bool,
         live_times = jnp.where(out.row_valid(), times, 0)
         total = jnp.sum(live_times)           # device scalar
         incl = jnp.cumsum(live_times)
-        i = jnp.arange(out_cap, dtype=jnp.int64)
-        p = jnp.searchsorted(incl, i, side="right")
-        p = jnp.clip(p, 0, out.capacity - 1)
+        p = jnp.clip(join_ops.run_positions(incl, out_cap), 0,
+                     out.capacity - 1)
         out = out.gather(p, total)
     return Batch({s: out.column(s) for s in out_syms}, out.num_rows)
 

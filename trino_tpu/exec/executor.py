@@ -473,11 +473,13 @@ class Executor:
         return (tr.span("host_read", site=site) if tr is not None
                 else _NO_SPAN)
 
-    def _jit_call(self, jitted, args: tuple, cache: str, hit: bool):
+    def _jit_call(self, jitted, args: tuple, cache: str, hit: bool,
+                  **attrs):
         """Invoke a jitted program under a live ``device_execute``
         (steady state) or ``jit_trace`` (first, cache-miss call: trace
         + XLA compile + execute) span carrying the program's identity
-        (``program=<kind>:<key8>``, exec/progkey.py named_jit),
+        (``program=<kind>:<key8>``, exec/progkey.py named_jit) and
+        ``attrs``,
         attributing compile wall to the current node's stats frame.
         ``device_ms`` is the HOST clock from dispatch to outputs ready
         (jax dispatch is async, so the wait is ``block_until_ready``):
@@ -494,7 +496,8 @@ class Executor:
         t1 = dev_s = None
         try:
             with dispatch_span(tr, getattr(jitted, "program", None)
-                               or f"{cache}:local", hit, cache) as sp:
+                               or f"{cache}:local", hit, cache,
+                               **attrs) as sp:
                 out = jitted(*args)
                 t1 = time.perf_counter()
                 if self._stream_depth == 0:
@@ -1345,9 +1348,9 @@ class Executor:
     # ------------------------------------------------------------------
     def _exec_UnnestNode(self, node) -> Batch:
         """UNNEST: expand array rows into element rows (reference:
-        operator/unnest/UnnestOperator.java). The expansion is the same
-        searchsorted pattern as join output materialization — per-row
-        emit count = max array length, two-phase capacity."""
+        operator/unnest/UnnestOperator.java). The expansion is the
+        join output materialization's (ops/join.py run_positions) —
+        per-row emit count = max array length, two-phase capacity."""
         src = self.execute(node.source)
         cap = src.capacity
         live = src.row_valid()
@@ -1370,7 +1373,7 @@ class Executor:
         incl = jnp.cumsum(count)
         offs = incl - count
         i = jnp.arange(out_cap, dtype=jnp.int64)
-        p = jnp.clip(jnp.searchsorted(incl, i, side="right"), 0, cap - 1)
+        p = jnp.clip(join_ops.run_positions(incl, out_cap), 0, cap - 1)
         j = i - jnp.take(offs, p)
         cols: Dict[str, Column] = {}
         for s in node.replicate:
@@ -1476,7 +1479,9 @@ class Executor:
                 jnp.asarray(count, jnp.int64),
                 jnp.asarray(order, jnp.int64))
         try:
-            out = self._jit_call(jitted, args, "join", hit)
+            out = self._jit_call(
+                jitted, args, "join", hit,
+                form=join_ops.expand_form(probe.capacity, out_cap))
         except UNTRACEABLE:
             PROGRAMS.deny("join", key)
             return None
@@ -2190,9 +2195,8 @@ def setop_batches(lb: Batch, rb: Batch, op: str, distinct: bool,
             total = int(jnp.sum(jnp.where(out.row_valid(), times, 0)))
         cap = capacity_for(max(total, 1))
         incl = jnp.cumsum(jnp.where(out.row_valid(), times, 0))
-        i = jnp.arange(cap, dtype=jnp.int64)
-        p = jnp.searchsorted(incl, i, side="right")
-        p = jnp.clip(p, 0, out.capacity - 1)
+        p = jnp.clip(join_ops.run_positions(incl, cap), 0,
+                     out.capacity - 1)
         out = out.gather(p, total)
     return Batch({s: out.column(s) for s in out_syms}, out.num_rows)
 

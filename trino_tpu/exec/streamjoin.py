@@ -935,8 +935,10 @@ def maybe_stream_join(ex, node: JoinNode
         if eager:                   # deny/fallback path
             return jitted(*args)
         try:
-            out = ex._jit_call(jitted, args, "streamjoin",
-                               bool(state["hit"]))
+            out = ex._jit_call(
+                jitted, args, "streamjoin", bool(state["hit"]),
+                form=join_ops.expand_form(probe_chunk.capacity,
+                                          state["out_cap"]))
             state["hit"] = True     # later chunks ride the program
             if not state["recorded"]:
                 state["recorded"] = True

@@ -571,6 +571,13 @@ JOIN_OUTPUT_ROWS = METRICS.counter(
     "Rows those joins put out (a mesh join: summed over its shards), "
     "from the same read: with the probe rows, a join chain's shape",
     ("site",))
+JOIN_EXPANDS = METRICS.counter(
+    "trino_tpu_join_expands_total",
+    "Join expand programs dispatched by traced queries, by the kind of "
+    "the program (join_expand, spmd_join_expand, streamjoin) and the "
+    "form its static shapes chose for mapping output rows to probe "
+    "rows (ops/join.py expand_form: histogram | search)",
+    ("site", "form"))
 EXPR_CONSTANT_SUBTREES = METRICS.counter(
     "trino_tpu_expr_constant_subtrees_total",
     "Subtrees of an expression with no column and no volatile call "
@@ -635,7 +642,11 @@ def observe_span(sp) -> None:
     elif name in ("device_execute", "jit_trace"):
         program = str(sp.attrs.get("program")
                       or sp.attrs.get("cache") or "other")
-        DEVICE_PROGRAMS.inc_at(_label_key(program.split(":", 1)[0]))
+        kind = _label_key(program.split(":", 1)[0])
+        DEVICE_PROGRAMS.inc_at(kind)
+        form = sp.attrs.get("form")
+        if form is not None:
+            JOIN_EXPANDS.inc_at(kind + _label_key(form))
     elif name == "scan_fill":
         SCAN_FILL_SECONDS.observe_at((), wall)
     elif name == "exchange":

@@ -447,11 +447,14 @@ class QueryTrace:
 
 
 def dispatch_span(trace: Optional[QueryTrace], program: str,
-                  hit: bool = True, cache: Optional[str] = None):
+                  hit: bool = True, cache: Optional[str] = None,
+                  **attrs):
     """The span of ONE device-program dispatch — the single helper
     behind every dispatch, so each is a span and a count:
     ``device_execute`` (``hit``) or ``jit_trace`` (first call: trace +
-    compile + run) with ``program=<kind>:<key8>``. ``trace=None``
+    compile + run) with ``program=<kind>:<key8>`` and what else the
+    dispatcher knows of the program (``attrs``: a join's expand
+    program carries ``form``). ``trace=None``
     falls back to the calling thread's active trace; outside a traced
     query it is a no-op context (``as`` yields None)."""
     if trace is None:
@@ -460,7 +463,7 @@ def dispatch_span(trace: Optional[QueryTrace], program: str,
         return nullcontext()
     return trace.span("device_execute" if hit else "jit_trace",
                       cache=cache or program.split(":", 1)[0],
-                      program=program)
+                      program=program, **attrs)
 
 
 def null_span(name: str, **attrs):

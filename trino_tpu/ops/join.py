@@ -40,7 +40,19 @@ sort + bucket-directory join:
 Output cardinality is data-dependent: callers run ``match_counts`` first,
 read the total on the host, pick a power-of-two capacity bucket, then run
 the expansion jit with that static capacity (the two-phase analog of
-Trino's incremental JoinProbe yielding pages).
+Trino's incremental JoinProbe yielding pages). There
+
+4. every output row finds its probe row (``expand_join`` through
+   ``run_positions``: how many running sums of the per-row counts are
+   <= its position) the way the directory is made: ONE scatter-add of
+   sorted indices into a histogram and an int32 ``cumsum``, no search.
+   That costs by the probe side's capacity, a bisection of the running
+   sums by output rows x log2(probe capacity): where few rows of a
+   large probe side are kept the bisection is cheaper, so the form is
+   chosen from the two static capacities (``expand_form``, one
+   measured constant) and the host that dispatches the program says
+   which (``form`` on its span, ``trino_tpu_join_expands_total``).
+   UNNEST and INTERSECT/EXCEPT ALL expand the same way.
 """
 
 from __future__ import annotations
@@ -318,6 +330,55 @@ def match_counts(probe: Batch, build: Batch,
     return left, count, side.order
 
 
+# ``run_positions`` by a histogram costs by the RUNS (one scatter update
+# each), by a search by the positions asked for times the steps of a
+# bisection over the runs: the histogram where
+# runs <= _HISTOGRAM_K x positions x bit_length(runs). On a v5e the
+# sorted scatter-add takes 8.83 ns an update whatever it is added into
+# (296.2 ms for 2^25 runs), a bisection step over 2^25 int32 sums
+# 18-25 ns a position: 2^25 runs -> 2^22 positions 297.0 ms by the
+# histogram and 2,344 by the search, 2^25 -> 2^15 296.3 and 20.9. They
+# cross at runs = 2.3 x positions x 26, between 2^25 -> 2^20 (296 /
+# 557) and -> 2^19 (296 / 261): 3 leaves that last shape, a first join
+# of the TPC-DS cell, on the form whose cost the data cannot move
+# (PERF.md §6, PR 35).
+_HISTOGRAM_K = 3
+
+
+def expand_form(runs: int, positions: int) -> str:
+    """``histogram`` or ``search``: the form ``run_positions`` takes
+    for ``runs`` counts expanded into ``positions`` output rows, from
+    the two static sizes alone — what the host that dispatches an
+    expand program says of it (the ``form`` of its span and of
+    ``trino_tpu_join_expands_total``)."""
+    return ("histogram" if runs <= _HISTOGRAM_K * positions
+            * runs.bit_length() else "search")
+
+
+def run_positions(incl, out_capacity: int):
+    """For ``i`` in [0, out_capacity), how many entries of the
+    non-decreasing ``incl`` (the running sum of per-row output counts)
+    are <= i: the row that output position i belongs to — exactly what
+    ``jnp.searchsorted(incl, i, side="right")`` returns, as int32, for
+    every input (zero counts anywhere, counts above one, a total below
+    or above ``out_capacity``). One algorithm in two forms, chosen from
+    the static sizes (``expand_form``): a histogram of ``incl`` summed
+    up — ONE scatter-add of sorted indices and one int32 ``cumsum``, no
+    loop, the way ``build_side`` makes its directory — or, where a few
+    positions are asked of many runs, the bisection."""
+    # a sum past the last position answers for none: cut down to
+    # out_capacity the sums fit 32 bits (a 64-bit gather is two)
+    at = jnp.minimum(incl, out_capacity).astype(jnp.int32)
+    if expand_form(incl.shape[0], out_capacity) == "search":
+        return jnp.searchsorted(
+            at, jnp.arange(out_capacity, dtype=jnp.int32), side="right")
+    # those that were cut fall into one slot more, which is left out
+    return jnp.cumsum(
+        jnp.zeros((out_capacity + 1,), jnp.int32).at[at].add(
+            1, indices_are_sorted=True,
+            mode="promise_in_bounds")[:out_capacity])
+
+
 def expand_join(probe: Batch, build: Batch, start, count, order,
                 out_capacity: int, join_type: str = "inner",
                 build_prefix: str = "") -> Batch:
@@ -337,8 +398,7 @@ def expand_join(probe: Batch, build: Batch, start, count, order,
     offs = incl - eff_count  # exclusive
 
     i = jnp.arange(out_capacity, dtype=jnp.int64)
-    p = jnp.searchsorted(incl, i, side="right")
-    p = jnp.clip(p, 0, probe.capacity - 1)
+    p = jnp.clip(run_positions(incl, out_capacity), 0, probe.capacity - 1)
     j = i - jnp.take(offs, p)
     b_sorted = jnp.take(start, p) + j
     b = jnp.take(order, jnp.clip(b_sorted, 0, build.capacity - 1))

@@ -82,12 +82,14 @@ def mesh_program(kind: str, key, mesh, operands,
     return PROGRAMS.program("spmd", ck, sharded, "spmd_" + kind, key)
 
 
-def mesh_call(kind: str, key, mesh, operands, build):
-    """Dispatch the mesh program on ``operands`` under its span. Inside
-    a traced query the outputs are waited for, so the span holds the
-    program's time on the chips and not only its dispatch."""
+def mesh_call(kind: str, key, mesh, operands, build, **attrs):
+    """Dispatch the mesh program on ``operands`` under its span (which
+    carries ``attrs``). Inside a traced query the outputs are waited
+    for, so the span holds the program's time on the chips and not only
+    its dispatch."""
     prog, hit = mesh_program(kind, key, mesh, operands, build)
-    with dispatch_span(None, prog.program, hit, "spmd_" + kind) as sp:
+    with dispatch_span(None, prog.program, hit, "spmd_" + kind,
+                       **attrs) as sp:
         out = prog(*operands)
         if sp is not None:
             jax.block_until_ready(out)
