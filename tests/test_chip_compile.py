@@ -308,3 +308,74 @@ def test_mesh_join_expand_has_no_loop_on_a_2x2_mesh(topo,
     ).compile().as_text()
     assert " while(" not in text
     assert len(re.findall(r" scatter\(", text)) == 1
+
+
+# ---- ISSUE 36: q18's programs -------------------------------------------
+@pytest.mark.parametrize("run,scatters", [(8, 0), (0, 2)])
+def test_dense_aggregation_and_its_compaction_compile(
+        one_chip, no_persistent_cache, run, scatters):
+    """q18's IN-subquery, ``group by l_orderkey having sum(l_quantity) >
+    300``, as the dense whole-table program makes it (ops/groupby.py
+    dense_group_slots, the HAVING a mask over what it returns) and the
+    counted compaction above it (ops/compact.py compact_batch into
+    2^10: a bisection). Keys that ascend in runs of at most eight
+    (lineitem's) are added up row by row: NO scatter and no loop, 2^20
+    rows in, 2^20 out. Longer runs scatter into 2^25 slots, with sorted
+    indices: two scatters (the s32 row count, the f64 sum: on the chip
+    0.59 s and 9.3 s for 2^26 updates, PERF.md) and still NO sort —
+    without the promise this compiler sorts the indices first."""
+    from trino_tpu import BIGINT, DOUBLE, batch_from_pylist
+    from trino_tpu.ops.compact import compact_batch
+    from trino_tpu.ops.groupby import (AggInput, DenseKeys,
+                                       dense_group_slots, dense_slots)
+    cap = 1 << 20
+    b = batch_from_pylist({"k": [1, 2], "q": [1.0, 2.0]},
+                          {"k": BIGINT, "q": DOUBLE})
+
+    def slots_and_having(b, base):
+        slots, exists = dense_group_slots(
+            b, ["k"], [AggInput("sum", "q", output="s")],
+            DenseKeys(base, True, run))
+        passed = exists & (jnp.asarray(slots.column("s").data) > 300.0)
+        return slots, passed, jnp.sum(passed.astype(jnp.int64))
+
+    args = (_as_structs(b, cap, one_chip), _struct((), jnp.int64, one_chip))
+    text = jax.jit(slots_and_having).lower(*args).compile().as_text()
+    assert " sort(" not in text and " while(" not in text
+    assert len(re.findall(r" scatter\(", text)) == scatters
+    slots = jax.tree.map(lambda a: _struct(a.shape, a.dtype, one_chip),
+                         jax.eval_shape(slots_and_having, *args)[0])
+    rows = slots.capacity
+    assert rows == (cap if run else dense_slots(cap))
+    text = jax.jit(lambda s, m: compact_batch(s, m, 1 << 10)).lower(
+        slots, _struct((rows,), jnp.bool_, one_chip)
+    ).compile().as_text()
+    assert " sort(" not in text
+    assert len(re.findall(r" while\(", text)) == 1
+
+
+def test_a_float64_group_key_compiles(one_chip, no_persistent_cache):
+    """q18 groups by o_totalprice, a DOUBLE: its equality lanes come
+    from the float32 pair the chip's float64 is (ops/hashing.py
+    ``_lanes_by_float32_pair``, chosen by the lowering's platform); the
+    ``jnp.frexp`` every other platform takes is refused here (PR 23)."""
+    from trino_tpu import BIGINT, DOUBLE, batch_from_pylist
+    from trino_tpu.ops.groupby import AggInput, group_aggregate
+    b = batch_from_pylist({"k": [1, 2], "p": [1.5, 2.5], "q": [1.0, 2.0]},
+                          {"k": BIGINT, "p": DOUBLE, "q": DOUBLE})
+    text = jax.jit(lambda b: group_aggregate(
+        b, ["k", "p"], [AggInput("sum", "q", output="s")])).lower(
+            _as_structs(b, 1 << 13, one_chip)).compile().as_text()
+    assert "bitcast-convert" in text
+
+
+def test_semi_join_program_compiles(one_chip, no_persistent_cache):
+    """The mark of ``o_orderkey IN (...)``: 2^20 probe keys against a
+    build side of 2^10 (what the counted HAVING leaves)."""
+    from trino_tpu import BIGINT, batch_from_pylist
+    from trino_tpu.exec.executor import semi_join_mark
+    k = batch_from_pylist({"k": [1, 2]}, {"k": BIGINT})
+    compiled = jax.jit(semi_join_mark).lower(
+        _as_structs(k, 1 << 20, one_chip),
+        _as_structs(k, 1 << 10, one_chip)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30

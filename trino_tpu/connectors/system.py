@@ -66,6 +66,11 @@ _RUNTIME_TABLES = {
         # chips this node's executor spans: the mesh's size where the
         # coordinator runs queries over the mesh, else 1
         ("devices", BIGINT),
+        # device memory (HBM) of those chips, bytes, as the backend
+        # reports its limit; NULL where it reports none (the CPU): what
+        # the scan-cache budget and the per-query memory limit of a
+        # deployment are sized against
+        ("device_memory_bytes", BIGINT),
     ),
     "resource_groups": (
         ("name", VARCHAR), ("running", BIGINT), ("queued", BIGINT),
@@ -193,7 +198,8 @@ class SystemConnector(Connector):
             rows = [
                 (i.get("nodeId", ""), i.get("uri", ""),
                  i.get("nodeVersion", ""), i.get("coordinator", False),
-                 i.get("state", "active"), int(i.get("devices", 1)))
+                 i.get("state", "active"), int(i.get("devices", 1)),
+                 i.get("deviceMemoryBytes"))
                 for i in self.provider.node_infos()]
         else:
             rows = [

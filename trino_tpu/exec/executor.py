@@ -28,7 +28,9 @@ from ..columnar import (Batch, Column, StringDictionary, batch_from_pylist,
 from ..config import (CONFIG, MemoryLimitExceeded, capacity_for,
                       reserve_bytes)
 from ..ops import compact, join as join_ops, sort as sort_ops
-from ..ops.groupby import AggInput, global_aggregate, group_aggregate
+from ..ops.groupby import (AggInput, dense_key_lane, dense_key_range,
+                           dense_keys, global_aggregate, group_aggregate,
+                           note_form, noted_forms)
 from ..ops.hashing import hash_columns, partition_of
 from ..plan.nodes import (AggregationNode, Aggregate, AssignUniqueIdNode,
                           EnforceSingleRowNode, ExchangeNode, FilterNode,
@@ -260,6 +262,8 @@ _NO_SPAN = _nullcontext()
 # the jit-cache family is defined ONCE in obs/metrics.py (streamjoin's
 # probe-program cache feeds the same family — a second registration
 # here would trip the metrics-hygiene lint)
+from ..obs.metrics import GROUPBYS as _M_GROUPBYS, \
+    GROUPBY_LANES as _M_GROUPBY_LANES
 from ..obs.metrics import JIT_CACHE_LOOKUPS as _M_JIT
 _M_SCAN = _METRICS.counter(
     "trino_tpu_scan_cache_total",
@@ -313,6 +317,34 @@ def join_verify_filter(left_cols, right_cols, pkeys, bkeys, filt):
     return and_all(([filt] if filt is not None else []) + eqs)
 
 
+def _call_noting_forms(jitted, args: tuple):
+    """Call a jitted program; on the call that TRACES it, keep with the
+    program the form each ``group_aggregate`` inside it chose
+    (``jitted.groupby_forms``: ((form, input lanes), ...), empty where
+    it groups nothing). A program's forms are static, so what the first
+    call noted is what every later dispatch runs."""
+    if getattr(jitted, "groupby_forms", None) is not None:
+        return jitted(*args)
+    with noted_forms() as notes:
+        out = jitted(*args)
+    jitted.groupby_forms = tuple(notes)
+    return out
+
+
+class _NotDense(Exception):
+    """Raised while the dense aggregation program is traced: the
+    lowered aggregates or the key lane turned out not to be the dense
+    form's (``ops/groupby.py dense_eligible``)."""
+
+
+# the rule of the counted filter (``Executor._counted_filter``): a
+# Filter over a batch of at least this many lanes is evaluated as a
+# mask, COUNTED (one host read, site ``filter_rows``) and compacted at
+# the capacity of what it keeps. Under it a filter compacts at its
+# input's capacity, as before: a sync costs more than it would save.
+COUNTED_FILTER_MIN_LANES = 1 << 22
+
+
 class Executor:
     def __init__(self, catalogs: CatalogManager, session: Session,
                  collect_stats: bool = False,
@@ -336,6 +368,7 @@ class Executor:
                 fragment_jit = jax.default_backend() not in ("cpu",)
         self.fragment_jit = fragment_jit
         self._no_jit_chains: set = set()
+        self._not_dense: set = set()    # ids of aggregations that declined
         self._jit_chains: dict = {}
         # per-query telemetry accumulators (obs/): stat frames track
         # each node's input flow (children add their output on exit);
@@ -432,6 +465,8 @@ class Executor:
             out = fn()
         finally:
             self._frames.pop()
+        if out is None:     # a path that declined: nothing ran
+            return None
         # CPU before the blocking row read below: the host decode of
         # the output is accounting overhead, not the operator's work
         cpu_s = max(time.thread_time() - cpu0, 0.0)
@@ -491,15 +526,24 @@ class Executor:
         no-telemetry path keeps jax's async pipeline untouched."""
         tr = self.trace
         if tr is None and not self.collect_stats:
-            return jitted(*args)
+            return _call_noting_forms(jitted, args)
         t0 = time.perf_counter()
         t1 = dev_s = None
         try:
             with dispatch_span(tr, getattr(jitted, "program", None)
                                or f"{cache}:local", hit, cache,
                                **attrs) as sp:
-                out = jitted(*args)
+                out = _call_noting_forms(jitted, args)
                 t1 = time.perf_counter()
+                forms = getattr(jitted, "groupby_forms", None)
+                if forms and sp is not None:
+                    # the grouped aggregations inside the program, by
+                    # the form each runs in (packed | dense | sort) and
+                    # its input lanes: obs/metrics.py counts them per
+                    # dispatch (trino_tpu_groupby_total / _lanes_total)
+                    sp.attrs["form"] = forms[0][0]
+                    sp.attrs["groupby"] = ",".join(
+                        f"{f}:{n}" for f, n in forms)
                 if self._stream_depth == 0:
                     # device attribution syncs — inside a streamed
                     # chunk loop that sync would serialize the double-
@@ -575,6 +619,9 @@ class Executor:
             if streamed is not None:
                 return streamed
         if isinstance(node, AggregationNode):
+            dense = self._try_dense_aggregation(node)
+            if dense is not None:
+                return dense
             streamed = self._try_streaming_aggregation(node)
             if streamed is not None:
                 return streamed
@@ -599,10 +646,12 @@ class Executor:
                 # program; plans outside the canonical subset keep
                 # per-query identity keys
                 from .progkey import canonicalize_nodes
+                base, chain = self._counted_base(cur, chain)
+                if not chain:
+                    return base
                 canon = canonicalize_nodes(chain)
                 key = canon.key if canon is not None \
                     else tuple(id(n) for n in chain)
-                base = self.execute(cur)
                 if key not in self._no_jit_chains:
                     try:
                         out = self._run_chain_jit(key, chain, base,
@@ -636,6 +685,208 @@ class Executor:
     # then combining partials)
     # ------------------------------------------------------------------
     _STREAM_CHAIN = None   # set after class body
+
+    # ------------------------------------------------------------------
+    # capacity that follows the live rows: a Filter (a HAVING, a semi
+    # join's mark) over millions of lanes that keeps a few hundred rows
+    # is evaluated as a MASK, counted, and compacted ONCE at the
+    # capacity of what is left — so that what runs above it (a join's
+    # build-side sort, ops/join.py build_side) runs at 2^10 lanes, not
+    # at the table's 2^24 or the aggregation's 2^26
+    # ------------------------------------------------------------------
+    def _counted_base(self, cur: PlanNode, chain: List[PlanNode]):
+        """``(base, rest)`` for a traceable chain (top-down) over
+        ``cur``: the executed source and the nodes still to run over
+        it. The chain's lowest Filter/Project run is taken off and run
+        counted where (a) ``cur`` is an aggregation that takes the
+        dense form (the HAVING filters the group slots BEFORE their one
+        compaction: ``_try_dense_aggregation``), or (b) it holds a
+        Filter and the source has ``COUNTED_FILTER_MIN_LANES`` lanes or
+        more. Else nothing changes: ``(execute(cur), chain)``."""
+        low = len(chain)
+        while low and isinstance(chain[low - 1],
+                                 (FilterNode, ProjectNode)):
+            low -= 1
+        bottom = chain[low:]
+        if isinstance(cur, AggregationNode):
+            def dense():
+                return self._try_dense_aggregation(cur, bottom)
+            # the aggregation's statistics then hold what the HAVING
+            # left of its groups: the two ran as one
+            out = (self._stats_wrap(cur, dense) if self.collect_stats
+                   else dense())
+            if out is not None:
+                return out, chain[:low]
+        base = self.execute(cur)
+        if base.capacity >= COUNTED_FILTER_MIN_LANES and any(
+                isinstance(n, FilterNode) for n in bottom):
+            out = self._counted_filter(bottom, base)
+            if out is not None:
+                return out, chain[:low]
+        return base, chain
+
+    def _counted_filter(self, nodes: List[PlanNode],
+                        base: Batch) -> Optional[Batch]:
+        """A Filter/Project run (top-down) over ``base`` in two
+        programs around ONE read: the run as a selection vector (its
+        columns at the input's capacity, the mask, the count), then the
+        compaction at ``capacity_for(count)``. None where the run
+        cannot be keyed or traced: the caller compacts as before."""
+        from .progkey import canonicalize_nodes
+        canon = canonicalize_nodes(nodes)
+        if canon is None:
+            return None
+        key = (canon.key, "mask")
+        got = PROGRAMS.program(
+            "chain", key,
+            lambda: make_mask_program(self._detached(), canon.nodes),
+            "chain_mask", key)
+        if got is None:
+            return None
+        jitted, hit = got
+        binding = canon.binding(base)
+        try:
+            cols, live, n = self._jit_call(
+                jitted, (binding.rename_in(base),), "chain", hit)
+        except UNTRACEABLE:
+            PROGRAMS.deny("chain", key)
+            return None
+        return binding.rename_out(self._compact_counted(cols, live, n))
+
+    def _compact_counted(self, cols: Batch, live, n) -> Batch:
+        """The rows of ``cols`` where ``live`` is set, in a batch whose
+        capacity follows their number ``n`` (a device scalar: read
+        here, site ``filter_rows``)."""
+        from .streamjoin import _lane_spec
+        with self._host_read("filter_rows") as sp:
+            rows = int(n)
+            if sp is not None:
+                sp.attrs["rows"] = rows
+                sp.attrs["lanes"] = cols.capacity
+        out_cap = capacity_for(rows)
+        key = ("compact", _lane_spec(cols), cols.capacity, out_cap)
+        jitted, hit = PROGRAMS.program(
+            "chain", key, lambda: make_compact_program(out_cap),
+            "compact", key)
+        return self._jit_call(jitted, (cols, live), "chain", hit)
+
+    # ------------------------------------------------------------------
+    # the dense aggregation (ops/groupby.py): GROUP BY one integer key
+    # of a resident table whose values span less than the slots, with
+    # the HAVING above it filtering the slots before the one compaction
+    # ------------------------------------------------------------------
+    _DENSE_PLAN_KINDS = {"sum", "count", "count_star", "min", "max",
+                         "avg"}
+
+    @staticmethod
+    def _scan_column(sym: str, chain, scan: TableScanNode):
+        """The scan column that ``sym`` (an output of the chain over
+        ``scan``) is a plain copy of, or None."""
+        for nd in chain:                # top-down
+            if isinstance(nd, ProjectNode):
+                e = nd.assignments.get(sym)
+                if not isinstance(e, InputRef):
+                    return None
+                sym = e.name
+        return scan.assignments.get(sym)
+
+    def _try_dense_aggregation(self, node: AggregationNode,
+                               above=()) -> Optional[Batch]:
+        """``node`` (and the Filter/Project run ``above`` it, top-down)
+        over a chain over a resident table, in the dense form, or None
+        where that form is not the one to take. What decides, all of it
+        observable: ONE group key that is a plain copy of an integer
+        scan column, aggregates a scatter computes, the table resident
+        as one batch, and the key's range: one counted read (site
+        ``groupby_key_range``) of its least and greatest value, which
+        have to span less than ``dense_slots(capacity)``. Then ONE
+        program makes the group slots, applies ``above`` to them as a
+        selection vector and counts; the slots that are left are
+        compacted at the capacity of their number
+        (``_compact_counted``). No sort, no ``nonzero``."""
+        if not self.fragment_jit or self.scan_partition is not None \
+                or id(node) in self._not_dense \
+                or len(node.group_keys) != 1 or any(
+                    a.distinct or a.kind not in self._DENSE_PLAN_KINDS
+                    for a in node.aggregates.values()):
+            return None
+        # a node that declines is not asked again with another
+        # ``above`` (its read would be made twice)
+        self._not_dense.add(id(node))
+        chain: List[PlanNode] = []
+        cur = node.source
+        while isinstance(cur, self._STREAM_CHAIN):
+            chain.append(cur)
+            cur = cur.source
+        if not isinstance(cur, TableScanNode):
+            return None
+        key_col = self._scan_column(node.group_keys[0], chain, cur)
+        from .streamjoin import agg_chunk_capacity
+        if key_col is None or agg_chunk_capacity(self, cur) is not None:
+            return None
+        from .progkey import canonicalize_nodes
+        n_above = len(above)
+        canon = canonicalize_nodes(list(above) + [node] + chain)
+        if canon is None:
+            return None
+        key = (canon.key, "dense", n_above)
+        conn = self.catalogs.connector(cur.handle.catalog)
+        par = int(self.session.get("task_concurrency")) or 1
+        whole = read_table_cached(
+            conn, cur.handle, sorted(set(cur.assignments.values())), par)
+        if whole is None:
+            return None
+        kcol = whole.column(key_col)
+        if not dense_key_lane(kcol):
+            return None
+        # the counted read: a superset of the live keys (the chain's
+        # filters have not run), so a range that fits holds them all
+        rkey = ("key_range", str(kcol.data.dtype), whole.capacity,
+                kcol.valid is not None)
+        ranger, rhit = PROGRAMS.program(
+            "stream", rkey,
+            lambda: (lambda b: dense_key_range(b, ["k"])),
+            "key_range", rkey)
+        key_range = self._jit_call(
+            ranger, (Batch({"k": kcol}, whole.num_rows),), "stream", rhit)
+        with self._host_read("groupby_key_range") as sp:
+            keys = dense_keys(np.asarray(key_range), whole.capacity)
+            if sp is not None:
+                sp.attrs["fits"] = int(keys is not None)
+                if keys is not None:
+                    sp.attrs["ascending"] = int(keys.ascending)
+                    sp.attrs["run"] = keys.run
+        if keys is None:
+            return None
+        # whether the keys ascend, and in how short runs, is static:
+        # a program each (its scatters promise sorted indices, or it
+        # adds a run up row by row), so part of the key
+        key += (keys.ascending, keys.run)
+        if PROGRAMS.denied("stream", key):
+            return None
+        helper = self._detached()
+        got = PROGRAMS.program(
+            "stream", key,
+            lambda: make_dense_program(
+                helper, canon.nodes[n_above + 1:], canon.nodes[n_above],
+                canon.nodes[:n_above], keys.ascending, keys.run),
+            "stream_dense", key)
+        if got is None:
+            return None
+        jitted, hit = got
+        batch = Batch({sym: whole.column(col)
+                       for sym, col in cur.assignments.items()},
+                      whole.num_rows)
+        binding = canon.binding(batch)
+        try:
+            slots, live, n = self._jit_call(
+                jitted, (binding.rename_in(batch), keys.base),
+                "stream", hit)
+        except (_NotDense,) + UNTRACEABLE:
+            PROGRAMS.deny("stream", key)
+            return None
+        self._not_dense.discard(id(node))
+        return binding.rename_out(self._compact_counted(slots, live, n))
 
     _NONSTREAMABLE = {"min_by", "max_by", "approx_distinct",
                       "approx_percentile", "array_agg", "map_agg",
@@ -842,14 +1093,7 @@ class Executor:
                                   finals)
         else:
             out = global_aggregate(merged, finals)
-        if post:
-            cols = dict(out.columns)
-            for sym, fn in post.items():
-                cols[sym] = fn(out)
-            keep = set(node_x.group_keys) | set(node_x.aggregates)
-            cols = {s: c for s, c in cols.items() if s in keep}
-            out = Batch(cols, out.num_rows)
-        return unbind(out)
+        return unbind(_with_post(out, post, node_x))
 
     # ------------------------------------------------------------------
     # masked (selection-vector) filter -> aggregation fusion: filters
@@ -912,14 +1156,7 @@ class Executor:
                 out = global_aggregate(src, phys, live=live)
             else:
                 return _single_row(src)
-            if post:
-                oc = dict(out.columns)
-                for sym, fn in post.items():
-                    oc[sym] = fn(out)
-                keep = set(node.group_keys) | set(node.aggregates)
-                oc = {s: c for s, c in oc.items() if s in keep}
-                out = Batch(oc, out.num_rows)
-            return out
+            return _with_post(out, post, node)
 
         if not self.fragment_jit:
             try:
@@ -1280,8 +1517,17 @@ class Executor:
     # aggregation
     # ------------------------------------------------------------------
     def _exec_AggregationNode(self, node: AggregationNode) -> Batch:
-        return self._apply_AggregationNode(
-            node, self.execute(node.source))
+        src = self.execute(node.source)
+        if self.trace is None:
+            return self._apply_AggregationNode(node, src)
+        # an aggregation that no program took runs operation by
+        # operation: its form is counted like a program's, site "eager"
+        with noted_forms() as notes:
+            out = self._apply_AggregationNode(node, src)
+        for form, lanes in notes:
+            _M_GROUPBYS.inc_at(("eager", form))
+            _M_GROUPBY_LANES.inc_at(("eager", form), lanes)
+        return out
 
     def _apply_AggregationNode(self, node: AggregationNode,
                                src: Batch) -> Batch:
@@ -1295,15 +1541,7 @@ class Executor:
         else:
             out = global_aggregate(src, phys) if phys else \
                 _single_row(src)
-        if post:
-            cols = dict(out.columns)
-            for sym, fn in post.items():
-                cols[sym] = fn(out)
-            # drop intermediate lanes
-            keep = set(node.group_keys) | set(node.aggregates)
-            cols = {s: c for s, c in cols.items() if s in keep}
-            out = Batch(cols, out.num_rows)
-        return out
+        return _with_post(out, post, node)
 
     def _exec_MarkDistinctNode(self, node: MarkDistinctNode) -> Batch:
         return self._apply_MarkDistinctNode(
@@ -1817,15 +2055,32 @@ class Executor:
     def _exec_SemiJoinNode(self, node: SemiJoinNode) -> Batch:
         src = self.execute(node.source)
         filt = self.execute(node.filtering_source)
-        matched, key_null, build_null, nonempty = join_ops.semi_join_mask(
-            src, filt, [node.source_key], [node.filtering_key])
-        # x IN (...): TRUE if matched; FALSE if build empty; NULL if the
-        # probe key is NULL or the build side contains NULLs; else FALSE
-        data = matched
-        valid = matched | ~nonempty | (~key_null & ~build_null)
+        data, valid = self._semi_join_mark(
+            Batch({"k": src.column(node.source_key)}, src.num_rows),
+            Batch({"k": filt.column(node.filtering_key)}, filt.num_rows))
         cols = dict(src.columns)
         cols[node.output] = Column(BOOLEAN, data, valid)
         return Batch(cols, src.num_rows)
+
+    def _semi_join_mark(self, probe: Batch, build: Batch):
+        """The mark of ``k IN (build's k)`` per probe row, (data,
+        valid), as ONE cached program of the two key lanes (bucket
+        ``join``, kind ``semi_join``: a ``device_execute`` span, a
+        name in the trace); eagerly where fragments are not jitted or
+        the keys cannot be traced."""
+        if self.fragment_jit and self._mjoin_jittable(probe, build):
+            from .streamjoin import _lane_spec
+            key = ("semi_join", _lane_spec(probe), _lane_spec(build),
+                   probe.capacity, build.capacity)
+            got = PROGRAMS.program("join", key, lambda: semi_join_mark,
+                                   "semi_join", key)
+            if got is not None:
+                try:
+                    return self._jit_call(got[0], (probe, build), "join",
+                                          got[1])
+                except UNTRACEABLE:
+                    PROGRAMS.deny("join", key)
+        return semi_join_mark(probe, build)
 
     def _exec_SemiJoinMultiNode(self, node: SemiJoinMultiNode) -> Batch:
         src = self.execute(node.source)
@@ -2009,16 +2264,83 @@ def make_stream_parts(helper: "Executor", chain, node):
                                   live=live)
         else:
             out = global_aggregate(out, fin, live=live)
-        if _post:
-            cols = dict(out.columns)
-            for sym, fn in _post.items():
-                cols[sym] = fn(out)
-            keep = set(node.group_keys) | set(node.aggregates)
-            cols = {s: c for s, c in cols.items() if s in keep}
-            out = Batch(cols, out.num_rows)
-        return out
+        return _with_post(out, _post, node)
 
     return partial, finish
+
+
+def semi_join_mark(probe: Batch, build: Batch):
+    """``k IN (build's k)`` per probe row with SQL's three values,
+    (data, valid): TRUE if matched; FALSE if the build side is empty;
+    NULL if the probe key is NULL or the build side holds a NULL; else
+    FALSE."""
+    matched, key_null, build_null, nonempty = join_ops.semi_join_mask(
+        probe, build, ["k"], ["k"])
+    return matched, matched | ~nonempty | (~key_null & ~build_null)
+
+
+def make_mask_program(helper: "Executor", nodes):
+    """The program of a Filter/Project run (top-down) as a selection
+    vector: ``(columns at the input's capacity, the mask of the rows
+    that pass, their number)`` — the first half of a counted filter
+    (``Executor._counted_filter``)."""
+
+    def fn(b: Batch):
+        cols, live = helper._masked_chain_eval(nodes, b)
+        return (Batch(cols, b.capacity), live,
+                jnp.sum(live.astype(jnp.int64)))
+
+    return fn
+
+
+def make_compact_program(out_cap: int):
+    """The second half: the masked rows at ``out_cap`` lanes."""
+
+    def fn(cols: Batch, live):
+        return compact.compact_batch(cols, live, out_cap)
+
+    return fn
+
+
+def make_dense_program(helper: "Executor", chain, node, above,
+                       ascending: bool, run: int):
+    """The dense whole-table aggregation: the chain below ``node`` as a
+    selection vector, the group slots of the ONE integer key
+    (``ops/groupby.py dense_group_slots``, ``base`` its least value,
+    ``ascending`` and ``run`` whether the table's keys ascend and in
+    how short runs: the caller read all three: ``DenseKeys``), the
+    aggregates' post-processing and the
+    Filter/Project run ``above`` applied to the slots, again as a
+    selection vector. Returns ``(the slots' columns, the mask of the
+    groups that exist and pass, their number)``; the caller compacts
+    at the capacity of that number."""
+    from ..ops.groupby import (DenseKeys, dense_eligible,
+                               dense_group_slots)
+
+    def fn(b: Batch, base):
+        cols, live = helper._masked_chain_eval(chain, b)
+        src = Batch(cols, jnp.sum(live.astype(jnp.int64)))
+        phys, post, extra = _lower_aggregates(node.aggregates, src)
+        if extra:
+            c2 = dict(src.columns)
+            c2.update(extra)
+            src = Batch(c2, src.num_rows)
+        keys = list(node.group_keys)
+        if not dense_eligible(src, keys, phys):
+            raise _NotDense()
+        note_form("dense", b.capacity)
+        # the read spanned every row of the table: one the chain's
+        # filters dropped keeps its slot and adds nothing there
+        slots, exists = dense_group_slots(
+            src, keys, phys, DenseKeys(base, ascending, run), live=live,
+            spanned=b.row_valid())
+        slots = _with_post(slots, post, node)
+        out, passed = helper._masked_chain_eval(above, slots)
+        passed = passed & exists
+        return (Batch(out, slots.capacity), passed,
+                jnp.sum(passed.astype(jnp.int64)))
+
+    return fn
 
 
 def make_chain_program(helper: "Executor", nodes):
@@ -2678,6 +3000,20 @@ def _single_row(src: Batch) -> Batch:
 # --------------------------------------------------------------------------
 # aggregate lowering (avg & friends -> segment-op primitives)
 # --------------------------------------------------------------------------
+
+def _with_post(out: Batch, post, node: AggregationNode) -> Batch:
+    """``out`` with the aggregates' post-processing (``_lower_aggregates``:
+    an avg from its sum and count ...) applied and the intermediate
+    lanes dropped."""
+    if not post:
+        return out
+    cols = dict(out.columns)
+    for sym, fn in post.items():
+        cols[sym] = fn(out)
+    keep = set(node.group_keys) | set(node.aggregates)
+    return Batch({s: c for s, c in cols.items() if s in keep},
+                 out.num_rows)
+
 
 def _lower_aggregates(aggregates: Dict[str, Aggregate], src: Batch):
     """Map logical aggregates onto the kernel-supported kinds

@@ -578,6 +578,19 @@ JOIN_EXPANDS = METRICS.counter(
     "form its static shapes chose for mapping output rows to probe "
     "rows (ops/join.py expand_form: histogram | search)",
     ("site", "form"))
+GROUPBYS = METRICS.counter(
+    "trino_tpu_groupby_total",
+    "Grouped aggregations of traced queries, by the kind of the "
+    "program they are in (stream_full, stream_dense, stream, chain, "
+    "masked ...; eager: run operation by operation, in no program) and "
+    "the form the aggregation RUNS in (ops/groupby.py group_aggregate: "
+    "packed | dense | sort), counted per dispatch, not per compile",
+    ("site", "form"))
+GROUPBY_LANES = METRICS.counter(
+    "trino_tpu_groupby_lanes_total",
+    "Input capacity (lanes) of those aggregations, by the same labels: "
+    "the dense form's share of it says how much of the grouping went "
+    "without a sort", ("site", "form"))
 EXPR_CONSTANT_SUBTREES = METRICS.counter(
     "trino_tpu_expr_constant_subtrees_total",
     "Subtrees of an expression with no column and no volatile call "
@@ -644,8 +657,17 @@ def observe_span(sp) -> None:
                       or sp.attrs.get("cache") or "other")
         kind = _label_key(program.split(":", 1)[0])
         DEVICE_PROGRAMS.inc_at(kind)
+        groupby = sp.attrs.get("groupby")
         form = sp.attrs.get("form")
-        if form is not None:
+        if groupby is not None:
+            # an aggregation program: "form:input lanes,...", what the
+            # dispatcher kept from the program's trace
+            for part in str(groupby).split(","):
+                gform, _, lanes = part.partition(":")
+                labels = kind + _label_key(gform)
+                GROUPBYS.inc_at(labels)
+                GROUPBY_LANES.inc_at(labels, float(lanes or 0))
+        elif form is not None:
             JOIN_EXPANDS.inc_at(kind + _label_key(form))
     elif name == "scan_fill":
         SCAN_FILL_SECONDS.observe_at((), wall)

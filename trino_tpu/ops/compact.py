@@ -38,6 +38,25 @@ def filter_batch(batch: Batch, mask: jax.Array) -> Batch:
     return batch.gather(idx, count)
 
 
+def compact_batch(batch: Batch, mask: jax.Array,
+                  out_capacity: int) -> Batch:
+    """``filter_batch`` at a capacity of the caller's choosing: the rows
+    where ``mask`` is set (it says which rows live: the batch's own
+    prefix is not consulted), in order, in a batch of ``out_capacity``
+    lanes. The caller has COUNTED the mask (one host read) and asks for
+    ``capacity_for(count)``: after a filter that keeps a thousandth the
+    operators above it (a join's build-side sort) run at the capacity
+    of what is left, not of what came in. Output row i is the row at
+    which the running sum of the mask passes i (``ops/join.py
+    run_positions``: a bisection where few rows are asked of many
+    lanes, else one scatter-add and a ``cumsum``); no ``nonzero``."""
+    from .join import run_positions
+    incl = jnp.cumsum(mask.astype(jnp.int32))
+    rows = jnp.clip(run_positions(incl, out_capacity), 0,
+                    batch.capacity - 1)
+    return batch.gather(rows, incl[-1].astype(jnp.int64))
+
+
 def limit_batch(batch: Batch, limit: Union[int, jax.Array]) -> Batch:
     """LIMIT n without data movement (reference: operator/LimitOperator.java).
     """

@@ -17,8 +17,10 @@ same kernel on partial states: every aggregate below declares a
 
 from __future__ import annotations
 
+import contextlib
+import threading
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +30,32 @@ from .hashing import equality_lanes
 from .sort import stable_lexsort
 
 _U64MAX = jnp.uint64(0xFFFFFFFFFFFFFFFF)
+
+# The form each ``group_aggregate`` of the calling thread took (packed |
+# dense | sort) with its input capacity, noted where it is CHOSEN: when
+# a program is traced, or per call where it runs eagerly. A dispatcher
+# that wants to say which form its program runs (exec/executor.py
+# ``_jit_call``: the ``form`` of the span, ``trino_tpu_groupby_total``)
+# collects the notes of the program's one trace and keeps them with it.
+_NOTES = threading.local()
+
+
+def note_form(form: str, lanes: int) -> None:
+    notes = getattr(_NOTES, "forms", None)
+    if notes is not None:
+        notes.append((form, int(lanes)))
+
+
+@contextlib.contextmanager
+def noted_forms():
+    """Collects ``(form, input capacity)`` of every ``group_aggregate``
+    the calling thread chooses a form for inside the block."""
+    before = getattr(_NOTES, "forms", None)
+    _NOTES.forms = notes = []
+    try:
+        yield notes
+    finally:
+        _NOTES.forms = before
 
 
 @dataclass(frozen=True)
@@ -379,10 +407,295 @@ def _masked_agg(batch: Batch, agg: AggInput, gmasks, live,
     return _replace(out, valid=group_valid)
 
 
+# --------------------------------------------------------------------------
+# the dense form: one integer key over a range the slots cover
+# --------------------------------------------------------------------------
+
+_DENSE_KINDS = {"sum", "count", "count_star", "min", "max"}
+
+
+def dense_slots(capacity: int) -> int:
+    """Slots of the dense form for an input of ``capacity`` lanes,
+    static: the size the join's exact directory has for a build side of
+    that capacity (32 slots a lane, at most 2^26: ops/join.py
+    ``_directory_bits``). TPC-H's l_orderkey spans 4 x rows(orders) =
+    60M at SF 10, under the 2^26 its 2^26-lane scan is given."""
+    from .join import _directory_bits
+    return 1 << _directory_bits(capacity)
+
+
+def _plain_lane(col: Column) -> bool:
+    return (col.data2 is None and col.dictionary is None
+            and col.elements is None and col.children is None)
+
+
+def dense_key_lane(col: Column) -> bool:
+    """Static: may this column be the dense form's key? One plain
+    integer lane."""
+    return _plain_lane(col) and jnp.issubdtype(col.data.dtype, jnp.integer)
+
+
+def dense_eligible(batch: Batch, key_names: Sequence[str],
+                   aggs: Sequence[AggInput]) -> bool:
+    """Static: ONE key column that is one plain integer lane (BIGINT,
+    INTEGER, DATE ...: ops/join.py ``_integer_key``'s rule, less
+    booleans and dictionary codes, which have a static domain and take
+    the packed form), and aggregates a scatter computes: sum, count,
+    count(*), min, max (avg arrives as sum and count) over plain
+    numeric lanes — no strings, no booleans, no Int128 sums."""
+    if len(key_names) != 1 or not dense_key_lane(
+            batch.column(key_names[0])):
+        return False
+    for a in aggs:
+        if a.kind not in _DENSE_KINDS:
+            return False
+        if a.kind in ("sum", "min", "max"):
+            col = batch.column(a.input)
+            if not _plain_lane(col) or col.data.dtype == jnp.bool_ \
+                    or _wide_decimal_agg(col, a.kind):
+                return False
+    return True
+
+
+def dense_key_range(batch: Batch, key_names: Sequence[str],
+                    live: Optional[jax.Array] = None) -> jax.Array:
+    """int64[5]: the least and the greatest non-NULL live key, how many
+    there are, whether they ASCEND (no NULL among the live keys, each
+    no less than the one before, the dead rows behind them all) and,
+    where they do, the longest run of equal keys — what ONE counted
+    read tells the host before it asks for the dense form
+    (``dense_fits``, ``dense_keys``). Ascending keys keep a group's
+    rows adjacent: short runs are added up row by row with no scatter
+    at all (``_run_groups``), and a scatter of ascending indices needs
+    no sort on the chip (its compiler sorts any others first)."""
+    col = batch.column(key_names[0])
+    live = batch.row_valid() if live is None else live
+    usable = live
+    if col.valid is not None:
+        usable = usable & jnp.asarray(col.valid)
+    key = jnp.asarray(col.data).astype(jnp.int64)
+    info = jnp.iinfo(jnp.int64)
+    lane = jnp.where(usable, key, info.max)
+    n = jnp.sum(usable.astype(jnp.int64))
+    ascend = jnp.all(lane[1:] >= lane[:-1]) \
+        & (n == jnp.sum(live.astype(jnp.int64)))
+    # a row's distance from the first row of its run of equal keys
+    pos = jnp.arange(lane.shape[0], dtype=jnp.int32)
+    first = jnp.concatenate([jnp.ones((1,), bool), lane[1:] != lane[:-1]])
+    behind = pos - jax.lax.cummax(jnp.where(first, pos, 0))
+    longest = jnp.max(jnp.where(usable, behind, -1)) + 1
+    return jnp.stack([jnp.min(lane),
+                      jnp.max(jnp.where(usable, key, info.min)), n,
+                      ascend.astype(jnp.int64),
+                      jnp.where(ascend, longest, 0).astype(jnp.int64)])
+
+
+def dense_fits(key_range, capacity: int) -> bool:
+    """Host: do the keys of ``key_range`` (``dense_key_range``, read)
+    span less than the slots of a ``capacity``-lane input? One slot,
+    the last, is the NULL key's group, so the greatest key has to land
+    before it. Python integers: a span past 2^63 cannot wrap."""
+    lo, hi, n = (int(v) for v in key_range[:3])
+    return n > 0 and hi - lo < dense_slots(capacity) - 1
+
+
+# the longest run of equal ascending keys that is added up row by row
+# (``_run_groups``: one shifted pass a row of the run); past it the
+# slots take over
+_RUN_MAX = 64
+
+
+class DenseKeys(NamedTuple):
+    """What the host read of the key before it asked for the dense
+    form: ``base``, the least key (an int64 scalar: DATA, an argument
+    of the program), ``ascending`` and ``run`` (STATIC: a program is
+    traced for one value of each): a power of two no less than the
+    longest run of equal keys where they ascend in runs of at most
+    ``_RUN_MAX``, else 0."""
+    base: object
+    ascending: bool = False
+    run: int = 0
+
+
+def dense_keys(key_range, capacity: int) -> Optional[DenseKeys]:
+    """``DenseKeys`` from one read of ``dense_key_range``, or None
+    where the span does not fit."""
+    if not dense_fits(key_range, capacity):
+        return None
+    longest = int(key_range[4])
+    run = 1 << (longest - 1).bit_length() if 0 < longest <= _RUN_MAX else 0
+    return DenseKeys(jnp.int64(int(key_range[0])), bool(key_range[3]), run)
+
+
+def _read_dense_keys(batch: Batch, key_names: Sequence[str], live):
+    """An EAGER call's own counted read: ``DenseKeys`` where the dense
+    form fits, else None. Inside a traced program nothing can be read:
+    the caller made the read before (``dense``), or the general path
+    runs."""
+    if isinstance(batch.column(key_names[0]).data, jax.core.Tracer) \
+            or isinstance(live, jax.core.Tracer) \
+            or isinstance(batch.num_rows, jax.core.Tracer):
+        return None
+    from ..obs.trace import active_span
+    with active_span("host_read", site="groupby_key_range"):
+        key_range = jax.device_get(dense_key_range(batch, key_names,
+                                                   live))
+    return dense_keys(key_range, batch.capacity)
+
+
+def dense_group_slots(batch: Batch, key_names: Sequence[str],
+                      aggs: Sequence[AggInput], dense: DenseKeys,
+                      live: Optional[jax.Array] = None,
+                      spanned: Optional[jax.Array] = None):
+    """The dense form before its compaction: ``(slots, exists)``. The
+    batch ``slots`` has ``dense_slots(capacity)`` rows, ALL live: row g
+    is the group of key ``dense.base + g`` (the last row the NULL
+    key's), its aggregates scattered there row by row; ``exists[g]``
+    says whether any live input row fell into it. A caller that filters
+    the groups (a HAVING) masks ``exists`` further and compacts ONCE,
+    what is left (``ops/compact.py compact_batch``);
+    ``group_aggregate`` compacts ``exists`` as it is. Null semantics
+    are the general path's: NULL keys are one group, sum/min/max over
+    no non-NULL input are NULL, count is 0.
+
+    Where the read found the keys ascending in short runs
+    (``dense.run``), a group's rows are adjacent and the SAME pair is
+    made in row space, with no slot and no scatter (``_run_groups``:
+    ``slots`` is then the input's own rows, ``exists`` the last row of
+    each run): on the chip a scatter of 2^26 32-bit updates takes 0.59
+    s and one of 64-bit updates (a DOUBLE or BIGINT sum) 8.5-9.3 s,
+    the row-by-row adds a few milliseconds (PERF.md §6, PR 36).
+
+    ``spanned`` says which rows' keys the host's read covered (default:
+    the live ones). A row that is spanned and not live (a fused filter
+    dropped it) keeps its OWN slot and adds nothing there, so that keys
+    the read found ascending stay ascending indices
+    (``dense.ascending``: the scatters then promise sorted indices, and
+    the chip's compiler sorts nothing); every other row's update goes
+    past the end and is dropped."""
+    cap = batch.capacity
+    n_slots = dense_slots(cap)
+    kcol = batch.column(key_names[0])
+    live = batch.row_valid() if live is None else live
+    spanned = live if spanned is None else spanned
+    if dense.run:
+        return _run_groups(batch, kcol, key_names[0], aggs, dense.run,
+                           live, spanned)
+    slot = jnp.asarray(kcol.data).astype(jnp.int64) \
+        - jnp.asarray(dense.base, jnp.int64)
+    if kcol.valid is not None:
+        slot = jnp.where(jnp.asarray(kcol.valid), slot, n_slots - 1)
+    slot = jnp.where(spanned, slot, n_slots).astype(jnp.int32)
+
+    def scatter(init, updates, op: str = "add"):
+        return getattr(init.at[slot], op)(
+            updates, mode="drop", indices_are_sorted=dense.ascending)
+
+    def count_of(mask):
+        return scatter(jnp.zeros((n_slots,), jnp.int32),
+                       mask.astype(jnp.int32))
+
+    rows = count_of(live)
+    g = jnp.arange(n_slots, dtype=jnp.int64)
+    key = (jnp.asarray(dense.base, jnp.int64) + g).astype(kcol.data.dtype)
+    cols: Dict[str, Column] = {key_names[0]: Column(
+        kcol.type, key,
+        None if kcol.valid is None else g < n_slots - 1)}
+
+    def reduce_(vals, ident, kind):
+        return scatter(jnp.full((n_slots,), ident, vals.dtype), vals,
+                       "add" if kind == "sum" else kind)
+
+    cols.update(_dense_aggregates(batch, aggs, live, rows, count_of,
+                                  reduce_))
+    return Batch(cols, n_slots), rows > 0
+
+
+def _run_groups(batch: Batch, kcol: Column, key_name: str,
+                aggs: Sequence[AggInput], run: int, live, spanned):
+    """``dense_group_slots`` where the spanned keys ASCEND in runs of
+    at most ``run`` rows (no NULL key among them): a group is a run of
+    adjacent rows, its aggregates are made at the run's LAST row by
+    combining the row with its ``run - 1`` predecessors where they
+    hold the same key (shifted, elementwise passes: no scatter, no
+    gather, no scan), and ``exists`` marks that last row where any row
+    of the run is live. The batch returned is row for row the input:
+    the key lane itself, the aggregates beside it."""
+    cap = batch.capacity
+    key = jnp.asarray(kcol.data)
+    pos = jnp.arange(cap, dtype=jnp.int32)
+
+    def back(lane, j):
+        # the lane j rows up (what wraps around is masked by ``same``)
+        return jnp.roll(lane, j)
+
+    # same[j - 1]: the row j up exists, was spanned and holds this key
+    same = [(pos >= j) & back(spanned, j) & (back(key, j) == key)
+            for j in range(1, run)]
+
+    def over_run(vals, ident, op):
+        out = vals
+        for j, s in enumerate(same, 1):
+            out = op(out, jnp.where(s, back(vals, j), ident))
+        return out
+
+    def count_of(mask):
+        return over_run(mask.astype(jnp.int32), jnp.int32(0), jnp.add)
+
+    ahead = jnp.concatenate([spanned[1:] & (key[1:] == key[:-1]),
+                             jnp.zeros((1,), bool)])
+    rows = count_of(live)
+    cols: Dict[str, Column] = {key_name: Column(kcol.type, key, None)}
+    ops = {"sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum}
+    cols.update(_dense_aggregates(
+        batch, aggs, live, rows, count_of,
+        lambda vals, ident, kind: over_run(vals, ident, ops[kind])))
+    return Batch(cols, cap), spanned & ~ahead & (rows > 0)
+
+
+def _dense_aggregates(batch: Batch, aggs: Sequence[AggInput], live, rows,
+                      count_of, reduce_) -> Dict[str, Column]:
+    """The aggregate columns of the dense form, whichever way a group
+    is added up: ``count_of(mask)`` counts a group's rows under a mask
+    (``rows``: under ``live``), ``reduce_(values, identity, kind)``
+    sums, or takes the least or greatest of, a group's values."""
+    from ..types import BIGINT
+    cols: Dict[str, Column] = {}
+    for agg in aggs:
+        mask = live     # the rows this aggregate counts
+        if agg.mask is not None:
+            mask = _agg_row_mask(batch, agg, live)
+        if agg.kind != "count_star":
+            col = batch.column(agg.input)
+            if col.valid is not None:
+                mask = mask & jnp.asarray(col.valid)
+        n = rows if mask is live else count_of(mask)
+        if agg.kind in ("count_star", "count"):
+            cols[agg.output] = Column(BIGINT, n.astype(jnp.int64), None)
+            continue
+        vals, ident, out_type = _scatter_operand(col, agg.kind)
+        cols[agg.output] = Column(
+            out_type, reduce_(jnp.where(mask, vals, ident), ident, agg.kind),
+            n > 0)
+    return cols
+
+
+def _scatter_operand(col: Column, kind: str):
+    """(values in the accumulator's type, the identity, the SQL type of
+    the result) of a sum, min or max over ``col``."""
+    vals = jnp.asarray(col.data)
+    if kind == "sum":
+        acc = vals.dtype if vals.dtype in (
+            jnp.float32, jnp.float64) else jnp.int64
+        return vals.astype(acc), jnp.asarray(0, acc), _sum_type(col.type)
+    return vals, _identity_for(kind, vals.dtype), col.type
+
+
 def group_aggregate(batch: Batch, key_names: Sequence[str],
                     aggs: Sequence[AggInput],
                     groups_capacity: Optional[int] = None,
-                    live: Optional[jax.Array] = None) -> Batch:
+                    live: Optional[jax.Array] = None,
+                    dense: Optional[DenseKeys] = None) -> Batch:
     """GROUP BY key_names with the given aggregates.
 
     Returns a Batch of key columns + aggregate columns, capacity-padded to
@@ -396,13 +709,21 @@ def group_aggregate(batch: Batch, key_names: Sequence[str],
     mask here instead of compacting — compaction's nonzero+gather costs
     seconds at SF1 row counts on TPU).
 
-    Two kernels (the BigintGroupByHash / MultiChannelGroupByHash split of
-    the reference, re-specialized for TPU):
+    Three kernels (the BigintGroupByHash / MultiChannelGroupByHash split
+    of the reference, re-specialized for TPU):
     - packed fast path when every key has a small STATIC domain
       (dictionary codes, bools): group id = packed key, aggregates =
       unrolled masked reductions — no sort, no gather, no scatter, which
       are all pathologically slow on TPU (measured v5e: lexsort 2.5s,
       take 5.1s, segment_sum 0.6s vs masked reduction 29ms at 8M rows).
+    - dense: ONE integer key whose live values span less than
+      ``dense_slots(capacity)`` (``dense_eligible``, ``dense_fits``):
+      group id = key - least key, every aggregate a scatter into the
+      slots (``dense_group_slots``), the slots that hold a group
+      compacted — no sort at all. The least key is DATA: a caller
+      inside a traced program passes it (``dense``, from one counted
+      read of ``dense_key_range``); an eager call reads it here.
+      Where the span does not fit, the general path runs.
     - general path: stable lexsort on key lanes + segment ops.
     """
     cap = batch.capacity
@@ -410,7 +731,17 @@ def group_aggregate(batch: Batch, key_names: Sequence[str],
     fast = _packed_group_aggregate(batch, key_names, aggs, gcap, live,
                                    clamp=groups_capacity is None)
     if fast is not None:
+        note_form("packed", cap)
         return fast
+    if dense is None and dense_eligible(batch, key_names, aggs):
+        dense = _read_dense_keys(batch, key_names, live)
+    if dense is not None:
+        note_form("dense", cap)
+        slots, exists = dense_group_slots(batch, key_names, aggs, dense,
+                                          live)
+        from .compact import compact_batch
+        return compact_batch(slots, exists, gcap)
+    note_form("sort", cap)
     live = batch.row_valid() if live is None else live
 
     lanes = _key_lanes(batch, key_names, live)

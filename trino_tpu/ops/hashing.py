@@ -31,27 +31,51 @@ def mix64(x: jax.Array) -> jax.Array:
     return x
 
 
+def _lanes_by_frexp(safe: jax.Array):
+    m, e = jnp.frexp(safe)
+    return (m * float(1 << 53)).astype(jnp.int64), e.astype(jnp.int64)
+
+
+def _lanes_by_float32_pair(safe: jax.Array):
+    """The chip's float64 IS a pair of float32 (its value their sum):
+    the nearest float32 and what is left of the value are that pair,
+    each bitcast at 32 bits, which the chip's compiler implements (the
+    64-bit bitcast, and ``jnp.frexp``, which has one inside, it
+    refuses). Equal values give equal pairs: the zero of the low word
+    is made positive like the value's own."""
+    hi = safe.astype(jnp.float32)
+    lo = (safe - hi.astype(jnp.float64)).astype(jnp.float32)
+    lo = jnp.where(lo == 0.0, jnp.float32(0.0), lo)
+
+    def bits(x):
+        return jax.lax.bitcast_convert_type(x, jnp.uint32).astype(
+            jnp.int64)
+    return bits(hi) * jnp.int64(1 << 32) + bits(lo), \
+        jnp.zeros(safe.shape, jnp.int64)
+
+
 def float_equality_lanes(d: jax.Array):
     """Exact equality-preserving decomposition of a float lane into two
-    int64 lanes (mantissa*2^53, exponent).
+    int64 lanes: (mantissa*2^53, exponent) from ``jnp.frexp``, or on a
+    TPU (words of the float32 pair, 0).
 
     The natural encoding — bitcast f64->u64 — is NOT implemented by the
-    TPU compiler's x64 rewrite, which is why this uses jnp.frexp. The
-    v5e compiler (jax 0.9.0, PR 23) refuses jnp.frexp on f64 as well —
-    it bitcasts f64->s64 inside — so FLOAT join/group keys do not
-    compile for the chip today (ROADMAP S5); integer, date and
-    dictionary keys never come here. Canonicalizes -0.0 == 0.0 and
-    all NaNs equal (SQL distinct-from semantics, reference:
-    spi/type/DoubleType.java#hash)."""
+    TPU compiler's x64 rewrite, and neither is ``jnp.frexp`` on f64 (it
+    bitcasts f64->s64 inside: v5e compiler, jax 0.9.0, PR 23), so the
+    lanes are made per platform (``lax.platform_dependent``: the
+    lowering for a TPU takes ``_lanes_by_float32_pair``, every other
+    one ``_lanes_by_frexp``; the lanes are compared within one program,
+    never across). Integer, date and dictionary keys never come here.
+    Canonicalizes -0.0 == 0.0 and all NaNs equal (SQL distinct-from
+    semantics, reference: spi/type/DoubleType.java#hash)."""
     d = jnp.asarray(d).astype(jnp.float64)
     d = jnp.where(d == 0.0, 0.0, d)
     isnan = jnp.isnan(d)
     isinf = jnp.isinf(d)
     special = isnan | isinf
     safe = jnp.where(special, 0.0, d)
-    m, e = jnp.frexp(safe)
-    mi = (m * float(1 << 53)).astype(jnp.int64)
-    ex = e.astype(jnp.int64)
+    mi, ex = jax.lax.platform_dependent(
+        safe, tpu=_lanes_by_float32_pair, default=_lanes_by_frexp)
     code = jnp.where(isnan, 1, jnp.where(d > 0, 2, 3))
     mi = jnp.where(special, code.astype(jnp.int64), mi)
     ex = jnp.where(special, jnp.int64(5000), ex)

@@ -136,7 +136,7 @@ def sort_batch(batch: Batch, keys: Sequence[SortKey]) -> Batch:
 
 
 # LIMITs up to this many rows are picked by selection, not by sorting
-TOPN_SELECT_MAX = 64
+TOPN_SELECT_MAX = 128
 
 
 def _select_first(lanes: Sequence[jax.Array], k: int) -> jax.Array:

@@ -251,6 +251,13 @@ def _push(node: PlanNode, conjuncts: List[RowExpr]) -> PlanNode:
     if isinstance(node, JoinNode):
         return _push_join(node, conjuncts)
 
+    if isinstance(node, SemiJoinNode):
+        sunk = _sink_semi_join(node)
+        if sunk is not None:
+            # the join routes the mark's conjuncts to the side that
+            # now carries the mark, and the sinking goes on from there
+            return _push(sunk, conjuncts)
+
     if isinstance(node, (SemiJoinNode, SemiJoinMultiNode)):
         # conjuncts not referencing the mark column push to the source
         mark = node.output
@@ -331,6 +338,31 @@ def _push(node: PlanNode, conjuncts: List[RowExpr]) -> PlanNode:
     if new_sources != node.sources and hasattr(node, "source"):
         node = dc_replace(node, source=new_sources[0])
     return _wrap(node, conjuncts)
+
+
+def _sink_semi_join(node: SemiJoinNode) -> Optional[PlanNode]:
+    """``SemiJoin(Join(a, b))`` -> ``Join(SemiJoin(a), b)`` where the
+    source key comes from ``a`` alone (PredicatePushDown's semi-join
+    case in the reference): ``x IN (subquery)`` reads one column of one
+    relation, so its mark belongs on that relation, below the joins
+    that would otherwise carry every row up to it (TPC-H q18: the IN
+    keeps a few hundred of 15M orders; above the joins it was asked of
+    all 60M lineitem rows joined to orders and customer). The mark is a
+    function of the row's key and of the filtering side only — TRUE,
+    FALSE or NULL per source row, ``_exec_SemiJoinNode`` — so an inner
+    or cross join above or below it sees the same marks. Outer joins
+    stay as they are: a null-extended row's mark is not the key's."""
+    src = node.source
+    if not (isinstance(src, JoinNode)
+            and src.join_type in ("inner", "cross")):
+        return None
+    in_left = node.source_key in src.left.output_schema()
+    in_right = node.source_key in src.right.output_schema()
+    if in_left == in_right:
+        return None
+    if in_left:
+        return dc_replace(src, left=dc_replace(node, source=src.left))
+    return dc_replace(src, right=dc_replace(node, source=src.right))
 
 
 def _push_join(node: JoinNode, conjuncts: List[RowExpr]) -> PlanNode:

@@ -132,8 +132,17 @@ def _derive(node, catalogs, cache):
             return max(l, 1.0), cols
         return max(l, r), cols
     if isinstance(node, SemiJoinNode):
+        # the rows whose mark is TRUE: the share of the source key's
+        # values that the filtering side can hold at most (its rows
+        # over the key's distinct values), never more than the half an
+        # unknown key is given. A filtering side of a few rows makes
+        # the marked relation a SMALL one, so join ordering puts it on
+        # the build side (q18: the orders over 300 against lineitem)
         rows, cols = derive_stats(node.source, catalogs, cache)
-        return rows * 0.5, cols
+        frows, _ = derive_stats(node.filtering_source, catalogs, cache)
+        key = cols.get(node.source_key)
+        ndv = key.ndv if key is not None and key.ndv >= 1.0 else rows
+        return max(rows * min(0.5, frows / max(ndv, 1.0)), 1.0), cols
     if isinstance(node, EnforceSingleRowNode):
         return 1.0, {}
     if isinstance(node, ValuesNode):

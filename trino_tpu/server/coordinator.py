@@ -1313,10 +1313,18 @@ class Coordinator:
     # ---- SystemProvider SPI (connectors/system.py) --------------------
     def node_infos(self) -> list:
         mesh = self._proto.mesh
+        import jax
+        spanned = (list(mesh.devices.flat) if mesh is not None
+                   else jax.local_devices()[:1])
+        limits = [(d.memory_stats() or {}).get("bytes_limit")
+                  for d in spanned]
         nodes = [{"nodeId": self.node_id, "uri": self.base_uri,
                   "nodeVersion": "trino-tpu-0.1", "coordinator": True,
-                  "state": "active",
-                  "devices": 1 if mesh is None else int(mesh.devices.size)}]
+                  "state": "active", "devices": len(spanned),
+                  # what the backend says its chips hold; the CPU
+                  # backend says nothing: NULL
+                  "deviceMemoryBytes": (sum(int(x) for x in limits)
+                                        if all(limits) else None)}]
         detector = getattr(self, "failure_detector", None)
         workers = getattr(self, "workers", None) or []
         for w in workers:
