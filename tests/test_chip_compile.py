@@ -3,10 +3,10 @@ compiler (installed here without the chip) sees the grouped-sum kernel
 at its real shapes, the fused q1 stage program with the kernel in it,
 q6's filter, the two programs of the materialized hash join (the
 expand in both forms of its ``run_positions``), the streamed probe,
-and the mesh executor's exchange and per-shard expand — what it
-refuses fails here at no chip time. Nothing runs, so these say
-nothing about results or speed; ``chip_smoke.py`` is the run on the
-chip.
+and the mesh executor's exchange, per-shard count and per-shard
+expand — what it refuses fails here at no chip time. Nothing runs, so
+these say nothing about results or speed; ``chip_smoke.py`` is the
+run on the chip.
 
 This is the ONLY file that describes the chip. The topology is
 described inside a module-scoped fixture (never at import: every xdist
@@ -168,17 +168,40 @@ def test_hash_join_expand_program_compiles(one_chip,
     assert len(re.findall(r" while\(", compiled.as_text())) == loops
 
 
+def _gathers_by_arm(text, lanes):
+    """For every ``conditional`` of a compiled program's HLO text, how
+    many gathers of ``lanes`` elements each of its arms holds (through
+    the fusions, loops and conditionals it calls), arm 0 the false
+    one: a gather costs the chip per element, so this is what an arm
+    costs."""
+    bodies = dict(re.findall(
+        r"^(?:ENTRY )?(%[\w.\-]+) [^\n]*\{\n(.*?)^\}", text, re.M | re.S))
+    wide = re.compile(r"= \w+\[%d\]\S* gather\(" % lanes)
+
+    def count(name):
+        # a computation's name inside another's body is a call of it
+        body = bodies[name]
+        return len(wide.findall(body)) + sum(
+            count(callee) for callee in set(re.findall(r"%[\w.\-]+", body))
+            if callee in bodies and callee != name)
+
+    return [tuple(count(arm) for arm in arms.split(", "))
+            for arms in re.findall(
+                r" conditional\(.*?branch_computations=\{([^}]*)\}", text)]
+
+
 def test_hash_join_count_program_compiles(one_chip,
                                           no_persistent_cache):
     """Phase 1: the build side sorted on its 64-bit key lane and
     indexed (a scatter-add and two scans: the bucket directory and the
-    run lengths), then the probe (ops/join.py probe_runs: two
-    directory gathers, ONE bisection loop whose trip count is a device
-    value, one run-length gather). The probe side is q3's at sf1; the
-    build side is CUT to 2^12 rows: the sorting network's compile time
-    grows with its size (40 s at 2^20, PERF.md) and this file has to
-    stay fast. What the compiler accepts does not depend on the
-    size."""
+    run lengths), then the probe (ops/join.py probe_runs: ONE
+    directory gather where the build side packed its words — two in
+    the arm that reads the adjacent sums —, ONE bisection loop whose
+    trip count is a device value, one run-length gather). The probe
+    side is q3's at sf1; the build side is CUT to 2^12 rows: the
+    sorting network's compile time grows with its size (40 s at 2^20,
+    PERF.md) and this file has to stay fast. What the compiler accepts
+    does not depend on the size."""
     from trino_tpu.exec.executor import make_mjoin_count_program
     probe, build = _q3_join_sides()
     fn = make_mjoin_count_program(["l_orderkey"], ["o_orderkey"], False)
@@ -187,6 +210,10 @@ def test_hash_join_count_program_compiles(one_chip,
         _as_structs(build, 1 << 12, one_chip)).compile().as_text()
     # one loop, not the two full-depth searches it replaced
     assert len(re.findall(r" while\(", text)) == 1
+    # the bounds: two probe-sized gathers in the plain arm, ONE in the
+    # packed one; the exact arm of the other conditional has none, its
+    # search arm the loop's two (a 64-bit lane) and the run length
+    assert sorted(_gathers_by_arm(text, 1 << 22)) == [(2, 1), (3, 0)]
 
 
 def test_streamed_join_probe_program_compiles(one_chip,
@@ -195,7 +222,10 @@ def test_streamed_join_probe_program_compiles(one_chip,
     same probe against a build side sorted and indexed ONCE outside it,
     plus the expansion at a static capacity (chunk 2^16 into 2^16: the
     histogram form, so the probe's bisection is the program's ONE
-    loop). Build 2^20 (nothing is sorted in here, so it is not cut)."""
+    loop). Build 2^20 (nothing is sorted in here, so it is not cut): a
+    directory of 2^25 entries, past its head, so the bounds are read in
+    one of FOUR arms (whole or head, word or sums), one gather in
+    either packed arm."""
     from trino_tpu.exec.streamjoin import make_probe_program
     from trino_tpu.ops.join import build_side
     probe, build = _q3_join_sides()
@@ -210,7 +240,12 @@ def test_streamed_join_probe_program_compiles(one_chip,
     compiled = jax.jit(fn).lower(_as_structs(probe, cap, one_chip),
                                  bstructs, side).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 30
-    assert len(re.findall(r" while\(", compiled.as_text())) == 1
+    text = compiled.as_text()
+    assert len(re.findall(r" while\(", text)) == 1
+    # the whole-or-head conditional holds the other two: its arms read
+    # one of theirs
+    assert sorted(_gathers_by_arm(text, cap)) == [
+        (2, 1), (2, 1), (3, 0), (3, 3)]
 
 
 @pytest.mark.parametrize("kind", ["counts", "move"])
@@ -263,6 +298,26 @@ def test_mesh_exchange_compiles_for_a_2x2_mesh(topo, no_persistent_cache,
         assert "all-to-all" in text
 
 
+def _q3_sides_on_a_2x2_mesh(topo):
+    """(mesh, q3's join sides, ``lanes(arrays, rows a shard)``: their
+    row-sharded shapes, the replicated live-row counts) for the
+    per-shard join programs of the mesh executor."""
+    from jax.sharding import Mesh, NamedSharding
+    from trino_tpu.parallel import spmd
+    n = 4
+    mesh = Mesh(np.asarray(topo.devices[:n]), (spmd.AXIS,))
+    rows = NamedSharding(mesh, spmd.P(spmd.AXIS))
+    probe, build = _q3_join_sides()
+
+    def lanes(arrays, per):
+        return jax.tree.map(
+            lambda a: _struct((n * per,), jnp.asarray(a).dtype, rows),
+            arrays)
+
+    return mesh, probe, build, lanes, _struct(
+        (n,), jnp.int64, NamedSharding(mesh, spmd.P()))
+
+
 def test_mesh_join_expand_has_no_loop_on_a_2x2_mesh(topo,
                                                     no_persistent_cache):
     """The mesh executor's per-shard expand (exec/distributed.py
@@ -273,20 +328,12 @@ def test_mesh_join_expand_has_no_loop_on_a_2x2_mesh(topo,
     no loop, where the search over the running sums was the cell's
     largest operation (PERF.md PR 35)."""
     from jax import shard_map
-    from jax.sharding import Mesh, NamedSharding
     from trino_tpu.columnar import Batch
     from trino_tpu.exec.distributed import _shard_join
     from trino_tpu.parallel import spmd
     P, AXIS = spmd.P, spmd.AXIS
-    n, per_p, per_b, out_cap = 4, 1 << 23, 1 << 21, 1 << 19
-    mesh = Mesh(np.asarray(topo.devices[:n]), (AXIS,))
-    rows, whole = NamedSharding(mesh, P(AXIS)), NamedSharding(mesh, P())
-    probe, build = _q3_join_sides()
-
-    def lanes(batch, per):
-        return jax.tree.map(
-            lambda a: _struct((n * per,), jnp.asarray(a).dtype, rows),
-            batch.columns)
+    per_p, per_b, out_cap = 1 << 23, 1 << 21, 1 << 19
+    mesh, probe, build, lanes, live = _q3_sides_on_a_2x2_mesh(topo)
 
     def f(pcols, pn, bcols, bn, start, count, order):
         d = jax.lax.axis_index(AXIS)
@@ -295,19 +342,55 @@ def test_mesh_join_expand_has_no_loop_on_a_2x2_mesh(topo,
         return out.columns, jax.lax.all_gather(out.num_rows_device(),
                                                AXIS)
 
-    lane = _struct((n * per_p,), jnp.int64, rows)
-    live = _struct((n,), jnp.int64, whole)
+    lane = lanes(jnp.int64(0), per_p)
     in_specs = (spmd._col_specs(probe.columns, P(AXIS)), P(),
                 spmd._col_specs(build.columns, P(AXIS)), P(),
                 P(AXIS), P(AXIS), P(AXIS))
     text = jax.jit(shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=(P(AXIS), P()),
         check_vma=False)).lower(
-            lanes(probe, per_p), live, lanes(build, per_b), live, lane,
-            lane, _struct((n * per_b,), jnp.int64, rows)
+            lanes(probe.columns, per_p), live,
+            lanes(build.columns, per_b), live, lane, lane,
+            lanes(jnp.int64(0), per_b)
     ).compile().as_text()
     assert " while(" not in text
     assert len(re.findall(r" scatter\(", text)) == 1
+
+
+def test_mesh_join_count_reads_one_word_on_a_2x2_mesh(topo,
+                                                      no_persistent_cache):
+    """The mesh executor's per-shard count program (exec/distributed.py
+    ``build_count``: ``match_runs`` inside ``shard_map``, the mode
+    gathered beside the total) for the described 2x2 mesh, a shard of
+    2^23 lineitem rows as q3 has on the four-chip cell (the build shard
+    CUT to 2^12 rows, as above: the sort's compile time): the probe
+    lowers per shard as on one chip, ONE probe-sized directory gather
+    in the packed arm."""
+    from jax import shard_map
+    from trino_tpu.columnar import Batch
+    from trino_tpu.ops import join as join_ops
+    from trino_tpu.parallel import spmd
+    P, AXIS = spmd.P, spmd.AXIS
+    per_p, per_b = 1 << 23, 1 << 12
+    mesh, probe, build, lanes, live = _q3_sides_on_a_2x2_mesh(topo)
+
+    def f(pcols, pn, bcols, bn):
+        d = jax.lax.axis_index(AXIS)
+        pb, bb = Batch(pcols, pn[d]), Batch(bcols, bn[d])
+        start, count, side = join_ops.match_runs(
+            pb, bb, ["l_orderkey"], ["o_orderkey"])
+        return (start, count, side.order, jax.lax.all_gather(
+            join_ops.total_and_mode(count, side, pb), AXIS))
+
+    text = jax.jit(shard_map(
+        f, mesh=mesh,
+        in_specs=(spmd._col_specs(probe.columns, P(AXIS)), P(),
+                  spmd._col_specs(build.columns, P(AXIS)), P()),
+        out_specs=(P(AXIS), P(AXIS), P(AXIS), P()), check_vma=False)
+    ).lower(lanes(probe.columns, per_p), live,
+            lanes(build.columns, per_b), live).compile().as_text()
+    assert "s64[4,5]" in text      # the one read: five entries a shard
+    assert sorted(_gathers_by_arm(text, per_p)) == [(2, 1), (3, 0)]
 
 
 # ---- ISSUE 36: q18's programs -------------------------------------------

@@ -8,7 +8,12 @@ the suite's virtual CPU devices.
   customer key: dense integer keys) read ``exact`` in their one
   ``host_read[join_total]``, with 0 steps;
 - a join on two columns and a join on a DOUBLE key count none, and
-  answer what numpy counts from the key columns.
+  answer what numpy counts from the key columns;
+- every one of them reads ``packed`` beside it (ISSUE 37: a probe row
+  read its bucket's bounds as ONE directory word), and so do the four
+  joins of a TPC-DS star chain (q7); a build side where one key fills
+  more of a bucket than the word's size bits hold reads 0, and answers
+  the same.
 """
 
 import collections
@@ -77,19 +82,21 @@ def counted() -> tuple:
         sum(v for _k, v in METRICS.counter(name).samples())
         for name in ("trino_tpu_join_probes_total",
                      "trino_tpu_join_exact_probes_total",
-                     "trino_tpu_join_search_steps_total"))
+                     "trino_tpu_join_search_steps_total",
+                     "trino_tpu_join_packed_probes_total"))
 
 
-def execute(co, sql):
-    """(rows, the (steps, exact) of each join's one read, the growth of
-    the three counters)."""
+def execute(co, sql, catalog="tpch"):
+    """(rows, the (steps, exact, packed) of each join's one read, the
+    growth of the four counters)."""
     from trino_tpu.client import StatementClient
     before = counted()
-    res = StatementClient(co.base_uri, catalog="tpch",
+    res = StatementClient(co.base_uri, catalog=catalog,
                           schema="tiny").execute(sql)
     assert res.state == "FINISHED", res.error
     spans = co.tracker.get(res.query_id).trace.all_spans()
-    reads = [(s.attrs.get("steps"), s.attrs.get("exact")) for s in spans
+    reads = [(s.attrs.get("steps"), s.attrs.get("exact"),
+              s.attrs.get("packed")) for s in spans
              if s.name == "host_read"
              and s.attrs.get("site") == "join_total"]
     return res.rows, reads, tuple(
@@ -102,8 +109,8 @@ def test_q3_s_joins_are_both_exact(coordinator, q3):
     mismatches, rel = gaps(rows, answer)
     assert mismatches <= limits["exact_mismatches"]
     assert rel <= limits["max_rel_err"]
-    assert reads == [(0, 1), (0, 1)]
-    assert grew == (2, 2, 0)
+    assert reads == [(0, 1, 1), (0, 1, 1)]
+    assert grew == (2, 2, 0, 2)
 
 
 @pytest.mark.parametrize("key", sorted(HASHED))
@@ -115,17 +122,61 @@ def test_a_hashed_key_counts_no_exact_probe(coordinator, key):
     want = sum(have[tuple(r)]
                for r in execute(coordinator, probe_keys)[0])
     assert rows == [[want]] and want > 0
-    assert len(reads) == 1 and reads[0][1] == 0 and reads[0][0] > 0
-    assert grew[:2] == (1, 0) and grew[2] == reads[0][0]
+    assert len(reads) == 1 and reads[0][1:] == (0, 1) and reads[0][0] > 0
+    assert grew[:2] == (1, 0) and grew[2:] == (reads[0][0], 1)
+
+
+def test_a_star_chain_s_probes_all_read_one_word(coordinator, request):
+    """TPC-DS q7 at ``tiny``: the fact table against four filtered
+    dimensions, every join on a dimension's surrogate key (its answer:
+    tests/test_tpcds_reference.py). The mesh executor shards no tpcds
+    table yet (PERF.md §7): there, TPC-H's chain from the fact table to
+    ``nation``."""
+    if request.node.callspec.params["coordinator"] == "mesh":
+        rows, reads, grew = execute(
+            coordinator,
+            "select n.n_name, count(*) from lineitem l "
+            "join orders o on l.l_orderkey = o.o_orderkey "
+            "join customer c on o.o_custkey = c.c_custkey "
+            "join nation n on c.c_nationkey = n.n_nationkey "
+            "join region r on n.n_regionkey = r.r_regionkey "
+            "group by n.n_name")
+        assert len(rows) == 25
+    else:
+        with open(os.path.join(BENCH, "traffic", "queries", "tpcds",
+                               "q7.sql")) as f:
+            rows, reads, grew = execute(coordinator, f.read(), "tpcds")
+        assert len(rows) > 0
+    assert reads == [(0, 1, 1)] * 4
+    assert grew == (4, 4, 0, 4)
+
+
+def test_a_build_side_with_one_heavy_key_reads_two_sums(coordinator):
+    """Every lineitem row under ONE key value (60,175 of them, where a
+    word at this capacity holds sizes under 2^15): the exact directory
+    still, 0 steps, but no packed probe, and the same count."""
+    rows, reads, grew = execute(
+        coordinator,
+        "select count(*), sum(l.l_quantity) from region r left join "
+        "lineitem l on r.r_regionkey = l.l_linenumber - l.l_linenumber")
+    n, quantity = execute(
+        coordinator, "select count(*), sum(l_quantity) from lineitem")[0][0]
+    assert rows == [[n + 4, quantity]]     # four regions find no row
+    assert reads == [(0, 1, 0)]
+    assert grew == (1, 1, 0, 0)
 
 
 def test_the_one_read_carries_the_join_s_shape(coordinator):
     """``probe_rows`` and ``total`` ride the same int64 vector as
-    ``steps`` and ``exact`` (ISSUE 34); on the mesh they are summed
-    over the shards, so both executors report the join's whole shape."""
+    ``steps`` and ``exact`` (ISSUE 34), and ``packed`` is its fifth
+    entry (ISSUE 37); on the mesh the rows are summed over the shards
+    and ``packed`` holds where every shard's was, so both executors
+    report the join's whole shape, on the span and at ``/metrics``."""
+    import urllib.request
     from trino_tpu.client import StatementClient
     names = ("trino_tpu_join_probe_rows_total",
-             "trino_tpu_join_output_rows_total")
+             "trino_tpu_join_output_rows_total",
+             "trino_tpu_join_packed_probes_total")
 
     def grown():
         return [sum(v for _k, v in METRICS.counter(n).samples())
@@ -141,4 +192,8 @@ def test_the_one_read_carries_the_join_s_shape(coordinator):
              and s.attrs.get("site") == "join_total"]
     assert res.rows == [[15000]] and len(reads) == 1
     assert reads[0]["probe_rows"] == 15000 and reads[0]["total"] == 15000
-    assert [a - b for a, b in zip(grown(), before)] == [15000, 15000]
+    assert reads[0]["packed"] == 1 and reads[0]["exact"] == 1
+    assert [a - b for a, b in zip(grown(), before)] == [15000, 15000, 1]
+    with urllib.request.urlopen(coordinator.base_uri + "/metrics") as r:
+        text = r.read().decode()
+    assert 'trino_tpu_join_packed_probes_total{site="join_total"}' in text

@@ -199,7 +199,8 @@ def _probe_unique(rng):
     cap = 1 << 16
     build = rng.permutation(1 << 20)[:cap] * _FAR
     return (_keys(cap, rng.choice(build, cap)), _keys(cap, build),
-            lambda steps, m, exact: not exact and 0 < steps <= 6)
+            lambda steps, m, exact, packed:
+            packed and not exact and 0 < steps <= 6)
 
 
 def _probe_one_key(rng):
@@ -209,7 +210,8 @@ def _probe_one_key(rng):
     cap = 1 << 10
     return (_keys(cap, rng.integers(5, 9, cap)),
             _keys(2 * cap, np.append(np.full(cap, 7), 7 + _FAR)),
-            lambda steps, m, exact: not exact and steps == 11)  # log2 + 1
+            lambda steps, m, exact, packed:  # log2 + 1
+            packed and not exact and steps == 11)
 
 
 def _probe_lineitem(rng):
@@ -217,37 +219,41 @@ def _probe_lineitem(rng):
     orders = rng.permutation(1 << 16)[:3000] * _FAR
     build = np.repeat(orders, rng.integers(1, 8, orders.size))
     return (_keys(1 << 12, orders), _keys(1 << 14, build[:1 << 14]),
-            lambda steps, m, exact: not exact and 3 <= steps <= 7)
+            lambda steps, m, exact, packed:
+            packed and not exact and 3 <= steps <= 7)
 
 
 def _probe_dead_build(rng):
     return (_keys(64, rng.integers(0, 50, 64)), _keys(32, [], rows=0),
-            lambda steps, m, exact: not exact and steps == 0 and m == 0)
+            lambda steps, m, exact, packed:
+            packed and not exact and steps == 0 and m == 0)
 
 
 def _probe_null_keys(rng):
     return (_keys(64, rng.integers(0, 40, 60) * _FAR, null_at=(0, 7, 59)),
             _keys(128, rng.integers(0, 40, 100) * _FAR,
                   null_at=(3, 4, 99)),
-            lambda steps, m, exact: not exact and m == 97 and steps > 0)
+            lambda steps, m, exact, packed:
+            packed and not exact and m == 97 and steps > 0)
 
 
 def _probe_absent_keys(rng):
     return (_keys(256, rng.integers(1000, 2000, 256) * _FAR),
             _keys(256, rng.integers(0, 1000, 200) * _FAR),
-            lambda steps, m, exact: not exact and 0 < steps <= 4)
+            lambda steps, m, exact, packed:
+            packed and not exact and 0 < steps <= 4)
 
 
 def _probe_smaller(rng):
     return (_keys(8, rng.integers(0, 300, 8)),
             _keys(1 << 12, rng.integers(0, 300, 4000)),
-            lambda steps, m, exact: exact and m == 4000)
+            lambda steps, m, exact, packed: packed and exact and m == 4000)
 
 
 def _probe_larger(rng):
     return (_keys(1 << 14, rng.integers(0, 300, 1 << 14)),
             _keys(16, rng.integers(0, 300, 11)),
-            lambda steps, m, exact: exact and steps == 0)
+            lambda steps, m, exact, packed: packed and exact and steps == 0)
 
 
 def _probe_u64max_lane(rng):
@@ -255,7 +261,7 @@ def _probe_u64max_lane(rng):
     # they must count into no run (see the patched mix64 below)
     return (_keys(32, [0, 1, 2, 0, 5]),
             _keys(32, [0, 3, 0, 1, 0, 2, _FAR]),
-            lambda steps, m, exact: not exact and m == 7)
+            lambda steps, m, exact, packed: packed and not exact and m == 7)
 
 
 def _probe_constant_hash(rng):
@@ -264,7 +270,8 @@ def _probe_constant_hash(rng):
     a, b = rng.integers(0, 9, (2, 100))
     return (_keys(64, a[:50], more=b[:50]), _keys(128, a, more=b),
             # bit_length(100)
-            lambda steps, m, exact: not exact and steps == 7)
+            lambda steps, m, exact, packed:
+            packed and not exact and steps == 7)
 
 
 def _probe_dense_unique(rng):
@@ -273,7 +280,7 @@ def _probe_dense_unique(rng):
     cap = 1 << 12
     return (_keys(cap, rng.integers(-3000, 3000, cap)),
             _keys(cap, rng.permutation(cap) - 2000),
-            lambda steps, m, exact: exact and steps == 0)
+            lambda steps, m, exact, packed: packed and exact and steps == 0)
 
 
 def _probe_dense_duplicates(rng):
@@ -282,7 +289,7 @@ def _probe_dense_duplicates(rng):
     build = np.repeat(orders, rng.integers(1, 8, orders.size))
     return (_keys(1 << 12, np.append(orders, orders[:1000] + 1)),
             _keys(1 << 14, build),
-            lambda steps, m, exact: exact and steps == 0)
+            lambda steps, m, exact, packed: packed and exact and steps == 0)
 
 
 def _probe_outside_range(rng):
@@ -294,7 +301,7 @@ def _probe_outside_range(rng):
                        lo - 2**40, hi + 2**40, hi + (1 << 13),
                        lo + _I64.min, 1500]),
             _keys(128, build),
-            lambda steps, m, exact: exact and steps == 0)
+            lambda steps, m, exact, packed: packed and exact and steps == 0)
 
 
 def _edge(span):
@@ -307,12 +314,16 @@ def _edge(span):
 
 def _probe_range_d_minus_1(rng):
     # the widest range that engages: the last bucket holds a key
-    return _edge(1023) + (lambda steps, m, exact: exact and steps == 0,)
+    return _edge(1023) + (
+        lambda steps, m, exact, packed:
+        packed and exact and steps == 0,)
 
 
 def _probe_range_d(rng):
     # one wider: searched, and as exact
-    return _edge(1024) + (lambda steps, m, exact: not exact and steps > 0,)
+    return _edge(1024) + (
+        lambda steps, m, exact, packed:
+        packed and not exact and steps > 0,)
 
 
 def _probe_int64_extremes(rng):
@@ -321,14 +332,14 @@ def _probe_int64_extremes(rng):
     return (_keys(16, [_I64.max, _I64.min, 0, 1, -1, _I64.min + 1,
                        _I64.max - 1]),
             _keys(16, build),
-            lambda steps, m, exact: not exact and m == 6)
+            lambda steps, m, exact, packed: packed and not exact and m == 6)
 
 
 def _probe_date_key(rng):
     days = rng.integers(8000, 10500, 300)        # 1992 to 1998, int32
     return (_keys(256, rng.integers(7900, 10600, 256), typ=DATE),
             _keys(512, days, typ=DATE),
-            lambda steps, m, exact: exact and steps == 0)
+            lambda steps, m, exact, packed: packed and exact and steps == 0)
 
 
 def _probe_dictionary_key(rng):
@@ -340,7 +351,7 @@ def _probe_dictionary_key(rng):
                               {"k": VARCHAR}),
             batch_from_pylist({"k": pick(90, 20, 50) + [None, None]},
                               {"k": VARCHAR}),
-            lambda steps, m, exact: exact and m == 90)
+            lambda steps, m, exact, packed: packed and exact and m == 90)
 
 
 def _wide(rng, span):
@@ -358,23 +369,89 @@ def _wide(rng, span):
 
 def _probe_wide_directory_head(rng):
     return _wide(rng, (1 << 24) - 1) + (
-        lambda steps, m, exact: exact and steps == 0,)
+        lambda steps, m, exact, packed: packed and exact and steps == 0,)
 
 
 def _probe_wide_directory_past_head(rng):
     return _wide(rng, (1 << 25) - 1) + (
-        lambda steps, m, exact: exact and steps == 0,)
+        lambda steps, m, exact, packed: packed and exact and steps == 0,)
 
 
 def _probe_wide_directory_hashed(rng):
     return _wide(rng, 1 << 25) + (
-        lambda steps, m, exact: not exact and 0 < steps <= 6,)
+        lambda steps, m, exact, packed:
+        packed and not exact and 0 < steps <= 6,)
 
 
 def _probe_null_keys_exact(rng):
     return (_keys(64, rng.integers(0, 40, 60), null_at=(0, 7, 59)),
             _keys(128, rng.integers(0, 40, 100), null_at=(3, 4, 99)),
-            lambda steps, m, exact: exact and m == 97 and steps == 0)
+            lambda steps, m, exact, packed:
+            packed and exact and m == 97 and steps == 0)
+
+
+def _heavy(rng, repeats, span, stride=1):
+    # a build capacity of 2^20 leaves a directory word 11 bits for a
+    # bucket's size: ONE key ``repeats`` times among keys that repeat
+    # a few times each (past 2^11 - 1 the two sums are read, as before
+    # PR 37, and give the same answers)
+    cap, heavy = 1 << 20, 1000
+    build = rng.integers(0, span, cap - 5, dtype=np.int64)
+    build[build == heavy] += 1
+    build[:repeats] = heavy
+    build[-2:] = 0, span - 1
+    probe = np.append(rng.choice(build, 4000),
+                      [heavy, heavy - 1, heavy + 1, -1, span, span - 1, 0])
+    return _keys(1 << 12, probe * stride), _keys(cap, build * stride)
+
+
+def _probe_heavy_key(rng):
+    return _heavy(rng, 1 << 11, 1 << 21) + (
+        lambda steps, m, exact, packed:
+        not packed and exact and steps == 0,)
+
+
+def _probe_heavy_key_fits(rng):
+    # the largest size a word at this capacity holds
+    return _heavy(rng, (1 << 11) - 1, 1 << 21) + (
+        lambda steps, m, exact, packed:
+        packed and exact and steps == 0,)
+
+
+def _probe_heavy_key_past_head(rng):
+    return _heavy(rng, (1 << 11) + 5, (1 << 25) - 1) + (
+        lambda steps, m, exact, packed:
+        not packed and exact and steps == 0,)
+
+
+def _probe_heavy_key_hashed(rng):
+    return _heavy(rng, 1 << 11, 1 << 21, _FAR) + (
+        lambda steps, m, exact, packed:   # bit_length(2^11)
+        not packed and not exact and steps == 12,)
+
+
+def _probe_no_dead_row(rng):
+    # every build row usable: ``left`` reaches m = the capacity, the
+    # widest position a word holds (2^12 << 19 = 2^31)
+    cap = 1 << 12
+    build = rng.integers(0, 3000, cap)
+    return (_keys(256, np.append(rng.integers(-5, 3005, 250),
+                                 [build.max(), build.max() + 1, 1 << 40,
+                                  build.min(), build.min() - 1, _I64.max])),
+            _keys(cap, build),
+            lambda steps, m, exact, packed:
+            packed and exact and m == 1 << 12)
+
+
+def _probe_no_dead_row_hashed(rng):
+    cap = 1 << 12
+    build = rng.integers(0, 3000, cap) * _FAR
+    return (_keys(256, np.append(rng.choice(build, 250),
+                                 [build.max(), build.max() + 1, -1,
+                                  build.min(), 7, _I64.max])),
+            _keys(cap, build),
+            lambda steps, m, exact, packed:
+            packed and not exact and m == 1 << 12 and steps > 0)
 
 
 @pytest.mark.parametrize("case", [
@@ -385,7 +462,9 @@ def _probe_null_keys_exact(rng):
     _probe_range_d, _probe_int64_extremes, _probe_date_key,
     _probe_dictionary_key, _probe_null_keys_exact,
     _probe_wide_directory_head, _probe_wide_directory_past_head,
-    _probe_wide_directory_hashed],
+    _probe_wide_directory_hashed, _probe_heavy_key, _probe_heavy_key_fits,
+    _probe_heavy_key_past_head, _probe_heavy_key_hashed,
+    _probe_no_dead_row, _probe_no_dead_row_hashed],
     ids=lambda c: c.__name__[7:])
 def test_join_probe_equals_searchsorted(case, monkeypatch):
     """The probe (bucket directory; where it is not exact, bounded
@@ -394,7 +473,10 @@ def test_join_probe_equals_searchsorted(case, monkeypatch):
     build lanes OF THE MODE THAT ENGAGED (``key - min`` where the
     directory is exact, the hash otherwise; both computed here),
     whatever the lane's distribution, and ``count`` is the number of
-    equal build keys; mode and steps are what the case says."""
+    equal build keys; mode and steps are what the case says, and so is
+    whether a probe row read its bounds as ONE word (``side.packed``:
+    the fullest bucket's size fits the bits a position leaves) or as
+    the two adjacent sums."""
     if case is _probe_u64max_lane:
         monkeypatch.setattr(
             join_ops, "mix64", lambda x: ~jnp.asarray(x).astype(jnp.uint64))
@@ -439,7 +521,17 @@ def test_join_probe_equals_searchsorted(case, monkeypatch):
     assert np.array_equal(np.asarray(start), lo)
     assert np.array_equal(np.asarray(count2), np.asarray(count))
     assert left.dtype == count.dtype == jnp.int64
-    assert mode_ok(int(side.steps), m, exact), (int(side.steps), m, exact)
+    packed = bool(side.packed)
+    assert mode_ok(int(side.steps), m, exact, packed), (
+        int(side.steps), m, exact, packed)
+    # the word: a bucket's first position above its size, where it fits
+    k = join_ops._size_bits(build.capacity)
+    sums = np.asarray(side.directory).astype(np.int64)
+    sizes = np.append(np.diff(sums), 0)
+    assert packed == bool(sizes.max() < (1 << k))
+    assert side.words.dtype == jnp.uint32
+    if packed:
+        assert np.array_equal(np.asarray(side.words), (sums << k) | sizes)
     if case is not _probe_constant_hash:
         # no lane at all: the build keys equal to each probe key
         vals, n = np.unique(key_b[usable_b], return_counts=True)

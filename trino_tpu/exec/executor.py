@@ -1677,17 +1677,19 @@ class Executor:
         """The count program's one host read (``ops/join.py
         total_and_mode``, one row a shard on the mesh): its output
         total — the largest shard's — and beside it the steps its probe
-        took, whether its directory was exact, on every shard, and the
-        join's shape: the probe side's live rows and the rows it puts
-        out, summed over the shards (``steps``, ``exact``,
+        took, whether its directory was exact and whether a probe row
+        read its bounds as one word, on every shard, and the join's
+        shape: the probe side's live rows and the rows it puts out,
+        summed over the shards (``steps``, ``exact``, ``packed``,
         ``probe_rows`` and ``total`` on the span, counted at /metrics:
         obs/metrics.py observe_span)."""
         with self._host_read("join_total") as sp:
-            total, steps, exact, probe_rows = \
-                np.asarray(tail).reshape(-1, 4).T
+            total, steps, exact, probe_rows, packed = \
+                np.asarray(tail).reshape(-1, 5).T
             if sp is not None:
                 sp.attrs["steps"] = int(steps.max())
                 sp.attrs["exact"] = int(exact.min())
+                sp.attrs["packed"] = int(packed.min())
                 sp.attrs["probe_rows"] = int(probe_rows.sum())
                 sp.attrs["total"] = int(total.sum())
         return int(total.max())
@@ -2422,9 +2424,10 @@ def make_mjoin_count_program(pkeys, bkeys, outer: bool):
     """Phase 1: build-side sort and index + probe match counts + the
     effective output total. Everything downstream of the total is host
     policy (bucket choice, memory reserve, oversized spill), so the
-    program ends exactly at the host-sync boundary: ONE int64[4],
+    program ends exactly at the host-sync boundary: ONE int64[5],
     [total, the probe's bisection steps, whether the directory was
-    exact, the probe side's live rows], read in one transfer
+    exact, the probe side's live rows, whether the probe read one
+    word a row], read in one transfer
     (``_read_join_total``). Output dtypes
     are pinned int64 — they cross into the separately-jitted expand
     program."""

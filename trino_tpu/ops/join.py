@@ -27,9 +27,15 @@ sort + bucket-directory join:
    position, and
 3. every probe row reads its bucket's bounds from the directory
    (``probe_runs``; from its head alone unless an exact range reaches
-   past it). Where exact they ARE its run of equal keys: no search, 0
-   steps, two probe-sized gathers in all (dense surrogate keys, what
-   warehouse joins join on: the "perfect hash join").
+   past it) in ONE gather: an entry is one 32-bit word, the bucket's
+   first position above its SIZE (``BuildSide.words``; the chip pays
+   a gather by the index, not by the byte), wherever the fullest
+   bucket's size fits the bits the position leaves
+   (``BuildSide.packed``, a device value: one key repeated past that
+   reads the two adjacent sums instead). Where exact the bounds ARE
+   the row's run of equal keys: no search, 0 steps, one probe-sized
+   gather in all (dense surrogate keys, what warehouse joins join on:
+   the "perfect hash join").
    Otherwise it bisects inside the bucket for the first entry >= its
    lane (as many steps as the FULLEST bucket needs, a device value —
    2-4 for a uniform hash, log2(capacity)+1 when one key fills a
@@ -138,6 +144,13 @@ def _directory_bits(capacity: int) -> int:
     return min(max(1, (capacity - 1).bit_length()) + 5, 26)
 
 
+def _size_bits(capacity: int) -> int:
+    """The bits of a directory word that hold a bucket's size, static
+    from the build capacity alone: those a position in [0, capacity]
+    leaves of 32 (11 at 2^20, 8 at 2^23, 5 at 2^26)."""
+    return max(32 - capacity.bit_length(), 0)
+
+
 # log2 of the directory's HEAD: a table of up to 2^24 int32 entries
 # (64 MB) is gathered from at 8.6 ns an element on a v5e, whatever the
 # order of the indices; one of 2^25 at 15 (random) to 25 (ascending)
@@ -160,6 +173,9 @@ class BuildSide(NamedTuple):
     #                         needs; 0 where exact
     exact: jax.Array        # bool: a bucket is ONE key value, no search
     base: jax.Array         # uint64: the least usable build key
+    words: jax.Array        # uint32[D+1]: directory[b] above the bucket's
+    #                         size in the low ``_size_bits(cap)`` bits
+    packed: jax.Array       # bool: every bucket's size fits those bits
 
 
 def build_side(batch: Batch, key_names: Sequence[str]) -> BuildSide:
@@ -210,8 +226,15 @@ def build_side(batch: Batch, key_names: Sequence[str]) -> BuildSide:
         jnp.zeros(((1 << bits) + 1,), jnp.int32).at[bucket + 1].add(
             live.astype(jnp.int32), indices_are_sorted=True,
             mode="promise_in_bounds"))
+    # the word a probe row reads both bounds from: entry D, the
+    # out-of-range lane of an exact directory, holds (m, 0)
+    sizes = jnp.concatenate([directory[1:] - directory[:-1],
+                             jnp.zeros((1,), jnp.int32)])
+    fullest = jnp.max(sizes)
+    k = _size_bits(cap)
+    words = ((directory.astype(jnp.uint32) << jnp.uint32(k))
+             | sizes.astype(jnp.uint32))
     # a lower-bound bisection over n entries takes bit_length(n) steps
-    fullest = jnp.max(directory[1:] - directory[:-1])
     steps = jnp.where(exact, 0, jnp.sum(
         (fullest >> jnp.arange(31, dtype=jnp.int32)) > 0, dtype=jnp.int32))
 
@@ -224,7 +247,7 @@ def build_side(batch: Batch, key_names: Sequence[str]) -> BuildSide:
         live, jax.lax.cummin(jnp.where(ends, after, cap), reverse=True)
         - pos, 0)
     return BuildSide(sorted_lane, order, m, directory, run_len, steps,
-                     exact, base)
+                     exact, base, words, fullest < (1 << k))
 
 
 def _at(lane, index):
@@ -246,18 +269,30 @@ def probe_runs(side: BuildSide, key_p, usable_p):
     head_bits = min(size.bit_length() - 1, _HEAD_BITS)
     head = 1 << head_bits
     last = side.sorted_lane.shape[0] - 1
+    k = _size_bits(last + 1)
     lane_p = _lane_of(key_p, side.exact, side.base)
 
-    def bounds(directory):
-        # the bucket's bounds. Where exact, a key outside the build
-        # side's range has a lane of D or more and reads [m, m): its
-        # lower bound
-        top = directory.shape[0] - 1
+    def bounds(top):
+        # the bucket's bounds, from the first ``top + 1`` entries.
+        # Where exact, a key outside the build side's range has a lane
+        # of D or more and reads [m, m): its lower bound
         bucket = jnp.where(
             side.exact, jnp.minimum(lane_p, jnp.uint64(top)),
             lane_p >> jnp.uint64(64 - head_bits)).astype(jnp.int32)
-        return (_at(directory, bucket),
-                _at(directory, jnp.minimum(bucket + 1, top)))
+
+        def word():
+            w = _at(side.words[:top + 1], bucket)
+            lo = (w >> jnp.uint32(k)).astype(jnp.int32)
+            return lo, lo + (w & jnp.uint32((1 << k) - 1)).astype(jnp.int32)
+
+        def pair():
+            directory = side.directory[:top + 1]
+            return (_at(directory, bucket),
+                    _at(directory, jnp.minimum(bucket + 1, top)))
+
+        # one gather where the sizes fit the word, else the two sums (a
+        # branch, as below: a gather costs per element either way)
+        return jax.lax.cond(side.packed, word, pair)
 
     if size > head:
         # every live bucket lies in the head unless an exact range
@@ -265,10 +300,9 @@ def probe_runs(side: BuildSide, key_p, usable_p):
         # at its fast rate
         lo, hi = jax.lax.cond(
             _at(side.directory, head) < side.m,
-            lambda: bounds(side.directory),
-            lambda: bounds(side.directory[:head + 1]))
+            lambda: bounds(size), lambda: bounds(head))
     else:
-        lo, hi = bounds(side.directory)
+        lo, hi = bounds(size)
 
     def step(_, state):
         # lower bound on [lo, hi); ``hit`` is whether the entry ``hi``
@@ -309,14 +343,15 @@ def match_runs(probe: Batch, build: Batch,
 
 
 def total_and_mode(eff, side: BuildSide, probe: Batch):
-    """int64[4], [output rows, the probe's bisection steps, whether the
-    directory was exact, the probe side's live rows]: what a count
-    program hands the host in its ONE read (the executors'
-    ``join_total``)."""
+    """int64[5], [output rows, the probe's bisection steps, whether the
+    directory was exact, the probe side's live rows, whether a probe
+    row read its bounds as one word]: what a count program hands the
+    host in its ONE read (the executors' ``join_total``)."""
     return jnp.stack([jnp.sum(eff).astype(jnp.int64),
                       side.steps.astype(jnp.int64),
                       side.exact.astype(jnp.int64),
-                      probe.num_rows_device()])
+                      probe.num_rows_device(),
+                      side.packed.astype(jnp.int64)])
 
 
 def match_counts(probe: Batch, build: Batch,
