@@ -168,7 +168,7 @@ def test_second_run_through_fresh_executor_is_warm(monkeypatch):
     # run 1 compiled at least one program; run 2 compiled NOTHING
     assert "jit_trace" in _span_names(traces[0])
     assert "jit_trace" not in _span_names(traces[1])
-    assert "device_execute" in _span_names(traces[1])
+    assert "dispatch" in _span_names(traces[1])
     # ...and the EXPLAIN ANALYZE rendering says so
     rendered = "\n".join(exmod.stats_lines(stats[1]))
     assert "cache hit" in rendered

@@ -138,7 +138,7 @@ def test_graft_preserves_ids_and_realigns_clock():
     wk = QueryTrace("task_9.0", trace_id=tid, parent_span_id=psid)
     assert wk.trace_id == co.trace_id
     with wk.span("task_execute", task="t0"):
-        with wk.span("device_execute", cache="chain"):
+        with wk.span("dispatch", cache="chain"):
             time.sleep(0.002)
     wire = wk.to_dicts()                 # what task status ships
     frag = co.record("stage_0_execute", co.origin_s,
@@ -275,10 +275,11 @@ def test_maybe_export_respects_config_and_session(tmp_path):
 # ---------------------------------------------------------------------------
 
 def test_device_time_attribution_on_jitted_dispatch(monkeypatch):
-    """device_ms rides the device_execute/jit_trace spans and the
-    per-node stats, distinct from wall — forced through the fragment
-    jit path (the CPU default would run eagerly and dispatch
-    nothing)."""
+    """A served query's dispatch spans time the host and wait for
+    nothing: no device_ms, no device_s. EXPLAIN ANALYZE waits for each
+    program: device_ms rides its device_execute spans and the per-node
+    stats, distinct from wall — forced through the fragment jit path
+    (the CPU default would run eagerly and dispatch nothing)."""
     monkeypatch.setenv("TRINO_TPU_FRAGMENT_JIT", "1")
     r = LocalQueryRunner(
         session=Session(catalog="tpch", schema="tiny"),
@@ -295,15 +296,21 @@ def test_device_time_attribution_on_jitted_dispatch(monkeypatch):
             walk(d.get("children") or [])
 
     walk(res.trace.to_dicts())
+    served = [d for d in spans if d["name"] == "dispatch"]
+    assert served, "no dispatch span on the warm run"
+    assert not any("device_ms" in (d.get("attrs") or {})
+                   for d in spans)
+    assert not any(s.device_s for s in res.stats)
+    assert all(s.cpu_s >= 0 for s in res.stats)
+    analyzed = r.execute("EXPLAIN ANALYZE " + sql)
+    spans = []
+    walk(analyzed.trace.to_dicts())
     dev = [d for d in spans if d["name"] == "device_execute"]
-    assert dev, "no device_execute span on the warm run"
+    assert dev, "no device_execute span under EXPLAIN ANALYZE"
     assert all("device_ms" in (d.get("attrs") or {}) for d in dev)
     assert any((d["attrs"]["device_ms"] or 0) > 0 for d in dev)
-    # per-node rollup: some node carries device_s > 0 and cpu_s >= 0
-    assert any(s.device_s > 0 for s in res.stats)
-    assert all(s.cpu_s >= 0 for s in res.stats)
-    text = "\n".join(
-        row[0] for row in r.execute("EXPLAIN ANALYZE " + sql).rows)
+    text = "\n".join(row[0] for row in analyzed.rows)
+    # per-node rollup: some node carries device time
     assert "device " in text
 
 
@@ -409,10 +416,11 @@ def test_distributed_trace_single_identity_default_mpp(workers):
     # coordinator pre-minted for its dispatch
     for t in task_spans:
         assert t.get("parentSpanId") in stage_spans
-    # the stage spans carry the attribution rollup
+    # the stage spans carry the attribution rollup; device time only
+    # under EXPLAIN ANALYZE (a served task waits for no program)
     for sp in stage_spans.values():
         attrs = sp.get("attrs") or {}
-        assert "cpu_s" in attrs and "device_ms" in attrs
+        assert "cpu_s" in attrs and "device_ms" not in attrs
     # the workers were BORN with the query's trace id (not merely
     # relabeled at graft time) — only THIS query's tasks, the module
     # fixture's registry still holds earlier tests' tasks
@@ -435,6 +443,12 @@ def test_distributed_explain_analyze_shows_cpu_and_device(workers):
                       r"device ([0-9.]+)ms\]", text)
     assert tags, text
     assert any(float(cpu) > 0 for cpu, _ in tags), tags
+    # the analysis reached the workers: their stage spans time devices
+    flat = []
+    _walk_dicts(res.trace.to_dicts(), flat)
+    stages = [s for s in flat if re.match(r"stage_\d+_execute", s["name"])]
+    assert stages and all("device_ms" in (s.get("attrs") or {})
+                          for s in stages)
 
 
 def test_coordinator_v1_trace_endpoint_e2e(workers):

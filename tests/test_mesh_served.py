@@ -134,10 +134,34 @@ def test_q3_with_both_sides_repartitioned_and_partials_exchanged(
                    kind="repartition") > moved
     programs = [a.get("program", "") for n, a in
                 spans_of(coordinator, res.query_id)
-                if n in ("device_execute", "jit_trace")]
+                if n in ("dispatch", "jit_trace")]
     for kind in ("spmd_exchange_counts", "spmd_exchange",
                  "spmd_join_count", "spmd_join_expand", "spmd_apply"):
         assert any(p.startswith(kind + ":") for p in programs), kind
+
+
+def test_a_repartition_s_exchange_span_closes_on_its_moved_rows(
+        coordinator):
+    """A mesh program's span waits for nothing, so an exchange span
+    ends in ONE ``host_read[exchange_done]`` on its moving program:
+    ``exchange_ms`` still times the exchange. The served query fences
+    no node and ends its execute in one ``host_read[node_rows]``."""
+    res = execute(coordinator, sql_of("q3"),
+                  join_distribution_type="PARTITIONED")
+    trace = coordinator.tracker.get(res.query_id).trace
+    spans = trace.all_spans()
+    exchanges = [s for s in spans if s.name == "exchange"
+                 and s.attrs.get("kind") == "repartition"]
+    assert exchanges
+    for ex in exchanges:
+        last = ex.children[-1]
+        assert (last.name, last.attrs.get("site")) == ("host_read",
+                                                       "exchange_done")
+        assert last.end_s <= ex.end_s
+    sites = [s.attrs.get("site") for s in spans if s.name == "host_read"]
+    assert sites.count("node_rows") == 1
+    assert not {"node_fence", "split_rows"} & set(sites)
+    assert "device_execute" not in {s.name for s in spans}
 
 
 def test_a_repeated_query_compiles_nothing_under_fragment_jit(
@@ -179,7 +203,7 @@ def test_second_execution_traces_nothing_and_hits_the_sharded_cache(
     for cls in CLASSES:
         res = execute(coordinator, sql_of(cls))
         names = [n for n, _ in spans_of(coordinator, res.query_id)]
-        assert "device_execute" in names
+        assert "dispatch" in names and "device_execute" not in names
         assert "jit_trace" not in names and "scan_fill" not in names
     assert counter("trino_tpu_jit_cache_total", cache="spmd",
                    result="miss") == miss
@@ -201,7 +225,7 @@ def test_spans_and_counters_of_a_mesh_query(coordinator):
     sites = {a.get("site") for n, a in spans if n == "host_read"}
     assert {"join_total", "broadcast_rows"} <= sites
     programs = {a["program"].split(":")[0] for n, a in spans
-                if n == "device_execute"}
+                if n == "dispatch"}
     assert {"spmd_broadcast", "spmd_join_count",
             "spmd_join_expand"} <= programs
     with urllib.request.urlopen(coordinator.base_uri + "/metrics",

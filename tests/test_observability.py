@@ -6,7 +6,7 @@ Covers the three layers end-to-end:
   counters) — reference analog: the JMX stats the web UI scrapes;
 - query tracing: span-tree shape for a single-node and a distributed
   query (parse -> plan -> optimize -> execute with jit_trace /
-  device_execute and per-fragment children);
+  dispatch and per-fragment children);
 - rich operator stats + the distributed rollup: worker-reported rows
   summing to coordinator totals, per-fragment EXPLAIN ANALYZE numbers.
 """

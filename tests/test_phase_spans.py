@@ -5,11 +5,16 @@
   new roots exist, are ordered, those of the query thread do not
   overlap, and all of them fit inside the client's latency;
 - each phase's ``_count`` at ``/metrics`` grows by one per query
-  (``host_read`` and ``device_execute`` by their span counts), and every
+  (``host_read`` and ``dispatch`` by their span counts), and every
   sample line matches the regex the benchmark reads ``/metrics`` with;
+- a served query waits on the chip only where its logic reads: no
+  ``device_execute``, no per-node fence, ONE ``host_read[node_rows]``
+  at the end of ``execute``, and its node row counts are EXPLAIN
+  ANALYZE's (ISSUE 38);
 - one clock: under a ``jax.profiler`` session the spans are IN the
   profiler's trace as ``tpusql:<name>`` annotations carrying the query
-  id, with the span's own duration;
+  id, with the span's own duration, and a dispatch's the program of
+  the ``jit_<kind>_<key8>`` module that follows it;
 - deterministic names: q1, q3, q6 planned and lowered in two fresh
   processes give the same program names and the same HLO text.
 """
@@ -36,6 +41,7 @@ SAMPLE = re.compile(r"^([a-zA-Z_:][^ ]*) ([-+0-9.eE]+|NaN)$")
 OLD_ROOTS = ("parse", "plan", "optimize", "execute")
 QUERY_THREAD = ("parse", "plan", "optimize", "execute", "fetch",
                 "persist", "finish")
+CLASSES = ("q1", "q3", "q6")
 FAMILY = "trino_tpu_query_phase_seconds"
 
 
@@ -180,7 +186,7 @@ def test_roots_old_and_new(coordinator, cls):
         return {a["key"]: a["value"] for a in s["attributes"]}.get(key)
 
     dispatches = [s for s in spans
-                  if s["name"] in ("device_execute", "jit_trace")]
+                  if s["name"] in ("dispatch", "jit_trace")]
     assert dispatches
     for s in dispatches:
         program = attr(s, "program")["stringValue"]
@@ -253,7 +259,7 @@ def test_each_phase_counts_once_per_query(coordinator):
         assert grew[phase] == n, (phase, grew)
     # under execute: by the span counts (at tpch.tiny on the CPU q6's
     # masked program is per-query, so each run traces it anew)
-    dispatches = seen["device_execute"] + seen["jit_trace"]
+    dispatches = seen["dispatch"] + seen["jit_trace"]
     assert dispatches > 0 and seen["host_read"] > 0
     for phase in EXECUTE_PHASES:
         assert grew[phase] == seen[phase], (phase, grew, seen)
@@ -346,7 +352,7 @@ def test_a_join_s_expand_program_says_its_form(coordinator):
     res, _lat = served(coordinator, sql_of("q3"))
     spans = coordinator.tracker.get(res.query_id).trace.all_spans()
     dispatches = [s for s in spans
-                  if s.name in ("device_execute", "jit_trace")]
+                  if s.name in ("dispatch", "jit_trace")]
     joins = [s for s in dispatches
              if str(s.attrs.get("program")).startswith("join_expand:")]
     assert len(joins) == 2
@@ -360,6 +366,72 @@ def test_a_join_s_expand_program_says_its_form(coordinator):
     assert expand_form(1 << 25, 1 << 22) == "histogram"
 
 
+# ---------------------------------------------------------------------------
+# what a served query waits for (ISSUE 38)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("cls", CLASSES)
+def test_a_served_query_waits_once_at_the_end_of_execute(coordinator,
+                                                         cls):
+    """No span waits for a program and no plan node is fenced: one
+    ``dispatch`` (or first-call ``jit_trace``) per program counted at
+    ``/metrics``, and ONE ``host_read[node_rows]``, the last child of
+    ``execute``."""
+    start = counts(coordinator)
+    served(coordinator, sql_of(cls))            # warm
+    before = settled_counts(coordinator, start, 1)
+    res, _lat = served(coordinator, sql_of(cls))
+    after = settled_counts(coordinator, before, 1)
+    trace = coordinator.tracker.get(res.query_id).trace
+    names = [s.name for s in trace.all_spans()]
+    sites = [s.attrs.get("site") for s in trace.all_spans()
+             if s.name == "host_read"]
+    assert "device_execute" not in names
+    assert not {"node_fence", "split_rows"} & set(sites), sites
+    assert sites.count("node_rows") == 1
+    execute, = [s for s in trace.roots if s.name == "execute"]
+    last = execute.children[-1]
+    assert (last.name, last.attrs.get("site")) == ("host_read",
+                                                   "node_rows")
+    assert names.count("dispatch") > 0
+    assert after["programs"] - before["programs"] == \
+        names.count("dispatch") + names.count("jit_trace")
+    assert after["device_execute"] == before["device_execute"]
+
+
+def analyzed_rows(lines):
+    """(operator, output rows, input rows or -1) of each stats line of
+    an EXPLAIN ANALYZE (exec/executor.py stats_lines)."""
+    out = []
+    for line in lines:
+        m = re.match(r"(\w+): [0-9.]+ms, (?:in (\d+) rows[^,]*, )?"
+                     r"out (\d+) rows", line)
+        if m:
+            out.append((m.group(1), int(m.group(3)),
+                        int(m.group(2)) if m.group(2) else -1))
+    return out
+
+
+@pytest.mark.parametrize("cls", CLASSES)
+def test_a_served_query_s_node_rows_are_explain_analyze_s(coordinator,
+                                                          cls):
+    """Read once at the end, the row counts are the ones EXPLAIN ANALYZE
+    reads node by node; EXPLAIN ANALYZE still waits for and times each
+    program (``device_execute``, ``device_ms``)."""
+    res, _lat = served(coordinator, sql_of(cls))
+    stats = coordinator.tracker.get(res.query_id).result.stats
+    mine = [(s.name, s.output_rows, s.input_rows) for s in stats]
+    assert mine and all(n >= 0 for _op, n, _in in mine)
+    explained = client(coordinator).execute("EXPLAIN ANALYZE "
+                                            + sql_of(cls))
+    lines = [row[0] for row in explained.rows]
+    assert analyzed_rows(lines) == mine
+    assert any("- device_execute:" in ln and "device_ms=" in ln
+               for ln in lines), lines
+    assert not any("- dispatch:" in ln for ln in lines)
+    assert any("site=node_fence" in ln for ln in lines)
+
+
 def test_the_hook_counts_only_the_fixed_phases():
     from trino_tpu.obs.metrics import (DEVICE_PROGRAMS, HOST_READS,
                                        QUERY_PHASE_SECONDS,
@@ -371,6 +443,10 @@ def test_the_hook_counts_only_the_fixed_phases():
     with tr.span("execute"):
         with tr.span("host_read", site="a site"):
             pass
+        with tr.span("dispatch", cache="join",
+                     program="join_count:1a2b3c4d"):
+            pass
+        # EXPLAIN ANALYZE's name for it counts alike
         with tr.span("device_execute", cache="join",
                      program="join_count:1a2b3c4d"):
             pass
@@ -378,7 +454,7 @@ def test_the_hook_counts_only_the_fixed_phases():
             pass
     assert QUERY_PHASE_SECONDS.count(phase="host_read") == c0 + 1
     assert HOST_READS.value(site="a_site") == r0 + 1   # no space
-    assert DEVICE_PROGRAMS.value(kind="join_count") == p0 + 1
+    assert DEVICE_PROGRAMS.value(kind="join_count") == p0 + 2
     assert QUERY_PHASE_SECONDS.count(phase="schedule") == 0
 
 
@@ -426,6 +502,57 @@ def test_spans_are_annotations_on_the_profiler_s_clock(coordinator,
     assert span_id == by_name["execute"].span_id
     assert abs(dur_ns / 1e6 - by_name["execute"].wall_s * 1e3) < 1.0
     assert time.perf_counter() - t0 < 20
+
+
+@pytest.mark.parametrize("cls", CLASSES)
+def test_a_dispatch_names_the_module_that_follows_it(coordinator,
+                                                     tmp_path, cls):
+    """The host's span and the device's module pair by name on one
+    clock: each ``tpusql:dispatch`` annotation carries its span's
+    ``program=<kind>:<key8>``, and a run of ``jit_<kind>_<key8>``
+    starts after it, one run for each dispatch (XLA:CPU names each
+    op's module at host tracer level 3; on the chip, ``XLA Modules``).
+    The runs are asynchronous: the program dispatched before may still
+    start after the annotation, so the pairing is by name."""
+    import jax
+    served(coordinator, sql_of(cls))            # warm
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 3
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        res, _lat = served(coordinator, sql_of(cls))
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    events = sorted(((ev.start_ns, ev.name, dict(ev.stats))
+                     for plane in data.planes
+                     if plane.name.startswith("/host:")
+                     for line in plane.lines for ev in line.events),
+                    key=lambda e: e[0])
+    runs = {}                   # a module's run: its first op's start
+    for t, _name, stats in events:
+        if stats.get("hlo_module"):
+            runs.setdefault(stats.get("run_id"),
+                            (t, stats["hlo_module"]))
+    runs = sorted(runs.values())
+    spans = [s for s in coordinator.tracker.get(
+        res.query_id).trace.all_spans() if s.name == "dispatch"]
+    dispatches = [(t, stats["program"]) for t, name, stats in events
+                  if name == "tpusql:dispatch"
+                  and stats.get("query_id") == res.query_id]
+    # (by program: the profile reads a hex span id such as "12e4..."
+    # back as a number)
+    assert dispatches and sorted(p for _t, p in dispatches) == sorted(
+        s.attrs["program"] for s in spans)
+    for t, program in dispatches:
+        module = "jit_" + program.replace(":", "_")
+        paired = next((i for i, (t_run, m) in enumerate(runs)
+                       if m == module and t_run >= t), None)
+        assert paired is not None, (program, runs)
+        del runs[paired]            # one run for each dispatch
 
 
 # ---------------------------------------------------------------------------

@@ -110,7 +110,7 @@ def test_over_budget_join_streams_instead_of_raising(monkeypatch):
 def test_one_compiled_program_per_streamed_join(mem_tables):
     """Acceptance: every chunk of a streamed operator shares ONE
     compiled program — one jit_trace span total inside the stream
-    (the first chunk), device_execute for all the rest."""
+    (the first chunk), dispatch for all the rest."""
     sql = ("SELECT count(*), sum(v), sum(w) "
            "FROM memory.default.sprobe "
            "JOIN memory.default.sbuild ON k = bk")
@@ -123,7 +123,7 @@ def test_one_compiled_program_per_streamed_join(mem_tables):
 
     def stream_kids(span, inside, out):
         inside = inside or span.name == "stream_chunk"
-        if inside and span.name in ("jit_trace", "device_execute"):
+        if inside and span.name in ("jit_trace", "dispatch"):
             out.append(span.name)
         for c in span.children:
             stream_kids(c, inside, out)
@@ -138,7 +138,7 @@ def test_one_compiled_program_per_streamed_join(mem_tables):
     # A fully pre-warmed process (cache already holds the program from
     # an earlier test) may even trace zero times.
     assert len(traces) <= 1
-    assert kinds.count("device_execute") >= len(chunks) - 1
+    assert kinds.count("dispatch") >= len(chunks) - 1
 
 
 def _walk(trace):
@@ -256,7 +256,7 @@ def test_streamjoin_hot_shape_recorded_and_aot_compiles(mem_tables):
 
 def _collect_stream_kinds(span, inside, out):
     inside = inside or span.name == "stream_chunk"
-    if inside and span.name in ("jit_trace", "device_execute"):
+    if inside and span.name in ("jit_trace", "dispatch"):
         out.append(span.name)
     for c in span.children:
         _collect_stream_kinds(c, inside, out)

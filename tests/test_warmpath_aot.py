@@ -144,7 +144,7 @@ def test_join_aot_zero_retrace(monkeypatch):
         "WHERE o_totalprice < 123000",
         {"join"})
     assert names.count("jit_trace") == 0, names
-    assert names.count("device_execute") >= 2
+    assert names.count("dispatch") >= 2
 
 
 def test_window_aot_zero_retrace(monkeypatch):

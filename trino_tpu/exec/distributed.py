@@ -128,7 +128,7 @@ class DistributedExecutor(Executor):
     def execute_host(self, node: PlanNode) -> Batch:
         return self._host(self.execute(node))
 
-    def execute(self, node: PlanNode):  # type: ignore[override]
+    def _execute_node(self, node: PlanNode):  # type: ignore[override]
         cancel = getattr(self.session, "cancel", None)
         if cancel is not None and cancel.is_set():
             raise QueryError("Query was canceled")
@@ -185,8 +185,11 @@ class DistributedExecutor(Executor):
             return self._exec_local(node)
         sb = read_table_sharded(conn, node.handle, columns, self.mesh)
         if self.collect_stats and self._frames:
-            with self._host_read("split_rows"):
-                self._frames[-1]["rows"] += sb.total_rows_host()
+            n = sb.num_rows     # per shard, read at the end of execute
+            if self.analyze:
+                with self._host_read("split_rows"):
+                    n = sb.total_rows_host()
+            self._frames[-1]["rows"].append(n)
         # rename connector columns to plan symbols
         cols = {sym: sb.columns[col]
                 for sym, col in node.assignments.items()}
@@ -712,8 +715,12 @@ class DistributedExecutor(Executor):
             (probe.columns, probe.num_rows) + operands, build_prune)
         kept = ShardedBatch(cols, counts, probe.mesh, probe.per_shard_cap)
         if self.collect_stats:
-            self.dynamic_filter_rows = (probe.total_rows_host(),
-                                        kept.total_rows_host())
+            # (rows before, rows kept), read with the node row counts
+            # at the end of execute
+            self._unread.append((
+                lambda kept_rows, before: setattr(
+                    self, "dynamic_filter_rows", (before, kept_rows)),
+                kept.num_rows, [probe.num_rows]))
         return kept
 
     def _dexec_SemiJoinNode(self, node: SemiJoinNode) -> Value:

@@ -101,13 +101,17 @@ class NodeStats:
     compile_s: float = 0.0
     cache_hit: Optional[bool] = None
     # seconds this node's jitted dispatches took on the HOST clock from
-    # dispatch to outputs ready (exec/executor.py _jit_call): an upper
-    # bound on device time — own dispatches only, NOT children's
-    # (unlike wall, which nests)
+    # dispatch to outputs ready (exec/executor.py _jit_call), under
+    # EXPLAIN ANALYZE only (0 in a served query, whose wall is host
+    # time): an upper bound on device time — own dispatches only, NOT
+    # children's (unlike wall, which nests)
     device_s: float = 0.0
     # thread-CPU seconds across this node's execution (includes
     # children, like wall — the two are directly comparable)
     cpu_s: float = 0.0
+
+    def put_rows(self, output_rows: int, input_rows: int) -> None:
+        self.output_rows, self.input_rows = output_rows, input_rows
 
     def to_dict(self) -> dict:
         return {"name": self.name, "detail": self.detail,
@@ -129,6 +133,11 @@ class NodeStats:
             int(d.get("output_bytes", -1)),
             float(d.get("compile_s", 0.0)), d.get("cache_hit"),
             float(d.get("device_s", 0.0)), float(d.get("cpu_s", 0.0)))
+
+
+def _discard_rows(output_rows: int, input_rows: int) -> None:
+    """The row counts of an internal wrapper: its parent's input, no
+    entry of its own."""
 
 
 def _sum_counts(vals: Sequence[int]) -> int:
@@ -374,6 +383,12 @@ class Executor:
         # each node's input flow (children add their output on exit);
         # peak/spill feed the enriched QueryCompletedEvent
         self._frames: List[dict] = []
+        # row counts held as the values the plan left them (a host int
+        # or a device count) until the end of ``execute`` reads them
+        # all in ONE transfer (``_settle_rows``): (put(rows, input
+        # rows), output count, input counts)
+        self._unread: List[tuple] = []
+        self._running = False
         self.peak_reserved_bytes: int = 0
         self.spilled_bytes: int = 0
         # morsel streaming (exec/streamjoin.py): chunks processed and
@@ -387,12 +402,12 @@ class Executor:
         # ragged program — exported in worker task status
         # (raggedBatched) and rolled up by the remote/stage schedulers
         self.ragged_batched: int = 0
-        # device-time attribution (ISSUE 15): seconds this executor's
-        # jitted dispatches took from dispatch to data-ready on the
-        # host clock (_jit_call; an upper bound on device time),
-        # exported as deviceSeconds in worker task status and rolled
-        # up per stage — the number distinct from wall that explains
-        # tensor-engine latency
+        # device-time attribution under EXPLAIN ANALYZE (ISSUE 15):
+        # seconds this executor's jitted dispatches took from dispatch
+        # to data-ready on the host clock (_jit_call; an upper bound on
+        # device time), exported as deviceSeconds in worker task status
+        # and rolled up per stage. 0 in a served query: its device time
+        # is read on the device trace, not waited for
         self.device_s: float = 0.0
         # > 0 while a morsel-streamed chunk loop is driving dispatches
         # (exec/streamjoin.py run_streamed): device timing's block-
@@ -425,8 +440,57 @@ class Executor:
         Session by the runner; None outside a traced query."""
         return getattr(self.session, "trace", None)
 
+    @property
+    def analyze(self) -> bool:
+        """EXPLAIN ANALYZE (its trace says so): every program is waited
+        for and timed, every plan node's rows are read at its end. A
+        served query does neither."""
+        tr = self.trace
+        return tr is not None and tr.analyze
+
     # ------------------------------------------------------------------
     def execute(self, node: PlanNode) -> Batch:
+        """Run ``node``'s plan. With telemetry (a trace or node stats)
+        the outermost call ends in ONE ``host_read[node_rows]``: the
+        plan's output waited for and every node's row count read."""
+        if self._running or not (self.collect_stats
+                                 or self.trace is not None):
+            return self._execute_node(node)
+        self._running = True
+        try:
+            out = self._execute_node(node)
+        finally:
+            self._running = False
+        self._settle_rows(out)
+        return out
+
+    def _settle_rows(self, out) -> None:
+        """The end of a telemetered ``execute``: wait for the plan's
+        output and bring every row count the stats hold back in ONE
+        transfer (``host_read[node_rows]``), then write them into the
+        stats. Under EXPLAIN ANALYZE every count was read at its node's
+        fence: nothing is left to wait for."""
+        pending, self._unread = self._unread, []
+        # a child's count is also its parent's input: each value once
+        held = {id(v): v for _s, o, ins in pending for v in (o, *ins)
+                if not isinstance(v, int)}
+        if not self.analyze:
+            lanes = [] if out is None else [
+                lane for c in out.columns.values()
+                for lane in (c.data, c.valid, c.data2) if lane is not None]
+            with self._host_read("node_rows"):
+                try:
+                    jax.block_until_ready(lanes)
+                except Exception:   # noqa: BLE001 — non-array lanes
+                    pass
+                held = dict(zip(held, jax.device_get(list(held.values()))))
+
+        def rows(v) -> int:
+            return v if isinstance(v, int) else int(np.sum(held[id(v)]))
+        for put, o, ins in pending:
+            put(rows(o), sum(rows(v) for v in ins))
+
+    def _execute_node(self, node: PlanNode) -> Batch:
         cancel = getattr(self.session, "cancel", None)
         if cancel is not None and cancel.is_set():
             # cooperative cancellation between plan nodes (reference:
@@ -455,9 +519,13 @@ class Executor:
         """Time one node's execution and record a NodeStats entry.
         A frame on the stack accumulates this node's input flow: every
         child node adds its own output rows/bytes to the parent frame
-        on exit, and split reads add the scanned rows directly."""
-        frame = {"rows": 0, "bytes": 0, "compile_s": 0.0, "cache": None,
-                 "device_s": 0.0}
+        on exit, and split reads add the scanned rows directly. The
+        row counts stay as the plan holds them (``_unread``) until the
+        end of ``execute`` reads them together; only EXPLAIN ANALYZE
+        reads each node's at its end (``host_read[node_fence]``), so
+        that its wall holds the node's device work."""
+        frame = {"rows": [], "bytes": 0, "compile_s": 0.0,
+                 "cache": None, "device_s": 0.0}
         self._frames.append(frame)
         t0 = time.perf_counter()
         cpu0 = time.thread_time()
@@ -467,16 +535,16 @@ class Executor:
             self._frames.pop()
         if out is None:     # a path that declined: nothing ran
             return None
-        # CPU before the blocking row read below: the host decode of
-        # the output is accounting overhead, not the operator's work
+        # CPU before EXPLAIN ANALYZE's blocking row read below: the host
+        # decode of the output is accounting, not the operator's work
         cpu_s = max(time.thread_time() - cpu0, 0.0)
-        # blocking read for accurate per-node timing
-        with self._host_read("node_fence"):
-            n = (out.total_rows_host()
-                 if hasattr(out, "total_rows_host")
-                 else out.num_rows_host())
+        n = out.num_rows    # a host int, or a device count (per shard)
+        if self.analyze:
+            with self._host_read("node_fence"):
+                n = int(np.sum(np.asarray(n)))
         obytes = sum(_col_bytes(c) for c in out.columns.values())
         name = type(node).__name__.replace("Node", "")
+        put = _discard_rows
         if not name.startswith("_"):
             # internal wrappers (_Pre preloaded batches) are plumbing,
             # not operators — they feed the parent's input, no entry
@@ -486,16 +554,18 @@ class Executor:
                 # operator, the EXPLAIN ANALYZE face of streamjoin.py
                 detail = (f"streamed {frame['stream_chunks']} chunks, "
                           f"{frame.get('stream_h2d', 0)}B h2d")
-            self.stats.append(NodeStats(
+            entry = NodeStats(
                 name, detail, wall_s=time.perf_counter() - t0,
-                output_rows=n,
-                input_rows=frame["rows"], input_bytes=frame["bytes"],
+                input_bytes=frame["bytes"],
                 output_bytes=obytes, compile_s=frame["compile_s"],
                 cache_hit=frame["cache"],
-                device_s=frame["device_s"], cpu_s=cpu_s))
+                device_s=frame["device_s"], cpu_s=cpu_s)
+            self.stats.append(entry)
+            put = entry.put_rows
+        self._unread.append((put, n, frame["rows"]))
         if self._frames:
             parent = self._frames[-1]
-            parent["rows"] += n
+            parent["rows"].append(n)
             parent["bytes"] += obytes
         return out
 
@@ -510,20 +580,18 @@ class Executor:
 
     def _jit_call(self, jitted, args: tuple, cache: str, hit: bool,
                   **attrs):
-        """Invoke a jitted program under a live ``device_execute``
-        (steady state) or ``jit_trace`` (first, cache-miss call: trace
-        + XLA compile + execute) span carrying the program's identity
+        """Invoke a jitted program under a ``dispatch`` (steady state)
+        or ``jit_trace`` (first, cache-miss call: trace + XLA compile +
+        dispatch) span carrying the program's identity
         (``program=<kind>:<key8>``, exec/progkey.py named_jit) and
-        ``attrs``,
-        attributing compile wall to the current node's stats frame.
-        ``device_ms`` is the HOST clock from dispatch to outputs ready
-        (jax dispatch is async, so the wait is ``block_until_ready``):
-        an upper bound on the program's device time, not a device
-        measurement — the profiler's trace holds that, under the same
-        program name. On a sync backend the dispatch itself runs the
-        program. The extra sync only happens under telemetry — the
-        stats fence next to it already syncs per node, so the
-        no-telemetry path keeps jax's async pipeline untouched."""
+        ``attrs``, attributing compile wall to the current node's stats
+        frame. The span runs from the call to its return and waits for
+        nothing: jax's dispatch is async, the program's device time is
+        read on the device trace under the same program name, and what
+        waits is the read that needs an output. Only EXPLAIN ANALYZE
+        waits here (``device_execute``, ``device_ms``: the HOST clock
+        from dispatch to outputs ready, an upper bound on the program's
+        device time, summed per node into ``device_s``)."""
         tr = self.trace
         if tr is None and not self.collect_stats:
             return _call_noting_forms(jitted, args)
@@ -544,7 +612,8 @@ class Executor:
                     sp.attrs["form"] = forms[0][0]
                     sp.attrs["groupby"] = ",".join(
                         f"{f}:{n}" for f, n in forms)
-                if self._stream_depth == 0:
+                if tr is not None and tr.analyze \
+                        and self._stream_depth == 0:
                     # device attribution syncs — inside a streamed
                     # chunk loop that sync would serialize the double-
                     # buffered transfer/compute overlap, so streamed
@@ -587,8 +656,11 @@ class Executor:
         wall = time.perf_counter() - t0
         _M_SPLITS.inc()
         if self.collect_stats and self._frames:
-            with self._host_read("split_rows"):
-                self._frames[-1]["rows"] += b.num_rows_host()
+            n = b.num_rows
+            if self.analyze:
+                with self._host_read("split_rows"):
+                    n = b.num_rows_host()
+            self._frames[-1]["rows"].append(n)
             self._frames[-1]["bytes"] += sum(
                 _col_bytes(c) for c in b.columns.values())
         events = getattr(self.session, "events", None)
@@ -2067,7 +2139,7 @@ class Executor:
     def _semi_join_mark(self, probe: Batch, build: Batch):
         """The mark of ``k IN (build's k)`` per probe row, (data,
         valid), as ONE cached program of the two key lanes (bucket
-        ``join``, kind ``semi_join``: a ``device_execute`` span, a
+        ``join``, kind ``semi_join``: a ``dispatch`` span, a
         name in the trace); eagerly where fragments are not jitted or
         the keys cannot be traced."""
         if self.fragment_jit and self._mjoin_jittable(probe, build):

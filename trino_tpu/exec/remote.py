@@ -758,6 +758,7 @@ class RemoteScheduler:
                     part=st.part, nparts=nparts,
                     properties=dict(session.properties),
                     collect_stats=self.collect_stats,
+                    analyze=trace is not None and trace.analyze,
                     attempt=attempt, spool=spool is not None,
                     # the worker re-derives an absolute deadline from
                     # the remaining budget: its own executor stops
@@ -942,13 +943,16 @@ class RemoteScheduler:
                     if trace is not None:
                         # the pre-minted id becomes the span the
                         # worker's subtree already points at
+                        # device time is the workers' EXPLAIN
+                        # ANALYZE waits; a served task waits for none
+                        dev = ({"device_ms": round(dev_s * 1000, 3)}
+                               if trace.analyze else {})
                         sp = trace.record(
                             f"fragment_{f.fid}_execute", t0, t1,
                             parent=trace_parent, span_id=span_id,
                             worker=wi, task=tid, attempt=attempt,
                             speculative=speculative,
-                            cpu_s=round(cpu_s, 6),
-                            device_ms=round(dev_s * 1000, 3))
+                            cpu_s=round(cpu_s, 6), **dev)
                         trace.graft(sp, status.get("spans") or [])
                 # a remote task IS this engine's split of work: its
                 # completion is the SplitCompleted lifecycle event
@@ -1374,6 +1378,10 @@ class DistributedHostQueryRunner:
             getattr(self.session, "query_id", ""))
         sp = trace.span if trace is not None else null_span
         self.session.trace = trace
+        if analyze:
+            # each program waited for and timed, on the workers too
+            # (the task payload's ``analyze``)
+            trace.analyze = True
         try:
             with sp("plan"):
                 planner = LogicalPlanner(self.catalogs, self.session)

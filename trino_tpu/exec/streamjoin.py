@@ -33,7 +33,7 @@ instead of materializing —
 
 Every chunk shares ONE canonical capacity, so every chunk hits the same
 compiled program (jax specializes per shape under one callable; the
-first chunk traces, the rest are device_execute). Chunk capacity comes
+first chunk traces, the rest are dispatches). Chunk capacity comes
 from ``stream_chunk_rows`` (session) / ``TRINO_TPU_STREAM_CHUNK_ROWS``,
 or is auto-derived from the memory budget when 0.
 

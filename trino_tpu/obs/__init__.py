@@ -12,7 +12,7 @@ Here the same three layers exist TPU-first:
 - ``obs.trace``: a per-query DISTRIBUTED span tree over the whole
   served life of a query (roots submit -> queued -> parse -> plan ->
   optimize -> execute -> fetch -> persist -> finish, respond from the
-  HTTP thread; under execute: jit_trace vs device_execute per named
+  HTTP thread; under execute: jit_trace vs dispatch per named
   program, host_read per blocking device-to-host read, scan_fill) —
   every span carries a real 128-bit-trace/64-bit-span identity, W3C
   ``traceparent`` context propagates into worker task payloads, and
@@ -22,8 +22,10 @@ Here the same three layers exist TPU-first:
   through one hook. On a tensor runtime compilation/dispatch
   overheads dominate (PAPERS.md "Query Processing on Tensor
   Computation Runtimes"), so trace-vs-execute separation is the single
-  most important measurement the JVM engine never needed;
-  ``device_ms`` is the host clock from dispatch to outputs ready, an
+  most important measurement the JVM engine never needed. A span
+  times the host; device time is read on the device trace, except
+  under EXPLAIN ANALYZE, whose ``device_execute`` spans carry
+  ``device_ms``: the host clock from dispatch to outputs ready, an
   upper bound on device time.
 - ``obs.otlp``: stdlib-only OTLP/JSON export of finished traces
   (ResourceSpans shape; file + HTTP sinks, plus the coordinator's

@@ -532,8 +532,9 @@ PHASE_BUCKETS = (0.00001, 0.0001, 0.0005, 0.001, 0.005, 0.025, 0.1,
 QUERY_PHASE_SECONDS = METRICS.histogram(
     "trino_tpu_query_phase_seconds",
     "Wall time of the spans of a served query, by span name (root "
-    "phases submit..finish; device_execute, jit_trace, host_read and "
-    "scan_fill under execute; exchange around each mesh exchange)",
+    "phases submit..finish; dispatch, jit_trace, host_read and "
+    "scan_fill under execute, device_execute under EXPLAIN ANALYZE; "
+    "exchange around each mesh exchange)",
     ("phase",), buckets=PHASE_BUCKETS)
 DEVICE_PROGRAMS = METRICS.counter(
     "trino_tpu_device_programs_total",
@@ -661,7 +662,7 @@ def observe_span(sp) -> None:
             JOIN_PACKED_PROBES.inc_at(site, sp.attrs.get("packed", 0))
             JOIN_PROBE_ROWS.inc_at(site, sp.attrs.get("probe_rows", 0))
             JOIN_OUTPUT_ROWS.inc_at(site, sp.attrs.get("total", 0))
-    elif name in ("device_execute", "jit_trace"):
+    elif name in ("dispatch", "device_execute", "jit_trace"):
         program = str(sp.attrs.get("program")
                       or sp.attrs.get("cache") or "other")
         kind = _label_key(program.split(":", 1)[0])

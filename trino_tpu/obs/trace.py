@@ -13,13 +13,18 @@ stage schedulers pre-mint a span id per dispatched task, ship it as a
 reported subtree back ID-PRESERVING — a worker span is born with the
 query's 128-bit trace id and its true 64-bit parent span id, so the
 merged tree is one distributed trace, not a clock-rebased collage.
-On a tensor runtime the jit_trace/device_execute split is the headline
+On a tensor runtime the jit_trace/dispatch split is the headline
 number — compilation/dispatch dominates latency (PAPERS.md "Query
 Processing on Tensor Computation Runtimes"), and a wall-clock total
-cannot show it; ``device_ms`` on those spans is the HOST clock from
-dispatch to outputs ready — an upper bound on the program's device
-time, not a device measurement — and is what EXPLAIN ANALYZE rolls up
-per stage.
+cannot show it.
+
+One rule: a span times what the HOST does; a program's device time is
+read on the device trace (the ``jit_<kind>_<key8>`` module that follows
+its ``tpusql:dispatch`` annotation, both carrying ``program``). Only
+EXPLAIN ANALYZE (``QueryTrace.analyze``) waits per program:
+``device_execute`` with ``device_ms``, the HOST clock from dispatch to
+outputs ready — an upper bound on the program's device time, not a
+device measurement — which it rolls up per node and stage.
 
 The span list of a served query (ROOT spans, in order; ``PHASES``):
 ``submit`` (POST body read -> tracker.submit returns), ``queued``
@@ -30,10 +35,14 @@ closed on the query thread), ``parse``, ``plan``, ``optimize``,
 after the client is released) on the query thread, and ``respond``
 (payload + JSON + socket write, one per POST or poll that carries data
 or the terminal state) on the HTTP thread. Under ``execute``:
-``device_execute`` / ``jit_trace`` per dispatched program (attrs
-``program=<kind>:<key8>``, ``cache``, ``device_ms``), ``host_read``
-(attr ``site``) per blocking device-to-host read of the executor, and
-``scan_fill`` (attrs ``table``, ``lanes``) per scan-cache miss.
+``dispatch`` / ``jit_trace`` per dispatched program (attrs
+``program=<kind>:<key8>``, ``cache``; no wait: the call to its return),
+``host_read`` (attr ``site``) per blocking device-to-host read of the
+executor — the last one ``node_rows``, the plan's output waited for and
+every node's row count in one transfer — and ``scan_fill`` (attrs
+``table``, ``lanes``) per scan-cache miss. Under EXPLAIN ANALYZE
+``device_execute`` (attr ``device_ms``) takes ``dispatch``'s place and
+``host_read[node_fence]`` ends every plan node.
 
 One clock: every span opened through ``span()`` also enters a
 ``jax.profiler.TraceAnnotation("tpusql:<name>", query_id=, span_id=)``
@@ -69,7 +78,7 @@ from jax.profiler import TraceAnnotation as _Annotation
 ROOT_PHASES = ("submit", "queued", "parse", "plan", "optimize",
                "execute", "fetch", "persist", "respond", "finish")
 EXECUTE_PHASES = ("device_execute", "jit_trace", "host_read",
-                  "scan_fill", "exchange")
+                  "scan_fill", "exchange", "dispatch")
 PHASES = ROOT_PHASES + EXECUTE_PHASES
 ANNOTATION_PREFIX = "tpusql:"
 
@@ -229,8 +238,13 @@ class QueryTrace:
                  trace_id: Optional[str] = None,
                  parent_span_id: Optional[str] = None,
                  on_close: Optional[Callable[[Span], None]] = None,
-                 origin_s: Optional[float] = None):
+                 origin_s: Optional[float] = None,
+                 analyze: bool = False):
         self.query_id = query_id
+        # EXPLAIN ANALYZE: every program is waited for and timed
+        # (``device_execute``, ``device_ms``) and every plan node fenced
+        # — the explicit analysis; a served query waits for neither
+        self.analyze = analyze
         self.trace_id = trace_id or new_trace_id()
         # the REMOTE parent: root spans opened here carry it as their
         # parentSpanId, which is what makes the coordinator-side merge
@@ -313,9 +327,14 @@ class QueryTrace:
             _active_stack().append(self)
             # on the profiler's clock too (a flag test without a
             # profiler session)
+            # a dispatch names its program, so a reader of the
+            # profile pairs it with the ``jit_<kind>_<key8>`` module
+            # that follows on the device
+            named = ({} if "program" not in attrs
+                     else {"program": attrs["program"]})
             sp._ann = _Annotation(ANNOTATION_PREFIX + name,
                                   query_id=self.query_id,
-                                  span_id=sp.span_id)
+                                  span_id=sp.span_id, **named)
             sp._ann.__enter__()
         return sp
 
@@ -450,19 +469,23 @@ def dispatch_span(trace: Optional[QueryTrace], program: str,
                   hit: bool = True, cache: Optional[str] = None,
                   **attrs):
     """The span of ONE device-program dispatch — the single helper
-    behind every dispatch, so each is a span and a count:
-    ``device_execute`` (``hit``) or ``jit_trace`` (first call: trace +
-    compile + run) with ``program=<kind>:<key8>`` and what else the
-    dispatcher knows of the program (``attrs``: a join's expand
-    program carries ``form``). ``trace=None``
-    falls back to the calling thread's active trace; outside a traced
-    query it is a no-op context (``as`` yields None)."""
+    behind every dispatch, so each is a span and a count: ``dispatch``
+    (``hit``: the call to its return, the host's work of handing the
+    program to the device) or ``jit_trace`` (first call: trace +
+    compile + dispatch), with ``program=<kind>:<key8>`` and what else
+    the dispatcher knows of the program (``attrs``: a join's expand
+    program carries ``form``). Under EXPLAIN ANALYZE
+    (``trace.analyze``) a hit is ``device_execute``, and the caller
+    waits for the outputs inside it. ``trace=None`` falls back to the
+    calling thread's active trace; outside a traced query it is a
+    no-op context (``as`` yields None)."""
     if trace is None:
         trace = active_trace()
     if trace is None:
         return nullcontext()
-    return trace.span("device_execute" if hit else "jit_trace",
-                      cache=cache or program.split(":", 1)[0],
+    name = ("jit_trace" if not hit
+            else "device_execute" if trace.analyze else "dispatch")
+    return trace.span(name, cache=cache or program.split(":", 1)[0],
                       program=program, **attrs)
 
 

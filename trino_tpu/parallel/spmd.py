@@ -18,7 +18,7 @@ Mesh programs are cached and named like every other program of the
 engine (exec/progkey.py, bucket "spmd"): ``mesh_program`` keeps ONE
 jitted ``shard_map`` per (kind, key, mesh, operand structure) under the
 name ``spmd_<kind>_<key8>``, so a repeated query traces nothing, and every
-dispatch is a ``device_execute`` / ``jit_trace`` span counted in
+dispatch is a ``dispatch`` / ``jit_trace`` span counted in
 ``trino_tpu_device_programs_total{kind="spmd_<kind>"}``. A caller of
 ``shard_apply`` and its kin that cannot name what its closure captures
 passes ``key=None`` and gets a per-call program
@@ -47,7 +47,7 @@ from jax import shard_map
 
 from ..columnar import Batch, Column
 from ..config import capacity_for
-from ..obs.trace import active_span, dispatch_span
+from ..obs.trace import active_span, active_trace, dispatch_span
 from ..ops.groupby import AggInput, group_aggregate
 from ..ops.hashing import hash_columns
 from .mesh import AXIS, ShardedBatch, row_bytes
@@ -84,14 +84,16 @@ def mesh_program(kind: str, key, mesh, operands,
 
 def mesh_call(kind: str, key, mesh, operands, build, **attrs):
     """Dispatch the mesh program on ``operands`` under its span (which
-    carries ``attrs``). Inside a traced query the outputs are waited
-    for, so the span holds the program's time on the chips and not only
-    its dispatch."""
+    carries ``attrs``). The span times the host's dispatch: the
+    program's time on the chips is read on the device trace, and what
+    waits for it is the read that needs its outputs (an exchange's
+    ``host_read[exchange_done]``). Only EXPLAIN ANALYZE waits here."""
     prog, hit = mesh_program(kind, key, mesh, operands, build)
-    with dispatch_span(None, prog.program, hit, "spmd_" + kind,
+    trace = active_trace()
+    with dispatch_span(trace, prog.program, hit, "spmd_" + kind,
                        **attrs) as sp:
         out = prog(*operands)
-        if sp is not None:
+        if sp is not None and trace.analyze:
             jax.block_until_ready(out)
     return out
 
@@ -269,6 +271,17 @@ def _exchange(sb: ShardedBatch, key, pid_fn, counts: np.ndarray,
     return ShardedBatch(cols, new_counts, sb.mesh, out_cap)
 
 
+def _exchange_done(sp, moved: ShardedBatch) -> ShardedBatch:
+    """Close an ``exchange`` span on its moving program's outputs: ONE
+    ``host_read[exchange_done]``, so the span (``exchange_ms``) times
+    the exchange and not only its dispatch. Outside a traced query
+    nothing waits."""
+    if sp is not None:
+        with active_span("host_read", site="exchange_done"):
+            jax.block_until_ready((moved.columns, moved.num_rows))
+    return moved
+
+
 def _two_phase_exchange(sb: ShardedBatch, key, pid_fn,
                         extra: tuple = ()) -> ShardedBatch:
     with active_span("exchange", kind="repartition") as sp:
@@ -277,7 +290,8 @@ def _two_phase_exchange(sb: ShardedBatch, key, pid_fn,
         if sp is not None:
             sp.attrs["rows"] = int(counts.sum())
             sp.attrs["bytes"] = int(counts.sum()) * row_bytes(sb.columns)
-        return _exchange(sb, key, pid_fn, counts, extra)
+        return _exchange_done(sp, _exchange(sb, key, pid_fn, counts,
+                                            extra))
 
 
 def repartition_by_hash(sb: ShardedBatch,
@@ -517,5 +531,6 @@ def broadcast_sharded(sb: ShardedBatch) -> ShardedBatch:
 
         cols, counts = mesh_call("broadcast", cap, sb.mesh,
                                  (sb.columns, sb.num_rows), build)
-    # every shard holds the same rows; counts[d] all equal the total
-    return ShardedBatch(cols, counts, sb.mesh, cap)
+        # every shard holds the same rows; counts[d] all equal the total
+        return _exchange_done(sp, ShardedBatch(cols, counts, sb.mesh,
+                                               cap))

@@ -73,8 +73,9 @@ class LocalQueryRunner:
                  catalogs: Optional[CatalogManager] = None,
                  mesh=None, collect_node_stats: bool = False):
         # per-node wall/row stats on every query (OperatorStats is
-        # always-on in the reference; here opt-in because the stats
-        # fence adds a device sync per plan node)
+        # always-on in the reference); a served query's row counts come
+        # back in one read at the end of execute, only EXPLAIN ANALYZE
+        # fences each node
         self.collect_node_stats = collect_node_stats
         if catalogs is not None:
             self.catalogs = catalogs
@@ -438,6 +439,9 @@ class LocalQueryRunner:
                 trace, _ = adopt_or_mint(self.session, True,
                                          self.session.query_id)
                 self.session.trace = trace
+            # the explicit analysis: each program waited for and timed
+            # (device_execute, device_ms), each plan node fenced
+            trace.analyze = True
             try:
                 res = self._run_query(inner, collect_stats=True)
             finally:

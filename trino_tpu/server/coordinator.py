@@ -719,7 +719,12 @@ class Coordinator:
                     # before every replacement dispatch)
                     worker_supplier=self.live_workers))
             # per-node wall/row stats feed the web UI's query detail
-            # (OperatorStats is always-on in the reference coordinator)
+            # (OperatorStats is always-on in the reference coordinator,
+            # and drains no pipeline to fill them): a served query's
+            # spans time the host, its row counts come back in ONE
+            # read at the end of execute, and nothing else waits on
+            # the chip for telemetry's sake — only EXPLAIN ANALYZE
+            # fences each node and times each program
             return wrap(LocalQueryRunner(session=session,
                                          catalogs=self._catalogs,
                                          mesh=self._proto.mesh,

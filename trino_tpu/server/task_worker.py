@@ -293,7 +293,8 @@ class _Task:
                     trace = QueryTrace(
                         self.task_id,
                         trace_id=ctx[0] if ctx else None,
-                        parent_span_id=ctx[1] if ctx else None)
+                        parent_span_id=ctx[1] if ctx else None,
+                        analyze=bool(payload.get("analyze")))
                     self.trace_id = trace.trace_id  # tt-lint: ignore[race-attr-write] task-thread-private until done.set() publishes
                 session.trace = trace
                 ex = Executor(runner.catalogs, session,
@@ -1128,6 +1129,7 @@ class RemoteTaskClient:
                         nparts: int,
                         properties: Optional[dict] = None,
                         collect_stats: bool = False,
+                        analyze: bool = False,
                         attempt: int = 0, spool: bool = False,
                         stage: Optional[dict] = None,
                         deadline_s: Optional[float] = None,
@@ -1136,7 +1138,9 @@ class RemoteTaskClient:
                         traceparent: Optional[str] = None):
         """POST a serialized plan fragment + split share (the
         HttpRemoteTask TaskUpdateRequest analog). ``attempt`` tags the
-        task's retry/speculation generation; ``spool`` asks the worker
+        task's retry/speculation generation; ``analyze`` (EXPLAIN
+        ANALYZE) has the worker wait for and time each program and
+        fence each plan node; ``spool`` asks the worker
         to commit completed output pages to its spool. ``stage``
         carries the stage-DAG task context (trino_tpu/stage/): the
         stage id, the attempt-independent exchange key, the output
@@ -1156,6 +1160,8 @@ class RemoteTaskClient:
             "collect_stats": collect_stats,
             "attempt": attempt, "spool": spool,
             "properties": properties or {}}
+        if analyze:
+            body["analyze"] = True
         if stage is not None:
             body["stage"] = stage
         if deadline_s is not None:

@@ -219,7 +219,7 @@ class Session:
     prepared: Dict[str, object] = field(default_factory=dict)
     # telemetry (obs/): the current query's span tree — the runner
     # installs one per query; the executor nests jit_trace /
-    # device_execute children under the open execute span
+    # dispatch children under the open execute span
     trace: Optional[object] = None
     # event fan-out (server/events.py EventListenerManager): when set,
     # the executor fires SplitCompletedEvents from the split-read path

@@ -378,6 +378,7 @@ class StageExecution:
                     part=st.part, nparts=ntasks,
                     properties=dict(session.properties),
                     collect_stats=s.collect_stats,
+                    analyze=trace is not None and trace.analyze,
                     attempt=attempt, spool=True,
                     deadline_s=s._remaining_s(),
                     resource_group=getattr(session, "resource_group",
@@ -500,13 +501,16 @@ class StageExecution:
                     if trace is not None:
                         # the pre-minted id is what the worker's spans
                         # already name as parent: id-preserving merge
+                        # device time is the workers' EXPLAIN
+                        # ANALYZE waits; a served task waits for none
+                        dev = ({"device_ms": round(dev_s * 1000, 3)}
+                               if trace.analyze else {})
                         sp = trace.record(
                             f"stage_{sid}_execute", t0, t1,
                             parent=trace_parent, span_id=span_id,
                             worker=wi, task=tid,
                             attempt=attempt, speculative=speculative,
-                            cpu_s=round(cpu_s, 6),
-                            device_ms=round(dev_s * 1000, 3))
+                            cpu_s=round(cpu_s, 6), **dev)
                         trace.graft(sp, status.get("spans") or [])
             except Exception:   # noqa: BLE001 — telemetry best-effort
                 pass
