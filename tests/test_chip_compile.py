@@ -168,6 +168,43 @@ def test_hash_join_expand_program_compiles(one_chip,
     assert len(re.findall(r" while\(", compiled.as_text())) == loops
 
 
+@pytest.mark.parametrize("outputs,gathers", [
+    (None, 16), (("ss_sold_time_sk", "ss_hdemo_sk"), 5)],
+    ids=["every_lane", "outputs"])
+def test_a_join_s_expand_gathers_the_lanes_it_is_handed(
+        one_chip, no_persistent_cache, outputs, gathers):
+    """TPC-DS q96's first join at SF 10 (store_sales' 2^25 lanes against
+    store, 2^22 output rows), handed every lane of both inputs or the
+    two its plan reads above it (executor.py ``expand_columns``): each
+    lane the program is handed costs a gather of 2^22 indices per 32-bit
+    word and per validity lane. Handed no build lane, the program
+    gathers neither the run starts nor the offsets either: 16 -> 5."""
+    from trino_tpu import BIGINT, VARCHAR, batch_from_pylist
+    from trino_tpu.columnar import Batch
+    from trino_tpu.exec.executor import (expand_columns, expand_lanes,
+                                         make_mjoin_expand_program)
+    probe = batch_from_pylist(
+        {"ss_sold_time_sk": [1, 2], "ss_hdemo_sk": [1, None],
+         "ss_store_sk": [1, None]},
+        dict.fromkeys(("ss_sold_time_sk", "ss_hdemo_sk", "ss_store_sk"),
+                      BIGINT))
+    build = batch_from_pylist({"s_store_sk": [1, 2],
+                               "s_store_name": ["ese", "ation"]},
+                              {"s_store_sk": BIGINT,
+                               "s_store_name": VARCHAR})
+    pcols, bcols, _ = expand_columns(probe.columns, build.columns,
+                                     expand_lanes(outputs))
+    pcap, bcap, out_cap = 1 << 25, 1 << 7, 1 << 22
+    lane = _struct((pcap,), jnp.int64, one_chip)
+    compiled = jax.jit(make_mjoin_expand_program(
+        "inner", None, out_cap)).lower(
+        _as_structs(Batch(pcols, probe.num_rows), pcap, one_chip),
+        _as_structs(Batch(bcols, build.num_rows), bcap, one_chip),
+        lane, lane, _struct((bcap,), jnp.int64, one_chip)).compile()
+    assert len(re.findall(rf"\[{out_cap}\][^=]* gather\(",
+                          compiled.as_text())) == gathers
+
+
 def _gathers_by_arm(text, lanes):
     """For every ``conditional`` of a compiled program's HLO text, how
     many gathers of ``lanes`` elements each of its arms holds (through

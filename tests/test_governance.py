@@ -257,7 +257,8 @@ def test_real_executor_feeds_pool_and_dies_with_trino_error():
     from trino_tpu.exec.executor import QueryError
     from trino_tpu.runner import LocalQueryRunner
     # the tiny-schema join's largest capacity reservation is ~940 KiB
-    # — a 512 KiB pool guarantees the breach
+    # (its two key lanes, which the plan above it reads) — a 512 KiB
+    # pool guarantees the breach
     memory = ClusterMemoryManager(ClusterMemoryPool(1 << 19))
     s = Session(catalog="tpch", schema="tiny")
     # pin the MATERIALIZED path: with morsel streaming engaged this
@@ -268,8 +269,8 @@ def test_real_executor_feeds_pool_and_dies_with_trino_error():
     s.memory = memory.register("qx", kill_fn=lambda m, n: None)
     lr = LocalQueryRunner(session=s)
     with pytest.raises(QueryError) as exc:
-        lr.execute("SELECT count(*) FROM lineitem JOIN orders "
-                   "ON l_orderkey = o_orderkey")
+        lr.execute("SELECT count(*), sum(l_orderkey + o_orderkey) "
+                   "FROM lineitem JOIN orders ON l_orderkey = o_orderkey")
     assert getattr(exc.value, "error_name", None) \
         == "CLUSTER_OUT_OF_MEMORY"
     assert "low-memory killer" in str(exc.value)

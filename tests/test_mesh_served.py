@@ -140,6 +140,42 @@ def test_q3_with_both_sides_repartitioned_and_partials_exchanged(
         assert any(p.startswith(kind + ":") for p in programs), kind
 
 
+@pytest.mark.parametrize("distribution", ["AUTOMATIC", "PARTITIONED"])
+def test_a_mesh_join_s_expand_gathers_the_join_s_outputs_alone(
+        coordinator, reference, distribution):
+    """q3 on the mesh, its build sides broadcast or both sides
+    repartitioned: the answers are the reference's, and each
+    ``spmd_join_expand`` is handed the lanes its join puts out (the
+    plan's ``outputs``) of all its two inputs offer: the ``lanes`` of
+    its dispatch span, ``<kept>/<offered>``."""
+    from trino_tpu.plan.nodes import JoinNode
+    from trino_tpu.runner import LocalQueryRunner
+    from trino_tpu.session import Session
+    gaps, answers, limits = reference
+    res = execute(coordinator, sql_of("q3"),
+                  join_distribution_type=distribution)
+    mismatches, rel = gaps(res.rows, answers["q3"])
+    assert (mismatches, rel <= limits["max_rel_err"]) == (0, True)
+
+    def joins(node):
+        if isinstance(node, JoinNode):
+            yield node
+        for s in node.sources:
+            yield from joins(s)
+
+    plan = LocalQueryRunner(session=Session(
+        catalog="tpch", schema="tiny")).plan_sql(sql_of("q3"))
+    def lanes(j):
+        offered = len(j.left.output_schema()) + len(j.right.output_schema())
+        return f"{len(j.outputs)}/{offered}"
+
+    want = sorted(lanes(j) for j in joins(plan))
+    got = sorted(a["lanes"] for n, a in spans_of(coordinator, res.query_id)
+                 if n in ("dispatch", "jit_trace")
+                 and a.get("program", "").startswith("spmd_join_expand:"))
+    assert got == want == ["5/8", "6/7"]
+
+
 def test_a_repartition_s_exchange_span_closes_on_its_moved_rows(
         coordinator):
     """A mesh program's span waits for nothing, so an exchange span

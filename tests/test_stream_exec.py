@@ -183,7 +183,10 @@ def test_streamed_peak_reported_to_cluster_pool(monkeypatch):
     from trino_tpu.exec.executor import QueryError
     from trino_tpu.server.memory import (ClusterMemoryManager,
                                          ClusterMemoryPool)
-    sql = ("SELECT count(*), sum(l_quantity), sum(o_totalprice) "
+    # the plan above the join reads its keys too: the join puts out all
+    # four lanes, and its output estimate counts them
+    sql = ("SELECT count(*), sum(l_quantity), sum(o_totalprice), "
+           "sum(l_orderkey - o_orderkey) "
            "FROM lineitem JOIN orders ON l_orderkey = o_orderkey")
     expected = _runner().execute(sql).rows
     pool_bytes = 1_200_000      # < the ~3.4MB join-output estimate

@@ -306,6 +306,8 @@ class ValidateDependenciesChecker:
             self._require(node, node.all_keys, env, "grouping keys")
             for gs in node.grouping_sets:
                 self._require(node, gs, env, "grouping set")
+        elif isinstance(node, JoinNode):
+            self._require(node, node.outputs or (), env, "outputs")
         elif isinstance(node, SemiJoinNode):
             self._require(node, [node.source_key],
                           _schema(node.source, memo), "source key")

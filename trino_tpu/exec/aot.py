@@ -120,16 +120,23 @@ def _mjoin_programs(payload: dict) -> list:
     def i64(n: int):
         return jax.ShapeDtypeStruct((n,), np.dtype(np.int64))
 
+    # the count program reads the whole inputs, the expand the lanes the
+    # join puts out and its residual reads (executor.py expand_lanes)
+    lanes = ex.expand_lanes(frag.outputs, frag.filter)
+    eprobe, ebuild = ex.narrow(probe, lanes), ex.narrow(build, lanes)
     ckey = ex.mjoin_count_key(outer, pkeys, bkeys, pspec, bspec,
                               pcap, bcap)
-    ekey = ex.mjoin_expand_key(frag.join_type, repr(frag.filter),
-                               pspec, bspec, pcap, bcap, out_cap)
+    ekey = ex.mjoin_expand_key(
+        frag.join_type, repr(frag.filter),
+        tuple(e for e in pspec if e[0] in eprobe.columns),
+        tuple(e for e in bspec if e[0] in ebuild.columns), pcap, bcap,
+        out_cap)
     return [
         (ckey, ex.make_mjoin_count_program(pkeys, bkeys, outer),
          (probe, build), "join", ex.mjoin_kind(ckey), ckey),
         (ekey, ex.make_mjoin_expand_program(frag.join_type,
                                             frag.filter, out_cap),
-         (probe, build, i64(pcap), i64(pcap), i64(bcap)),
+         (eprobe, ebuild, i64(pcap), i64(pcap), i64(bcap)),
          "join", ex.mjoin_kind(ekey), ekey)]
 
 

@@ -55,8 +55,8 @@ from ..planner.logical import SemiJoinMultiNode
 from ..session import Session
 from ..types import BOOLEAN, BIGINT
 from .executor import (Executor, QueryError, _Pre, _lower_aggregates,
-                       join_verify_filter, make_stream_parts,
-                       read_table_sharded)
+                       expand_columns, expand_lanes, join_verify_filter,
+                       make_stream_parts, narrow, read_table_sharded)
 from .progkey import (PROGRAMS, UNTRACEABLE, canonicalize_nodes,
                       node_fingerprint)
 from .expr import eval_expr, eval_predicate
@@ -508,7 +508,7 @@ class DistributedExecutor(Executor):
             return self._dexec_JoinNode(JoinNode(
                 node.right, node.left, "left",
                 tuple(JoinClause(c.right, c.left) for c in node.criteria),
-                node.filter))
+                node.filter, outputs=node.outputs))
         left = self.execute(node.left)
         right = self.execute(node.right)
         if not isinstance(left, ShardedBatch) and \
@@ -569,7 +569,8 @@ class DistributedExecutor(Executor):
         keeps its run starts, counts and build order ON the shards, one
         blocking read of the per-shard totals (and the probe's mode
         beside them, as on one chip), an expand program at the capacity
-        they give."""
+        they give, handed the lanes the plan above reads (executor.py
+        ``expand_columns``: the same rule as on one chip)."""
         outer = jt == "left"
         filt = node.filter
         pkeys, bkeys = tuple(pkeys), tuple(bkeys)
@@ -577,6 +578,9 @@ class DistributedExecutor(Executor):
                     build.columns, build.num_rows)
         in_specs = (_col_specs(probe.columns, P(AXIS)), P(),
                     _col_specs(build.columns, P(AXIS)), P())
+        pcols, bcols, kept = expand_columns(
+            probe.columns, build.columns, expand_lanes(node.outputs, filt))
+        expand_operands = (pcols, probe.num_rows, bcols, build.num_rows)
 
         def build_count():
             def f(pcols, pn, bcols, bn):
@@ -602,16 +606,21 @@ class DistributedExecutor(Executor):
                 d = jax.lax.axis_index(AXIS)
                 out = _shard_join(Batch(pcols, pn[d]), Batch(bcols, bn[d]),
                                   start, count, order, jt, filt, out_cap,
-                                  pad_cap)
+                                  pad_cap, node.outputs)
                 return out.columns, jax.lax.all_gather(
                     out.num_rows_device(), AXIS)
-            return (f, in_specs + (P(AXIS), P(AXIS), P(AXIS)),
+            return (f, (_col_specs(pcols, P(AXIS)), P(),
+                        _col_specs(bcols, P(AXIS)), P(),
+                        P(AXIS), P(AXIS), P(AXIS)),
                     (P(AXIS), P()))
 
         cols, counts = mesh_call(
-            "join_expand", (jt, repr(filt), out_cap, pad_cap),
-            probe.mesh, operands + (start, count, order), build_expand,
-            form=join_ops.expand_form(probe.per_shard_cap, out_cap))
+            "join_expand", (jt, repr(filt), out_cap, pad_cap,
+                            node.outputs),
+            probe.mesh, expand_operands + (start, count, order),
+            build_expand,
+            form=join_ops.expand_form(probe.per_shard_cap, out_cap),
+            lanes=kept)
         return ShardedBatch(cols, counts, probe.mesh, out_cap + pad_cap)
 
     def _dynamic_filter_probe(self, probe: ShardedBatch, build: Value,
@@ -790,8 +799,10 @@ class DistributedExecutor(Executor):
                     probe2, fb, skeys, fkeys)
             else:
                 start, count, order = join_ops.cross_counts(probe2, fb)
-            cand = join_ops.expand_join(probe2, fb, start, count, order,
-                                        cand_cap, "inner")
+            lanes = expand_lanes((), node.filter)
+            cand = join_ops.expand_join(narrow(probe2, lanes),
+                                        narrow(fb, lanes), start, count,
+                                        order, cand_cap, "inner")
             mask = (eval_predicate(node.filter, cand)
                     if node.filter is not None else cand.row_valid())
             pp = jnp.asarray(cand.column(ppos).data)
@@ -967,42 +978,41 @@ def _trace_concat(a: Batch, b: Batch, out_cap: int) -> Batch:
 
 
 def _shard_join(pb: Batch, bb: Batch, start, count, order, jt: str,
-                filt, out_cap: int, pad_cap: int) -> Batch:
+                filt, out_cap: int, pad_cap: int, outputs=None) -> Batch:
     """Trace-safe single-shard join expansion from the count program's
     run starts, counts and build order (the per-shard body of both
-    join distributions)."""
+    join distributions). ``pb`` and ``bb`` hold the lanes the expand
+    gathers (either may hold none); the capacities are ``count``'s and
+    ``order``'s; what only the residual read leaves after it."""
     outer = jt == "left"
     if filt is None:
         return join_ops.expand_join(pb, bb, start, count, order, out_cap,
                                     "left" if outer else "inner")
+    pcap = count.shape[0]
     ppos = "__probe_pos$"
     pcols = dict(pb.columns)
-    pcols[ppos] = Column(BIGINT,
-                         jnp.arange(pb.capacity, dtype=jnp.int64), None)
+    pcols[ppos] = Column(BIGINT, jnp.arange(pcap, dtype=jnp.int64), None)
     probe2 = Batch(pcols, pb.num_rows)
     cand = join_ops.expand_join(probe2, bb, start, count, order, out_cap,
                                 "inner")
     mask = eval_predicate(filt, cand)
     out = compact.filter_batch(cand, mask)
-    if not outer:
-        return Batch({s: c for s, c in out.columns.items() if s != ppos},
-                     out.num_rows)
     pp = jnp.asarray(out.column(ppos).data)
     live_out = out.row_valid()
-    matched = jnp.zeros((pb.capacity,), bool).at[
+    out = Batch({s: c for s, c in out.columns.items() if s != ppos
+                 and (outputs is None or s in outputs)}, out.num_rows)
+    if not outer:
+        return out
+    matched = jnp.zeros((pcap,), bool).at[
         jnp.where(live_out, pp, 0)].max(live_out)
-    unmatched = pb.row_valid() & ~matched
-    pad_src = compact.filter_batch(pb, unmatched)
-    pad_cols = dict(pad_src.columns)
-    for s, c in bb.columns.items():
-        z = jnp.zeros((pad_src.capacity,), dtype=jnp.asarray(c.data).dtype)
-        pad_cols[s] = Column(c.type, z,
-                             jnp.zeros((pad_src.capacity,), bool),
+    live_p = jnp.arange(pcap, dtype=jnp.int64) < pb.num_rows_device()
+    idx, n_pad = compact.mask_to_gather(live_p & ~matched)
+    pad_cols = dict(narrow(pb, outputs).gather(idx, n_pad).columns)
+    for s, c in narrow(bb, outputs).columns.items():
+        z = jnp.zeros((pcap,), dtype=jnp.asarray(c.data).dtype)
+        pad_cols[s] = Column(c.type, z, jnp.zeros((pcap,), bool),
                              c.dictionary)
-    pad = Batch(pad_cols, pad_src.num_rows)
-    out = Batch({s: c for s, c in out.columns.items() if s != ppos},
-                out.num_rows)
-    return _trace_concat(out, pad, out_cap + pad_cap)
+    return _trace_concat(out, Batch(pad_cols, n_pad), out_cap + pad_cap)
 
 
 def _key_views(cols, keys) -> Dict[str, Column]:

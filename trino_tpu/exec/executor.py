@@ -40,7 +40,7 @@ from ..plan.nodes import (AggregationNode, Aggregate, AssignUniqueIdNode,
                           SemiJoinNode, SetOpNode, SortNode, TableScanNode,
                           TopNNode, UnionNode, ValuesNode, WindowNode)
 from ..planner.logical import SemiJoinMultiNode
-from ..rex import Const, InputRef
+from ..rex import Const, InputRef, input_names
 from ..session import Session
 from ..types import (BIGINT, BOOLEAN, DOUBLE, REAL, DecimalType, Type,
                      is_integral, is_string)
@@ -324,6 +324,47 @@ def join_verify_filter(left_cols, right_cols, pkeys, bkeys, filt):
                      InputRef(bk, right_cols[bk].type)), BOOLEAN)
         for pk, bk in zip(pkeys, bkeys)]
     return and_all(([filt] if filt is not None else []) + eqs)
+
+
+def expand_lanes(outputs, residual=None):
+    """The lanes a join's expand gathers (the reference's
+    left/rightOutputSymbols, PruneJoinColumns): the join's ``outputs``,
+    what its residual filter reads, and the position lanes the outer
+    repair reads; None where the join puts out every lane."""
+    if outputs is None:
+        return None
+    keep = set(outputs) | {_PPOS, _BPOS}
+    if residual is not None:
+        keep |= input_names(residual)
+    return frozenset(keep)
+
+
+def expand_columns(probe_cols, build_cols, lanes):
+    """(probe columns, build columns, ``"<kept>/<offered>"``): the
+    columns of a join's two inputs that its expand is handed — ONE rule
+    for every join path (one chip, the mesh, the streamed probe) — and
+    the ``lanes`` attr of the expand's dispatch span."""
+    offered = len(probe_cols) + len(build_cols)
+    if lanes is not None:
+        probe_cols = {s: c for s, c in probe_cols.items() if s in lanes}
+        build_cols = {s: c for s, c in build_cols.items() if s in lanes}
+    return (probe_cols, build_cols,
+            f"{len(probe_cols) + len(build_cols)}/{offered}")
+
+
+def narrow(b: Batch, lanes) -> Batch:
+    """``b`` with the lanes in ``lanes`` alone (every lane for None)."""
+    if lanes is None:
+        return b
+    return Batch({s: c for s, c in b.columns.items() if s in lanes},
+                 b.num_rows)
+
+
+def _expand_width(probe: Batch, build: Batch, lanes) -> int:
+    """The lanes an expand of ``probe`` and ``build`` puts out: what
+    its memory reservation counts."""
+    p, b, _ = expand_columns(probe.columns, build.columns, lanes)
+    return len(p) + len(b)
 
 
 def _call_noting_forms(jitted, args: tuple):
@@ -1766,19 +1807,46 @@ class Executor:
                 sp.attrs["total"] = int(total.sum())
         return int(total.max())
 
+    def _expand(self, probe: Batch, build: Batch, start, count, order,
+                jt: str, residual, out_cap: int, outputs,
+                criteria=None) -> Batch:
+        """A join's expand, handed only the lanes of ``probe`` and
+        ``build`` that ``expand_lanes`` keeps: the jitted program where
+        the count program ran (``criteria`` given), else eagerly. With a
+        residual, ``jt`` is inner, and the candidates are filtered; the
+        filter's own lanes leave in ``_repair_outer``."""
+        out = None
+        if criteria is not None:
+            out = self._mjoin_expand(probe, build, start, count, order,
+                                     jt, residual, out_cap, criteria,
+                                     outputs)
+        if out is None:
+            lanes = expand_lanes(outputs, residual)
+            out = join_ops.expand_join(narrow(probe, lanes),
+                                       narrow(build, lanes), start, count,
+                                       order, out_cap, jt)
+            if residual is not None:
+                out = compact.filter_batch(
+                    out, eval_predicate(residual, out))
+        return out
+
     def _mjoin_expand(self, probe: Batch, build: Batch, start, count,
                       order, jt: str, residual, out_cap: int,
-                      criteria=None) -> Optional[Batch]:
-        """Jitted expand phase (+ fused residual filter). On first
-        success the join's full two-program shape is recorded into the
-        hot-shape registry (exec/hotshapes.py) so exec/aot.py can
-        pre-compile BOTH phases into these same cache slots."""
+                      criteria, outputs) -> Optional[Batch]:
+        """Jitted expand phase (+ fused residual filter) over the lanes
+        ``expand_lanes`` keeps of the two inputs. On first success the
+        join's full two-program shape is recorded into the hot-shape
+        registry (exec/hotshapes.py) so exec/aot.py can pre-compile
+        BOTH phases into these same cache slots."""
         if not (self.fragment_jit
                 and self._mjoin_jittable(probe, build)):
             return None
         from .streamjoin import _join_payload, _lane_spec
-        key = mjoin_expand_key(jt, repr(residual), _lane_spec(probe),
-                               _lane_spec(build), probe.capacity,
+        pcols, bcols, kept = expand_columns(
+            probe.columns, build.columns, expand_lanes(outputs, residual))
+        p, b = Batch(pcols, probe.num_rows), Batch(bcols, build.num_rows)
+        key = mjoin_expand_key(jt, repr(residual), _lane_spec(p),
+                               _lane_spec(b), probe.capacity,
                                build.capacity, out_cap)
         got = PROGRAMS.program(
             "join", key,
@@ -1787,30 +1855,31 @@ class Executor:
         if got is None:
             return None
         jitted, hit = got
-        args = (probe, build, jnp.asarray(start, jnp.int64),
+        args = (p, b, jnp.asarray(start, jnp.int64),
                 jnp.asarray(count, jnp.int64),
                 jnp.asarray(order, jnp.int64))
         try:
             out = self._jit_call(
                 jitted, args, "join", hit,
-                form=join_ops.expand_form(probe.capacity, out_cap))
+                form=join_ops.expand_form(probe.capacity, out_cap),
+                lanes=kept)
         except UNTRACEABLE:
             PROGRAMS.deny("join", key)
             return None
-        if criteria is not None:
-            from .hotshapes import record_program
+        from .hotshapes import record_program
 
-            def build_pl():
-                return _join_payload(jt, criteria, residual, probe,
-                                     build, out_cap, kind="join")
-            # the registry key carries the join keys too: two joins
-            # sharing lane specs share the expand program but each
-            # needs its own count program compiled
-            record_program(
-                "join",
-                ("mjoin", tuple(c.left for c in criteria),
-                 tuple(c.right for c in criteria), key),
-                None, None, self.session, payload_fn=build_pl)
+        def build_pl():
+            return _join_payload(jt, criteria, residual, probe, build,
+                                 out_cap, kind="join", outputs=outputs)
+        # the registry key carries the join keys and both inputs' lanes
+        # too: two joins sharing an expand program each need their own
+        # count program compiled
+        record_program(
+            "join",
+            ("mjoin", tuple(c.left for c in criteria),
+             tuple(c.right for c in criteria), key, _lane_spec(probe),
+             _lane_spec(build)),
+            None, None, self.session, payload_fn=build_pl)
         return out
 
     def _exec_JoinNode(self, node: JoinNode) -> Batch:
@@ -1820,7 +1889,7 @@ class Executor:
                                tuple(join_ops and
                                      _flip_clause(c)
                                      for c in node.criteria),
-                               node.filter)
+                               node.filter, outputs=node.outputs)
             return self._exec_JoinNode(flipped)
         # beyond-HBM probe streaming (exec/streamjoin.py): when the
         # probe side is a scan chain whose working set exceeds the
@@ -1838,14 +1907,18 @@ class Executor:
         # reuse that batch instead of executing node.right twice
         right = (pre_built if pre_built is not None
                  else self.execute(node.right))
+        outputs = node.outputs
 
         if jt == "cross" or not node.criteria:
-            return self._cross_join(left, right, node.filter, jt)
+            return self._cross_join(left, right, node.filter, jt, outputs)
 
         pkeys = [c.left for c in node.criteria]
         bkeys = [c.right for c in node.criteria]
         filt = join_verify_filter(left.columns, right.columns,
                                   pkeys, bkeys, node.filter)
+        # the count program reads the keys of the whole inputs; the
+        # expand is handed the lanes the plan above reads (and, with a
+        # residual, the filter's inputs): ``_expand``
         if filt is None:
             outer = jt in ("left", "full")
             counted = self._mjoin_counts(left, right, pkeys, bkeys,
@@ -1862,7 +1935,7 @@ class Executor:
                     if outer else count
                 with self._host_read("join_total"):
                     total = int(jnp.sum(eff))
-            width = len(left.columns) + len(right.columns)
+            width = _expand_width(left, right, expand_lanes(outputs))
             if total > CONFIG.max_batch_rows:
                 if eff is None:
                     eff = jnp.where(left.row_valid(),
@@ -1870,23 +1943,17 @@ class Executor:
                         if outer else count
                 out = self._oversized_join(
                     left, right, start, count, eff, order, total,
-                    width, "left" if outer else "inner")
+                    width, "left" if outer else "inner", outputs=outputs)
             else:
                 self._reserve(total, width, "join output")
-                cap = capacity_for(total)
-                out = None
-                if counted is not None:
-                    out = self._mjoin_expand(
-                        left, right, start, count, order,
-                        "left" if outer else "inner", None, cap,
-                        criteria=node.criteria)
-                if out is None:
-                    out = join_ops.expand_join(
-                        left, right, start, count, order, cap,
-                        "left" if outer else "inner")
+                out = self._expand(
+                    left, right, start, count, order,
+                    "left" if outer else "inner", None,
+                    capacity_for(total), outputs,
+                    node.criteria if counted is not None else None)
             if jt == "full":
                 out = self._append_right_unmatched(
-                    out, left, right, pkeys, bkeys)
+                    out, left, right, pkeys, bkeys, outputs)
             return out
         # residual filter: expand as inner candidates with probe+build
         # position tracks, filter, then repair unmatched outer rows from
@@ -1904,25 +1971,17 @@ class Executor:
                 probe, build, pkeys, bkeys)
             with self._host_read("join_total"):
                 total = int(jnp.sum(count))
-        width = len(probe.columns) + len(build.columns)
+        width = _expand_width(probe, build, expand_lanes(outputs, filt))
         if total > CONFIG.max_batch_rows and jt == "inner":
             out = self._oversized_join(probe, build, start, count, count,
                                        order, total, width, "inner",
-                                       residual=filt)
-            return self._repair_outer(out, left, right, jt)
+                                       residual=filt, outputs=outputs)
+            return self._repair_outer(out, left, right, jt, outputs)
         self._reserve(total, width, "join candidates")
-        cap = capacity_for(total)
-        out = None
-        if counted is not None:
-            out = self._mjoin_expand(probe, build, start, count, order,
-                                     "inner", filt, cap,
-                                     criteria=node.criteria)
-        if out is None:
-            cand = join_ops.expand_join(probe, build, start, count,
-                                        order, cap, "inner")
-            mask = eval_predicate(filt, cand)
-            out = compact.filter_batch(cand, mask)
-        return self._repair_outer(out, left, right, jt)
+        out = self._expand(probe, build, start, count, order, "inner",
+                           filt, capacity_for(total), outputs,
+                           node.criteria if counted is not None else None)
+        return self._repair_outer(out, left, right, jt, outputs)
 
     def _reserve(self, rows: int, n_lanes: int, what: str) -> None:
         limit = int(self.session.get("query_max_memory_per_node"))
@@ -1970,7 +2029,7 @@ class Executor:
 
     def _oversized_join(self, probe: Batch, build: Batch, start, count,
                         eff, order, total: int, width: int,
-                        jt: str, residual=None) -> Batch:
+                        jt: str, residual=None, outputs=None) -> Batch:
         """Join whose output exceeds the per-batch device budget:
         expand probe-row chunks device-side and accumulate the results
         in HOST memory (the spiller role — reference:
@@ -1981,6 +2040,8 @@ class Executor:
         the memory guard fires."""
         if not bool(self.session.get("spill_enabled")):
             self._reserve(total, width, "join output (spill disabled)")
+        lanes = expand_lanes(outputs, residual)
+        kprobe, kbuild = narrow(probe, lanes), narrow(build, lanes)
         with self._host_read("join_spill"):
             eff_np = np.asarray(eff)
             cum = np.cumsum(eff_np)
@@ -2000,12 +2061,12 @@ class Executor:
             sel = jnp.arange(lo, hi, dtype=jnp.int64)
             # gathered rows are live iff their original position was in
             # the live prefix — gathered liveness is again a prefix
-            sub_probe = probe.gather(sel, max(min(n_live, hi) - lo, 0))
+            sub_probe = kprobe.gather(sel, max(min(n_live, hi) - lo, 0))
             sub_start = jnp.take(jnp.asarray(start), sel)
             sub_count = jnp.take(jnp.asarray(count), sel)
             cap = capacity_for(max(chunk_rows, 1))
             out = join_ops.expand_join(
-                sub_probe, build, sub_start, sub_count, order, cap, jt)
+                sub_probe, kbuild, sub_start, sub_count, order, cap, jt)
             consumed += chunk_rows
             lo = hi
             if residual is not None:
@@ -2025,12 +2086,12 @@ class Executor:
             chunks.append(spilled)
         if not chunks:
             return _to_host(join_ops.expand_join(
-                probe, build, jnp.asarray(start),
+                kprobe, kbuild, jnp.asarray(start),
                 jnp.zeros_like(jnp.asarray(count)), order, 8, jt), 0)
         return _host_concat(chunks, sum(c.num_rows for c in chunks))
 
     def _cross_join(self, left: Batch, right: Batch, filt,
-                    jt: str = "inner") -> Batch:
+                    jt: str = "inner", outputs=None) -> Batch:
         """Cross / non-equi join (no equi criteria). For left/full outer
         variants, probe/build positions are tracked through the filter so
         unmatched rows null-extend (JoinNode with empty criteria in
@@ -2038,19 +2099,17 @@ class Executor:
         with self._host_read("cross_rows"):
             nl, nr = left.num_rows_host(), right.num_rows_host()
         total = nl * nr
-        self._reserve(total, len(left.columns) + len(right.columns),
+        self._reserve(total, _expand_width(left, right,
+                                           expand_lanes(outputs, filt)),
                       "cross join output")
         cap = capacity_for(max(total, 1))
         probe = self._with_pos(left, _PPOS) if jt in ("left", "full") \
             else left
         build = self._with_pos(right, _BPOS) if jt == "full" else right
         start, count, order = join_ops.cross_counts(probe, build)
-        out = join_ops.expand_join(probe, build, start, count, order,
-                                   cap, "inner")
-        if filt is not None:
-            mask = eval_predicate(filt, out)
-            out = compact.filter_batch(out, mask)
-        return self._repair_outer(out, left, right, jt)
+        out = self._expand(probe, build, start, count, order, "inner",
+                           filt, cap, outputs)
+        return self._repair_outer(out, left, right, jt, outputs)
 
     def _with_pos(self, b: Batch, name: str) -> Batch:
         cols = dict(b.columns)
@@ -2059,71 +2118,71 @@ class Executor:
         return Batch(cols, b.num_rows)
 
     def _repair_outer(self, out: Batch, left: Batch, right: Batch,
-                      jt: str) -> Batch:
-        """Strip position lanes; null-extend outer rows whose matches
-        all died in the filter (surviving-match repair)."""
+                      jt: str, outputs=None) -> Batch:
+        """Strip position lanes and the lanes only the residual read
+        (the join puts out ``outputs``); null-extend outer rows whose
+        matches all died in the filter (surviving-match repair)."""
         live_out = out.row_valid()
         pp = (jnp.asarray(out.column(_PPOS).data)
               if jt in ("left", "full") else None)
         bb = (jnp.asarray(out.column(_BPOS).data)
               if jt == "full" else None)
-        if pp is not None or bb is not None:
-            out = Batch({s: c for s, c in out.columns.items()
-                         if s not in (_PPOS, _BPOS)}, out.num_rows)
+        out = Batch({s: c for s, c in out.columns.items()
+                     if s not in (_PPOS, _BPOS)
+                     and (outputs is None or s in outputs)}, out.num_rows)
         if pp is not None:
             matched = jnp.zeros((left.capacity,), bool).at[
                 jnp.where(live_out, pp, 0)].max(live_out)
             unmatched = left.row_valid() & ~matched
             out = device_concat(
-                [out, self._null_extend(left, right, unmatched)])
+                [out, self._null_extend(left, right, unmatched, outputs)])
         if bb is not None:
             matched_b = jnp.zeros((right.capacity,), bool).at[
                 jnp.where(live_out, bb, 0)].max(live_out)
             unmatched_b = right.row_valid() & ~matched_b
             out = device_concat(
-                [out, self._null_extend_right(left, right, unmatched_b)])
+                [out, self._null_extend_right(left, right, unmatched_b,
+                                              outputs)])
         return out
 
-    def _null_extend(self, left: Batch, right: Batch,
-                     row_mask) -> Batch:
-        """Rows of ``left`` where mask, with all-NULL right columns."""
-        sub = compact.filter_batch(left, row_mask)
-        cols = dict(sub.columns)
-        for s, c in right.columns.items():
-            with self._host_read("null_extend_dtype"):
-                dt = np.asarray(c.data).dtype
-            z = jnp.zeros((sub.capacity,), dtype=dt)
-            cols[s] = Column(c.type, z,
-                             jnp.zeros((sub.capacity,), bool),
-                             c.dictionary,
-                             None if c.data2 is None else
-                             jnp.zeros((sub.capacity,), jnp.int64))
-        return Batch(cols, sub.num_rows)
-
-    def _null_extend_right(self, left: Batch, right: Batch,
-                           row_mask) -> Batch:
-        """Rows of ``right`` where mask, with all-NULL left columns."""
-        sub = compact.filter_batch(right, row_mask)
+    def _null_lanes(self, side: Batch, capacity: int) -> Dict[str, Column]:
+        """All-NULL columns of ``side``'s lanes at ``capacity``."""
         cols = {}
-        for s, c in left.columns.items():
+        for s, c in side.columns.items():
             with self._host_read("null_extend_dtype"):
                 dt = np.asarray(c.data).dtype
-            z = jnp.zeros((sub.capacity,), dtype=dt)
-            cols[s] = Column(c.type, z, jnp.zeros((sub.capacity,), bool),
-                             c.dictionary,
+            cols[s] = Column(c.type, jnp.zeros((capacity,), dtype=dt),
+                             jnp.zeros((capacity,), bool), c.dictionary,
                              None if c.data2 is None else
-                             jnp.zeros((sub.capacity,), jnp.int64))
-        cols.update(sub.columns)
-        return Batch(cols, sub.num_rows)
+                             jnp.zeros((capacity,), jnp.int64))
+        return cols
+
+    def _null_extend(self, left: Batch, right: Batch, row_mask,
+                     outputs=None) -> Batch:
+        """Rows of ``left`` where mask, with all-NULL right columns: the
+        lanes of ``outputs`` (a side may put out none)."""
+        idx, n = compact.mask_to_gather(row_mask & left.row_valid())
+        cols = dict(narrow(left, outputs).gather(idx, n).columns)
+        cols.update(self._null_lanes(narrow(right, outputs), idx.shape[0]))
+        return Batch(cols, n)
+
+    def _null_extend_right(self, left: Batch, right: Batch, row_mask,
+                           outputs=None) -> Batch:
+        """Rows of ``right`` where mask, with all-NULL left columns."""
+        idx, n = compact.mask_to_gather(row_mask & right.row_valid())
+        cols = self._null_lanes(narrow(left, outputs), idx.shape[0])
+        cols.update(narrow(right, outputs).gather(idx, n).columns)
+        return Batch(cols, n)
 
     def _append_right_unmatched(self, out: Batch, left: Batch,
-                                right: Batch, pkeys, bkeys) -> Batch:
+                                right: Batch, pkeys, bkeys,
+                                outputs=None) -> Batch:
         # FULL JOIN tail (no residual filter): right rows with no key
         # match, null-extended
         start, count, order = join_ops.match_counts(
             right, left, bkeys, pkeys)
         unmatched = right.row_valid() & (count == 0)
-        pad = self._null_extend_right(left, right, unmatched)
+        pad = self._null_extend_right(left, right, unmatched, outputs)
         return device_concat([out, pad])
 
     def _exec_SemiJoinNode(self, node: SemiJoinNode) -> Batch:
@@ -2188,8 +2247,11 @@ class Executor:
             with self._host_read("semijoin_total"):
                 total = int(jnp.sum(count))
         cap = capacity_for(total)
-        cand = join_ops.expand_join(probe, filt, start, count, order,
-                                    cap, "inner")
+        # the candidates carry what the filter reads and the position
+        lanes = expand_lanes((), node.filter)
+        cand = join_ops.expand_join(narrow(probe, lanes),
+                                    narrow(filt, lanes), start, count,
+                                    order, cap, "inner")
         if node.filter is not None:
             mask = eval_predicate(node.filter, cand)
         else:

@@ -590,26 +590,31 @@ def _spec_from_payload(cols: List[dict]) -> tuple:
 def join_program_key(jt: str, pkeys, bkeys, residual_repr: str,
                      probe_spec: tuple, build_spec: tuple,
                      chunk_cap: int, build_cap: int,
-                     out_cap: int) -> tuple:
+                     out_cap: int, lanes=None) -> tuple:
     return ("streamjoin", jt, tuple(pkeys), tuple(bkeys),
             residual_repr, probe_spec, build_spec,
-            int(chunk_cap), int(build_cap), int(out_cap))
+            int(chunk_cap), int(build_cap), int(out_cap),
+            None if lanes is None else tuple(sorted(lanes)))
 
 
 _PPOS = "__probe_pos$"
 
 
 def make_probe_program(jt: str, pkeys: Sequence[str],
-                       bkeys: Sequence[str], residual, out_cap: int):
+                       bkeys: Sequence[str], residual, out_cap: int,
+                       lanes=None):
     """The per-chunk probe kernel: match counts against the prebuilt,
     indexed build side (ops/join.py probe_runs) + output expansion at
     a STATIC capacity, fused into one traceable function -> every
-    chunk of one streamed join runs the same compiled program. Returns
+    chunk of one streamed join runs the same compiled program. The
+    expansion gathers the chunk's and the build side's lanes in
+    ``lanes`` alone (executor.py ``expand_lanes``; None: all). Returns
     (out_batch, total_matches) — the total is the overflow signal the
     host checks (a chunk whose matches exceed ``out_cap`` reruns
     through a grown program). Module-level so exec/aot.py rebuilds the
     EXACT closure for worker pre-warm."""
     from ..ops import compact, join as join_ops
+    from .executor import expand_columns
     from .expr import eval_predicate
     pkeys = list(pkeys)
     outer = jt == "left"
@@ -618,23 +623,27 @@ def make_probe_program(jt: str, pkeys: Sequence[str],
         key_p, usable_p = join_ops.equality_lane(chunk, pkeys)
         left, count = join_ops.probe_runs(side, key_p, usable_p)
         order = side.order
-        if residual is None:
-            live_p = chunk.row_valid()
-            eff = (jnp.where(live_p, jnp.maximum(count, 1), 0)
-                   if outer else count)
-            total = jnp.sum(eff)
-            out = join_ops.expand_join(
-                chunk, build, left, count, order, out_cap,
-                "left" if outer else "inner")
-            return out, total
         probe = chunk
-        if outer:
+        if residual is not None and outer:
             cols = dict(chunk.columns)
             from ..types import BIGINT
             cols[_PPOS] = Column(
                 BIGINT, jnp.arange(chunk.capacity, dtype=jnp.int64),
                 None)
             probe = Batch(cols, chunk.num_rows)
+        pcols, bcols, _ = expand_columns(probe.columns, build.columns,
+                                         lanes)
+        probe = Batch(pcols, chunk.num_rows)
+        build = Batch(bcols, build.num_rows)
+        if residual is None:
+            live_p = chunk.row_valid()
+            eff = (jnp.where(live_p, jnp.maximum(count, 1), 0)
+                   if outer else count)
+            total = jnp.sum(eff)
+            out = join_ops.expand_join(
+                probe, build, left, count, order, out_cap,
+                "left" if outer else "inner")
+            return out, total
         total = jnp.sum(count)
         cand = join_ops.expand_join(probe, build, left, count, order,
                                     out_cap, "inner")
@@ -646,12 +655,13 @@ def make_probe_program(jt: str, pkeys: Sequence[str],
 
 
 def _join_payload(jt, criteria, residual, chunk: Batch, build: Batch,
-                  out_cap: int, kind: str = "streamjoin"
+                  out_cap: int, kind: str = "streamjoin", outputs=None
                   ) -> Optional[dict]:
     """AOT transport form of one hash-join program set: the join shape
     as a wire fragment (JoinNode over two schema-carrying RemoteSource
     leaves, ``filter`` holding the FULL residual incl. hash-verify
-    conjuncts) + both sides' lane specs at their capacities. Shared by
+    conjuncts, and the join's ``outputs``: what its expand gathers) +
+    both sides' lane specs at their capacities. Shared by
     the streamed probe program (kind="streamjoin") and the
     materialized two-phase programs (kind="join" — exec/executor.py);
     for the latter ``chunk`` is the whole probe batch. None when a
@@ -688,7 +698,8 @@ def _join_payload(jt, criteria, residual, chunk: Batch, build: Batch,
         return None
     frag = JoinNode(RemoteSourceNode((), pschema, "gather"),
                     RemoteSourceNode((), bschema, "gather"),
-                    jt, tuple(criteria), residual)
+                    jt, tuple(criteria), residual, outputs=outputs)
+
     def nrows_kind(b: Batch) -> str:
         return ("int" if isinstance(b.num_rows, int)
                 else str(np.dtype(b.num_rows.dtype)))
@@ -721,13 +732,15 @@ def aot_entry(payload: dict):
     chunk_cap = int(payload["chunk_capacity"])
     build_cap = int(payload["build_capacity"])
     out_cap = int(payload["out_capacity"])
+    from .executor import expand_lanes
+    lanes = expand_lanes(frag.outputs, frag.filter)
     key = join_program_key(
         frag.join_type, pkeys, bkeys, repr(frag.filter),
         _spec_from_payload(payload["probe_cols"]),
         _spec_from_payload(payload["build_cols"]),
-        chunk_cap, build_cap, out_cap)
+        chunk_cap, build_cap, out_cap, lanes)
     fn = make_probe_program(frag.join_type, pkeys, bkeys, frag.filter,
-                            out_cap)
+                            out_cap, lanes)
     chunk = _aval_batch({"cols": payload["probe_cols"],
                          "capacity": chunk_cap,
                          "num_rows": payload.get("probe_num_rows",
@@ -857,6 +870,8 @@ def maybe_stream_join(ex, node: JoinNode
         return None, None
     residual = _verify_filter_types(pschema, bschema, pkeys, bkeys,
                                     node.filter)
+    from .executor import expand_columns, expand_lanes, narrow
+    lanes = expand_lanes(node.outputs, residual)
 
     # build once: the engine's hash table is the sorted key lane, its
     # permutation and its int32 directory and run lengths (ops/join.py
@@ -891,7 +906,7 @@ def maybe_stream_join(ex, node: JoinNode
         return None, build      # build alone exhausts the budget
     state = {"out_cap": chunk_cap, "prog": None, "prog_cap": None,
              "probe_spec": None, "hit": None, "eager": False,
-             "recorded": False}
+             "recorded": False, "kept": None}
     ex._reserve_streamed(
         build_bytes + chunk_cap * per_row,
         f"streamed join (build {build_bytes}B + chunk capacity "
@@ -914,9 +929,9 @@ def maybe_stream_join(ex, node: JoinNode
         key = join_program_key(
             jt, pkeys, bkeys, repr(residual), state["probe_spec"],
             _lane_spec(build), chunk_cap, build.capacity,
-            state["out_cap"])
+            state["out_cap"], lanes)
         fn = make_probe_program(jt, pkeys, bkeys, residual,
-                                state["out_cap"])
+                                state["out_cap"], lanes)
         got = None if state["eager"] else PROGRAMS.program(
             "streamjoin", key, lambda: fn, "streamjoin", key)
         if got is None:
@@ -930,6 +945,10 @@ def maybe_stream_join(ex, node: JoinNode
     def run_chunk(probe_chunk: Batch):
         if state["probe_spec"] is None:
             state["probe_spec"] = _lane_spec(probe_chunk)
+            # what the expand gathers of the two inputs (the chunk's
+            # position lane, where one is added, counts on both sides)
+            state["kept"] = expand_columns(
+                probe_chunk.columns, build.columns, lanes)[2]
         jitted, key, eager = program()
         args = (probe_chunk, build, side)
         if eager:                   # deny/fallback path
@@ -938,7 +957,8 @@ def maybe_stream_join(ex, node: JoinNode
             out = ex._jit_call(
                 jitted, args, "streamjoin", bool(state["hit"]),
                 form=join_ops.expand_form(probe_chunk.capacity,
-                                          state["out_cap"]))
+                                          state["out_cap"]),
+                lanes=state["kept"])
             state["hit"] = True     # later chunks ride the program
             if not state["recorded"]:
                 state["recorded"] = True
@@ -947,7 +967,8 @@ def maybe_stream_join(ex, node: JoinNode
                 def build_pl():
                     return _join_payload(jt, node.criteria, residual,
                                          probe_chunk, build,
-                                         state["out_cap"])
+                                         state["out_cap"],
+                                         outputs=node.outputs)
                 record_program("streamjoin", key, None, None,
                                ex.session, payload_fn=build_pl)
             return out
@@ -956,7 +977,7 @@ def maybe_stream_join(ex, node: JoinNode
             state["eager"] = True
             state["prog"] = None
             fn = make_probe_program(jt, pkeys, bkeys, residual,
-                                    state["out_cap"])
+                                    state["out_cap"], lanes)
             return fn(*args)
 
     def dispatch(chunk: Batch, i: int):
@@ -988,7 +1009,7 @@ def maybe_stream_join(ex, node: JoinNode
             state["out_cap"] = grown
             out, total = run_chunk(b)
         if residual is not None:
-            out = ex._repair_outer(out, b, build, jt)
+            out = ex._repair_outer(out, b, build, jt, node.outputs)
         n = out.num_rows_host()
         if n:
             outs.append(_to_host(out, n))
@@ -1004,7 +1025,9 @@ def maybe_stream_join(ex, node: JoinNode
         chunk0 = chain_run(_h2d(empty_batch(
             {s: scan.schema[s] for s in scan.assignments})))
         z = jnp.zeros((chunk0.capacity,), jnp.int64)
-        out = join_ops.expand_join(chunk0, build, z, z, side.order,
-                                   8, "inner")
+        out_lanes = expand_lanes(node.outputs)
+        out = join_ops.expand_join(narrow(chunk0, out_lanes),
+                                   narrow(build, out_lanes), z, z,
+                                   side.order, 8, "inner")
         return _to_host(out, 0), None
     return _host_concat(outs, total_rows), None

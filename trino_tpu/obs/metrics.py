@@ -587,6 +587,13 @@ JOIN_EXPANDS = METRICS.counter(
     "form its static shapes chose for mapping output rows to probe "
     "rows (ops/join.py expand_form: histogram | search)",
     ("site", "form"))
+JOIN_EXPAND_LANES = METRICS.counter(
+    "trino_tpu_join_expand_lanes_total",
+    "Lanes of the two inputs of those expand programs, by the kind of "
+    "the program and whether the expand gathered them (kept=yes: what "
+    "the plan above the join reads, and its residual filter's inputs) "
+    "or left them out (kept=no: join keys and columns read by nothing "
+    "above)", ("site", "kept"))
 GROUPBYS = METRICS.counter(
     "trino_tpu_groupby_total",
     "Grouped aggregations of traced queries, by the kind of the "
@@ -679,6 +686,13 @@ def observe_span(sp) -> None:
                 GROUPBY_LANES.inc_at(labels, float(lanes or 0))
         elif form is not None:
             JOIN_EXPANDS.inc_at(kind + _label_key(form))
+        lanes = sp.attrs.get("lanes")
+        if lanes is not None:
+            # a join's expand: "<gathered>/<offered>" lanes of its inputs
+            kept, _, offered = str(lanes).partition("/")
+            JOIN_EXPAND_LANES.inc_at(kind + ("yes",), int(kept))
+            JOIN_EXPAND_LANES.inc_at(kind + ("no",),
+                                     int(offered) - int(kept))
     elif name == "scan_fill":
         SCAN_FILL_SECONDS.observe_at((), wall)
     elif name == "exchange":

@@ -421,9 +421,14 @@ def expand_join(probe: Batch, build: Batch, start, count, order,
 
     join_type: inner | left. For 'left', probe rows with no match emit one
     row with NULL build columns (reference: LookupJoinOperator
-    outer-position tracking)."""
+    outer-position tracking). The capacities are those of ``count``
+    (the probe side's) and ``order`` (the build side's): a side may be
+    handed with no lane at all, where the plan above reads none of it;
+    only the lanes handed are gathered."""
     outer = join_type == "left"
-    live_p = probe.row_valid()
+    probe_capacity = count.shape[0]
+    live_p = (jnp.arange(probe_capacity, dtype=jnp.int64)
+              < probe.num_rows_device())
     eff_count = (jnp.where(live_p, jnp.maximum(count, 1), 0)
                  if outer else count)
     no_match = count == 0
@@ -433,10 +438,10 @@ def expand_join(probe: Batch, build: Batch, start, count, order,
     offs = incl - eff_count  # exclusive
 
     i = jnp.arange(out_capacity, dtype=jnp.int64)
-    p = jnp.clip(run_positions(incl, out_capacity), 0, probe.capacity - 1)
+    p = jnp.clip(run_positions(incl, out_capacity), 0, probe_capacity - 1)
     j = i - jnp.take(offs, p)
     b_sorted = jnp.take(start, p) + j
-    b = jnp.take(order, jnp.clip(b_sorted, 0, build.capacity - 1))
+    b = jnp.take(order, jnp.clip(b_sorted, 0, order.shape[0] - 1))
 
     pad_build = (jnp.take(no_match, p) if outer else None)
 

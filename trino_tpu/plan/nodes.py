@@ -151,13 +151,17 @@ class JoinClause:
 class JoinNode(PlanNode):
     """plan/JoinNode.java. join_type: inner|left|right|full|cross.
     ``criteria`` are equi-clauses; ``filter`` is the residual non-equi
-    condition evaluated over combined columns."""
+    condition evaluated over combined columns. ``outputs`` are the
+    symbols of both sides the join puts out (the reference's
+    left/rightOutputSymbols, set by column pruning to what the plan
+    above reads); None puts out every symbol of both sides."""
     left: PlanNode
     right: PlanNode
     join_type: str
     criteria: Tuple[JoinClause, ...] = ()
     filter: Optional[RowExpr] = None
     distribution: Optional[str] = None   # PARTITIONED | REPLICATED (set by optimizer)
+    outputs: Optional[Tuple[str, ...]] = None
 
     @property
     def sources(self):
@@ -166,7 +170,10 @@ class JoinNode(PlanNode):
     def output_schema(self):
         out = dict(self.left.output_schema())
         out.update(self.right.output_schema())
-        return out
+        if self.outputs is None:
+            return out
+        keep = set(self.outputs)
+        return {s: t for s, t in out.items() if s in keep}
 
 
 @dataclass(frozen=True)

@@ -488,12 +488,14 @@ def _prune(node: PlanNode, needed: Set[str]) -> PlanNode:
             child.add(c.right)
         if node.filter is not None:
             child |= rex.input_names(node.filter)
-        lsyms = set(node.left.output_schema())
-        rsyms = set(node.right.output_schema())
-        return dc_replace(
-            node,
-            left=_prune(node.left, child & lsyms),
-            right=_prune(node.right, child & rsyms))
+        left = _prune(node.left, child & set(node.left.output_schema()))
+        right = _prune(node.right,
+                       child & set(node.right.output_schema()))
+        # the join puts out what the plan above reads (PruneJoinColumns):
+        # its keys and its filter's inputs ride no further than the join
+        syms = list(left.output_schema()) + list(right.output_schema())
+        outputs = tuple(s for s in syms if s in needed) or tuple(syms[:1])
+        return dc_replace(node, left=left, right=right, outputs=outputs)
 
     if isinstance(node, SemiJoinNode):
         child = (needed - {node.output}) | {node.source_key}
