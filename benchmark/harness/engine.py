@@ -104,11 +104,12 @@ class _Answer:
 
 class ControlEngine:
     """The output check's control: a reference (computed one precision
-    below the configuration's) answers in the program's place. It serves
-    no table, has no spans and no counters."""
+    below the configuration's) answers in the program's place, from
+    ``rows`` (the text of a query -> its rows). It serves no table, has
+    no spans and no counters."""
 
-    def __init__(self, answers, sql: dict):
-        self._rows = {text: answers.answer(cls) for cls, text in sql.items()}
+    def __init__(self, rows: dict):
+        self._rows = rows
         self._n = 0
 
     def client(self, _stream):

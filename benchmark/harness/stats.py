@@ -22,6 +22,7 @@ class Record:
     rows: Optional[list] = None
     error: str = ""
     due_s: Optional[float] = None     # open loop: when it was due
+    params: Optional[tuple] = None    # its substitution parameters
     tags: dict = field(default_factory=dict)
 
     @property

@@ -21,6 +21,12 @@ Keys of a mix:
 Every seed gives the same classes in the same numbers in another order:
 a closed stream runs WHOLE cycles and stops at the first cycle boundary
 past the deadline, so the window's work is cycles of the same content.
+
+Where the configuration declares substitution parameters, ``parameters``
+is its module (``reference/<module>.py``): each query carries a set
+drawn by ``parameters.draw(cls, rng)`` from an rng of its own stream's
+(``params/<stream>``), so the rng that orders the classes draws exactly
+what it draws without them, and every seed keeps its class order.
 """
 
 import contextlib
@@ -80,27 +86,33 @@ def next_cycle(mix: dict, classes: List[str], rng: random.Random):
     return rng.choices(classes, weights=weights, k=len(classes))
 
 
+def _draw(parameters, cls: str, rng: random.Random):
+    return None if parameters is None else parameters.draw(cls, rng)
+
+
 def _one(execute: Callable, cls: str, stream: int, due_s=None,
-         on_query=None) -> Record:
+         on_query=None, params=None) -> Record:
     t0 = time.perf_counter()
     try:
         with (on_query(cls, stream) if on_query is not None
               else contextlib.nullcontext()):
-            res = execute(stream, cls)
+            res = execute(stream, cls, params)
         t1 = time.perf_counter()
         ok = res.state == "FINISHED"
         return Record(cls, stream, t0, t1, ok, res.query_id, res.rows,
-                      "" if ok else f"state {res.state}", due_s)
+                      "" if ok else f"state {res.state}", due_s, params)
     except Exception as e:      # noqa: BLE001 — a failed query is data
         return Record(cls, stream, t0, time.perf_counter(), False,
-                      error=f"{type(e).__name__}: {e}"[:300], due_s=due_s)
+                      error=f"{type(e).__name__}: {e}"[:300], due_s=due_s,
+                      params=params)
 
 
 def run_closed(mix, classes, seed, seconds, execute, on_query=None,
-               cycles=None, stream_tag=""):
+               cycles=None, stream_tag="", parameters=None):
     """Drive ``clients`` closed streams; returns (t0, records). With
     ``cycles`` set, each stream runs that many cycles (the warm-up);
-    else whole cycles until ``seconds`` have passed."""
+    else whole cycles until ``seconds`` have passed. ``execute(stream,
+    cls, params)`` sends one query."""
     n = int(mix.get("clients", 1))
     think = float(mix.get("think_ms", 0)) / 1000.0
     out = [[] for _ in range(n)]
@@ -109,13 +121,15 @@ def run_closed(mix, classes, seed, seconds, execute, on_query=None,
 
     def stream(i):
         rng = stream_rng(seed, f"{stream_tag}{i}")
+        prng = stream_rng(seed, f"params/{stream_tag}{i}")
         gate.wait()
         t0 = t0_box[0]
         done = 0
         while (done < cycles if cycles is not None
                else time.perf_counter() - t0 < seconds):
             for cls in next_cycle(mix, classes, rng):
-                out[i].append(_one(execute, cls, i, None, on_query))
+                out[i].append(_one(execute, cls, i, None, on_query,
+                                   _draw(parameters, cls, prng)))
                 if think:
                     time.sleep(think)
             done += 1
@@ -131,7 +145,8 @@ def run_closed(mix, classes, seed, seconds, execute, on_query=None,
     return t0_box[0], [r for s in out for r in s]
 
 
-def run_open(mix, classes, seed, seconds, execute, on_query=None):
+def run_open(mix, classes, seed, seconds, execute, on_query=None,
+             parameters=None):
     """Queries fall due at seeded arrival times over ``seconds``; a pool
     of ``clients`` senders takes them in order. A query's latency counts
     from when it was due, so a stall is paid by all that wait behind it."""
@@ -147,7 +162,9 @@ def run_open(mix, classes, seed, seconds, execute, on_query=None):
     plan, crng = [], stream_rng(seed, "classes")
     while len(plan) < len(due):
         plan.extend(next_cycle(mix, classes, crng))
-    jobs = list(zip(due, plan))
+    prng = stream_rng(seed, "params/classes")
+    jobs = [(when, cls, _draw(parameters, cls, prng))
+            for when, cls in zip(due, plan)]
     lock, out, nxt = threading.Lock(), [], [0]
     t0 = time.perf_counter()
 
@@ -156,12 +173,12 @@ def run_open(mix, classes, seed, seconds, execute, on_query=None):
             with lock:
                 if nxt[0] >= len(jobs):
                     return
-                when, cls = jobs[nxt[0]]
+                when, cls, params = jobs[nxt[0]]
                 nxt[0] += 1
             wait = t0 + when - time.perf_counter()
             if wait > 0:
                 time.sleep(wait)
-            rec = _one(execute, cls, i, t0 + when, on_query)
+            rec = _one(execute, cls, i, t0 + when, on_query, params)
             with lock:
                 out.append(rec)
 
@@ -174,18 +191,20 @@ def run_open(mix, classes, seed, seconds, execute, on_query=None):
     return t0, out
 
 
-def run_window(mix, classes, seed, seconds, execute, on_query=None):
-    if mix["loop"] == "closed":
-        return run_closed(mix, classes, seed, seconds, execute, on_query)
-    return run_open(mix, classes, seed, seconds, execute, on_query)
+def run_window(mix, classes, seed, seconds, execute, on_query=None,
+               parameters=None):
+    run = run_closed if mix["loop"] == "closed" else run_open
+    return run(mix, classes, seed, seconds, execute, on_query,
+               parameters=parameters)
 
 
-def warm_up(mix, classes, seed, execute):
+def warm_up(mix, classes, seed, execute, parameters=None):
     """The mix's own shapes before the window: ``warm_cycles`` cycles
-    per stream at the mix's concurrency, from a seed of their own."""
+    per stream at the mix's concurrency, from a seed of their own (and
+    parameters from a tag of their own: ``params/warm<stream>``)."""
     shape = dict(mix, loop="closed", think_ms=0)
     if mix["loop"] == "open":
         shape["clients"] = min(int(mix.get("clients", 8)), 2)
     return run_closed(shape, classes, seed, 0, execute,
                       cycles=int(mix.get("warm_cycles", 1)),
-                      stream_tag="warm")[1]
+                      stream_tag="warm", parameters=parameters)[1]

@@ -54,27 +54,13 @@ class Answers(tpch_answers.Answers):
         self._want18 = "q18" in want
         super().__init__(sf, [c for c in want if c != "q18"], dtype)
 
-    def _run(self) -> None:
-        building = None
-        if "q3" in self.want:
-            c = rows.customer(self.sf)
-            building = np.zeros(len(c["c_custkey"]) + 1, bool)
-            building[c["c_custkey"][c["c_mktsegment"]
-                                    == tpch_answers.Q3_SEGMENT]] = True
-        n_orders = rows.table_rows("orders", self.sf)
-        for lo in range(0, n_orders, tpch_answers.ORDERS_PER_CHUNK):
-            hi = min(lo + tpch_answers.ORDERS_PER_CHUNK, n_orders)
-            idx = np.arange(lo + 1, hi + 1, dtype=np.int64)
-            li = rows.lineitem(idx, self.sf)
-            self.n_lineitem += len(li["l_orderkey"])
-            for k in ("l_quantity", "l_extendedprice", "l_discount",
-                      "l_tax"):
-                li[k] = li[k].astype(self.dtype)
-            o = rows.orders(idx, self.sf)
-            if "q3" in self.want:
-                self._fold_q3(o, building, li)
-            if self._want18:
-                self._fold_q18(o, li)
+    def _needs_orders(self) -> bool:
+        return self._want18 or super()._needs_orders()
+
+    def _fold(self, li, o) -> None:
+        super()._fold(li, o)
+        if self._want18:
+            self._fold_q18(o, li)
 
     def _fold_q18(self, o, li) -> None:
         o_key = o["o_orderkey"]               # ascending, one an order
@@ -106,3 +92,7 @@ class Answers(tpch_answers.Answers):
                      days=int(t["o_orderdate"][i]))).isoformat(),
                  float(t["o_totalprice"][i]), float(t["sum_qty"][i])]
                 for i in order]
+
+    def answer(self, name: str, params=None):
+        # q18's one parameter is the constructor's ``quantity``
+        return self.q18() if name == "q18" else super().answer(name, params)

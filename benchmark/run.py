@@ -136,26 +136,51 @@ def read_metrics(run: Run, specs, package: str, excused: str = "") -> dict:
     return out
 
 
-def reference_answers(config: dict, sf: float, classes):
-    """{class: rows} from the plain reference, kept per checkout in a
-    file keyed by the reference's own source, the scale and the classes."""
+def answer_of(answers, cls: str, params):
+    """The rows of one (class, parameters) pair; ``params`` None: the
+    class as its one fixed text has it."""
+    return (answers.answer(cls) if params is None
+            else answers.answer(cls, params))
+
+
+def wanted(pairs):
+    """What the reference's ``Answers`` is asked for: a class name, or a
+    (class, parameters) pair."""
+    return [c if p is None else (c, p) for c, p in pairs]
+
+
+def reference_answers(config: dict, sf: float, pairs):
+    """{(class, params): rows} from the plain reference, kept per
+    checkout with one file per (reference source, scale, class,
+    parameters); the pairs no file holds yet are answered in ONE pass."""
     module = importlib.import_module(f"reference.{config['reference']}")
-    h = hashlib.sha256()
+    source = hashlib.sha256()
     for name in sorted(os.listdir(os.path.join(HERE, "reference"))):
         if name.endswith(".py"):
             with open(os.path.join(HERE, "reference", name), "rb") as f:
-                h.update(f.read())
-    h.update(json.dumps([config["reference"], sf, sorted(classes)]).encode())
-    path = os.path.join(CACHE_DIR, f"answers-{h.hexdigest()[:16]}.json")
-    if os.path.exists(path):
-        with open(path) as f:
-            return json.load(f)
-    answers = module.Answers(sf, classes)
-    out = {c: answers.answer(c) for c in classes}
-    os.makedirs(CACHE_DIR, exist_ok=True)
-    with open(path + ".tmp", "w") as f:
-        json.dump(out, f)
-    os.replace(path + ".tmp", path)
+                source.update(f.read())
+
+    def path(cls, params):
+        h = source.copy()
+        h.update(json.dumps([config["reference"], sf, cls,
+                             None if params is None else list(params)
+                             ]).encode())
+        return os.path.join(CACHE_DIR, f"answers-{h.hexdigest()[:16]}.json")
+    out, missing = {}, []
+    for key in sorted(set(pairs), key=repr):
+        if os.path.exists(path(*key)):
+            with open(path(*key)) as f:
+                out[key] = json.load(f)
+        else:
+            missing.append(key)
+    if missing:
+        answers = module.Answers(sf, wanted(missing))
+        os.makedirs(CACHE_DIR, exist_ok=True)
+        for key in missing:
+            out[key] = answer_of(answers, *key)
+            with open(path(*key) + ".tmp", "w") as f:
+                json.dump(out[key], f)
+            os.replace(path(*key) + ".tmp", path(*key))
     return out
 
 
@@ -204,13 +229,14 @@ def check_pins(engine, config: dict, sf: float, rehearse: bool):
 
 
 def compare_window(records, answers):
-    """Every finished result of the window against the reference."""
+    """Every finished result of the window against the reference's
+    answer for its own class and parameters."""
     from reference.compare import gaps
     mismatches, worst = 0, 0.0
     for r in records:
         if not r.ok:
             continue
-        m, rel = gaps(r.rows, answers[r.cls])
+        m, rel = gaps(r.rows, answers[r.cls, r.params])
         mismatches += m
         worst = max(worst, rel)
         r.tags["mismatches"], r.tags["rel_err"] = m, rel
@@ -317,7 +343,21 @@ def main(argv=None) -> int:
     run.mix = traffic.load_mix(cell["traffic"])
     run.classes = traffic.classes_of(run.mix, config)
     run.streams = int(run.mix.get("clients", 1))
-    sql = {c: traffic.load_sql(c, config) for c in run.classes}
+    # a configuration with "parameters" sends each query its own drawn
+    # parameters, substituted into its class's template
+    parameters = (importlib.import_module(
+        f"reference.{config['parameters']}") if "parameters" in config
+        else None)
+    if parameters is None:
+        sql = {c: traffic.load_sql(c, config) for c in run.classes}
+    else:
+        sql = {c: traffic.load_sql(c, {"queries_dir":
+                                       parameters.TEMPLATES_DIR})
+               for c in run.classes}
+
+    def text(cls, params):
+        return sql[cls] if params is None else parameters.substitute(
+            sql[cls], params)
     schema = config["rehearsal_schema" if args.rehearse else "schema"]
     sf = float(config["rehearsal_scale_factor" if args.rehearse
                       else "scale_factor"])
@@ -329,15 +369,18 @@ def main(argv=None) -> int:
     t = time.perf_counter()
     if args.control:
         module = importlib.import_module(f"reference.{config['reference']}")
-        engine = eng.ControlEngine(
-            module.Answers(sf, run.classes, dtype=args.control), sql)
+        pairs = [(c, p) for c in run.classes
+                 for p in (parameters.domain(c) if parameters else [None])]
+        answers = module.Answers(sf, wanted(pairs), dtype=args.control)
+        engine = eng.ControlEngine({text(c, p): answer_of(answers, c, p)
+                                    for c, p in pairs})
     else:
         engine = eng.Engine(config.get("catalog", "tpch"), schema,
                             os.path.join(RUN_DIR, "state"))
     run.phases["start"] = time.perf_counter() - t
 
-    def execute(stream, cls):
-        return engine.client(stream).execute(sql[cls])
+    def execute(stream, cls, params=None):
+        return engine.client(stream).execute(text(cls, params))
 
     t = time.perf_counter()
     pin_mismatches, pin_log = (
@@ -349,14 +392,16 @@ def main(argv=None) -> int:
     t = time.perf_counter()
     for cls in run.classes:                 # first execution: compiles
         t1 = time.perf_counter()
-        res = execute("warm", cls)
+        res = execute("warm", cls, None if parameters is None
+                      else parameters.validation(cls))
         say(f"first {cls}: {time.perf_counter() - t1:.3f} s {res.state} "
             f"{json.dumps(counters.snapshot())}")
         if res.state != "FINISHED":
             raise SystemExit(f"warm-up of {cls} ended {res.state}")
     run.phases["first_pass"] = time.perf_counter() - t
     t = time.perf_counter()
-    warm = traffic.warm_up(run.mix, run.classes, args.seed, execute)
+    warm = traffic.warm_up(run.mix, run.classes, args.seed, execute,
+                           parameters)
     run.phases["warm_cycles"] = time.perf_counter() - t
     bad = [r for r in warm if not r.ok]
     if bad:
@@ -383,7 +428,8 @@ def main(argv=None) -> int:
 
     # ---- the window ------------------------------------------------------
     run.t0, run.records = traffic.run_window(
-        run.mix, run.classes, args.seed, args.seconds, execute, on_query)
+        run.mix, run.classes, args.seed, args.seconds, execute, on_query,
+        parameters)
     t_close = time.perf_counter()
 
     if args.trace:
@@ -405,7 +451,8 @@ def main(argv=None) -> int:
 
     # ---- the output check, once the window has closed ---------------------
     t = time.perf_counter()
-    answers = reference_answers(config, sf, run.classes)
+    answers = reference_answers(config, sf,
+                                [(r.cls, r.params) for r in run.records])
     mismatches, worst = compare_window(run.records, answers)
     reference_s = time.perf_counter() - t
     failed = sum(1 for r in run.records
@@ -449,7 +496,11 @@ def main(argv=None) -> int:
     for r in sorted(run.records, key=lambda r: r.start_s):
         spans = {n: round((e - b) / 1e6, 2)
                  for n, (b, e) in run.spans.get(r.query_id, {}).items()}
-        say(f"  {r.cls} {r.stream} {r.latency_ms:.2f} {json.dumps(spans)}")
+        say(f"  {r.cls} {r.stream} {r.latency_ms:.2f} {json.dumps(spans)}"
+            + ("" if r.params is None else f" {json.dumps(r.params)}"))
+    param_sets = {} if parameters is None else {"class_param_sets": {
+        c: len({r.params for r in run.records if r.cls == c})
+        for c in run.classes}}
     say("window", json.dumps({
         "seconds": t_close - run.t0, "phases_s": run.phases,
         "class_mean_ms": stats.class_means_ms(run.records),
@@ -461,7 +512,7 @@ def main(argv=None) -> int:
         "rate_per_s": stats.rate_per_s(run.records, run.t0),
         "gc": run.gc_window,
         "window_compile_requests": run.jax_window["compile_requests"],
-        "reference_s": reference_s}))
+        "reference_s": reference_s, **param_sets}))
     checks_pass = correct
     if args.rehearse:
         correct = False
