@@ -499,3 +499,69 @@ def test_semi_join_program_compiles(one_chip, no_persistent_cache):
         _as_structs(k, 1 << 20, one_chip),
         _as_structs(k, 1 << 10, one_chip)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def _q6_plan():
+    from trino_tpu.plan.nodes import FilterNode
+    from trino_tpu.runner import LocalQueryRunner
+    with open(os.path.join(os.path.dirname(__file__), os.pardir,
+                           "benchmark", "traffic", "queries",
+                           "q6.sql")) as f:
+        node = LocalQueryRunner().plan_sql(f.read())
+    while not isinstance(node, FilterNode):
+        node, = node.sources
+    return node
+
+
+def test_q6_filter_with_literal_slots_compiles(one_chip,
+                                               no_persistent_cache):
+    """q6's Filter as its canonical program runs it (exec/literals.py):
+    the year's end and the two discount bounds are slots of the
+    program's literal vectors, folded on the host; what is left per
+    row is compares against one element of a vector."""
+    from trino_tpu import batch_from_pylist
+    from trino_tpu.exec.expr import eval_predicate
+    from trino_tpu.exec.literals import LITERAL_SLOTS, BoundBatch
+    from trino_tpu.exec.progkey import canonicalize_nodes, named_jit
+    node = _q6_plan()
+    canon = canonicalize_nodes([node])
+    assert {s.dtype for s in canon.slots} == {"int32", "float64"}
+    schema = node.source.output_schema()
+    rows = canon.binding(batch_from_pylist(
+        {c: [1, 2] for c in schema}, schema)).rename_in(
+            batch_from_pylist({c: [1, 2] for c in schema}, schema))
+    lanes = _as_structs(rows.columns, Q1_CAPACITY, one_chip)
+    args = BoundBatch(lanes, Q1_CAPACITY, {
+        dt: _struct((LITERAL_SLOTS,), np.dtype(dt), one_chip)
+        for dt in rows.literals}, rows.bound)
+    pred = canon.nodes[0].predicate
+    compiled = named_jit(lambda b: eval_predicate(pred, b), "chain",
+                         canon.key).lower(args).compile()
+    assert len(compiled.as_text()) < 100_000
+    assert compiled.cost_analysis()["flops"] < 64 * Q1_CAPACITY
+
+
+def test_scan_derive_program_compiles(one_chip, no_persistent_cache):
+    """The derive of q6's pushed constraint (exec/scanderive.py) over
+    sf1 lineitem's base lanes at 2^23: the mask of the bounds, the
+    stable compaction at the same capacity by shifts: no sort and no
+    gather (a gather of 2^23 indices a lane word held the chip for
+    about 130 ms)."""
+    from trino_tpu import DATE, DOUBLE, batch_from_pylist
+    from trino_tpu.exec.scanderive import (bound_vectors, constraint_shape,
+                                           make_derive_program)
+    scan = _q6_plan().source
+    shape, values = constraint_shape(scan.handle.constraint)
+    keep = ("l_discount", "l_extendedprice", "l_shipdate")
+    base = batch_from_pylist(
+        {"l_discount": [0.1], "l_extendedprice": [1.0],
+         "l_quantity": [1.0], "l_shipdate": [1]},
+        {"l_discount": DOUBLE, "l_extendedprice": DOUBLE,
+         "l_quantity": DOUBLE, "l_shipdate": DATE})
+    bounds = {dt: _struct(np.shape(v), np.asarray(v).dtype, one_chip)
+              for dt, v in bound_vectors(values).items()}
+    compiled = jax.jit(make_derive_program(shape, keep)).lower(
+        _as_structs(base, 1 << 23, one_chip), bounds).compile()
+    text = compiled.as_text()
+    assert " sort(" not in text and " gather(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30

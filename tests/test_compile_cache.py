@@ -22,7 +22,7 @@ from trino_tpu.exec.hotshapes import (HOT_SHAPES, HotShapeRegistry,
 from trino_tpu.exec.progkey import (PROGRAMS, ProgramCache,
                                     canonicalize_nodes)
 from trino_tpu.obs.metrics import METRICS, parse_exposition
-from trino_tpu.plan.nodes import FilterNode, ProjectNode
+from trino_tpu.plan.nodes import FilterNode, LimitNode, ProjectNode
 from trino_tpu.planner import LogicalPlanner
 from trino_tpu.planner.optimizer import optimize
 from trino_tpu.rex import Call, Const, InputRef
@@ -61,9 +61,17 @@ def test_canonical_key_ignores_symbol_names():
 
 
 def test_canonical_key_distinguishes_constants():
+    """A literal VALUE is a slot of its program (exec/literals.py): two
+    filters that differ only in their constant share the program key,
+    and what tells the two plans apart is their literal key. A constant
+    that fixes a shape, a LIMIT count, is still part of the key."""
     a = canonicalize_nodes(_filter_chain("x", 10))
     b = canonicalize_nodes(_filter_chain("x", 20))
-    assert a.key != b.key
+    assert a.key == b.key
+    assert a.literal_key != b.literal_key
+    la = canonicalize_nodes([LimitNode(None, 10)])
+    lb = canonicalize_nodes([LimitNode(None, 20)])
+    assert la.key != lb.key
 
 
 def test_canonical_key_rejects_volatile():
@@ -433,7 +441,7 @@ def _builder(calls):
 
 def test_the_buckets_are_the_metric_labels():
     assert BUCKETS == ("chain", "stream", "ragged", "join", "window",
-                       "streamjoin", "repartition", "spmd")
+                       "streamjoin", "repartition", "spmd", "scan")
     assert isinstance(PROGRAMS, ProgramCache)
 
 

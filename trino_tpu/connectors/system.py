@@ -26,7 +26,9 @@ from typing import List, Optional, Sequence
 from ..catalog import (ColumnMetadata, Connector, Split, TableHandle,
                        TableMetadata)
 from ..columnar import Batch, batch_from_pylist
+from ..exec.literals import LITERAL_SLOTS
 from ..types import BIGINT, BOOLEAN, DOUBLE, VARCHAR
+
 
 _RUNTIME_TABLES = {
     "queries": (
@@ -71,6 +73,11 @@ _RUNTIME_TABLES = {
         # the scan-cache budget and the per-query memory limit of a
         # deployment are sized against
         ("device_memory_bytes", BIGINT),
+        # the width of a compiled program's literal vector
+        # (exec/literals.py LITERAL_SLOTS, a constant of the code): a
+        # query's literals are arguments of its programs, and a program
+        # with more literals than this bakes the rest
+        ("program_literal_slots", BIGINT),
     ),
     "resource_groups": (
         ("name", VARCHAR), ("running", BIGINT), ("queued", BIGINT),
@@ -199,7 +206,7 @@ class SystemConnector(Connector):
                 (i.get("nodeId", ""), i.get("uri", ""),
                  i.get("nodeVersion", ""), i.get("coordinator", False),
                  i.get("state", "active"), int(i.get("devices", 1)),
-                 i.get("deviceMemoryBytes"))
+                 i.get("deviceMemoryBytes"), LITERAL_SLOTS)
                 for i in self.provider.node_infos()]
         else:
             rows = [

@@ -76,6 +76,14 @@ def _aval_batch(payload: dict, schema):
         import jax as _jax
         num_rows = _jax.ShapeDtypeStruct(
             (), np.dtype(payload["num_rows"]))
+    literals = payload.get("literals")
+    if literals:
+        # the literal vectors a program with slots takes beside its
+        # lanes (exec/literals.py): shapes only
+        from .literals import LITERAL_SLOTS, BoundBatch
+        return BoundBatch(cols, num_rows, {
+            dt: jax.ShapeDtypeStruct((LITERAL_SLOTS,), np.dtype(dt))
+            for dt in literals["dtypes"]}, int(literals["bound"]))
     return Batch(cols, num_rows)
 
 

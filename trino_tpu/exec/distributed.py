@@ -57,6 +57,7 @@ from ..types import BOOLEAN, BIGINT
 from .executor import (Executor, QueryError, _Pre, _lower_aggregates,
                        expand_columns, expand_lanes, join_verify_filter,
                        make_stream_parts, narrow, read_table_sharded)
+from .literals import literal_scope
 from .progkey import (PROGRAMS, UNTRACEABLE, canonicalize_nodes,
                       node_fingerprint)
 from .expr import eval_expr, eval_predicate
@@ -465,14 +466,22 @@ class DistributedExecutor(Executor):
             return None
         binding = None
         cols = src.columns
+        literals = {}
         if canon is not None:
             binding = canon.binding(Batch(cols, 0))
-            cols = binding.rename_in(Batch(cols, 0)).columns
+            bound = binding.rename_in(Batch(cols, 0))
+            cols = bound.columns
+            # the plan's literal vectors, replicated beside the lanes
+            literals = getattr(bound, "literals", None) or {}
         partial, finish = make_stream_parts(self._detached(), chain_x,
                                             node_x)
 
         def build():
-            def f(cols, num_rows_vec):
+            def f(cols, num_rows_vec, literals):
+                with literal_scope(literals or None):
+                    return fused(cols, num_rows_vec)
+
+            def fused(cols, num_rows_vec):
                 d = jax.lax.axis_index(AXIS)
                 out, phys, post = partial(Batch(cols, num_rows_vec[d]))
                 if out.capacity > FUSED_PARTIAL_ROWS:
@@ -486,11 +495,11 @@ class DistributedExecutor(Executor):
                 return finish(
                     Batch(gathered, jnp.sum(glive.astype(jnp.int64))),
                     phys, post, live=glive)
-            return f, (_col_specs(cols, P(AXIS)), P()), P()
+            return f, (_col_specs(cols, P(AXIS)), P(), P()), P()
 
         try:
-            out = mesh_call("agg", key, src.mesh, (cols, src.num_rows),
-                            build)
+            out = mesh_call("agg", key, src.mesh,
+                            (cols, src.num_rows, literals), build)
         except (_PartialTooLarge,) + UNTRACEABLE:
             if key is not None:
                 PROGRAMS.deny("spmd", key)

@@ -45,6 +45,7 @@ from ..session import Session
 from ..types import (BIGINT, BOOLEAN, DOUBLE, REAL, DecimalType, Type,
                      is_integral, is_string)
 from .expr import EvalError, eval_expr, eval_predicate
+from .literals import bound_count, call_bound
 from .progkey import PROGRAMS, UNTRACEABLE, named_jit
 
 
@@ -303,10 +304,10 @@ def _keys_inexact(cols, keys) -> bool:
     c = cols[keys[0]]
     if c.data2 is not None:
         return True
-    # np.asarray on a device lane copies the WHOLE lane to the host to
-    # learn its dtype: a blocking read, so it is a span (ROADMAP S3)
-    with active_span("host_read", site="join_key_dtype"):
-        return np.asarray(c.data).dtype.kind == "f"
+    # a lane's dtype is known without reading it (np.asarray would copy
+    # the whole lane to the host: 450 ms for a key lane a scan derive
+    # had just made)
+    return np.dtype(c.data.dtype).kind == "f"
 
 
 def join_verify_filter(left_cols, right_cols, pkeys, bkeys, filt):
@@ -636,6 +637,11 @@ class Executor:
         tr = self.trace
         if tr is None and not self.collect_stats:
             return _call_noting_forms(jitted, args)
+        bound = bound_count(args)
+        if bound:
+            # the literals the program takes as arguments
+            # (exec/literals.py): trino_tpu_program_literal_args_total
+            attrs["args"] = bound
         t0 = time.perf_counter()
         t1 = dev_s = None
         try:
@@ -1145,9 +1151,9 @@ class Executor:
                     run_jit = None
                     if fkey is not None:
                         PROGRAMS.deny("stream", fkey)
-                    out = run(batch)
+                    out = call_bound(run, batch)
             else:
-                out = run(batch)
+                out = call_bound(run, batch)
             return out
 
         from ..ops.groupby import COMBINABLE_KINDS
@@ -1392,7 +1398,7 @@ class Executor:
         # compatibility signature: canonical program + column layout
         # (same canonical key from DIFFERENT tables can carry different
         # types) + catalog (one connector per batch)
-        sig = (key, session.catalog,
+        sig = (key, canon.literal_key, session.catalog,
                tuple((name, repr(c.type))
                      for name, c in cb.columns.items()))
         from .taskexec import ragged_batcher
@@ -1441,6 +1447,15 @@ class Executor:
         ragged = Batch(
             {**combined.columns,
              RAGGED_LANE: Column(BIGINT, jnp.asarray(lane))}, total)
+        if canon.slots:
+            # co-batched queries bind the same literals (their signature
+            # holds them); a varchar slot's code is looked up again in
+            # the dictionary the concatenation merged
+            from .literals import LiteralBinding
+            lits = LiteralBinding([
+                dc_replace(s, code_of=canon.mapping[s.code_of])
+                if s.code_of is not None else s for s in canon.slots])
+            ragged = lits.bind(ragged, ragged)
         jitted, hit = PROGRAMS.program(
             "ragged", ("ragged",) + tuple(key),
             lambda: make_chain_program(self._detached(),
@@ -2752,6 +2767,8 @@ def read_split_cached(conn, split, columns) -> Batch:
     if not missing:
         _M_SCAN.inc(cache="split", result="hit")
         with _SCAN_CACHE_LOCK:
+            if skey in state["entries"]:
+                _touch(state, skey)
             return Batch({c: entry["cols"][c] for c in columns},
                          entry["num_rows"])
     _M_SCAN.inc(cache="split", result="miss")
@@ -2888,36 +2905,62 @@ def _whole_table_mode() -> bool:
     return mode == "1"
 
 
-def read_table_cached(conn, handle, columns, par) -> Optional[Batch]:
+def _touch(state: dict, key) -> None:
+    """A hit keeps its entry: the eviction order is the order of last
+    use (a table's base lanes, read by every derive, stay resident)."""
+    order = state["order"]
+    if order and order[-1] != key:
+        order.remove(key)
+        order.append(key)
+
+
+def _table_key(h) -> tuple:
+    """A whole-table entry's key in the scan cache: part -1."""
+    return (h.schema, h.table, -1, 0, h.constraint, h.limit)
+
+
+def read_table_cached(conn, handle, columns, par,
+                      count: bool = True) -> Optional[Batch]:
     """Whole-table read through the HBM cache: all splits concatenated
     ONCE into a single device-resident Batch cached under part=-1, so
     every later scan of the table is a dictionary lookup — no per-split
     dispatch, no per-query re-concat. The whole-table entry supersedes
     the table's per-split entries (the concat copies the lanes, so
-    keeping both would double-count the budget). Returns None when the
-    mode is off or the table exceeds the cache budget; callers fall
-    back to split streaming."""
+    keeping both would double-count the budget). Once the connector's
+    pushed-down constraints change their literals, a constrained miss
+    is derived from the table's base lanes (``_derive_constrained``),
+    not filled.
+    Returns None when the mode is off or the table exceeds the cache
+    budget; callers fall back to split streaming. ``count=False``: a
+    lookup of the scan cache's own (a derive's base), not a scan."""
     if not columns or not getattr(conn, "scan_cache_ok", False) \
             or CONFIG.scan_cache_bytes <= 0 or not _whole_table_mode():
         return None
     h = handle
-    wkey = (h.schema, h.table, -1, 0, h.constraint, h.limit)
+    wkey = _table_key(h)
     with _SCAN_CACHE_LOCK:
         state = _SCAN_CACHES.get(conn)
         entry = state["entries"].get(wkey) if state else None
         missing = [c for c in columns
                    if entry is None or c not in entry["cols"]]
         if not missing:
-            _M_SCAN.inc(cache="table", result="hit")
+            if count:
+                _M_SCAN.inc(cache="table", result="hit")
+            _touch(state, wkey)
             return Batch({c: entry["cols"][c] for c in columns},
                          entry["num_rows"])
+    if h.constraint is not None:
+        derived = _derive_constrained(conn, h, columns, par)
+        if derived is not None:
+            return derived
     splits = conn.get_splits(h, par)
     if len(splits) == 1:
         # a table of one split IS its split: no whole-table entry is
         # ever made for it, so the split's own lookup counts the hit or
         # the miss (a resident dimension is not a table-level miss)
         return read_split_cached(conn, splits[0], columns)
-    _M_SCAN.inc(cache="table", result="miss")
+    if count:
+        _M_SCAN.inc(cache="table", result="miss")
     # cheap pre-check from the handle's row estimate so an over-budget
     # table (inventory@sf10 is ~4GB of lanes) is never transiently
     # materialized whole in HBM just to discover it doesn't fit. Sized
@@ -2970,6 +3013,120 @@ def read_table_cached(conn, handle, columns, par) -> Optional[Batch]:
                          entry["num_rows"])
     # the budget evicted our own entry mid-insert: stream instead
     return None
+
+
+def _derive_constrained(conn, h, columns, par) -> Optional[Batch]:
+    """The lanes of a pushed-down constraint DERIVED from the table's
+    resident base lanes (exec/scanderive.py), or None where the miss
+    fills as before. A connector's scan cache fills every constraint
+    until one constraint SHAPE meets a second distinct set of literals
+    (a re-miss of the same set after an eviction refills); from then on
+    it derives, and that first derive derives every shape it has met
+    once, so each shape's program compiles while the literals first
+    change (a benchmark's set-up: the validation set, then a drawn
+    one), not at a later set."""
+    from .scanderive import constraint_shape
+    got = None if h.limit is not None else constraint_shape(h.constraint)
+    if got is None:
+        return None
+    shape, values = got
+    with _SCAN_CACHE_LOCK:
+        state = _SCAN_CACHES.get(conn)
+        if state is None:
+            state = {"entries": {}, "order": [], "bytes": 0}
+            _SCAN_CACHES[conn] = state
+        shapes = state.setdefault("shapes", {})
+        seen = shapes.get((h.schema, h.table, shape))
+        if seen is None:
+            seen = shapes[(h.schema, h.table, shape)] = {
+                "first": h, "lanes": set()}
+        seen["lanes"].update(columns)
+        others = []
+        if not state.get("vary"):
+            if constraint_shape(seen["first"].constraint)[1] == values:
+                return None
+            state["vary"] = True
+            others = [o for o in shapes.values() if o is not seen]
+    for o in others:
+        _derive_once(conn, o["first"], o, par)
+    return _derive_once(conn, h, seen, par, columns)
+
+
+def _derive_once(conn, h, seen, par, columns=None) -> Optional[Batch]:
+    """ONE ``scan_derive`` program: ``h``'s constraint over the table's
+    base lanes, the lanes its shape's entries deliver (``seen``) kept,
+    compacted and cut to the shape's capacity: its first copy's, or the
+    bucket of the rows where they outgrow it. The copy is kept in the
+    scan cache under ``h`` (beside a copy already there, which holds
+    the same rows); ``columns`` of it returned."""
+    from .scanderive import (bound_vectors, constraint_shape,
+                             make_derive_program, make_prefix_program)
+    from .streamjoin import _lane_spec
+    shape, values = constraint_shape(h.constraint)
+    keep = tuple(sorted(seen["lanes"]))
+    base = read_table_cached(
+        conn, dc_replace(h, constraint=None),
+        sorted(set(keep) | {c for c, _ in h.constraint.domains}), par,
+        count=False)
+    if base is None:
+        return None
+    key = ("scan_derive", shape, keep, _lane_spec(base), base.capacity)
+    jitted, hit = PROGRAMS.program(
+        "scan", key, lambda: make_derive_program(shape, keep),
+        "scan_derive", key)
+    _M_SCAN.inc(cache="table", result="miss")
+    with active_span("scan_derive", table=h.table, rows_in=base.num_rows,
+                     lanes=len(keep)) as sp:
+        with dispatch_span(None, jitted.program, hit, "scan"):
+            out, n = jitted(base, bound_vectors(values))
+        with active_span("host_read", site="scan_derive"):
+            rows = int(n)
+        if sp is not None:
+            sp.attrs["rows_out"] = rows
+        with _SCAN_CACHE_LOCK:
+            if "cap" not in seen:
+                # the first copy: whole-table, or its one split's
+                f = seen["first"]
+                entries = _SCAN_CACHES[conn]["entries"]
+                first = entries.get(_table_key(f)) or entries.get(
+                    (f.schema, f.table, 0, 1, f.constraint, f.limit))
+                seen["cap"] = 0 if first is None else next(
+                    iter(first["cols"].values())).capacity
+                # its row count as the fill gave it (a device count from
+                # one split's filter): one signature for the programs
+                seen["device_rows"] = first is not None and not isinstance(
+                    first["num_rows"], int)
+            seen["cap"] = cap = min(max(seen["cap"], capacity_for(rows)),
+                                    out.capacity)
+        if cap < out.capacity:
+            pkey = ("scan_prefix", _lane_spec(out), out.capacity, cap)
+            jitted, hit = PROGRAMS.program(
+                "scan", pkey, lambda: make_prefix_program(cap),
+                "scan_prefix", pkey)
+            with dispatch_span(None, jitted.program, hit, "scan"):
+                out = jitted(out)
+    out = Batch(out.columns, n if seen["device_rows"] else rows)
+    wkey = _table_key(h)
+    size = sum(_col_bytes(c) for c in out.columns.values())
+    with _SCAN_CACHE_LOCK:
+        state = _SCAN_CACHES[conn]
+        if size <= CONFIG.scan_cache_bytes:
+            entry = state["entries"].get(wkey)
+            if entry is None:
+                _make_room(state, size)
+                entry = {"cols": {}, "num_rows": out.num_rows}
+                state["entries"][wkey] = entry
+                state["order"].append(wkey)
+            for name, col in out.columns.items():
+                if name not in entry["cols"]:
+                    entry["cols"][name] = col
+                    state["bytes"] += _col_bytes(col)
+            _M_SCAN_BYTES.set(state["bytes"],
+                              connector=getattr(conn, "name",
+                                                type(conn).__name__))
+    if columns is None:
+        return None
+    return Batch({c: out.columns[c] for c in columns}, out.num_rows)
 
 
 def _fill_sharded(conn, h, columns, mesh):

@@ -29,8 +29,8 @@ import numpy as np
 from ..columnar import Batch, Column, StringDictionary
 from ..ops.datetime import (add_months, date_trunc_days, extract_field)
 from ..obs.metrics import EXPR_CONSTANT_SUBTREES
-from ..rex import (Call, CaseExpr, Cast, Const, InputRef, RowExpr,
-                   expr_volatile, walk)
+from ..rex import (Call, CaseExpr, Cast, Const, InputRef, Param,
+                   RowExpr, expr_volatile, walk)
 from ..types import (BIGINT, BOOLEAN, DATE, DOUBLE, INTEGER, REAL, UNKNOWN,
                      VARCHAR, CharType, DecimalType, IntervalDayTime,
                      IntervalYearMonth, TimestampType, Type, VarcharType,
@@ -46,6 +46,8 @@ def eval_expr(e: RowExpr, batch: Batch) -> Column:
         return batch.column(e.name)
     if isinstance(e, Const):
         return _const_column(e, batch.capacity)
+    if isinstance(e, Param):
+        return _param_column(e, batch)
     if batch.capacity > 1 and _constant_subtree(e):
         return _eval_constant(e, batch.capacity)
     if isinstance(e, Cast):
@@ -104,6 +106,21 @@ def _eval_constant(e: RowExpr, cap: int) -> Column:
     return Column(col.type, jnp.broadcast_to(_lane(col), (cap,)), valid)
 
 
+def _param_column(e: Param, batch: Batch) -> Column:
+    """A literal slot (exec/literals.py) broadcast to the batch: its
+    value from the program's literal vectors; a varchar slot is a code
+    in the dictionary of the lane it is compared with."""
+    from .literals import param_value
+    v = param_value(e)
+    if v is None:
+        raise EvalError(f"literal slot {e} evaluated outside its program")
+    dt = np.int32 if e.code_of is not None else e.type.np_dtype
+    data = jnp.broadcast_to(jnp.asarray(v).astype(dt), (batch.capacity,))
+    dictionary = (batch.column(e.code_of).dictionary
+                  if e.code_of is not None else None)
+    return Column(e.type, data, None, dictionary)
+
+
 # --------------------------------------------------------------------------
 # helpers
 # --------------------------------------------------------------------------
@@ -134,19 +151,8 @@ def _const_column(e: Const, cap: int) -> Column:
         return Column(t, jnp.full((cap,), ms, jnp.int64), None,
                       data2=jnp.full((cap,), off, jnp.int64))
     if isinstance(t, DecimalType):
-        v = e.value
-        if isinstance(v, int):
-            q = v * 10 ** t.scale
-        elif isinstance(v, str):
-            # exact: a float round-trip would corrupt literals beyond
-            # 2^53 (q34-style wide-decimal comparisons); prec=80 because
-            # the default 28-digit context rounds DECIMAL(38) magnitudes
-            from decimal import (Context as _DC, Decimal as _D,
-                                 ROUND_HALF_UP as _RHU)
-            q = int(_D(v).scaleb(t.scale, _DC(prec=80))
-                    .to_integral_value(rounding=_RHU))
-        else:
-            q = int(round(float(v) * (10 ** t.scale)))
+        from .literals import decimal_unscaled
+        q = decimal_unscaled(e.value, t)
         if not t.is_short:
             lo = q & ((1 << 64) - 1)
             lo = lo - (1 << 64) if lo >= (1 << 63) else lo

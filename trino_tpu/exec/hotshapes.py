@@ -298,8 +298,15 @@ def build_payload(kind: str, canon, batch) -> Optional[dict]:
         schema[name] = c.type
     num_rows = ("int" if isinstance(batch.num_rows, int)
                 else str(np.dtype(batch.num_rows.dtype)))
-    return {"kind": kind,
-            "fragment": canon.wire_fragment(schema),
-            "cols": cols,
-            "capacity": int(batch.capacity),
-            "num_rows": num_rows}
+    out = {"kind": kind,
+           "fragment": canon.wire_fragment(schema),
+           "cols": cols,
+           "capacity": int(batch.capacity),
+           "num_rows": num_rows}
+    literals = getattr(batch, "literals", None)
+    if literals:
+        # the program's literal vectors (exec/literals.py): their
+        # dtypes and the slots bound, not their values
+        out["literals"] = {"dtypes": sorted(literals),
+                           "bound": int(batch.bound)}
+    return out

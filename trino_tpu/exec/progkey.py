@@ -36,11 +36,17 @@ root: a traceable node chain is REWRITTEN over canonical symbol names
 Capacity buckets are deliberately ABSENT from the key: jax
 specializes per input shape under one callable, and the power-of-two
 bucketing of config.capacity_for already collapses minor cardinality
-changes onto the same shapes. Constant literals are canonicalized to
-their typed planner values (``DATE '1998-09-02'`` and its int form
-key identically) but never erased — a constant is baked into the
-compiled program, so erasing it would alias genuinely different
-programs.
+changes onto the same shapes. A literal VALUE is absent too: where a
+comparison or an addition-like call has one (a constant, or a
+column-free subtree the host folds to one value), the canonical node
+holds a typed slot, ``rex.Param``, and the program takes the value as
+an argument (exec/literals.py): two texts that differ only in literals
+share one key, one trace and one compiled program, and the binding
+carries each plan's values. What fixes a shape or a dictionary stays a
+constant of the key (LIMIT and TopN counts, IN lists, LIKE patterns,
+interval units, a literal no slot can hold); ``literal_key`` is what
+the slots hold in one plan, for identities of a RESULT rather than of
+a program (the result cache, co-batched queries).
 
 Program NAMES (``program_name`` / ``named_jit``): every cached program
 is jitted under a function named ``<kind>_<key8>`` — kind = the cache
@@ -67,6 +73,9 @@ import jax
 
 from ..columnar import Batch
 from ..config import CONFIG
+from .literals import (LITERAL_SLOTS, LiteralBinding, LiteralSlot,
+                       host_fold, literal_scope, slot_dtype,
+                       unbind, value_slot_type)
 from ..obs.metrics import (CACHE_PRESSURE_EVICTS, JIT_CACHE_LOOKUPS,
                            METRICS)
 from ..plan.nodes import (Aggregate, AggregationNode, AssignUniqueIdNode,
@@ -75,7 +84,8 @@ from ..plan.nodes import (Aggregate, AggregationNode, AssignUniqueIdNode,
                           RemoteSourceNode, SampleNode, SortKey,
                           SortNode, TopNNode, WindowFunction, WindowNode)
 from ..rex import (VOLATILE_FNS, Call, CaseExpr, Cast, Const, InputRef,
-                   Lambda, RowExpr)
+                   Lambda, Param, RowExpr, expr_volatile, walk)
+from ..types import VARCHAR, is_string
 
 
 _ADDRESS = re.compile(r" at 0x[0-9a-fA-F]+")
@@ -99,7 +109,10 @@ def named_jit(fn, kind: str, key, **jit_kwargs):
     name = program_name(kind, key)
 
     def program(*args, **kwargs):
-        with jax.named_scope(name):
+        # a bound input batch carries the program's literal vectors
+        # (exec/literals.py): they are arguments, the slots read them
+        args, literals = unbind(args)
+        with jax.named_scope(name), literal_scope(literals):
             return fn(*args, **kwargs)
 
     program.__name__ = program.__qualname__ = name
@@ -135,7 +148,7 @@ class ProgramCache:
     allowlist, analysis/lint.py), a lookup takes none."""
 
     BUCKETS = ("chain", "stream", "ragged", "join", "window",
-               "streamjoin", "repartition", "spmd")
+               "streamjoin", "repartition", "spmd", "scan")
     # what memory pressure halves (exec/executor.py
     # evict_cache_pressure): the plan-program buckets
     SHED = ("chain", "stream", "ragged")
@@ -229,12 +242,17 @@ class _NotCanonical(Exception):
 class _SymbolMap:
     """Deterministic symbol renaming: first use (in execution order)
     wins ``c<i>``. The map is a bijection — two distinct source
-    symbols can never alias one canonical name."""
+    symbols can never alias one canonical name. Beside it the
+    program's literal slots, in the order the canonicalizer meets
+    them, and the symbols the chain itself produces (a varchar slot
+    codes against an INPUT lane's dictionary only)."""
 
-    __slots__ = ("names",)
+    __slots__ = ("names", "slots", "produced")
 
     def __init__(self) -> None:
         self.names: Dict[str, str] = {}
+        self.slots: List[LiteralSlot] = []
+        self.produced: set = set()
 
     def sym(self, name: str) -> str:
         got = self.names.get(name)
@@ -242,6 +260,76 @@ class _SymbolMap:
             got = f"c{len(self.names)}"
             self.names[name] = got
         return got
+
+    def slot(self, e: RowExpr, code_of: Optional[str] = None
+             ) -> Optional[Param]:
+        """A new slot for the literal value ``e``, or None where the
+        program's ``LITERAL_SLOTS`` are taken (the literal stays
+        baked)."""
+        if len(self.slots) >= LITERAL_SLOTS:
+            return None
+        dtype = slot_dtype(e.type)
+        index = sum(1 for s in self.slots if s.dtype == dtype)
+        self.slots.append(LiteralSlot(dtype, index, e, code_of))
+        return Param(index, e.type, dtype,
+                     None if code_of is None else self.sym(code_of))
+
+
+# calls whose literal operands are VALUES (no handler reads them as a
+# constant): comparisons and the exact or addition-like arithmetic.
+# Division stays baked (the chip's float64 division is approximate).
+_SLOTTED_CALLS = frozenset({
+    "=", "<>", "<", "<=", ">", ">=", "+", "-", "*",
+    "decimal_+", "decimal_-", "date_add_interval", "date_sub_interval"})
+
+
+def _string_input(e: RowExpr, m: _SymbolMap) -> Optional[InputRef]:
+    """The input lane a varchar operand reads as it lies (a plain
+    reference, or a string-to-string cast of one: the cast keeps its
+    codes and dictionary), or None."""
+    while isinstance(e, Cast) and is_string(e.type) \
+            and is_string(e.arg.type):
+        e = e.arg
+    if isinstance(e, InputRef) and is_string(e.type) \
+            and e.name not in m.produced:
+        return e
+    return None
+
+
+def _literal_operand(e: RowExpr) -> bool:
+    """A constant or a column-free subtree that folds on the host to
+    one value of a slot type."""
+    if isinstance(e, Const):
+        return e.value is not None and value_slot_type(e.type)
+    if not isinstance(e, (Call, Cast)) or not value_slot_type(e.type) \
+            or expr_volatile(e):
+        return False
+    if any(isinstance(x, InputRef) for x in walk(e)):
+        return False
+    return host_fold(e) is not None
+
+
+def _canon_operands(e: Call, m: _SymbolMap) -> Tuple[RowExpr, ...]:
+    if e.fn in ("=", "<>") and len(e.args) == 2:
+        # a varchar literal against a dictionary lane: the slot holds
+        # its code, compared with the lane's codes as they lie, so the
+        # literal's length (which decides whether the planner casts the
+        # lane) leaves the program as it is
+        for i, a in enumerate(e.args):
+            if not (isinstance(a, Const) and isinstance(a.value, str)
+                    and is_string(a.type)):
+                continue
+            lane = _string_input(e.args[1 - i], m)
+            p = None if lane is None else m.slot(
+                Const(a.value, VARCHAR), lane.name)
+            if p is not None:
+                ref = _canon_expr(lane, m)
+                return (ref, p) if i else (p, ref)
+    out = []
+    for a in e.args:
+        p = m.slot(a) if _literal_operand(a) else None
+        out.append(p if p is not None else _canon_expr(a, m))
+    return tuple(out)
 
 
 def _canon_expr(e: RowExpr, m: _SymbolMap) -> RowExpr:
@@ -252,6 +340,8 @@ def _canon_expr(e: RowExpr, m: _SymbolMap) -> RowExpr:
     if isinstance(e, Call):
         if e.fn in VOLATILE_FNS:
             raise _NotCanonical(e.fn)
+        if e.fn in _SLOTTED_CALLS:
+            return Call(e.fn, _canon_operands(e, m), e.type)
         return Call(e.fn, tuple(_canon_expr(a, m) for a in e.args),
                     e.type)
     if isinstance(e, Cast):
@@ -290,6 +380,8 @@ def _canon_node(nd: PlanNode, m: _SymbolMap) -> PlanNode:
         # every assignment maps first, THEN the assignment targets —
         # keeps pass-through projections (x -> x) idempotent
         exprs = {s: _canon_expr(e, m) for s, e in nd.assignments.items()}
+        m.produced.update(s for s, e in nd.assignments.items()
+                          if e != InputRef(s, e.type))
         return dc_replace(nd, assignments={m.sym(s): e
                                            for s, e in exprs.items()})
     if isinstance(nd, (SampleNode, LimitNode, OffsetNode)):
@@ -303,13 +395,16 @@ def _canon_node(nd: PlanNode, m: _SymbolMap) -> PlanNode:
             SortKey(m.sym(k.symbol), k.ascending, k.nulls_first)
             for k in nd.keys))
     if isinstance(nd, AssignUniqueIdNode):
+        m.produced.add(nd.symbol)
         return dc_replace(nd, symbol=m.sym(nd.symbol))
     if isinstance(nd, MarkDistinctNode):
+        m.produced.add(nd.marker)
         return dc_replace(nd, keys=tuple(m.sym(k) for k in nd.keys),
                           marker=m.sym(nd.marker))
     if isinstance(nd, AggregationNode):
         if nd.group_id_symbol is not None:
             raise _NotCanonical("grouping-set aggregation")
+        m.produced.update(nd.aggregates)
         return dc_replace(
             nd,
             group_keys=tuple(m.sym(k) for k in nd.group_keys),
@@ -324,6 +419,7 @@ def _canon_node(nd: PlanNode, m: _SymbolMap) -> PlanNode:
                               k.nulls_first) for k in nd.order_by)
         fns = {out: _canon_window_fn(f, m)
                for out, f in nd.functions.items()}
+        m.produced.update(nd.functions)
         return dc_replace(nd, partition_by=part, order_by=order,
                           functions={m.sym(out): f
                                      for out, f in fns.items()})
@@ -390,21 +486,26 @@ class Binding:
     -> this plan's names after it. Columns the chain never references
     (pass-through lanes under a filter) extend the map in sorted
     original-name order — deterministic for a given input schema, so
-    every split of one scan binds identically."""
+    every split of one scan binds identically. The plan's literal
+    values ride the renamed input batch (exec/literals.py): the
+    program's ``Param`` slots read them."""
 
-    __slots__ = ("fwd", "inv")
+    __slots__ = ("fwd", "inv", "literals")
 
     def __init__(self, mapping: Dict[str, str],
-                 columns: Sequence[str]) -> None:
+                 columns: Sequence[str],
+                 slots: Sequence[LiteralSlot] = ()) -> None:
         self.fwd = dict(mapping)
         for name in sorted(c for c in columns if c not in self.fwd):
             self.fwd[name] = f"x{len(self.fwd)}"
         self.inv = {v: k for k, v in self.fwd.items()}
+        self.literals = LiteralBinding(slots)
 
     def rename_in(self, b: Batch) -> Batch:
         cols = sorted(b.columns, key=lambda c: self.fwd[c])
-        return Batch({self.fwd[c]: b.columns[c] for c in cols},
-                     b.num_rows)
+        return self.literals.bind(
+            b, Batch({self.fwd[c]: b.columns[c] for c in cols},
+                     b.num_rows))
 
     def rename_out(self, b: Batch) -> Batch:
         return Batch({self.inv.get(s, s): c
@@ -413,18 +514,27 @@ class Binding:
 
 class CanonicalProgram:
     """A canonicalized traceable node stack (top-down order) + its
-    cache key and the plan's symbol map."""
+    cache key, the plan's symbol map and its literal slots."""
 
-    __slots__ = ("key", "nodes", "mapping")
+    __slots__ = ("key", "nodes", "mapping", "slots")
 
     def __init__(self, key: tuple, nodes: List[PlanNode],
-                 mapping: Dict[str, str]) -> None:
+                 mapping: Dict[str, str],
+                 slots: Sequence[LiteralSlot] = ()) -> None:
         self.key = key
         self.nodes = nodes          # top-down, like the executor chain
         self.mapping = mapping      # original symbol -> canonical
+        self.slots = tuple(slots)   # the literals the key leaves out
+
+    @property
+    def literal_key(self) -> tuple:
+        """What the slots hold in THIS plan: with ``key``, the identity
+        of a result (the result cache, co-batched queries), where
+        ``key`` alone is the identity of a program."""
+        return tuple((repr(s.expr), s.code_of) for s in self.slots)
 
     def binding(self, b: Batch) -> Binding:
-        return Binding(self.mapping, list(b.columns))
+        return Binding(self.mapping, list(b.columns), self.slots)
 
     def wire_fragment(self, input_schema: Dict[str, object]) -> dict:
         """Serialize the canonical stack as a plan fragment rooted in
@@ -500,4 +610,4 @@ def canonicalize_nodes(nodes_top_down: Sequence[PlanNode]
     fps = tuple(node_fingerprint(n) for n in canon)
     if any(f is None for f in fps):
         return None
-    return CanonicalProgram(fps, canon, dict(m.names))
+    return CanonicalProgram(fps, canon, dict(m.names), m.slots)

@@ -269,7 +269,9 @@ def _chain_key(plan: OutputNode) -> Optional[tuple]:
     outs = tuple(
         (name, canon.mapping.get(sym, cur.assignments.get(sym, sym)))
         for name, sym in zip(plan.names, plan.symbols))
-    return ("chain", canon.key, ins, outs)
+    # the program key leaves the literals out (exec/literals.py); a
+    # result is theirs too
+    return ("chain", canon.key, canon.literal_key, ins, outs)
 
 
 # ---- runner wrapper --------------------------------------------------

@@ -624,6 +624,16 @@ EXCHANGE_BYTES = METRICS.counter(
 EXCHANGE_ROWS = METRICS.counter(
     "trino_tpu_mesh_exchange_rows_total",
     "Live rows those exchanges moved, by kind", ("kind",))
+PROGRAM_LITERAL_ARGS = METRICS.counter(
+    "trino_tpu_program_literal_args_total",
+    "Literals bound to device programs as arguments (exec/literals.py: "
+    "the slots of a canonical program), by the kind of the program: "
+    "the args attr of its dispatch span", ("kind",))
+SCAN_DERIVES = METRICS.counter(
+    "trino_tpu_scan_derive_total",
+    "Pushed-down constraints whose lanes one program derived from the "
+    "table's resident base lanes (exec/scanderive.py), by table",
+    ("table",))
 SCAN_FILL_SECONDS = METRICS.histogram(
     "trino_tpu_scan_fill_seconds",
     "Scan-cache miss path: reading or generating a split's missing "
@@ -674,6 +684,9 @@ def observe_span(sp) -> None:
                       or sp.attrs.get("cache") or "other")
         kind = _label_key(program.split(":", 1)[0])
         DEVICE_PROGRAMS.inc_at(kind)
+        args = sp.attrs.get("args")
+        if args:
+            PROGRAM_LITERAL_ARGS.inc_at(kind, args)
         groupby = sp.attrs.get("groupby")
         form = sp.attrs.get("form")
         if groupby is not None:
@@ -695,6 +708,8 @@ def observe_span(sp) -> None:
                                      int(offered) - int(kept))
     elif name == "scan_fill":
         SCAN_FILL_SECONDS.observe_at((), wall)
+    elif name == "scan_derive":
+        SCAN_DERIVES.inc_at(_label_key(sp.attrs.get("table", "other")))
     elif name == "exchange":
         kind = _label_key(sp.attrs.get("kind", "other"))
         EXCHANGE_BYTES.inc_at(kind, sp.attrs.get("bytes", 0))

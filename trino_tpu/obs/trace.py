@@ -78,7 +78,7 @@ from jax.profiler import TraceAnnotation as _Annotation
 ROOT_PHASES = ("submit", "queued", "parse", "plan", "optimize",
                "execute", "fetch", "persist", "respond", "finish")
 EXECUTE_PHASES = ("device_execute", "jit_trace", "host_read",
-                  "scan_fill", "exchange", "dispatch")
+                  "scan_fill", "exchange", "dispatch", "scan_derive")
 PHASES = ROOT_PHASES + EXECUTE_PHASES
 ANNOTATION_PREFIX = "tpusql:"
 

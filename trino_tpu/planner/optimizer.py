@@ -103,14 +103,18 @@ def optimize(plan: PlanNode, catalogs=None, session=None) -> PlanNode:
 def _domain_pushable(t) -> bool:
     """Types whose plan-constant values compare 1:1 against the
     connector's host lanes (predicate.filter_batch_host): integrals,
-    date, bool, float, dictionary strings. DECIMAL consts are strings
-    at plan time — skip."""
-    from ..types import DecimalType, is_string
+    date, bool, float. DECIMAL consts are strings at plan time — skip.
+    A string stays in the plan: compared with a dictionary lane it is
+    an argument of the program (exec/literals.py: its code), where a
+    pushed string would key a compacted copy per value, and only a
+    literal of the lane's declared length would be pushed at all (a
+    shorter one compares through a cast), so the plan would depend on
+    the value."""
+    from ..types import DecimalType
     if isinstance(t, DecimalType):
         return False
     return t.name in ("tinyint", "smallint", "integer", "bigint",
-                      "real", "double", "date", "boolean") \
-        or is_string(t)
+                      "real", "double", "date", "boolean")
 
 
 def push_into_scan(node: PlanNode, catalogs) -> PlanNode:

@@ -46,6 +46,22 @@ class Const(RowExpr):
 
 
 @dataclass(frozen=True)
+class Param(RowExpr):
+    """Slot ``index`` of a canonical program's ``dtype`` literal vector
+    (exec/literals.py): a literal whose value the program takes as an
+    argument, so that the program's key holds the slot's type and
+    position and not its value. A varchar slot holds the code of its
+    string in the dictionary of the input lane ``code_of``."""
+    index: int
+    type: Type
+    dtype: str
+    code_of: Optional[str] = None
+
+    def __str__(self):
+        return f":{self.dtype}[{self.index}]"
+
+
+@dataclass(frozen=True)
 class Call(RowExpr):
     """Scalar function or operator application. ``fn`` is the resolved
     function name (lower case); operators use their symbol ('+', '=',
