@@ -230,11 +230,13 @@ def _gathers_by_arm(text, lanes):
 def test_hash_join_count_program_compiles(one_chip,
                                           no_persistent_cache):
     """Phase 1: the build side sorted on its 64-bit key lane and
-    indexed (a scatter-add and two scans: the bucket directory and the
-    run lengths), then the probe (ops/join.py probe_runs: ONE
-    directory gather where the build side packed its words — two in
-    the arm that reads the adjacent sums —, ONE bisection loop whose
-    trip count is a device value, one run-length gather). The probe
+    indexed (a scatter-add and two scans: the bucket directory or the
+    bitmap directory, and the run lengths), then the probe (ops/join.py
+    probe_runs: ONE gather of a bitmap word in that form's arm;
+    otherwise ONE directory gather where the build side packed its
+    words — two in the arm that reads the adjacent sums —, ONE
+    bisection loop whose trip count is a device value, one run-length
+    gather). The probe
     side is q3's at sf1; the build side is CUT to 2^12 rows: the
     sorting network's compile time grows with its size (40 s at 2^20,
     PERF.md) and this file has to stay fast. What the compiler accepts
@@ -249,8 +251,12 @@ def test_hash_join_count_program_compiles(one_chip,
     assert len(re.findall(r" while\(", text)) == 1
     # the bounds: two probe-sized gathers in the plain arm, ONE in the
     # packed one; the exact arm of the other conditional has none, its
-    # search arm the loop's two (a 64-bit lane) and the run length
-    assert sorted(_gathers_by_arm(text, 1 << 22)) == [(2, 1), (3, 0)]
+    # search arm the loop's two (a 64-bit lane) and the run length; the
+    # bitmap's arm ONE word against the six of the directory's; the
+    # lane's branch (key - base or its hash) and the build side's index
+    # (bitmap or directory, on the build rows) gather none
+    assert sorted(_gathers_by_arm(text, 1 << 22)) == [
+        (0, 0), (0, 0), (2, 1), (3, 0), (6, 1)]
 
 
 def test_streamed_join_probe_program_compiles(one_chip,
@@ -262,7 +268,7 @@ def test_streamed_join_probe_program_compiles(one_chip,
     loop). Build 2^20 (nothing is sorted in here, so it is not cut): a
     directory of 2^25 entries, past its head, so the bounds are read in
     one of FOUR arms (whole or head, word or sums), one gather in
-    either packed arm."""
+    either packed arm, or as ONE bitmap word."""
     from trino_tpu.exec.streamjoin import make_probe_program
     from trino_tpu.ops.join import build_side
     probe, build = _q3_join_sides()
@@ -280,9 +286,10 @@ def test_streamed_join_probe_program_compiles(one_chip,
     text = compiled.as_text()
     assert len(re.findall(r" while\(", text)) == 1
     # the whole-or-head conditional holds the other two: its arms read
-    # one of theirs
+    # one of theirs; the bitmap's arm reads one word where the other
+    # holds all nine; the lane's branch gathers nothing
     assert sorted(_gathers_by_arm(text, cap)) == [
-        (2, 1), (2, 1), (3, 0), (3, 3)]
+        (0, 0), (2, 1), (2, 1), (3, 0), (3, 3), (9, 1)]
 
 
 @pytest.mark.parametrize("kind", ["counts", "move"])
@@ -402,7 +409,7 @@ def test_mesh_join_count_reads_one_word_on_a_2x2_mesh(topo,
     2^23 lineitem rows as q3 has on the four-chip cell (the build shard
     CUT to 2^12 rows, as above: the sort's compile time): the probe
     lowers per shard as on one chip, ONE probe-sized directory gather
-    in the packed arm."""
+    in the packed arm, ONE bitmap word in the bitmap's."""
     from jax import shard_map
     from trino_tpu.columnar import Batch
     from trino_tpu.ops import join as join_ops
@@ -426,8 +433,9 @@ def test_mesh_join_count_reads_one_word_on_a_2x2_mesh(topo,
         out_specs=(P(AXIS), P(AXIS), P(AXIS), P()), check_vma=False)
     ).lower(lanes(probe.columns, per_p), live,
             lanes(build.columns, per_b), live).compile().as_text()
-    assert "s64[4,5]" in text      # the one read: five entries a shard
-    assert sorted(_gathers_by_arm(text, per_p)) == [(2, 1), (3, 0)]
+    assert "s64[4,6]" in text      # the one read: six entries a shard
+    assert sorted(_gathers_by_arm(text, per_p)) == [
+        (0, 0), (0, 0), (2, 1), (3, 0), (6, 1)]
 
 
 # ---- ISSUE 36: q18's programs -------------------------------------------

@@ -13,7 +13,11 @@ the suite's virtual CPU devices.
   read its bucket's bounds as ONE directory word), and so do the four
   joins of a TPC-DS star chain (q7); a build side where one key fills
   more of a bucket than the word's size bits hold reads 0, and answers
-  the same.
+  the same;
+- none of them reads ``bitmap``; a build side of a few unique keys
+  spread wider than its exact directory holds does (the bitmap
+  directory: 0 steps, one word a probe row), and answers what numpy
+  counts.
 """
 
 import collections
@@ -83,12 +87,13 @@ def counted() -> tuple:
         for name in ("trino_tpu_join_probes_total",
                      "trino_tpu_join_exact_probes_total",
                      "trino_tpu_join_search_steps_total",
-                     "trino_tpu_join_packed_probes_total"))
+                     "trino_tpu_join_packed_probes_total",
+                     "trino_tpu_join_bitmap_probes_total"))
 
 
 def execute(co, sql, catalog="tpch"):
-    """(rows, the (steps, exact, packed) of each join's one read, the
-    growth of the four counters)."""
+    """(rows, the (steps, exact, packed, bitmap) of each join's one
+    read, the growth of the five counters)."""
     from trino_tpu.client import StatementClient
     before = counted()
     res = StatementClient(co.base_uri, catalog=catalog,
@@ -96,7 +101,7 @@ def execute(co, sql, catalog="tpch"):
     assert res.state == "FINISHED", res.error
     spans = co.tracker.get(res.query_id).trace.all_spans()
     reads = [(s.attrs.get("steps"), s.attrs.get("exact"),
-              s.attrs.get("packed")) for s in spans
+              s.attrs.get("packed"), s.attrs.get("bitmap")) for s in spans
              if s.name == "host_read"
              and s.attrs.get("site") == "join_total"]
     return res.rows, reads, tuple(
@@ -109,8 +114,8 @@ def test_q3_s_joins_are_both_exact(coordinator, q3):
     mismatches, rel = gaps(rows, answer)
     assert mismatches <= limits["exact_mismatches"]
     assert rel <= limits["max_rel_err"]
-    assert reads == [(0, 1, 1), (0, 1, 1)]
-    assert grew == (2, 2, 0, 2)
+    assert reads == [(0, 1, 1, 0), (0, 1, 1, 0)]
+    assert grew == (2, 2, 0, 2, 0)
 
 
 @pytest.mark.parametrize("key", sorted(HASHED))
@@ -122,8 +127,8 @@ def test_a_hashed_key_counts_no_exact_probe(coordinator, key):
     want = sum(have[tuple(r)]
                for r in execute(coordinator, probe_keys)[0])
     assert rows == [[want]] and want > 0
-    assert len(reads) == 1 and reads[0][1:] == (0, 1) and reads[0][0] > 0
-    assert grew[:2] == (1, 0) and grew[2:] == (reads[0][0], 1)
+    assert len(reads) == 1 and reads[0][1:] == (0, 1, 0) and reads[0][0] > 0
+    assert grew[:2] == (1, 0) and grew[2:] == (reads[0][0], 1, 0)
 
 
 def test_a_star_chain_s_probes_all_read_one_word(coordinator, request):
@@ -147,8 +152,8 @@ def test_a_star_chain_s_probes_all_read_one_word(coordinator, request):
                                "q7.sql")) as f:
             rows, reads, grew = execute(coordinator, f.read(), "tpcds")
         assert len(rows) > 0
-    assert reads == [(0, 1, 1)] * 4
-    assert grew == (4, 4, 0, 4)
+    assert reads == [(0, 1, 1, 0)] * 4
+    assert grew == (4, 4, 0, 4, 0)
 
 
 def test_a_build_side_with_one_heavy_key_reads_two_sums(coordinator):
@@ -162,8 +167,26 @@ def test_a_build_side_with_one_heavy_key_reads_two_sums(coordinator):
     n, quantity = execute(
         coordinator, "select count(*), sum(l_quantity) from lineitem")[0][0]
     assert rows == [[n + 4, quantity]]     # four regions find no row
-    assert reads == [(0, 1, 0)]
-    assert grew == (1, 1, 0, 0)
+    assert reads == [(0, 1, 0, 0)]
+    assert grew == (1, 1, 0, 0, 0)
+
+
+def test_a_wide_unique_build_side_reads_the_bitmap(coordinator):
+    """Twenty-one order keys from 1 to 59,981 (a build capacity of 32: an
+    exact directory of 1,024 values) against lineitem: the bitmap
+    directory, 0 steps, one word a probe row, where every shard had it
+    on the mesh; the count is numpy's."""
+    keys = list(range(1, 60_000, 2_999))
+    rows, reads, grew = execute(
+        coordinator,
+        "select count(*) from lineitem l join (values %s) o(k) "
+        "on l.l_orderkey = o.k" % ",".join("(%d)" % k for k in keys))
+    have = [r[0] for r in execute(
+        coordinator, "select l_orderkey from lineitem")[0]]
+    want = sum(k in set(keys) for k in have)
+    assert rows == [[want]] and want > 0
+    assert reads == [(0, 0, 1, 1)]
+    assert grew == (1, 0, 0, 1, 1)
 
 
 def test_the_one_read_carries_the_join_s_shape(coordinator):

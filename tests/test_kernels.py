@@ -471,7 +471,9 @@ def test_join_probe_equals_searchsorted(case, monkeypatch):
     bisection and run lengths) against numpy: ``left`` and ``count``
     are what ``searchsorted`` left and right give on the sorted usable
     build lanes OF THE MODE THAT ENGAGED (``key - min`` where the
-    directory is exact, the hash otherwise; both computed here),
+    key is the lane — the directory exact, or searched by the key's
+    top bits where duplicates span more than it holds —, the hash
+    otherwise; both computed here),
     whatever the lane's distribution, and ``count`` is the number of
     equal build keys; mode and steps are what the case says, and so is
     whether a probe row read its bounds as ONE word (``side.packed``:
@@ -499,12 +501,15 @@ def test_join_probe_equals_searchsorted(case, monkeypatch):
     key_p, usable_p = map(np.asarray, join_ops.equality_lane(probe, keys))
     m = int(usable_b.sum())
     exact = bool(side.exact)
-    if exact:
+    assert not bool(side.bitmap)
+    if bool(side.linear):
         base = key_b[usable_b].astype(np.int64).min().astype(np.uint64)
         assert int(side.base) == int(base)
         lane_b, lane_p = key_b - base, key_p - base     # modulo 2^64
-        assert int(lane_b[usable_b].max()) < side.directory.shape[0] - 1
+        assert exact == bool(
+            int(lane_b[usable_b].max()) < side.directory.shape[0] - 1)
     else:
+        assert not exact
         lane_b, lane_p = (np.asarray(join_ops.mix64(k))
                           for k in (key_b, key_p))
     assert side.directory.shape[0] - 1 == min(32 * build.capacity, 1 << 26)

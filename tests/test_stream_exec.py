@@ -60,6 +60,10 @@ _PROPERTY_QUERIES = (
     # residual (non-equi conjunct) join through the streamed path
     "SELECT count(*), sum(w) FROM memory.default.sprobe "
     "JOIN memory.default.sbuild ON k = bk WHERE v > w / 10",
+    # a few unique keys spread wider than the exact directory holds:
+    # the bitmap directory on both paths
+    "SELECT count(*), sum(v) FROM memory.default.sprobe "
+    "JOIN (VALUES (0), (5), (36), (10000), (-20000)) t(x) ON k = x",
 )
 
 

@@ -14,7 +14,10 @@ chip.
 - the scans deliver the configuration's lanes and rows;
 - q18 runs the way the issue asks: the grouping dense, the HAVING and
   the semi join's mark counted (capacity follows the live rows), the
-  semi join a cached program, and the counters say so.
+  semi join a cached program, and the counters say so;
+- where the kept orders are compacted, lineitem's join against them
+  reads the bitmap directory (0 steps, one word a probe row) and
+  ``trino_tpu_join_bitmap_probes_total`` grows.
 """
 
 import json
@@ -297,3 +300,33 @@ def test_a_big_filter_is_counted_and_its_capacity_follows(engine,
             and s.attrs.get("site") == "filter_rows"]
     assert [k["lanes"] for k in kept] == [1 << 16, 1 << 14]
     assert kept[0]["rows"] == kept[1]["rows"] > 100
+
+
+def test_q18_s_lineitem_join_reads_the_bitmap(engine, reference, config,
+                                              monkeypatch):
+    """The orders the HAVING keeps, compacted (the counted filter's
+    bound moved to tiny's 2^14 lanes): a build side of a few hundred
+    unique order keys over tiny's whole range of 60,000, wider than the
+    32 values a build row its exact directory holds. Lineitem's probe
+    reads the bitmap directory; the answer is the reference's."""
+    from trino_tpu.exec import executor as ex
+    from trino_tpu.benchmarks.tpch_queries import TPCH_QUERIES
+    gaps = bench_module("reference.compare").gaps
+    monkeypatch.setattr(ex, "COUNTED_FILTER_MIN_LANES", 1 << 14)
+    name = "trino_tpu_join_bitmap_probes_total"
+    before = samples(name)
+    res = engine.client("t").execute(low(TPCH_QUERIES[18]))
+    assert res.state == "FINISHED", res.error
+    want = reference.Answers(TINY, ["q18"],
+                             quantity=LOW_QUANTITY).answer("q18")
+    mismatches, rel = gaps(res.rows, want)
+    assert mismatches <= config["limits"]["exact_mismatches"]
+    assert rel <= config["limits"]["max_rel_err"]
+    spans = engine.co.tracker.get(res.query_id).trace.all_spans()
+    reads = [s.attrs for s in spans if s.name == "host_read"
+             and s.attrs.get("site") == "join_total"]
+    lineitem = [r for r in reads if r["probe_rows"] == 59_969]
+    assert len(lineitem) == 1
+    assert (lineitem[0]["bitmap"], lineitem[0]["steps"],
+            lineitem[0]["exact"]) == (1, 0, 0)
+    assert grown(samples(name), before)[("join_total",)] >= 1

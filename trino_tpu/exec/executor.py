@@ -1805,19 +1805,21 @@ class Executor:
         """The count program's one host read (``ops/join.py
         total_and_mode``, one row a shard on the mesh): its output
         total — the largest shard's — and beside it the steps its probe
-        took, whether its directory was exact and whether a probe row
-        read its bounds as one word, on every shard, and the join's
-        shape: the probe side's live rows and the rows it puts out,
-        summed over the shards (``steps``, ``exact``, ``packed``,
+        took, whether its directory was exact, whether a probe row
+        read its bounds as one word and whether the bitmap directory
+        served it, on every shard, and the join's shape: the probe
+        side's live rows and the rows it puts out, summed over the
+        shards (``steps``, ``exact``, ``packed``, ``bitmap``,
         ``probe_rows`` and ``total`` on the span, counted at /metrics:
         obs/metrics.py observe_span)."""
         with self._host_read("join_total") as sp:
-            total, steps, exact, probe_rows, packed = \
-                np.asarray(tail).reshape(-1, 5).T
+            total, steps, exact, probe_rows, packed, bitmap = \
+                np.asarray(tail).reshape(-1, 6).T
             if sp is not None:
                 sp.attrs["steps"] = int(steps.max())
                 sp.attrs["exact"] = int(exact.min())
                 sp.attrs["packed"] = int(packed.min())
+                sp.attrs["bitmap"] = int(bitmap.min())
                 sp.attrs["probe_rows"] = int(probe_rows.sum())
                 sp.attrs["total"] = int(total.sum())
         return int(total.max())

@@ -590,11 +590,11 @@ def _spec_from_payload(cols: List[dict]) -> tuple:
 def join_program_key(jt: str, pkeys, bkeys, residual_repr: str,
                      probe_spec: tuple, build_spec: tuple,
                      chunk_cap: int, build_cap: int,
-                     out_cap: int, lanes=None) -> tuple:
+                     out_cap: int, lanes=None, word_bits=None) -> tuple:
     return ("streamjoin", jt, tuple(pkeys), tuple(bkeys),
             residual_repr, probe_spec, build_spec,
             int(chunk_cap), int(build_cap), int(out_cap),
-            None if lanes is None else tuple(sorted(lanes)))
+            None if lanes is None else tuple(sorted(lanes)), word_bits)
 
 
 _PPOS = "__probe_pos$"
@@ -732,13 +732,14 @@ def aot_entry(payload: dict):
     chunk_cap = int(payload["chunk_capacity"])
     build_cap = int(payload["build_capacity"])
     out_cap = int(payload["out_capacity"])
+    word_bits = payload.get("bitmap_word_bits")
     from .executor import expand_lanes
     lanes = expand_lanes(frag.outputs, frag.filter)
     key = join_program_key(
         frag.join_type, pkeys, bkeys, repr(frag.filter),
         _spec_from_payload(payload["probe_cols"]),
         _spec_from_payload(payload["build_cols"]),
-        chunk_cap, build_cap, out_cap, lanes)
+        chunk_cap, build_cap, out_cap, lanes, word_bits)
     fn = make_probe_program(frag.join_type, pkeys, bkeys, frag.filter,
                             out_cap, lanes)
     chunk = _aval_batch({"cols": payload["probe_cols"],
@@ -750,7 +751,8 @@ def aot_entry(payload: dict):
                          "num_rows": payload.get("build_num_rows",
                                                  "int")}, bschema)
     from ..ops import join as join_ops
-    side = jax.eval_shape(lambda b: join_ops.build_side(b, bkeys), build)
+    side = jax.eval_shape(
+        lambda b: join_ops.build_side(b, bkeys, word_bits), build)
     return key, fn, (chunk, build, side)
 
 
@@ -882,13 +884,21 @@ def maybe_stream_join(ex, node: JoinNode
     build = ex.execute(node.right)
     build_bytes = sum(_col_bytes(c) for c in build.columns.values()) \
         + 3 * build.capacity * 8
+    # and its bitmap directory's words, a table of up to the head's
+    # 2^24 whatever the rows: held where it takes at most half of what
+    # the budget leaves beside the rest, else one word (the side keeps
+    # its exact and hashed forms)
+    word_bits = join_ops._bitmap_word_bits(build.capacity)
+    if 8 << word_bits > budget - build_bytes:
+        word_bits = 0
+    build_bytes += 4 * ((1 << word_bits) + 1)
     # the exact engagement rule: stream iff the probe does not fit in
     # what the budget leaves after the (capacity-rounded) build state
     # — the materialized path would hold probe + build concurrently
     if forced <= 0 and (est is None
                         or est <= max(budget - build_bytes, 0)):
         return None, build
-    side = join_ops.build_side(build, bkeys)
+    side = join_ops.build_side(build, bkeys, word_bits)
     # probe-side canonical dictionaries: key columns seed from the
     # BUILD dictionary so remapped probe codes compare directly
     # against the sorted build key lane just computed
@@ -929,7 +939,7 @@ def maybe_stream_join(ex, node: JoinNode
         key = join_program_key(
             jt, pkeys, bkeys, repr(residual), state["probe_spec"],
             _lane_spec(build), chunk_cap, build.capacity,
-            state["out_cap"], lanes)
+            state["out_cap"], lanes, word_bits)
         fn = make_probe_program(jt, pkeys, bkeys, residual,
                                 state["out_cap"], lanes)
         got = None if state["eager"] else PROGRAMS.program(
@@ -965,10 +975,13 @@ def maybe_stream_join(ex, node: JoinNode
                 from .hotshapes import record_program
 
                 def build_pl():
-                    return _join_payload(jt, node.criteria, residual,
-                                         probe_chunk, build,
-                                         state["out_cap"],
-                                         outputs=node.outputs)
+                    payload = _join_payload(jt, node.criteria, residual,
+                                            probe_chunk, build,
+                                            state["out_cap"],
+                                            outputs=node.outputs)
+                    if payload is not None:
+                        payload["bitmap_word_bits"] = word_bits
+                    return payload
                 record_program("streamjoin", key, None, None,
                                ex.session, payload_fn=build_pl)
             return out

@@ -549,21 +549,24 @@ JOIN_PROBES = METRICS.counter(
     "Join probes whose step count traced queries read, by the host "
     "read that carried it (join_total: the count program of a "
     "materialized join; of a mesh join, one probe: the most steps of "
-    "its shards, exact and packed where every shard was)", ("site",))
+    "its shards, exact, packed and bitmap where every shard was)",
+    ("site",))
 JOIN_SEARCH_STEPS = METRICS.counter(
     "trino_tpu_join_search_steps_total",
     "Bisection steps those probes took inside their directory buckets "
     "(ops/join.py probe_runs), after the ONE gather that reads a "
     "bucket's bounds: over the probes, 0 where the directory is exact "
-    "(a bucket is one key value: no search ran), 2-4 over a hashed "
-    "lane, log2(build capacity)+1 where one key fills a bucket",
+    "(a bucket is one key value: no search ran) or a bitmap, 2-4 "
+    "over a hashed lane, log2(build capacity)+1 where one key fills a "
+    "bucket",
     ("site",))
 JOIN_EXACT_PROBES = METRICS.counter(
     "trino_tpu_join_exact_probes_total",
     "Those probes whose build side chose the exact directory (one "
     "integer key column whose usable values span less than the "
     "directory: 0 steps, one probe-sized gather in all where packed); "
-    "the others searched a hashed lane", ("site",))
+    "the others read the bitmap directory or searched their buckets",
+    ("site",))
 JOIN_PACKED_PROBES = METRICS.counter(
     "trino_tpu_join_packed_probes_total",
     "Those probes whose rows read their bucket's bounds in ONE gather "
@@ -571,6 +574,13 @@ JOIN_PACKED_PROBES = METRICS.counter(
     "fullest bucket's size fits the bits a position leaves: 11 at a "
     "build capacity of 2^20, 5 at 2^26); the others read two adjacent "
     "sums, two gathers", ("site",))
+JOIN_BITMAP_PROBES = METRICS.counter(
+    "trino_tpu_join_bitmap_probes_total",
+    "Those probes whose build side chose the bitmap directory (one "
+    "integer key column of unique values whose span the exact "
+    "directory cannot serve from its head: a probe row reads ONE word, "
+    "its keys' first position above a bit a key value; no search)",
+    ("site",))
 JOIN_PROBE_ROWS = METRICS.counter(
     "trino_tpu_join_probe_rows_total",
     "Live rows on the probe side of those joins (a mesh join: summed "
@@ -677,6 +687,7 @@ def observe_span(sp) -> None:
             JOIN_SEARCH_STEPS.inc_at(site, steps)
             JOIN_EXACT_PROBES.inc_at(site, sp.attrs.get("exact", 0))
             JOIN_PACKED_PROBES.inc_at(site, sp.attrs.get("packed", 0))
+            JOIN_BITMAP_PROBES.inc_at(site, sp.attrs.get("bitmap", 0))
             JOIN_PROBE_ROWS.inc_at(site, sp.attrs.get("probe_rows", 0))
             JOIN_OUTPUT_ROWS.inc_at(site, sp.attrs.get("total", 0))
     elif name in ("dispatch", "device_execute", "jit_trace"):

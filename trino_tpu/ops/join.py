@@ -7,35 +7,55 @@ NestedLoopJoinOperator, HashSemiJoinOperator. Redesign for XLA
 sort + bucket-directory join:
 
 1. build keys are reduced to a single uint64 equality lane, in one of
-   two modes the BUILD SIDE chooses on the device (``BuildSide.exact``):
+   two modes the BUILD SIDE chooses on the device (``BuildSide.linear``):
    where the key is one integer column (integers, dates, booleans,
-   dictionary codes) whose usable build values span less than the
-   directory's size, the lane is ``key - min`` — the integer compared
-   as the integer it is; otherwise a hash (bijective splitmix64 of one
-   column — exact; multi-column and float keys are hash-combined,
-   accepting a ~n^2/2^64 collision probability with NO re-verification
-   — acknowledged in SURVEY.md §7 "hard parts"). String keys are first
-   remapped onto a dictionary merged across both sides so codes are
-   comparable,
+   dictionary codes) whose usable build values span less than one of
+   the two directories over the key holds (below), the lane is ``key -
+   min`` — the integer compared as the integer it is; otherwise a hash
+   (bijective splitmix64 of one column — exact; multi-column and float
+   keys are hash-combined, accepting a ~n^2/2^64 collision probability
+   with NO re-verification — acknowledged in SURVEY.md §7 "hard
+   parts"). String keys are first remapped onto a dictionary merged
+   across both sides so codes are comparable,
 2. the build side is sorted by that lane (nulls/dead rows forced past the
-   valid prefix) and indexed ONCE, at O(directory): a bucket directory
-   of 32 buckets a build row (at most 2^26) — over the lane itself
-   where exact, ONE KEY VALUE a bucket; over the hash's top bits
-   otherwise (at most 24 of them: the directory's head, a table the
-   chip gathers from at its fast rate), a handful of entries a bucket
-   — and the length of the run of equal lanes that starts at each
+   valid prefix) and indexed ONCE, in one of three forms the build side
+   chooses on the device from the span, and from whether the sort left
+   two equal keys side by side:
+   - the BITMAP directory (``BuildSide.bitmap``) where the usable keys
+     are unique and span more than the exact directory serves from its
+     head (32 values a build row, or past 2^24) but less than the
+     bitmap holds: W = 2^14 words a build row, at most the head's
+     2^24, one bit a key value; a word holds the first sorted position
+     of its keys in the ``bit_length(capacity)`` high bits and a bitmap
+     of 2^s key values in the low ones, 2^s the largest power of two the
+     rest holds (16 values up to 2^15 build rows, 8 up to 2^23: 2^28
+     and 2^27 values). ONE sorted scatter-add of the keys' bits and a
+     running count of them make it, the cost of the other directory's
+     histogram;
+   - otherwise a bucket directory of 32 buckets a build row (at most
+     2^26) — over the lane itself where the span fits it (exact), ONE
+     KEY VALUE a bucket; over the lane's top bits otherwise (at most 24
+     of them: the directory's head, a table the chip gathers from at its
+     fast rate), a handful of entries a bucket: the hash's, or where
+     duplicate keys span wider than the exact directory within the
+     bitmap's reach, the key's own (the lane is chosen before the sort
+     shows whether the keys are unique) —
+   and the length of the run of equal lanes that starts at each
    position, and
-3. every probe row reads its bucket's bounds from the directory
-   (``probe_runs``; from its head alone unless an exact range reaches
-   past it) in ONE gather: an entry is one 32-bit word, the bucket's
-   first position above its SIZE (``BuildSide.words``; the chip pays
-   a gather by the index, not by the byte), wherever the fullest
-   bucket's size fits the bits the position leaves
-   (``BuildSide.packed``, a device value: one key repeated past that
-   reads the two adjacent sums instead). Where exact the bounds ARE
-   the row's run of equal keys: no search, 0 steps, one probe-sized
-   gather in all (dense surrogate keys, what warehouse joins join on:
-   the "perfect hash join").
+3. a probe row against the bitmap reads ONE word: its lower bound is
+   the word's position plus the bits set below its own, its count its
+   own bit; no search, 0 steps. Against a bucket directory every probe
+   row reads its bucket's bounds (``probe_runs``; from its head alone
+   unless an exact range reaches past it) in ONE gather: an entry is
+   one 32-bit word, the bucket's first position above its SIZE
+   (``BuildSide.words``; the chip pays a gather by the index, not by
+   the byte), wherever the fullest bucket's size fits the bits the
+   position leaves (``BuildSide.packed``, a device value: one key
+   repeated past that reads the two adjacent sums instead). Where exact
+   the bounds ARE the row's run of equal keys: no search, 0 steps, one
+   probe-sized gather in all (dense surrogate keys, what warehouse
+   joins join on: the "perfect hash join"); the bitmap is that join for
+   a few keys spread wide (q18's 652 orders over 60M order keys).
    Otherwise it bisects inside the bucket for the first entry >= its
    lane (as many steps as the FULLEST bucket needs, a device value —
    2-4 for a uniform hash, log2(capacity)+1 when one key fills a
@@ -63,6 +83,7 @@ Trino's incremental JoinProbe yielding pages). There
 
 from __future__ import annotations
 
+import functools
 from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
@@ -127,12 +148,13 @@ def _integer_key(batch: Batch, key_names: Sequence[str]) -> bool:
         jnp.asarray(col.data).dtype, jnp.floating)
 
 
-def _lane_of(key, exact, base):
+def _lane_of(key, linear, base):
     """The lane of a key (``equality_lane``) of either side, by the
     build side's mode: both are bijections of the key, so equality is
     the keys' (``key - min`` modulo 2^64: a key outside the build
-    side's range lands past its span)."""
-    return jnp.where(exact, key - base, mix64(key))
+    side's range lands past its span). A select here, over the build
+    rows; the probe branches (``probe_runs``)."""
+    return jnp.where(linear, key - base, mix64(key))
 
 
 def _directory_bits(capacity: int) -> int:
@@ -155,8 +177,23 @@ def _size_bits(capacity: int) -> int:
 # (64 MB) is gathered from at 8.6 ns an element on a v5e, whatever the
 # order of the indices; one of 2^25 at 15 (random) to 25 (ascending)
 # (PERF.md §6, PR 29). Hashed buckets stay inside the head, and so do
-# the bounds' gathers unless an exact range reaches past it.
+# the bounds' gathers unless an exact range reaches past it, and the
+# bitmap directory's words.
 _HEAD_BITS = 24
+
+
+def _bitmap_shift(capacity: int) -> int:
+    """log2 of the key values a bitmap word holds, static from the
+    build capacity: the largest power of two the bits a position leaves
+    hold (16 values up to 2^15, 8 up to 2^23, 4 up to 2^27)."""
+    return _size_bits(capacity).bit_length() - 1
+
+
+def _bitmap_word_bits(capacity: int) -> int:
+    """log2 of the bitmap directory's words W, static from the build
+    capacity: 2^14 words a build row, at most the head — from 2^10 rows
+    up the whole head, 2^28 key values at 16 a word."""
+    return min(max(1, (capacity - 1).bit_length()) + 14, _HEAD_BITS)
 
 
 class BuildSide(NamedTuple):
@@ -166,25 +203,39 @@ class BuildSide(NamedTuple):
     order: jax.Array        # sorted position -> build row
     m: jax.Array            # usable build rows
     directory: jax.Array    # int32[D+1]: first position in [0, m] whose
-    #                         bucket (the lane where exact, its top
-    #                         min(log2(D), 24) bits otherwise) is >= b
+    #                         bucket (``lane >> shift``) is >= b
     run_len: jax.Array      # int32[cap]: entries of [i, m) equal to entry i
     steps: jax.Array        # int32: bisection steps the fullest bucket
-    #                         needs; 0 where exact
+    #                         needs; 0 where exact or bitmap
     exact: jax.Array        # bool: a bucket is ONE key value, no search
     base: jax.Array         # uint64: the least usable build key
     words: jax.Array        # uint32[D+1]: directory[b] above the bucket's
     #                         size in the low ``_size_bits(cap)`` bits
     packed: jax.Array       # bool: every bucket's size fits those bits
+    linear: jax.Array       # bool: the lane is ``key - base``, not a hash
+    shift: jax.Array        # uint64: a lane's bucket is ``lane >> shift``
+    bitmap: jax.Array       # bool: the bitmap directory serves the probe
+    marks: jax.Array        # uint32[W+1]: the bitmap directory, word w
+    #                         the first position of its keys above one bit
+    #                         a key value of [w << s, (w + 1) << s)
 
 
-def build_side(batch: Batch, key_names: Sequence[str]) -> BuildSide:
+def build_side(batch: Batch, key_names: Sequence[str],
+               word_bits: Optional[int] = None) -> BuildSide:
     """Sort the build side by key lane and index it. The first m
     entries of ``sorted_lane`` are the usable keys, the tail is forced
-    to U64MAX (and counted into no bucket and no run)."""
+    to U64MAX (and counted into no bucket and no run). ``word_bits``:
+    log2 of the bitmap directory's words, ``_bitmap_word_bits`` unless
+    the caller holds the side under a budget that cannot spare them (0:
+    one word, too few for any span the exact directory cannot serve)."""
     key, usable = equality_lane(batch, key_names)
     cap = batch.capacity
     bits = _directory_bits(cap)
+    head_bits = min(bits, _HEAD_BITS)
+    s = _bitmap_shift(cap)
+    if word_bits is None:
+        word_bits = _bitmap_word_bits(cap)
+    reach = (1 << word_bits) << s       # the key values the bitmap holds
     m = jnp.sum(usable.astype(jnp.int64))
     if _integer_key(batch, key_names):
         # the range of the usable keys, in the order of the integers
@@ -195,11 +246,14 @@ def build_side(batch: Batch, key_names: Sequence[str]) -> BuildSide:
         base = jnp.min(jnp.where(usable, signed, info.max))
         span = (jnp.max(jnp.where(usable, signed, info.min))
                 .astype(jnp.uint64) - base.astype(jnp.uint64))
-        exact = (m > 0) & (span < jnp.uint64(1 << bits))
+        # the key itself is the lane wherever a directory over it can
+        # serve: the exact one, or the bitmap
+        linear = (m > 0) & (span < jnp.uint64(max(1 << bits, reach)))
         base = base.astype(jnp.uint64)
     else:
-        exact, base = jnp.zeros((), bool), jnp.zeros((), jnp.uint64)
-    lane = _lane_of(key, exact, base)
+        span = jnp.zeros((), jnp.uint64)
+        linear, base = jnp.zeros((), bool), jnp.zeros((), jnp.uint64)
+    lane = _lane_of(key, linear, base)
     if all(batch.column(k).valid is None for k in key_names):
         # no NULL keys: the usable rows are exactly the live prefix, so
         # they already precede every dead row in input order. ONE
@@ -215,29 +269,6 @@ def build_side(batch: Batch, key_names: Sequence[str]) -> BuildSide:
     live = pos < m
     sorted_lane = jnp.where(live, jnp.take(lane, order), _U64MAX)
 
-    # directory: a histogram of the live entries' buckets, summed up.
-    # Never a search of the D boundaries, which would cost what the
-    # directory saves. Both buckets rise with the lane.
-    bucket = jnp.where(
-        exact, jnp.minimum(sorted_lane, jnp.uint64((1 << bits) - 1)),
-        sorted_lane >> jnp.uint64(64 - min(bits, _HEAD_BITS))
-    ).astype(jnp.int32)
-    directory = jnp.cumsum(
-        jnp.zeros(((1 << bits) + 1,), jnp.int32).at[bucket + 1].add(
-            live.astype(jnp.int32), indices_are_sorted=True,
-            mode="promise_in_bounds"))
-    # the word a probe row reads both bounds from: entry D, the
-    # out-of-range lane of an exact directory, holds (m, 0)
-    sizes = jnp.concatenate([directory[1:] - directory[:-1],
-                             jnp.zeros((1,), jnp.int32)])
-    fullest = jnp.max(sizes)
-    k = _size_bits(cap)
-    words = ((directory.astype(jnp.uint32) << jnp.uint32(k))
-             | sizes.astype(jnp.uint32))
-    # a lower-bound bisection over n entries takes bit_length(n) steps
-    steps = jnp.where(exact, 0, jnp.sum(
-        (fullest >> jnp.arange(31, dtype=jnp.int32)) > 0, dtype=jnp.int32))
-
     # run lengths: entry i's run ends at the first position after it
     # that differs from the one before, or is dead (m at the latest)
     after = pos + 1
@@ -246,8 +277,78 @@ def build_side(batch: Batch, key_names: Sequence[str]) -> BuildSide:
     run_len = jnp.where(
         live, jax.lax.cummin(jnp.where(ends, after, cap), reverse=True)
         - pos, 0)
+
+    # the form: the bitmap where the keys are unique and the exact
+    # directory cannot serve them from its head, exact where the range
+    # fits the directory, else a search of buckets — the hash's top
+    # bits, or the key's where it is the lane (duplicates in a range
+    # the exact directory cannot hold)
+    bitmap = (linear & (jnp.max(run_len) <= 1)
+              & (span >= jnp.uint64(1 << head_bits))
+              & (span < jnp.uint64(reach)))
+    exact = linear & (span < jnp.uint64(1 << bits)) & ~bitmap
+    width = 64 - jax.lax.clz(span).astype(jnp.int32)
+    shift = jnp.where(exact, 0, jnp.where(
+        linear, jnp.maximum(width - head_bits, 0),
+        64 - head_bits)).astype(jnp.uint64)
+    # only the form the side uses is built: one scatter of the build rows
+    directory, words, fullest, marks = _index(sorted_lane, live, shift,
+                                              bitmap, word_bits)
+    # a lower-bound bisection over n entries takes bit_length(n) steps
+    steps = jnp.where(exact, 0, jnp.sum(
+        (fullest >> jnp.arange(31, dtype=jnp.int32)) > 0, dtype=jnp.int32))
     return BuildSide(sorted_lane, order, m, directory, run_len, steps,
-                     exact, base, words, fullest < (1 << k))
+                     exact, base, words, fullest < (1 << _size_bits(cap)),
+                     linear, shift, bitmap, marks)
+
+
+@functools.partial(jax.jit, static_argnums=4)
+def _index(sorted_lane, live, shift, bitmap, w_bits):
+    """(directory, words, the fullest bucket's size, marks) of a sorted
+    build lane: the directory of buckets ``lane >> shift`` where
+    ``bitmap`` is false, else the bitmap directory of ``2^w_bits``
+    words (the other's arrays empty). Jitted: an eager build side
+    reuses one program a shape."""
+    cap = sorted_lane.shape[0]
+    bits, k, s = _directory_bits(cap), _size_bits(cap), _bitmap_shift(cap)
+
+    def directory():
+        # a histogram of the live entries' buckets, summed up. Never a
+        # search of the D boundaries, which would cost what the
+        # directory saves. The bucket rises with the lane
+        bucket = jnp.minimum(sorted_lane >> shift,
+                             jnp.uint64((1 << bits) - 1)).astype(jnp.int32)
+        directory = jnp.cumsum(
+            jnp.zeros(((1 << bits) + 1,), jnp.int32).at[bucket + 1].add(
+                live.astype(jnp.int32), indices_are_sorted=True,
+                mode="promise_in_bounds"))
+        # the word a probe row reads both bounds from: entry D, the
+        # out-of-range lane of an exact directory, holds (m, 0)
+        sizes = jnp.concatenate([directory[1:] - directory[:-1],
+                                 jnp.zeros((1,), jnp.int32)])
+        words = ((directory.astype(jnp.uint32) << jnp.uint32(k))
+                 | sizes.astype(jnp.uint32))
+        return (directory, words, jnp.max(sizes),
+                jnp.zeros(((1 << w_bits) + 1,), jnp.uint32))
+
+    def marks():
+        # the keys are unique: ONE sorted scatter-add of a bit a key
+        # makes the bitmaps, and the running count of their bits the
+        # positions. Dead entries add nothing to word W, which holds
+        # (m, 0): a key past the range reads no match
+        at = jnp.where(live, sorted_lane, jnp.uint64((1 << w_bits) << s))
+        marks = jnp.zeros(((1 << w_bits) + 1,), jnp.uint32).at[
+            (at >> jnp.uint64(s)).astype(jnp.int32)].add(
+            jnp.where(live, jnp.uint32(1) << (
+                at & jnp.uint64((1 << s) - 1)).astype(jnp.uint32), 0),
+            indices_are_sorted=True, mode="promise_in_bounds")
+        ones = jax.lax.population_count(marks)
+        first = jnp.cumsum(ones) - ones
+        empty = jnp.zeros(((1 << bits) + 1,), jnp.int32)
+        return (empty, empty.astype(jnp.uint32), jnp.zeros((), jnp.int32),
+                (first << jnp.uint32(k)) | marks)
+
+    return jax.lax.cond(bitmap, marks, directory)
 
 
 def _at(lane, index):
@@ -270,15 +371,31 @@ def probe_runs(side: BuildSide, key_p, usable_p):
     head = 1 << head_bits
     last = side.sorted_lane.shape[0] - 1
     k = _size_bits(last + 1)
-    lane_p = _lane_of(key_p, side.exact, side.base)
+    # ``_lane_of`` as a branch: the lane not taken is not computed
+    lane_p = jax.lax.cond(side.linear, lambda: key_p - side.base,
+                          lambda: mix64(key_p))
+
+    def marks():
+        # ONE word: the first position of its keys, and the bit of each
+        # key value it holds; the keys below the row's in the word are
+        # the bits under its own. Word W, past the range, holds (m, 0)
+        s = _bitmap_shift(last + 1)
+        w = _at(side.marks, jnp.minimum(
+            lane_p >> jnp.uint64(s),
+            jnp.uint64(side.marks.shape[0] - 1)).astype(jnp.int32))
+        bit = (lane_p & jnp.uint64((1 << s) - 1)).astype(jnp.uint32)
+        low = w & jnp.uint32((1 << k) - 1)
+        left = (w >> jnp.uint32(k)) + jax.lax.population_count(
+            low & ((jnp.uint32(1) << bit) - 1))
+        return (left.astype(jnp.int32),
+                ((low >> bit) & 1).astype(jnp.int32))
 
     def bounds(top):
         # the bucket's bounds, from the first ``top + 1`` entries.
         # Where exact, a key outside the build side's range has a lane
         # of D or more and reads [m, m): its lower bound
-        bucket = jnp.where(
-            side.exact, jnp.minimum(lane_p, jnp.uint64(top)),
-            lane_p >> jnp.uint64(64 - head_bits)).astype(jnp.int32)
+        bucket = jnp.minimum(lane_p >> side.shift,
+                             jnp.uint64(top)).astype(jnp.int32)
 
         def word():
             w = _at(side.words[:top + 1], bucket)
@@ -294,16 +411,6 @@ def probe_runs(side: BuildSide, key_p, usable_p):
         # branch, as below: a gather costs per element either way)
         return jax.lax.cond(side.packed, word, pair)
 
-    if size > head:
-        # every live bucket lies in the head unless an exact range
-        # passes it: the head alone is a table the chip gathers from
-        # at its fast rate
-        lo, hi = jax.lax.cond(
-            _at(side.directory, head) < side.m,
-            lambda: bounds(size), lambda: bounds(head))
-    else:
-        lo, hi = bounds(size)
-
     def step(_, state):
         # lower bound on [lo, hi); ``hit`` is whether the entry ``hi``
         # was last moved onto equals the probe lane: where the search
@@ -318,16 +425,33 @@ def probe_runs(side: BuildSide, key_p, usable_p):
         return (jnp.where(right, mid + 1, lo), jnp.where(down, mid, hi),
                 jnp.where(down, v == lane_p, hit))
 
-    def search():
-        left, _, hit = jax.lax.fori_loop(
-            0, side.steps, step, (lo, hi, jnp.zeros(lane_p.shape, bool)))
-        return left, jnp.where(
-            hit, _at(side.run_len, jnp.minimum(left, last)), 0)
+    def directory():
+        if size > head:
+            # every live bucket lies in the head unless an exact range
+            # passes it: the head alone is a table the chip gathers
+            # from at its fast rate
+            lo, hi = jax.lax.cond(
+                _at(side.directory, head) < side.m,
+                lambda: bounds(size), lambda: bounds(head))
+        else:
+            lo, hi = bounds(size)
 
-    # a bucket of ONE key value is the run itself: neither the search
-    # nor the run-length gather runs (a branch, not a select: a gather
-    # costs the chip per element whatever becomes of it)
-    left, count = jax.lax.cond(side.exact, lambda: (lo, hi - lo), search)
+        def search():
+            left, _, hit = jax.lax.fori_loop(
+                0, side.steps, step,
+                (lo, hi, jnp.zeros(lane_p.shape, bool)))
+            return left, jnp.where(
+                hit, _at(side.run_len, jnp.minimum(left, last)), 0)
+
+        # a bucket of ONE key value is the run itself: neither the
+        # search nor the run-length gather runs (a branch, not a
+        # select: a gather costs the chip per element whatever becomes
+        # of it)
+        return jax.lax.cond(side.exact, lambda: (lo, hi - lo), search)
+
+    # the bitmap directory answers from its word alone: no bounds, no
+    # search, no run length
+    left, count = jax.lax.cond(side.bitmap, marks, directory)
     return (left.astype(jnp.int64),
             jnp.where(usable_p, count, 0).astype(jnp.int64))
 
@@ -343,15 +467,17 @@ def match_runs(probe: Batch, build: Batch,
 
 
 def total_and_mode(eff, side: BuildSide, probe: Batch):
-    """int64[5], [output rows, the probe's bisection steps, whether the
+    """int64[6], [output rows, the probe's bisection steps, whether the
     directory was exact, the probe side's live rows, whether a probe
-    row read its bounds as one word]: what a count program hands the
-    host in its ONE read (the executors' ``join_total``)."""
+    row read its bounds as one word, whether the bitmap directory
+    served it]: what a count program hands the host in its ONE read
+    (the executors' ``join_total``)."""
     return jnp.stack([jnp.sum(eff).astype(jnp.int64),
                       side.steps.astype(jnp.int64),
                       side.exact.astype(jnp.int64),
                       probe.num_rows_device(),
-                      side.packed.astype(jnp.int64)])
+                      side.packed.astype(jnp.int64),
+                      side.bitmap.astype(jnp.int64)])
 
 
 def match_counts(probe: Batch, build: Batch,
